@@ -151,6 +151,20 @@ extern "C" {
 
 const char *ed_version(void) { return "edtpu_core 0.1.0"; }
 
+// What this binary was built FROM and FOR (csrc/Makefile passes both).
+// native.py reads the marker out of the file's bytes before dlopen, so a
+// library built from other sources or for another CPU (-march=native) is
+// rebuilt instead of executed.
+#ifndef ED_SOURCE_DIGEST
+#define ED_SOURCE_DIGEST "unknown"
+#endif
+#ifndef ED_BUILD_CPU
+#define ED_BUILD_CPU "unknown"
+#endif
+const char *ed_build_info(void) {
+  return "EDTPU_BUILD{" ED_SOURCE_DIGEST "|" ED_BUILD_CPU "}";
+}
+
 int32_t ed_last_send_errno(void) { return g_stop_errno; }
 
 void ed_get_stats(ed_stats *out) {
@@ -979,8 +993,12 @@ constexpr uint8_t kOpNop = 0;
 constexpr uint8_t kOpSendmsg = 9;
 constexpr uint8_t kOpRecvmsg = 10;
 constexpr uint8_t kOpProvideBuffers = 31;
-constexpr uint8_t kOpSendZc = 26;
-constexpr uint8_t kOpSendmsgZc = 30;
+// (26 and 30, as first written here, are IORING_OP_SEND and
+// IORING_OP_SPLICE: the probe then granted "zerocopy" on every 5.6+
+// kernel and each send carried SEND_ZC ioprio flags on a plain SEND,
+// which 6.x kernels reject with EINVAL — a hard per-datagram error)
+constexpr uint8_t kOpSendZc = 47;
+constexpr uint8_t kOpSendmsgZc = 48;
 // cqe flags
 constexpr uint32_t kCqeFBuffer = 1u << 0;
 constexpr uint32_t kCqeFMore = 1u << 1;

@@ -20,6 +20,8 @@ extern "C" {
 #endif
 
 const char *ed_version(void);
+/* "EDTPU_BUILD{<source digest>|<build cpu key>}" — see csrc/Makefile. */
+const char *ed_build_info(void);
 
 /* Why the calling thread's last send entry point stopped short of n_ops:
  * 0 = completed, EAGAIN/EWOULDBLOCK = flow control (keep bookmarks,

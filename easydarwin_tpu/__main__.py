@@ -8,9 +8,18 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import os
 import signal
+import sys
 
 from .server import ServerConfig, StreamingServer
+
+
+#: exit code when tpu_fanout is on and the engine tier cannot run where
+#: it was told to (no TPU and no explicit JAX_PLATFORMS=cpu, or a native
+#: core that will not build/load) — distinct from EXIT_RESTART and from
+#: argparse's 2
+EXIT_NO_DEVICE = 3
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -76,7 +85,8 @@ async def amain(cfg: ServerConfig, exit_after_boot: bool = False) -> int:
     await app.start()
     print(f"easydarwin-tpu listening: rtsp://{cfg.bind_ip}:{app.rtsp.port} "
           f"service http://{cfg.bind_ip}:{app.rest.port}/api/v1 "
-          f"tpu_fanout={'on' if cfg.tpu_fanout else 'off'}", flush=True)
+          f"tpu_fanout={'on' if cfg.tpu_fanout else 'off'} "
+          f"{app.engine_banner()}", flush=True)
     if exit_after_boot:
         await app.stop()
         return 0
@@ -98,7 +108,6 @@ async def amain(cfg: ServerConfig, exit_after_boot: bool = False) -> int:
 
 
 def main(argv=None) -> int:
-    import sys
     args = build_parser().parse_args(argv)
     if args.watchdog:
         from .server.supervisor import run_supervised
@@ -107,8 +116,16 @@ def main(argv=None) -> int:
             if a not in ("-w", "--watchdog")]
         return run_supervised(child)
     cfg = config_from_args(args)
+    from . import device, native
+    print(f"jax: JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '(unset)')} "
+          f"compile_cache={device.enable_compile_cache()}", flush=True)
     try:
         return asyncio.run(amain(cfg, args.exit_after_boot))
+    except (device.DeviceError, native.NativeCoreError) as e:
+        # the engine tier cannot run where it was told to: stop, loudly
+        print(f"easydarwin-tpu: boot refused: {e}", file=sys.stderr,
+              flush=True)
+        return EXIT_NO_DEVICE
     except KeyboardInterrupt:
         return 0
 

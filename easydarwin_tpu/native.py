@@ -1,16 +1,26 @@
 """ctypes bridge to the C++ data-plane (csrc/libedtpu_core.so).
 
-Auto-builds with ``make`` on first use when the shared object is missing
-(g++ is part of the supported toolchain); every entry point degrades
-gracefully — callers check ``available()`` and fall back to the Python/numpy
-paths, the same CPU-fallback discipline the TPU engine follows.
+The library is built from the tracked sources with ``make`` and is only
+ever loaded when the file on disk can be tied to them AND to this
+machine: ``csrc/Makefile`` compiles in a digest of every build input and
+a key of the CPU ``-march=native`` specialised the code for;
+``_load`` reads both out of the file's bytes before ``dlopen`` and
+rebuilds when either differs (a checkout copied from another machine, a
+source edited under an existing ``.so``).
+
+Callers that can serve without it check ``available()`` and take their
+numpy path; the TPU engine cannot — ``require()`` turns a core that
+will not build or load into a boot error (``server/app.py``).
 """
 
 from __future__ import annotations
 
 import ctypes
 import errno
+import glob
+import hashlib
 import os
+import re
 import socket
 import struct
 import subprocess
@@ -24,12 +34,24 @@ _CSRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
                      "csrc")
 # EDTPU_CORE_SO overrides the library path (sanitizer builds: make
 # asan/tsan in csrc/ produce instrumented .so variants for the CI jobs
-# the reference never had)
-_SO = os.environ.get("EDTPU_CORE_SO",
-                     os.path.join(_CSRC, "libedtpu_core.so"))
+# the reference never had); an override is loaded as given, never rebuilt
+_SO_OVERRIDE = os.environ.get("EDTPU_CORE_SO")
+_SO = _SO_OVERRIDE or os.path.join(_CSRC, "libedtpu_core.so")
 _lock = threading.Lock()
 _lib = None
 _tried = False
+#: why the last ``_load`` returned None ("" while loaded / not tried)
+_load_error = ""
+#: True when THIS process ran ``make`` (the smoke prints it: the library
+#: on a chip machine must have been built there)
+_built_here = False
+#: (source digest, cpu key) of the library this process loaded
+_build_tag: tuple[str, str] | None = None
+_BUILD_MARK = re.compile(rb"EDTPU_BUILD\{([0-9a-z]+)\|([0-9a-z]+)\}")
+
+
+class NativeCoreError(RuntimeError):
+    """The native core is required and will not build or load."""
 
 
 class SendOp(ctypes.Structure):
@@ -73,52 +95,100 @@ class Dest(ctypes.Structure):
                 ("_pad", ctypes.c_uint16)]
 
 
-def _build() -> bool:
+def source_digest() -> str:
+    """sha256 (first 16 hex) over every tracked build input, in the
+    order ``csrc/Makefile`` hashes them."""
+    names = ["Makefile"] + sorted(
+        os.path.basename(p) for pat in ("*.cpp", "*.h")
+        for p in glob.glob(os.path.join(_CSRC, pat)))
+    h = hashlib.sha256()
+    for n in names:
+        with open(os.path.join(_CSRC, n), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()[:16]
+
+
+def cpu_key() -> str:
+    """Key of the CPU ``-march=native`` targets: sha256 of the first
+    ``flags`` line of /proc/cpuinfo (``csrc/Makefile`` CPUKEY)."""
+    line = b""
     try:
-        subprocess.run(["make", "-s", "-C", _CSRC], check=True,
-                       capture_output=True, timeout=120)
-        return os.path.exists(_SO)
-    except (subprocess.SubprocessError, OSError):
-        return False
+        with open("/proc/cpuinfo", "rb") as f:
+            for ln in f:
+                if ln.startswith(b"flags"):
+                    line = ln
+                    break
+    except OSError:
+        pass
+    return hashlib.sha256(line).hexdigest()[:16]
+
+
+def _embedded_build(path: str) -> tuple[str, str] | None:
+    """(source digest, cpu key) compiled into the library at ``path``,
+    read from its bytes — nothing in the file is executed."""
+    try:
+        with open(path, "rb") as f:
+            m = _BUILD_MARK.search(f.read())
+    except OSError:
+        return None
+    return (m.group(1).decode(), m.group(2).decode()) if m else None
+
+
+def _build() -> str:
+    """Run ``make`` for the default library; "" on success, else why."""
+    global _built_here
+    try:
+        subprocess.run(
+            ["make", "-s", "-B", "-C", _CSRC, f"DIGEST={source_digest()}",
+             f"CPUKEY={cpu_key()}"],
+            check=True, capture_output=True, timeout=300)
+    except subprocess.CalledProcessError as e:
+        return ("make failed: "
+                + (e.stderr or b"").decode("utf-8", "replace")[-600:])
+    except (subprocess.SubprocessError, OSError) as e:
+        return f"make failed: {e!r}"
+    _built_here = True
+    return ""
 
 
 def _load():
-    global _lib, _tried
+    global _lib, _tried, _load_error, _build_tag
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) and not _build():
-            return None
+        if _SO_OVERRIDE is None:
+            want = (source_digest(), cpu_key())
+            if _embedded_build(_SO) != want:
+                # missing, built from other sources, or for another CPU:
+                # rebuild in place (make renames a fresh inode over the
+                # old file) and insist the result carries OUR marker
+                err = _build()
+                if not err and _embedded_build(_SO) != want:
+                    err = "rebuilt library does not carry this tree's digest"
+                if err:
+                    _load_error = err
+                    return None
         try:
             lib = ctypes.CDLL(_SO)
-        except OSError:
+        except OSError as e:
+            _load_error = f"dlopen {_SO}: {e}"
             return None
-        def _abi_ok(candidate) -> bool:
-            """The handshake proper: the library must write EXACTLY the
-            fields our EdStats buffer holds.  Fewer means a stale build
-            (the timing tail would read as zeros); more means a NEWER
-            library whose ed_get_stats would write past our buffer —
-            heap corruption, the one failure mode worse than refusing."""
-            if not hasattr(candidate, "ed_stats_fields"):
-                return False
-            candidate.ed_stats_fields.restype = ctypes.c_int32
-            candidate.ed_stats_fields.argtypes = []
-            return candidate.ed_stats_fields() == len(_STAT_FIELDS)
-
-        if not _abi_ok(lib):
-            # stale prebuilt .so from an older source tree: rebuild in place
-            # (make relinks to a fresh inode, so a second dlopen maps the
-            # new library; the old one is never deleted, in case no
-            # toolchain is present) and re-load once
-            if not _build():
-                return None
-            try:
-                lib = ctypes.CDLL(_SO)
-            except OSError:
-                return None
-            if not _abi_ok(lib):
-                return None
+        # ABI handshake: the library must write EXACTLY the fields our
+        # EdStats buffer holds.  Fewer would read the tail as zeros;
+        # more would write past our buffer — heap corruption, the one
+        # failure mode worse than refusing.  (Only an EDTPU_CORE_SO
+        # override can still trip this; the digest covers the default.)
+        if not hasattr(lib, "ed_stats_fields"):
+            _load_error = "library has no ed_stats_fields (stale ABI)"
+            return None
+        lib.ed_stats_fields.restype = ctypes.c_int32
+        lib.ed_stats_fields.argtypes = []
+        if lib.ed_stats_fields() != len(_STAT_FIELDS):
+            _load_error = (f"ed_stats ABI mismatch: library has "
+                           f"{lib.ed_stats_fields()} fields, bridge "
+                           f"expects {len(_STAT_FIELDS)}")
+            return None
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
@@ -239,12 +309,31 @@ def _load():
         lib.ed_wheel_next.argtypes = [ctypes.c_void_p, ctypes.c_int64]
         lib.ed_wheel_pending.restype = ctypes.c_int32
         lib.ed_wheel_pending.argtypes = [ctypes.c_void_p]
+        _build_tag = _embedded_build(_SO)
         _lib = lib
         return _lib
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def require() -> None:
+    """Raise ``NativeCoreError`` unless the core is loaded — for callers
+    (the TPU engine's boot) that have no honest fallback."""
+    if _load() is None:
+        raise NativeCoreError(
+            f"native core unavailable: {_load_error or 'unknown'}")
+
+
+def build_info() -> dict:
+    """How the library ties to this tree and machine (the marker is read
+    from the file once per process, at load)."""
+    emb = _build_tag or _embedded_build(_SO) or ("?", "?")
+    return {"so": _SO, "loaded": _lib is not None,
+            "source_digest": emb[0], "cpu_key": emb[1],
+            "built_this_process": _built_here,
+            "error": _load_error}
 
 
 def loaded() -> bool:

@@ -171,6 +171,11 @@ SCHEMA: dict[str, tuple[str, ...]] = {
     "storage.scrub_error": ("asset", "shard"),
     "storage.solve_singular": ("asset", "missing"),
     "storage.host_fallback": ("mismatches",),
+    # a device-path exception a handler caught so the host path keeps
+    # serving (device.note_swallowed: vod HBM upload, storage parity,
+    # megabatch mesh build) — counted in device_errors_swallowed_total;
+    # never silent, a chip smoke fails on any occurrence
+    "device.error_swallowed": ("site", "error"),
     # recording crash safety (vod/record.py): a leftover <file>.tmp
     # found at boot means a recorder died mid-write — the orphan is
     # reported, never silently deleted or served

@@ -110,6 +110,30 @@ TPU_PARAM_REFRESHES = REGISTRY.counter(
     "tpu_param_refreshes_total",
     "Device affine-param recomputes (membership/rebase state changes)")
 
+# Fed by jax.monitoring listeners (device.enable_compile_cache): a jit
+# specialisation costs one executable build, whether XLA compiled it or
+# the persistent cache supplied it.  A served window should add none.
+JAX_EXECUTABLES_BUILT = REGISTRY.counter(
+    "jax_executables_built_total",
+    "XLA executables this process built, one per new jit specialisation "
+    "(compiled, or loaded from the persistent compilation cache)")
+JAX_EXECUTABLE_BUILD_SECONDS = REGISTRY.counter(
+    "jax_executable_build_seconds_total",
+    "Wall seconds spent building XLA executables (backend compile, or "
+    "the persistent-cache load that replaced it)")
+JAX_CACHE_HITS = REGISTRY.counter(
+    "jax_persistent_cache_hits_total",
+    "Executable builds served from the persistent compilation cache "
+    "instead of a backend compile")
+DEVICE_ERRORS_SWALLOWED = REGISTRY.counter(
+    "device_errors_swallowed_total",
+    "Device-path exceptions a handler caught so the host path could keep "
+    "serving, by site (vod_device_rows = segment-cache HBM upload, "
+    "storage_parity = erasure-stripe parity matmul, megabatch_mesh = "
+    "serving-mesh build); each is also logged — any nonzero value means "
+    "work the config put on the device is running on the host",
+    labels=("site",))
+
 # -------------------------------------------------------- megabatch scheduler
 # The cross-stream relay scheduler (relay/megabatch.py): one shape-bucketed
 # stacked device pass per pump wake instead of one dispatch per stream.

@@ -89,7 +89,7 @@ class SloWatchdog:
     def __init__(self, config: SloConfig | None = None, *,
                  clock=time.monotonic, latency_hist=None,
                  offender=None, flight=None, events=None,
-                 violations=None, budget_gauge=None):
+                 violations=None, budget_gauge=None, compiles=None):
         self.config = config or SloConfig()
         self._clock = clock
         self._lat = latency_hist if latency_hist is not None \
@@ -101,6 +101,14 @@ class SloWatchdog:
             else families.SLO_VIOLATIONS
         self._budget_gauge = budget_gauge if budget_gauge is not None \
             else families.SLO_BUDGET_REMAINING
+        #: () -> executables XLA has built in this process (the
+        #: jax.monitoring-fed counter); see ``note_wake``
+        self._compiles = compiles if compiles is not None \
+            else families.JAX_EXECUTABLES_BUILT.total
+        self._built_seen = self._compiles()
+        self._after_compile = False
+        self._wake_prev = self._read_latency()
+        self._lat_excluded = (0, 0)
         #: (t, {slo: (total, bad)}) cumulative samples, oldest first
         self._samples: deque = deque()
         self._objectives = {
@@ -118,9 +126,7 @@ class SloWatchdog:
         # the registry's pre-scrape collectors; without this pull a
         # server nobody scrapes would watch frozen zeros forever
         families.REGISTRY.collect()
-        lat_total = self._lat.total_count()
-        lat_bad = self._lat.count_above(
-            self.config.latency_objective_ms / 1e3)
+        lat_total, lat_bad = self._read_latency()
         drops_bad = int(families.EGRESS_SEND_ERRORS.total()
                         + families.INGEST_OVERSIZE_DROPPED.total())
         # denominator = every DELIVERED packet: the ingest→wire histogram
@@ -129,8 +135,37 @@ class SloWatchdog:
         # TCP-players deployment that narrower denominator would let a
         # handful of ingest drops read as a ~100% bad ratio
         drops_total = lat_total + drops_bad
-        return {"latency": (lat_total, lat_bad),
+        # net of the wakes note_wake() exempted (cold compiles)
+        return {"latency": (lat_total - self._lat_excluded[0],
+                            lat_bad - self._lat_excluded[1]),
                 "drops": (drops_total, drops_bad)}
+
+    def _read_latency(self) -> tuple[int, int]:
+        return (self._lat.total_count(),
+                self._lat.count_above(
+                    self.config.latency_objective_ms / 1e3))
+
+    def note_wake(self) -> None:
+        """The pump calls this after every wake.  A wake in which XLA
+        built an executable stalled the pump for the whole build; the
+        packets it held back leave in that wake or — a megabatch bucket
+        compiles at the END of a wake — in the next one.  That lateness
+        is start-up cost, not service: the profiler already keeps the
+        compiling pass out of the phase histograms (``note_compile``),
+        and this keeps those two wakes' observations out of the latency
+        objective, or one new bucket shape could by itself charge the
+        ladder a rung (``reason="slo_burn"``).  Every other wake counts
+        in full."""
+        built = self._compiles()
+        cur = self._read_latency()
+        compiled = built != self._built_seen
+        if compiled or self._after_compile:
+            self._lat_excluded = (
+                self._lat_excluded[0] + cur[0] - self._wake_prev[0],
+                self._lat_excluded[1] + cur[1] - self._wake_prev[1])
+        self._after_compile = compiled
+        self._built_seen = built
+        self._wake_prev = cur
 
     def _window_delta(self, slo: str, now: float, window_s: float,
                       cur: tuple[int, int]) -> tuple[int, int]:

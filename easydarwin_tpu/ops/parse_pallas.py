@@ -4,19 +4,25 @@ Same contract as ``parse.parse_packets`` (the jnp reference), fused into a
 single VMEM pass per tile of packets.  TPU-friendly formulation: the only
 data-dependent indices are the header-size-relative byte peeks
 (``hs = 12 + 4·CC``), and CC has just 16 possible values — so each needed
-byte is computed as a sum of 16 *static* column slices masked by
-``CC == k``, avoiding per-row dynamic gathers entirely (Mosaic lowers the
-whole kernel to vector selects).
+byte is computed as a sum of 16 *static* slices masked by ``CC == k``,
+avoiding per-row dynamic gathers entirely (Mosaic lowers the whole kernel
+to vector selects).
 
-Outputs are packed as two arrays to keep the out_specs simple:
-``words  [P, 4] uint32``  — seq, timestamp, ssrc, payload_start
-``flagsv [P, 5] int32``   — nal_type, keyframe_first, frame_first,
-frame_last, marker
+Layout: packets ride the LANE axis.  The wrapper hands the kernel the
+prefix transposed, ``[96, N]`` uint8 (byte index on sublanes), and the
+lengths as ``[1, N]``; byte ``c`` of every packet in the tile is then the
+``[1, TILE]`` row slab ``x[c:c+1, :]``, every intermediate is a
+``[1, TILE]`` slab, and the nine result fields are stored as rows of one
+``[16, N]`` int32 array (rows 9-15 are sublane padding).  This is the
+form Mosaic compiles on a TPU v5e, where all nine fields equalled the
+jnp reference in value (CHANGES.md PR 21; ``seq`` is uint32 here and
+int32 in the reference, as it always was); the packets-on-sublanes form it
+replaced — ``x[:, col]`` 1-D columns, a 1-D ``(TILE,)`` length block,
+``(TILE, 4)``/``(TILE, 5)`` outputs — was refused ("XLA layout …
+does not match Mosaic layout … for an operand of shape s32[512]").
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,28 +31,36 @@ from .parse import PARSE_PREFIX, _AGG_OFFSETS, _KEYFRAME_TYPES, \
     _MIN_CLASSIFY_LEN
 
 TILE = 256
+#: result rows, in order; padded to 16 sublanes in the kernel's output
+_FIELDS = ("seq", "timestamp", "ssrc", "payload_start", "nal_type",
+           "keyframe_first", "frame_first", "frame_last", "marker")
+_OUT_ROWS = 16
 
 
 def _byte_at_hs_plus(x: jnp.ndarray, cc: jnp.ndarray, delta: int
                      ) -> jnp.ndarray:
-    """x[p, 12 + 4*cc[p] + delta] via 16 masked static slices."""
-    out = jnp.zeros(x.shape[0], dtype=jnp.int32)
+    """byte ``12 + 4*cc[p] + delta`` of packet p via 16 masked static
+    row slabs; ``x`` is ``[W, P]``, the result ``[1, P]``."""
+    out = jnp.zeros_like(cc)
     for k in range(16):
-        col = 12 + 4 * k + delta
-        if col < x.shape[1]:
-            out = jnp.where(cc == k, x[:, col], out)
+        row = 12 + 4 * k + delta
+        if row < x.shape[0]:
+            out = jnp.where(cc == k, x[row:row + 1, :], out)
     return out
 
 
-def _parse_tile(x: jnp.ndarray, length: jnp.ndarray):
-    b0, b1 = x[:, 0], x[:, 1]
+def _parse_tile(x: jnp.ndarray, length: jnp.ndarray) -> list[jnp.ndarray]:
+    """``x``: [W, P] int32 bytes · ``length``: [1, P] int32 → the
+    ``_FIELDS`` as [1, P] int32 slabs (uint32 fields as bit patterns)."""
+    def b(c):
+        return x[c:c + 1, :]
+
+    b0, b1 = b(0), b(1)
     cc = b0 & 0x0F
     hs = 12 + 4 * cc
-    seq = ((x[:, 2] << 8) | x[:, 3]).astype(jnp.uint32)
-    ts = ((x[:, 4] << 24) | (x[:, 5] << 16) | (x[:, 6] << 8) | x[:, 7]
-          ).astype(jnp.uint32)
-    ssrc = ((x[:, 8] << 24) | (x[:, 9] << 16) | (x[:, 10] << 8) | x[:, 11]
-            ).astype(jnp.uint32)
+    seq = (b(2) << 8) | b(3)
+    ts = (b(4) << 24) | (b(5) << 16) | (b(6) << 8) | b(7)
+    ssrc = (b(8) << 24) | (b(9) << 16) | (b(10) << 8) | b(11)
     marker = (b1 & 0x80) != 0
     classifiable = (length >= _MIN_CLASSIFY_LEN) & (length > hs)
     nal0 = _byte_at_hs_plus(x, cc, 0) & 0x1F
@@ -65,35 +79,28 @@ def _parse_tile(x: jnp.ndarray, length: jnp.ndarray):
     kf &= classifiable
     frame_first = classifiable & (((nal0 >= 1) & (nal0 <= 27)) | fu_start)
     frame_last = (length >= _MIN_CLASSIFY_LEN) & marker
-    words = jnp.stack([seq, ts, ssrc, hs.astype(jnp.uint32)], axis=-1)
-    flagsv = jnp.stack([eff, kf.astype(jnp.int32),
-                        frame_first.astype(jnp.int32),
-                        frame_last.astype(jnp.int32),
-                        marker.astype(jnp.int32)], axis=-1)
-    return words, flagsv
+    return [seq, ts, ssrc, hs, eff] + [
+        v.astype(jnp.int32) for v in (kf, frame_first, frame_last, marker)]
 
 
-def _kernel(prefix_ref, length_ref, words_ref, flags_ref):
+def _kernel(prefix_ref, length_ref, out_ref):
     x = prefix_ref[:].astype(jnp.int32)
-    length = length_ref[:].astype(jnp.int32)
-    words, flagsv = _parse_tile(x, length)
-    words_ref[:] = words
-    flags_ref[:] = flagsv
+    for i, v in enumerate(_parse_tile(x, length_ref[:])):
+        out_ref[i:i + 1, :] = v
 
 
 def parse_packets_pallas(prefix: jnp.ndarray, length: jnp.ndarray,
-                         interpret: bool | None = None
+                         interpret: bool = False
                          ) -> dict[str, jnp.ndarray]:
     """Pallas-fused parse; same results as ``parse.parse_packets``.
 
-    ``interpret`` defaults to True on the CPU backend (tests/fallback) and
-    False on TPU.  Not jitted itself — callers jit the surrounding step.
+    ``interpret=True`` runs the kernel in the Pallas interpreter (the
+    CPU tests pass it); the default compiles it with Mosaic, which needs
+    a TPU — it never decides to interpret on its own.  Not jitted
+    itself — callers jit the surrounding step.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
 
     n = prefix.shape[0]
     pad = (-n) % TILE
@@ -101,30 +108,29 @@ def parse_packets_pallas(prefix: jnp.ndarray, length: jnp.ndarray,
         prefix = jnp.concatenate(
             [prefix, jnp.zeros((pad, prefix.shape[1]), prefix.dtype)])
         length = jnp.concatenate([length, jnp.zeros(pad, length.dtype)])
-    grid = prefix.shape[0] // TILE
-    words, flagsv = pl.pallas_call(
+    n_pad, width = prefix.shape
+    out = pl.pallas_call(
         _kernel,
-        out_shape=(jax.ShapeDtypeStruct((prefix.shape[0], 4), jnp.uint32),
-                   jax.ShapeDtypeStruct((prefix.shape[0], 5), jnp.int32)),
-        grid=(grid,),
+        out_shape=jax.ShapeDtypeStruct((_OUT_ROWS, n_pad), jnp.int32),
+        grid=(n_pad // TILE,),
         in_specs=[
-            pl.BlockSpec((TILE, prefix.shape[1]), lambda i: (i, 0),
+            pl.BlockSpec((width, TILE), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((TILE,), lambda i: (i,), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, TILE), lambda i: (0, i),
+                         memory_space=pltpu.VMEM),
         ],
-        out_specs=(pl.BlockSpec((TILE, 4), lambda i: (i, 0),
-                                memory_space=pltpu.VMEM),
-                   pl.BlockSpec((TILE, 5), lambda i: (i, 0),
-                                memory_space=pltpu.VMEM)),
+        out_specs=pl.BlockSpec((_OUT_ROWS, TILE), lambda i: (0, i),
+                               memory_space=pltpu.VMEM),
         interpret=interpret,
-    )(prefix, length.astype(jnp.int32))
-    words, flagsv = words[:n], flagsv[:n]
+    )(prefix.T, length.astype(jnp.int32).reshape(1, n_pad))
+    f = dict(zip(_FIELDS, out[:len(_FIELDS), :n]))
+    u32 = lambda v: jax.lax.bitcast_convert_type(v, jnp.uint32)  # noqa: E731
     return {
-        "seq": words[:, 0], "timestamp": words[:, 1], "ssrc": words[:, 2],
-        "payload_start": words[:, 3].astype(jnp.int32),
-        "nal_type": flagsv[:, 0],
-        "keyframe_first": flagsv[:, 1].astype(bool),
-        "frame_first": flagsv[:, 2].astype(bool),
-        "frame_last": flagsv[:, 3].astype(bool),
-        "marker": flagsv[:, 4].astype(bool),
+        "seq": u32(f["seq"]), "timestamp": u32(f["timestamp"]),
+        "ssrc": u32(f["ssrc"]), "payload_start": f["payload_start"],
+        "nal_type": f["nal_type"],
+        "keyframe_first": f["keyframe_first"].astype(bool),
+        "frame_first": f["frame_first"].astype(bool),
+        "frame_last": f["frame_last"].astype(bool),
+        "marker": f["marker"].astype(bool),
     }

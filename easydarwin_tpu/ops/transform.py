@@ -166,7 +166,9 @@ def _decode_kernel(levels_ref, qt_ref, inv_ref, out_ref):
     y = jax.lax.dot_general(x, inv_ref[:],
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    out_ref[:] = jnp.clip(jnp.round(y + 128.0), 0, 255).astype(jnp.uint8)
+    # int32 out, narrowed to uint8 by the wrapper: Mosaic on a TPU v5e
+    # refuses the in-kernel cast ("Unsupported cast: float32 -> uint8")
+    out_ref[:] = jnp.clip(jnp.round(y + 128.0), 0, 255).astype(jnp.int32)
 
 
 def decode_blocks_pallas(levels: jnp.ndarray, qtable: jnp.ndarray,
@@ -189,7 +191,7 @@ def decode_blocks_pallas(levels: jnp.ndarray, qtable: jnp.ndarray,
     grid = levels.shape[0] // TILE
     out = pl.pallas_call(
         _decode_kernel,
-        out_shape=jax.ShapeDtypeStruct((levels.shape[0], 64), jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct((levels.shape[0], 64), jnp.int32),
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((TILE, 64), lambda i: (i, 0),
@@ -202,7 +204,7 @@ def decode_blocks_pallas(levels: jnp.ndarray, qtable: jnp.ndarray,
         interpret=interpret,
     )(levels, qtable.reshape(1, 64).astype(jnp.float32),
       jnp.asarray(inv))          # contraction ((1,),(1,)) ≡ x @ inv.T
-    return out[:n]
+    return out[:n].astype(jnp.uint8)
 
 
 # ------------------------------------------------- DCT-domain 2x downscale

@@ -6,8 +6,8 @@ a ``(src)``-axis device mesh vs the single-device dispatch — and
 reports packets/s for both plus the scaling efficiency of the mesh.
 One harness, three callers:
 
-* ``bench.py`` — the ``extra.multichip`` section (in-process when the
-  box has devices, via a forced-host-device child otherwise);
+* ``bench.py`` — the ``extra.multichip`` section (in-process, on the
+  devices the bench holds; a one-device box reports a note);
 * ``__graft_entry__.dryrun_multichip`` — so MULTICHIP_r*.json reports
   packets/s from the mesh, not just "dryrun OK";
 * ``tools/soak.py --devices N`` — the sharded multi-source section.

@@ -35,11 +35,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..ops import fanout as fanout_ops
 from ..ops import parse as parse_ops
 
-try:                                    # jax >= 0.4.38 exports it top-level
-    _shard_map = jax.shard_map
-except AttributeError:                  # older: the experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 AXES = ("src", "sub", "win")
 
 
@@ -117,7 +112,7 @@ def sharded_relay_step(mesh: Mesh, bucket_delay_ms: int = 73):
                 P("src", "sub", None), P("src", "sub"))
     out_specs = (P("src", "sub", "win", None), P("src", "sub", "win"),
                  P("src"), P())
-    step = _shard_map(
+    step = jax.shard_map(
         functools.partial(_local_step, bucket_delay_ms=bucket_delay_ms),
         mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     return jax.jit(step)

@@ -152,6 +152,10 @@ class StreamingServer:
         #: start() so a bad device config fails loudly at boot, not on
         #: the first busy wake; None = single-device dispatch
         self.megabatch_mesh = None
+        #: ``device.resolve`` result ({"platform","kind","count"}) —
+        #: filled by start() when tpu_fanout is on, BEFORE any listener
+        #: opens; None = the engine tier is off and JAX was not touched
+        self.device_info: dict | None = None
         #: VOD segment cache + shared group pacer (ISSUE 10): hot file
         #: sessions become megabatch-eligible relay streams the pump
         #: steps alongside live; built in start() (engines need the
@@ -238,6 +242,8 @@ class StreamingServer:
                 self.register_module(m)
         if self.config.fec_enabled:
             self.config.fec_config()    # raises at boot on a bad window/kind
+        if self.config.tpu_fanout:
+            self._resolve_engine_tier()
         # chaos plan (resilience/inject.py): armed before anything serves
         # so the very first pass already runs under the fault schedule
         plan = self.config.fault_plan()
@@ -274,24 +280,31 @@ class StreamingServer:
         if (self.config.tpu_fanout and self.config.megabatch_enabled
                 and self.config.megabatch_devices != 1):
             # the megabatch serving mesh (ISSUE 7): built before the
-            # pump's first wake; any failure here (bad device count, no
-            # backend) degrades to single-device dispatch with a logged
-            # warning rather than a dead pump
+            # pump's first wake.  A mesh that cannot be built — an
+            # exception, or fewer devices than megabatch_devices asked
+            # for — serves single-device, COUNTED and logged
+            # (device_errors_swallowed_total{site="megabatch_mesh"}),
+            # never quietly
+            from ..device import note_swallowed
+            from ..parallel.mesh import make_megabatch_mesh
+            want = self.config.megabatch_devices
             try:
-                from ..parallel.mesh import make_megabatch_mesh
-                self.megabatch_mesh = make_megabatch_mesh(
-                    self.config.megabatch_devices)
-                if self.megabatch_mesh is not None and self.error_log:
-                    from ..parallel.distributed import process_span
-                    self.error_log.info(
-                        "megabatch mesh: "
-                        f"{process_span(self.megabatch_mesh)}")
+                self.megabatch_mesh = make_megabatch_mesh(want)
+                if self.megabatch_mesh is None and want > 1:
+                    raise RuntimeError(
+                        f"megabatch_devices={want} but only "
+                        f"{self.device_info['count']} device(s) present")
             except Exception as e:
                 self.megabatch_mesh = None
+                note_swallowed("megabatch_mesh", e)
                 if self.error_log:
                     self.error_log.warning(
                         f"megabatch mesh unavailable, serving "
                         f"single-device: {e!r}")
+            if self.megabatch_mesh is not None and self.error_log:
+                from ..parallel.distributed import process_span
+                self.error_log.info(
+                    f"megabatch mesh: {process_span(self.megabatch_mesh)}")
         if self.config.vod_cache_enabled:
             from ..vod.cache import SegmentCache
             from ..vod.session import VodPacerGroup
@@ -541,6 +554,34 @@ class StreamingServer:
             native.uring_ingest_disarm()
             self.uring_ingest_enabled = False
         await self.rest.stop()
+
+    def _resolve_engine_tier(self) -> None:
+        """tpu_fanout is on: decide NOW what the engine runs on, before
+        a listener opens.  The native core must build from this tree and
+        load (``native.require``) and the backend must be a TPU unless
+        ``JAX_PLATFORMS`` asked for the CPU by name (``device.resolve``)
+        — either failure raises and the boot stops.  Leaving this to
+        the first ``eng.step`` would hand a backend-init failure to the
+        per-stream guard and the degradation ladder, and the process
+        would serve from the scalar loop behind a ``tpu_fanout=on``
+        banner."""
+        from .. import device, native
+        native.require()
+        self.device_info = device.resolve(require_tpu=True)
+        if self.error_log:
+            self.error_log.info("engine tier: " + self.engine_banner())
+
+    def engine_banner(self) -> str:
+        """``platform=… device_kind=… devices=… native=…`` — the boot
+        line's and the error log's account of what serves the engine."""
+        from .. import native
+        d = self.device_info
+        if d is None:
+            return "platform=none (tpu_fanout off)"
+        nb = native.build_info()
+        return (f"platform={d['platform']} device_kind=\"{d['kind']}\" "
+                f"devices={d['count']} native_src={nb['source_digest']} "
+                f"native_built_at_boot={int(nb['built_this_process'])}")
 
     def request_restart(self) -> None:
         """REST /restart: under the supervisor (server.supervisor) the main
@@ -1408,6 +1449,10 @@ class StreamingServer:
                 pass
             self._pump_event.clear()
             self._reflect_all()
+            if self.config.slo_enabled:
+                # a wake that compiled (and the one after) stays out of
+                # the latency objective — SloWatchdog.note_wake
+                self.slo.note_wake()
             if wheel is not None:
                 # advance and schedule against the SAME clock sample, or
                 # timers fire early by the reflect-pass duration
@@ -1628,10 +1673,27 @@ class StreamingServer:
             "OutRatePps": str(d["out_rate"]),
             "IngestToWireP99Ms": str(d["ingest_to_wire_p99_ms"]),
             "TpuFanout": "1" if self.config.tpu_fanout else "0",
+            # what the engine actually runs on (device.resolve at boot;
+            # empty strings = engine tier off, JAX never initialised)
+            **self._device_keys(),
             # wake-ledger summary (ISSUE 16): the console's "is the pump
             # starving" answer without a /metrics scrape
             "LedgerTopWaitClass": str(d.get("ledger_top_wait_class", "")),
             "LedgerLastWakeMs": str(d.get("ledger_last_wake_ms", 0.0)),
+        }
+
+    def _device_keys(self) -> dict:
+        from .. import native
+        d = self.device_info or {"platform": "", "kind": "", "count": 0}
+        nb = native.build_info() if native.loaded() else {}
+        return {
+            "Platform": d["platform"],
+            "DeviceKind": d["kind"],
+            "DeviceCount": str(d["count"]),
+            "NativeCore": "1" if native.loaded() else "0",
+            "NativeSourceDigest": nb.get("source_digest", ""),
+            "NativeBuiltAtBoot":
+                "1" if nb.get("built_this_process") else "0",
         }
 
     def live_sessions(self) -> list[dict]:

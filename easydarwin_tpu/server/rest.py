@@ -332,6 +332,22 @@ class RestApi:
         return (200, json.dumps(admin.profile_snapshot(self.app),
                                 default=str), "application/json")
 
+    async def _cmd_devicecheck(self, params: dict,
+                               body: bytes) -> tuple[int, str, str]:
+        """GET /api/v1/devicecheck — compile and run every served jitted
+        step on the device this process holds, each against its host
+        oracle (server/devicecheck.py).  Runs on a helper thread: a cold
+        compile takes seconds and the pump must keep relaying.  Raw
+        JSON; 200 with ``"ok": false`` when a step diverged or was
+        refused by the compiler."""
+        from . import devicecheck
+        try:
+            seed = int(params.get("seed", ["0"])[0])
+        except ValueError:
+            seed = 0
+        doc = await asyncio.to_thread(devicecheck.run, seed)
+        return 200, json.dumps(doc), "application/json"
+
     def _cmd_ledger(self, params: dict,
                     body: bytes) -> tuple[int, str, str]:
         """GET /api/v1/ledger — the wake-loop ledger's live snapshot

@@ -84,8 +84,12 @@ class StripeCodec:
                 obs.TPU_H2D_BYTES.inc(rows.nbytes + coeff.nbytes)
                 obs.TPU_D2H_BYTES.inc(dev.nbytes)
                 self.device_passes += 1
-            except Exception:
-                dev = None               # no backend: host parity serves
+            except Exception as e:
+                # host parity (already computed above) serves this
+                # stripe; counted + logged, never silent
+                from ..device import note_swallowed
+                note_swallowed("storage_parity", e)
+                dev = None
             if dev is not None and not np.array_equal(dev, host):
                 # the _install_segment discipline: count, discard the
                 # device result, latch host parity — never persist an

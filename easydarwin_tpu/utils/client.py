@@ -59,6 +59,10 @@ class RtspClient:
         self.writer: asyncio.StreamWriter | None = None
         self.wire = rtsp.RtspWireReader(parse_responses=True)
         self.cseq = 0
+        #: seconds a request waits for its response unless the call
+        #: says otherwise (a load generator raises it: a server busy
+        #: compiling answers late, not never)
+        self.request_timeout = 5.0
         self.session_id: str | None = None
         #: headers merged into EVERY request (overridable per call) —
         #: the pull-relay envelope sets the cluster-peer correlation
@@ -76,8 +80,12 @@ class RtspClient:
         self.stats = ReceiverStats()
         self._reader_task: asyncio.Task | None = None
 
-    async def connect(self, host: str, port: int) -> None:
-        self.reader, self.writer = await asyncio.open_connection(host, port)
+    async def connect(self, host: str, port: int, *,
+                      local_addr: tuple[str, int] | None = None) -> None:
+        """``local_addr`` binds the client side first — the address a
+        UDP player connects FROM is where the server sends its media."""
+        self.reader, self.writer = await asyncio.open_connection(
+            host, port, local_addr=local_addr)
         self._reader_task = asyncio.create_task(self._read_loop())
 
     async def close(self) -> None:
@@ -118,8 +126,10 @@ class RtspClient:
 
     # ------------------------------------------------------------ requests
     async def request(self, method: str, uri: str, headers=None,
-                      body: bytes = b"", timeout: float = 5.0
+                      body: bytes = b"", timeout: float | None = None
                       ) -> rtsp.RtspResponse:
+        if timeout is None:
+            timeout = self.request_timeout
         self.cseq += 1
         want = self.cseq
         hdrs = {"cseq": str(want)}
@@ -222,6 +232,8 @@ class RtspClient:
                 r.headers.get("transport", "RTP/AVP")))
         r = await self.request("PLAY", uri)
         assert r.status == 200, r.status
+        #: the PLAY response — its RTP-Info carries each track's first seq
+        self.play_response = r
         return sd
 
     async def teardown(self, uri: str) -> None:

@@ -239,18 +239,21 @@ class CachedWindow:
         """The HBM-resident staged rows — uploaded ONCE per window (one
         ``device_put``), then shared by every subscriber whose affine
         prime stacks this window on the device.  Returns the resident
-        jax array, or None if no backend is importable."""
+        jax array, or None when the upload failed (counted and logged
+        by ``device.note_swallowed``; the caller stages from the host)."""
         if self._device is None:
             try:
                 import jax
                 self._device = jax.device_put(self.staged)
-                self.device_uploads += 1
-                obs.TPU_H2D_BYTES.inc(self.staged.nbytes)
-                if self._on_device is not None:
-                    # count the HBM copy into the cache's byte budget
-                    self._on_device(self.staged.nbytes)
-            except Exception:
+            except Exception as e:
+                from ..device import note_swallowed
+                note_swallowed("vod_device_rows", e)
                 return None
+            self.device_uploads += 1
+            obs.TPU_H2D_BYTES.inc(self.staged.nbytes)
+            if self._on_device is not None:
+                # count the HBM copy into the cache's byte budget
+                self._on_device(self.staged.nbytes)
         return self._device
 
     def drop_device(self) -> None:

@@ -43,12 +43,17 @@ def test_relay_pipeline_spans_carry_trace_id():
     assert tids == [None, "sess-default", "sess-override"]
 
 
-def test_relay_pipeline_pallas_backend_matches():
+def test_relay_pipeline_pallas_backend_matches(monkeypatch):
     cfg = RelayPipelineConfig(window=64, subscribers=8)
     a = RelayPipeline(cfg)
     args = a.example_args()
     ref = a(*args)
-    # pallas backend auto-selects interpret mode on CPU
+    # the kernel never interprets on its own: on the CPU backend the
+    # test asks for the interpreter by name
+    import functools
+    from easydarwin_tpu.models import relay_pipeline as rp
+    monkeypatch.setattr(rp, "parse_packets_pallas", functools.partial(
+        rp.parse_packets_pallas, interpret=True))
     b = RelayPipeline(RelayPipelineConfig(window=64, subscribers=8,
                                           use_pallas_parse=True))
     out = b(*args)

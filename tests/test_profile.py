@@ -216,8 +216,9 @@ def test_ed_stats_send_ns_monotone_across_multi_calls():
 
 
 # -------------------------------------------------------------- SLO watchdog
-def _watchdog(events, **cfg_kw):
-    """Private watchdog over private families + event log."""
+def _watchdog(events, *, compiles=lambda: 0, **cfg_kw):
+    """Private watchdog over private families + event log (and a private
+    executables-built source, so no real jit moves ``note_wake``)."""
     reg = Registry()
     lat = reg.histogram("lat_seconds", "lat", labels=("engine",))
     viol = reg.counter("slo_violations_total", "v", labels=("slo",))
@@ -233,7 +234,7 @@ def _watchdog(events, **cfg_kw):
                               fast_burn=10.0, slow_burn=2.0), **cfg_kw})
     w = SloWatchdog(cfg, clock=lambda: 0.0, latency_hist=lat,
                     flight=_NoFlight(), events=events, violations=viol,
-                    budget_gauge=gauge)
+                    budget_gauge=gauge, compiles=compiles)
     return w, lat, viol, gauge
 
 
@@ -301,6 +302,73 @@ def test_slo_watchdog_ignores_slow_window_blip():
             lat.observe_many(np.full(300, 0.5), engine="test")
         w.tick(now=float(t))
     assert viol.total() == 0
+
+
+# -- cold compiles stay out of the latency objective (note_wake, PR 21) ----
+def _spike_wake(w, lat):
+    """One pump wake that delivers a burning mix: 40 % past objective."""
+    lat.observe_many(np.full(600, 0.001), engine="test")
+    lat.observe_many(np.full(400, 0.5), engine="test")
+    w.note_wake()
+
+
+def test_slo_compile_wake_and_the_next_are_exempt():
+    """A wake in which XLA built an executable, and the wake after it
+    (a bucket compiles at the END of a wake; what it held back leaves in
+    the next), never reach the burn windows."""
+    from easydarwin_tpu.obs.events import EventLog
+    built = [0]
+    w, lat, viol, gauge = _watchdog(EventLog(), compiles=lambda: built[0])
+    lat.observe_many(np.full(1000, 0.001), engine="test")
+    w.note_wake()
+    assert w.tick(now=0.0) == []
+    built[0] += 1                       # the compile lands in this wake
+    _spike_wake(w, lat)
+    _spike_wake(w, lat)                 # ...and its stall drains in this
+    assert w.tick(now=1.0) == []
+    assert viol.total() == 0
+    # both wakes' 2,000 observations are excluded, nothing else is
+    assert w._read()["latency"] == (1000, 0)
+    assert gauge.value(slo="latency") == 1.0
+
+
+def test_slo_burn_without_a_compile_still_fires():
+    """The same traffic with no executable built is a real burn."""
+    from easydarwin_tpu.obs.events import EventLog
+    w, lat, viol, _ = _watchdog(EventLog(), compiles=lambda: 0)
+    lat.observe_many(np.full(1000, 0.001), engine="test")
+    w.note_wake()
+    w.tick(now=0.0)
+    _spike_wake(w, lat)
+    _spike_wake(w, lat)
+    assert len(w.tick(now=1.0)) == 1
+    assert viol.value(slo="latency") == 1
+
+
+def test_slo_compile_grace_expires_after_one_more_wake():
+    """Two wakes are exempt, the third counts in full: a compile cannot
+    blank the objective for longer than its own stall."""
+    from easydarwin_tpu.obs.events import EventLog
+    built = [0]
+    w, lat, viol, _ = _watchdog(EventLog(), compiles=lambda: built[0])
+    lat.observe_many(np.full(1000, 0.001), engine="test")
+    w.note_wake()
+    w.tick(now=0.0)
+    built[0] += 1
+    _spike_wake(w, lat)                 # exempt: compiled
+    _spike_wake(w, lat)                 # exempt: the wake after
+    assert w.tick(now=1.0) == []
+    _spike_wake(w, lat)                 # counts
+    assert w._read()["latency"] == (2000, 400)
+    assert len(w.tick(now=2.0)) == 1
+    assert viol.value(slo="latency") == 1
+    # a build between wakes (another thread) is charged to the wake that
+    # sees it, once
+    built[0] += 1
+    _spike_wake(w, lat)
+    _spike_wake(w, lat)
+    _spike_wake(w, lat)
+    assert w._read()["latency"] == (3000, 800)
 
 
 # --------------------------------------------------- e2e spike → flight dump

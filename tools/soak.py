@@ -1449,6 +1449,17 @@ async def soak(seconds: float, n_sources: int = 0,
 # ===================================================================== cluster
 # The multi-process cluster soak (ISSUE 6 acceptance scenario).
 
+def _node_env(index: int) -> dict:
+    """One process per chip.  The launchers below never initialise a
+    JAX backend themselves; node 0 inherits the platform selection —
+    and takes the chip where there is one — and every other node is
+    started on an explicit ``JAX_PLATFORMS=cpu``, which it prints in
+    its NODE_READY line."""
+    if index == 0:
+        return dict(os.environ)
+    return dict(os.environ, JAX_PLATFORMS="cpu")
+
+
 async def _cluster_node_main(node_id: str, redis_port: int,
                              fault_plan: str = "",
                              skewed: bool = False,
@@ -1527,7 +1538,9 @@ async def _cluster_node_main(node_id: str, redis_port: int,
         # the latency SLO before the soak clock even starts
         await asyncio.to_thread(prewarm_batch_shapes)
     await app.start()
-    print(f"NODE_READY rtsp={app.rtsp.port} rest={app.rest.port}",
+    print(f"NODE_READY rtsp={app.rtsp.port} rest={app.rest.port} "
+          f"jax_platforms={os.environ.get('JAX_PLATFORMS') or 'unset'} "
+          f"platform={(app.device_info or {}).get('platform') or 'none'}",
           flush=True)
     try:
         while True:
@@ -1637,12 +1650,12 @@ async def cluster_soak(n_nodes: int, seconds: float,
     rtsp_ports: dict[str, int] = {}
     rest_ports: dict[str, int] = {}
     here = os.path.abspath(__file__)
-    for nid in node_ids:
+    for i, nid in enumerate(node_ids):
         p = await asyncio.create_subprocess_exec(
             sys.executable, here, "--cluster-node", "--node-id", nid,
             "--redis-port", str(mini.port),
             stdout=asyncio.subprocess.PIPE,
-            stderr=asyncio.subprocess.DEVNULL)
+            stderr=asyncio.subprocess.DEVNULL, env=_node_env(i))
         procs[nid] = p
         line = await asyncio.wait_for(p.stdout.readline(), 60)
         if not line.startswith(b"NODE_READY"):
@@ -2096,14 +2109,15 @@ async def composed_soak(n_nodes: int, seconds: float,
     rtsp_ports: dict[str, int] = {}
     rest_ports: dict[str, int] = {}
     here = os.path.abspath(__file__)
-    for nid in node_ids:
+    for i, nid in enumerate(node_ids):
         # child stderr lands next to the node's logs — the composed
         # round exists to make cross-node failures attributable
         err = open(f"/tmp/edtpu_composed_soak/{nid}/stderr.log", "wb")
         p = await asyncio.create_subprocess_exec(
             sys.executable, here, "--cluster-node", "--composed-child",
             "--node-id", nid, "--redis-port", str(mini.port),
-            stdout=asyncio.subprocess.PIPE, stderr=err)
+            stdout=asyncio.subprocess.PIPE, stderr=err,
+            env=_node_env(i))
         err.close()
         procs[nid] = p
         line = await asyncio.wait_for(p.stdout.readline(), 90)
@@ -2882,7 +2896,7 @@ async def skewed_soak(n_nodes: int, seconds: float,
     rtsp_ports: dict[str, int] = {}
     rest_ports: dict[str, int] = {}
     here = os.path.abspath(__file__)
-    for nid in node_ids:
+    for i, nid in enumerate(node_ids):
         args = [sys.executable, here, "--cluster-node", "--skewed-child",
                 "--node-id", nid, "--redis-port", str(mini.port)]
         if nid == weak:
@@ -2890,7 +2904,7 @@ async def skewed_soak(n_nodes: int, seconds: float,
                      f"seed={seed},capacity_spoof={weak_cap}"]
         p = await asyncio.create_subprocess_exec(
             *args, stdout=asyncio.subprocess.PIPE,
-            stderr=asyncio.subprocess.DEVNULL)
+            stderr=asyncio.subprocess.DEVNULL, env=_node_env(i))
         procs[nid] = p
         line = await asyncio.wait_for(p.stdout.readline(), 60)
         if not line.startswith(b"NODE_READY"):
@@ -3640,6 +3654,9 @@ def _parse_args(argv: list[str]):
 
 if __name__ == "__main__":
     _ns = _parse_args(sys.argv[1:])
+    # every mode, child modes included, before its first jit
+    from easydarwin_tpu import device as _device
+    _device.enable_compile_cache()
     if _ns.devices > 1:
         # jax backends have not initialized yet (imports above only
         # DEFINE jitted fns) — force the virtual host-device mesh now
