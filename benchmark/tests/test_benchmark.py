@@ -1,0 +1,263 @@
+"""The benchmark's own tests: its files hang together, its arithmetic is
+right on hand-made numbers, its trace reduction gives the known numbers
+on one small recorded trace, its comparison fails the control, and a
+whole run at a debug size on the CPU prints the contract's last line.
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q -p no:cacheprovider
+"""
+
+import importlib
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+sys.path.insert(0, ROOT)
+
+from benchmark import kernels, reduce_trace, reference, stats  # noqa: E402
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+def load(path):
+    with open(os.path.join(ROOT, path)) as f:
+        return json.load(f)
+
+
+BENCH = load("BENCHMARK.json")
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
+def reports(metric, cell):
+    return cell in metric.get("workloads", CELLS)
+
+
+# ------------------------------------------------------------ the files
+@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
+def test_workload_resolves_to_files(cell):
+    conf = next(c for c in BENCH["configs"] if c["name"] == cell["config"])
+    cfg = load(conf["file"])
+    assert cfg["name"] == conf["name"] and cfg["reduced"] == conf["reduced"]
+    assert set(cfg["guarantees"]) == set(reference.GUARANTEES)
+    traffic = load(f"benchmark/traffic/{cell['traffic']}.json")
+    assert traffic["fps_per_source"] > 0 and traffic["warm_frames"] > 0
+    assert cell["chips"] == 1 and len(cell["why"]) <= 200
+    for name in (cell["name"], cell["config"], cell["traffic"]):
+        assert NAME.match(name), name
+    e2e = [m for m in BENCH["end_to_end"] if reports(m, cell["name"])]
+    assert "setup_s" in [m["name"] for m in e2e] and len(e2e) >= 2
+    assert any(reports(m, cell["name"]) for m in BENCH["per_layer"])
+
+
+@pytest.mark.parametrize("metric", BENCH["end_to_end"] + BENCH["per_layer"],
+                         ids=lambda m: m["name"])
+def test_metric_names_units_and_moves(metric):
+    assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
+    assert metric["better"] in ("lower", "higher")
+    assert set(metric.get("workloads", [])) <= set(CELLS)
+    if "moves" not in metric:               # end to end
+        assert 0.01 <= metric["bound"] <= 0.25
+        assert metric["source"] in ("host_clock", "device_trace")
+        return
+    # per layer: moves an end-to-end metric that each of its cells reports
+    moved = next(m for m in BENCH["end_to_end"] if m["name"] == metric["moves"])
+    for cell in metric.get("workloads", CELLS):
+        assert reports(moved, cell), (metric["name"], cell)
+    spec = load(f"benchmark/layer_metrics/{metric['name']}.json")
+    assert spec["name"] == metric["name"]
+    reader = importlib.import_module(
+        f"benchmark.readers.{spec['reader']['kind']}")
+    assert callable(reader.read)
+
+
+def test_a_reader_with_nothing_to_read_returns_nothing():
+    from benchmark import readers
+    ctx = {"m0": {}, "m1": {}, "harness": {}, "trace": None, "peaks": None}
+    for m in BENCH["per_layer"]:
+        spec = load(f"benchmark/layer_metrics/{m['name']}.json")
+        assert readers.read(spec, ctx) is None, m["name"]
+
+
+# ------------------------------------------------------- the arithmetic
+def test_percentile_interpolates_between_order_statistics():
+    v = list(range(1, 101))
+    assert stats.percentile(v, 50) == 50.5
+    assert stats.percentile(v, 95) == pytest.approx(95.05)
+    assert stats.percentile(v, 100) == 100
+    assert stats.percentile([7.0], 95) == 7.0
+    assert stats.percentile([3, 1, 2], 50) == 2
+    # two clusters of equal weight: the median is their midpoint
+    assert stats.percentile([10, 11, 12, 80, 81, 82], 50) == 46.0
+    with pytest.raises(ValueError):
+        stats.percentile([], 50)
+
+
+def test_delay_and_pdv_on_hand_made_stamps():
+    # two flows; due at 0 ms and 100 ms; arrivals in ns
+    due = [0, 0, 100_000_000, 100_000_000]
+    flow_a = stats.flow_delays_ms(
+        [10_000_000, 12_000_000, 115_000_000, 130_000_000], due)
+    flow_b = stats.flow_delays_ms(
+        [83_000_000, 84_000_000, 183_000_000, 283_000_000], due)
+    assert flow_a == [10.0, 12.0, 15.0, 30.0]
+    assert flow_b == [83.0, 84.0, 83.0, 183.0]
+    # PDV: each flow against its own least delay (RFC 5481)
+    assert stats.pdv_ms([flow_a, flow_b]) == [0.0, 2.0, 5.0, 20.0,
+                                              0.0, 1.0, 0.0, 100.0]
+    m = stats.delay_metrics([flow_a, flow_b])
+    assert m["delay_p60_ms"] == pytest.approx(83 + 0.2 * 0)  # 4.2 of 0..7
+    assert m["delay_p95_ms"] == pytest.approx(84 + 0.65 * 99)
+    assert m["pdv_p95_ms"] == pytest.approx(20 + 0.65 * 80)
+    assert stats.pdv_ms([[], [5.0]]) == [0.0]
+
+
+# ------------------------------------------------- the trace reduction
+def test_trace_reduction_on_the_recorded_trace():
+    events = load("benchmark/tests/data/recorded_trace.json")
+    r = reduce_trace.reduce(events)
+    assert r["chips"] == 1
+    assert r["window_s"] == pytest.approx(0.94330042)
+    assert r["busy_s"] == pytest.approx(2.4909e-05)
+    idle = 100.0 * (1 - r["busy_s"] / r["window_s"])
+    assert idle == pytest.approx(99.99735938, abs=1e-6)
+    assert r["device_ops"][0][0] == "program megabatch_window_step"
+    assert r["device_ops"][0][1] == pytest.approx(2.5018e-05)
+    assert len(r["device_ops"]) <= 10 and len(r["idle_gaps"]) <= 10
+    # two gaps between three program executions, the longest first
+    assert [g[1] for g in r["idle_gaps"]] == sorted(
+        (g[1] for g in r["idle_gaps"]), reverse=True)
+    assert r["idle_gaps"][0][0].endswith("-> convert_element_type")
+    mod = r["modules"]["megabatch_window_step"]
+    assert mod["count"] == 2
+    shapes = sorted(kernels.megabatch_shape(p["shapes"])
+                    for p in mod["programs"].values())
+    assert shapes == [(2, 16, 256), (16, 16, 256)]
+    # bytes the shapes need: window + state in, packed params out
+    assert kernels.megabatch_window_step_bytes(2, 16, 256) == (
+        2 * 16 * 100 + 2 * 256 * 6 * 4 + 2 * 1025 * 4)
+    need = kernels.least_seconds("megabatch_window_step", mod,
+                                 {"hbm_bytes_per_s": 819e9},
+                                 "hbm_bytes_per_s")
+    assert need == pytest.approx((23688 + 189504) / 819e9)
+    assert 0 < 100 * need / mod["seconds"] < 100
+
+
+def test_module_name_drops_prefix_and_fingerprint():
+    assert reduce_trace.module_name(
+        "jit_megabatch_window_step(5320593160282135921)"
+    ) == "megabatch_window_step"
+    assert reduce_trace.union_ns([(0, 10), (5, 10), (30, 5)])[0] == 20
+
+
+# ------------------------------------------- the reference, the control
+def _pushed(n=40):
+    from benchmark.loadgen import Source
+    shapes = load("benchmark/configs/relay-16x256.json")["stream"]
+    return Source(0, 7, 4, 1.0, shapes).packets[:n]
+
+
+def test_reference_output_is_judged_clean():
+    pushed = _pushed()
+    flow = reference.reference_flow(pushed, 65530, 0xDEADBEEF, 12345)
+    assert reference.judge_flow(flow, pushed, 65530, 0xDEADBEEF) == {
+        "missing": 0, "out_of_order": 0, "altered": 0, "unannounced": 0}
+    assert flow[0][12:] == pushed[0][12:] and flow[0][:2] == pushed[0][:2]
+    assert int.from_bytes(flow[7][2:4], "big") == (65530 + 7) & 0xFFFF
+
+
+@pytest.mark.parametrize("guarantee", reference.GUARANTEES)
+@pytest.mark.parametrize("seed", [3, 2**31 + 5, 77])
+def test_control_fails_the_comparison(guarantee, seed):
+    """The control: the reference's own output with one stated guarantee
+    broken once.  Some count must pass its limit, 0."""
+    pushed = _pushed()
+    flow = reference.reference_flow(pushed, 100, 0xDEADBEEF, 999)
+    broken = reference.break_guarantee(
+        flow, guarantee, seed, int.from_bytes(pushed[0][8:12], "big"))
+    got = reference.judge_flow(broken, pushed, 100, 0xDEADBEEF)
+    assert sum(got.values()) >= 1, (guarantee, got)
+
+
+def test_a_session_that_announced_nothing_is_not_correct():
+    pushed = _pushed(5)
+    got = reference.judge_flow(pushed, pushed, None, None)
+    assert got["unannounced"] == 1 and got["missing"] == 5
+
+
+# ---------------------------------------------------------- whole runs
+def _run(*extra):
+    """run.py at a debug size on the CPU by name; returns (exit code,
+    last stdout line parsed, stderr)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", "relay-16x256.paced", "--seed", str(2**31 + 11),
+         "--seconds", "4", "--debug-size", "2x32", "--fps", "4", *extra],
+        capture_output=True, text=True, env=env, timeout=600)
+    lines = r.stdout.strip().splitlines()
+    try:
+        last = json.loads(lines[-1])
+    except (IndexError, ValueError):
+        last = None
+    return r.returncode, last, r.stdout[-3000:] + r.stderr[-3000:]
+
+
+def test_debug_run_prints_the_contracts_last_line():
+    rc, last, tail = _run("--trace", "0")
+    assert rc == 0 and last is not None, tail
+    assert list(last)[:5] == ["correct", "attempted", "failed", "metrics",
+                              "device"], tail
+    assert list(last)[-1] == "compared"
+    assert last["correct"] is True and last["failed"] == 0, tail
+    assert last["attempted"] > 0
+    assert last["device"]["platform"] == "cpu"
+    assert set(last["metrics"]) == {
+        m["name"] for m in BENCH["end_to_end"]
+        if reports(m, "relay-16x256.paced")}
+    for m in last["metrics"].values():
+        assert m["value"] > 0 and UNIT.match(m["unit"])
+    assert all(v["value"] <= v["limit"] for v in last["compared"].values())
+    assert "compared stamped_missing: 0 limit 0" in tail
+
+
+def test_full_size_run_refuses_a_cpu():
+    """No --debug-size: a CPU is no result, whatever JAX_PLATFORMS says."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", "relay-1x64.live", "--seed", "1", "--seconds", "1",
+         "--trace", "0"], capture_output=True, text=True, env=env,
+        timeout=600)
+    assert r.returncode != 0
+    assert "NO RESULT" in r.stdout
+    assert not r.stdout.strip().splitlines()[-1].startswith("{")
+
+
+@pytest.mark.parametrize("control", [
+    "fault:seed=5,ingest_drop=0.05",        # every_packet
+    "fault:seed=5,ingest_corrupt=0.05",     # bit_equal
+    "ref:in_order", "ref:header_rewritten"])
+def test_control_run_comes_out_not_correct(control):
+    """The control through a whole run: the program with its own fault
+    path switched on, or the reference with a guarantee broken put in
+    one flow's place."""
+    rc, last, tail = _run("--trace", "0", "--control", control)
+    assert last is not None, tail
+    assert last["correct"] is False, tail
+    assert any(v["value"] > v["limit"] for v in last["compared"].values())
+
+
+def test_timed_path_broken_underneath_comes_out_not_correct():
+    """An answer altered where it is produced (broken_child.py flips a
+    bit of every SSRC on its way into native egress)."""
+    rc, last, tail = _run("--trace", "0", "--child-script",
+                          os.path.join(HERE, "broken_child.py"))
+    assert last is not None, tail
+    assert last["correct"] is False, tail
+    assert last["compared"]["stamped_altered"]["value"] > 0, tail
