@@ -763,8 +763,7 @@ class Smoke:
 
     def record_profile(self, doc: dict) -> None:
         """From ``/api/v1/profile``: the per-pass phase costs (the pace
-        was chosen against them), the SLO's view and the compile
-        notes."""
+        was chosen against them) and the SLO's view."""
         ph = doc.get("phases", {})
         brief = {p: {e: (v["count"], v["mean_ms"], v["p99_ms"])
                      for e, v in engines.items()}
@@ -774,8 +773,6 @@ class Smoke:
         self.facts["phases_count_mean_p99_ms"] = brief
         log(f"phases (count, mean ms, p99 ms): {brief}")
         log(f"SLO status: {doc.get('slo', {}).get('objectives')}")
-        notes = sorted(doc.get("compiles", {}))
-        self.facts["compile_notes"] = notes
 
     def check_error_log(self) -> None:
         path = os.path.join(self.server.log_dir, "error.log")

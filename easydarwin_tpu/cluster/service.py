@@ -384,6 +384,9 @@ class ClusterService:
         # recorded even when the tick aborts on a (real or injected)
         # partition — the timeout path is the expensive one.
         led = obs.LEDGER if obs.LEDGER.enabled else None
+        # filed post hoc through LEDGER.record, so the tick opens its own
+        # pump.cluster_tick span (the other classes' come from unit_start)
+        span = obs.TRACER.open("pump.cluster_tick", "pump") if led else None
         t0_ns = time.monotonic_ns() if led else 0
         rt_mark = ROUNDTRIPS.mark() if led else (0, 0)
         try:
@@ -391,6 +394,7 @@ class ClusterService:
         finally:
             if led:
                 d_ops, d_ns = ROUNDTRIPS.delta_since(rt_mark)
+                obs.TRACER.close(span, items=1, redis_ops=d_ops)
                 due = getattr(self, "_tick_due_ns", t0_ns)
                 led.record(
                     "cluster_tick",

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 
 #: the in-checkout cache directory used when ``JAX_COMPILATION_CACHE_DIR``
 #: is not set.  Fixed on purpose: the path is part of how a later process
@@ -35,6 +36,8 @@ _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 _lock = threading.Lock()
 _listening = False
+#: a cache-hit event precedes the duration event of the same build
+_hit_pending = False
 
 
 class DeviceError(RuntimeError):
@@ -43,16 +46,41 @@ class DeviceError(RuntimeError):
 
 
 def _on_duration(event: str, duration: float, **_kw) -> None:
+    global _hit_pending
     if event == _BACKEND_COMPILE_EVENT:
         from . import obs
         obs.JAX_EXECUTABLES_BUILT.inc()
         obs.JAX_EXECUTABLE_BUILD_SECONDS.inc(float(duration))
+        # post hoc and ring only: XLA's own compile events are already
+        # on the profiler's host plane
+        dur_ns = int(duration * 1e9)
+        obs.TRACER.add("jax.build", time.perf_counter_ns() - dur_ns, dur_ns,
+                       cat="jax", seconds=round(float(duration), 6),
+                       cache_hit=int(_hit_pending))
+        _hit_pending = False
 
 
 def _on_event(event: str, **_kw) -> None:
+    global _hit_pending
     if event == _CACHE_HIT_EVENT:
         from . import obs
         obs.JAX_CACHE_HITS.inc()
+        _hit_pending = True
+
+
+def listen_builds() -> None:
+    """Feed ``jax.monitoring`` into the ``jax_*_total`` counters (once a
+    process).  Every engine calls it when built: the phase histograms
+    keep a compiling pass out by watching ``jax_executables_built_total``
+    across it (``obs.profile.builds``)."""
+    global _listening
+    from jax import monitoring
+
+    with _lock:
+        if not _listening:
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            monitoring.register_event_listener(_on_event)
+            _listening = True
 
 
 def enable_compile_cache() -> str:
@@ -63,19 +91,13 @@ def enable_compile_cache() -> str:
     ``CACHE_DIR``.  The persist threshold is dropped to zero because the
     served kernels are dozens of sub-second pow2 bucket specialisations
     — at JAX's default (1 s) none of them would ever be kept."""
-    global _listening
     import jax
-    from jax import monitoring
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    with _lock:
-        if not _listening:
-            monitoring.register_event_duration_secs_listener(_on_duration)
-            monitoring.register_event_listener(_on_event)
-            _listening = True
+    listen_builds()
     return jax.config.jax_compilation_cache_dir
 
 

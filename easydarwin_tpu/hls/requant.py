@@ -500,10 +500,10 @@ class RequantLadder(RelayOutput):
         # AU, never per packet) lets the ledger subtract it from
         # live_relay and charge the requant class with its own service
         from ..obs.ledger import LEDGER
-        tok = LEDGER.unit_start()
+        tok = LEDGER.unit_start("hls_requant")
         for au in units:
             self._on_unit(au)
-        LEDGER.unit_end(tok, "hls_requant", items=len(units))
+        LEDGER.unit_end(tok, items=len(units))
         return WriteResult.OK
 
     def _latch_ps(self, au: AccessUnit) -> None:

@@ -12,7 +12,6 @@ two output modes:
 from __future__ import annotations
 
 import functools
-import os
 import time
 import warnings
 from dataclasses import dataclass
@@ -22,7 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-from ..obs import PROFILER, TRACER
+from ..device import listen_builds
+from ..obs import PROFILER, TRACER, t0_of
+from ..obs.profile import builds
 from ..ops import fanout as fanout_ops
 from ..ops import gop as gop_ops
 from ..ops.parse import PARSE_PREFIX, parse_packets
@@ -49,9 +50,9 @@ class RelayPipeline:
         #: call — so one Perfetto query selects that session across
         #: pipeline/engine/egress hops.  Unset, spans stay uncorrelated
         self.trace_id: str | None = None
-        #: arg-shape tuples already traced: jit recompiles per shape, and
-        #: a recompile is compile noise, not a phase sample
-        self._traced_shapes: set[tuple] = set()
+        # jit recompiles per arg shape, and a pass that held a build is
+        # compile noise, not a phase sample: builds() tells
+        listen_builds()
         self._step = jax.jit(functools.partial(
             _pipeline_step,
             use_pallas=self.config.use_pallas_parse,
@@ -69,40 +70,40 @@ class RelayPipeline:
         # brackets exactly the work the phases cover (explicit H2D
         # staging + device step incl. block-until-ready), and the
         # profiler's Σ(phases) ≈ total invariant keeps it that way.
-        t0 = time.perf_counter_ns()
+        span_args = {"mode": self.config.mode}
+        tid = trace_id or self.trace_id
+        if tid is not None:
+            span_args["trace_id"] = tid
+        span = TRACER.open("pipeline.step", "tpu", **span_args)
+        t0 = t0_of(span)
         args = (prefix, length, age_ms, out_state, buckets)
         if not PROFILER.enabled:
             # profiler off: the original async-dispatch hot path — no
             # explicit staging, no block-until-ready serialization; the
             # device pass overlaps whatever the caller does next
             out = self._step(*args)
-            dur = time.perf_counter_ns() - t0
+            dur = TRACER.close(span) - t0
             obs.TPU_PASS_SECONDS.observe(dur / 1e9,
                                          stage="pipeline_dispatch")
             self._count_bytes(args, out_state, length)
-            self._trace_span(t0, dur, trace_id)
             return out
-        shape_key = tuple(getattr(a, "shape", ()) for a in args)
-        first = shape_key not in self._traced_shapes   # jit traces per shape
+        built0 = builds()
         staged = jax.device_put(args)
         t_h2d = time.perf_counter_ns()
         out = self._step(*staged)
         t_disp = time.perf_counter_ns()
         jax.block_until_ready(out)
-        t_done = time.perf_counter_ns()
+        t_done = TRACER.close(span)
         # dispatch-side accounting (the host cost the pump loop pays to
         # launch one step, compile excluded after the first trace)
         obs.TPU_PASS_SECONDS.observe((t_disp - t_h2d) / 1e9,
                                      stage="pipeline_dispatch")
         self._count_bytes(args, out_state, length)
-        if first:
-            # the cold trace goes to the compile notes ONLY — never into
-            # the phase histograms, whose p99 would keep the compile
-            # outlier forever (same rule as the fanout engine's latches)
-            self._traced_shapes.add(shape_key)
-            self._note_compile(args, (t_done - t_h2d) / 1e9)
-        else:
-            # the checked total stamps AFTER the bookkeeping above, so
+        if builds() == built0:
+            # (a pass that held a build — the cold trace, a new shape —
+            # stays out of the phase histograms, whose p99 would keep
+            # the compile outlier forever: the fanout engine's rule.)
+            # The checked total stamps AFTER the bookkeeping above, so
             # the Σ(phases) ≈ total invariant guards something real:
             # unphased work creeping into this bracket trips the drift
             # counter once it outgrows the tolerance
@@ -111,7 +112,6 @@ class RelayPipeline:
                 "pipeline", total,
                 {"h2d": t_h2d - t0, "device_step": t_done - t_h2d},
                 check=True)
-        self._trace_span(t0, t_done - t0, trace_id)
         return out
 
     def _count_bytes(self, args, out_state, length) -> None:
@@ -120,33 +120,6 @@ class RelayPipeline:
         if self.config.mode == "headers":
             obs.TPU_HEADERS_RENDERED.inc(out_state.shape[-2]
                                          * length.shape[-1])
-
-    def _trace_span(self, t0: int, dur: int,
-                    trace_id: str | None) -> None:
-        span_args = {"mode": self.config.mode}
-        tid = trace_id or self.trace_id
-        if tid is not None:
-            span_args["trace_id"] = tid
-        TRACER.add("pipeline.step", t0, dur, cat="tpu", **span_args)
-
-    def _note_compile(self, args, compile_s: float) -> None:
-        """First-trace capture: compile wall time always; XLA cost
-        analysis (flops / bytes accessed) only when asked for via
-        ``EDTPU_PROFILE_XLA=1`` — the AOT lower+compile it needs costs a
-        second compilation, wrong for production but right for the
-        attribution deep-dive the flag exists for."""
-        cost = None
-        if os.environ.get("EDTPU_PROFILE_XLA") == "1":
-            try:
-                ca = self._step.lower(*args).compile().cost_analysis()
-                if isinstance(ca, (list, tuple)):
-                    ca = ca[0] if ca else {}
-                cost = {k: float(ca[k]) for k in
-                        ("flops", "bytes accessed") if k in ca}
-            except Exception:
-                cost = None
-        PROFILER.note_compile(f"pipeline.step[{self.config.mode}]",
-                              compile_s, cost)
 
     @property
     def step_fn(self):
@@ -190,7 +163,8 @@ def megabatch_window_step(window, out_state):
     filter state on the pump hot path and is not thread-safe.
     """
     from ..ops.fanout import relay_affine_step_window
-    return relay_affine_step_window(window, out_state)
+    with jax.named_scope("megabatch_window_step"):
+        return relay_affine_step_window(window, out_state)
 
 
 warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
@@ -288,14 +262,15 @@ def fec_parity_window_step(rows: jnp.ndarray,
     nothing (gf_mul(0, ·) = 0), so window padding is free."""
     from ..relay.fec import GF_EXP512, GF_LOG
 
-    log = jnp.asarray(GF_LOG)              # [256] int32 (log[0] sentinel)
-    exp = jnp.asarray(GF_EXP512)           # [512] int32 (no modulo needed)
-    lr = log[rows.astype(jnp.int32)]       # [K, B]
-    lc = log[coeff.astype(jnp.int32)]      # [R, K]
-    prod = exp[lc[:, :, None] + lr[None, :, :]]           # [R, K, B]
-    nz = (rows != 0)[None, :, :] & (coeff != 0)[:, :, None]
-    prod = jnp.where(nz, prod, 0).astype(jnp.uint8)
-    return jax.lax.reduce(prod, np.uint8(0), jax.lax.bitwise_xor, (1,))
+    with jax.named_scope("fec_parity_window_step"):
+        log = jnp.asarray(GF_LOG)          # [256] int32 (log[0] sentinel)
+        exp = jnp.asarray(GF_EXP512)       # [512] int32 (no modulo needed)
+        lr = log[rows.astype(jnp.int32)]   # [K, B]
+        lc = log[coeff.astype(jnp.int32)]  # [R, K]
+        prod = exp[lc[:, :, None] + lr[None, :, :]]           # [R, K, B]
+        nz = (rows != 0)[None, :, :] & (coeff != 0)[:, :, None]
+        prod = jnp.where(nz, prod, 0).astype(jnp.uint8)
+        return jax.lax.reduce(prod, np.uint8(0), jax.lax.bitwise_xor, (1,))
 
 
 def _pipeline_step(prefix, length, age_ms, out_state, buckets, *,

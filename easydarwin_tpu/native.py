@@ -90,6 +90,40 @@ class EdStats(ctypes.Structure):
     _fields_ = [(n, ctypes.c_int64) for n in _STAT_FIELDS]
 
 
+#: scratch for the egress spans' ``syscalls`` arg (pump thread only)
+_SPAN_STATS = EdStats()
+
+
+def _egress_syscalls(lib) -> int:
+    """Send-side syscalls the library has made so far, of every rung."""
+    lib.ed_get_stats(ctypes.byref(_SPAN_STATS))
+    st = _SPAN_STATS
+    return (st.sendmmsg_calls + st.sendto_calls + st.uring_submits
+            + st.stream_writev_calls)
+
+
+def _egress_open(lib, name: str, trace_id: str | None, **args):
+    """Open a ``native.egress`` / ``native.stream_egress`` span
+    (``obs.trace``) around one library call; ``_egress_close`` ends it
+    with what the call sent and, while a profiler session is live (the
+    span is on its host plane), the syscalls it took — two stats reads
+    that an untraced send does not pay."""
+    if trace_id is not None:
+        args["trace_id"] = trace_id
+    span = TRACER.open(name, "native", **args)
+    traced = span is not None and span.tm is not None
+    return span, (_egress_syscalls(lib) if traced else None)
+
+
+def _egress_close(lib, opened, r: int) -> None:
+    span, sys0 = opened
+    if sys0 is not None:
+        TRACER.close(span, sent=int(r), datagrams=max(int(r), 0),
+                     syscalls=_egress_syscalls(lib) - sys0)
+    elif span is not None:
+        TRACER.close(span, sent=int(r), datagrams=max(int(r), 0))
+
+
 class Dest(ctypes.Structure):
     _fields_ = [("ip_be", ctypes.c_uint32), ("port_be", ctypes.c_uint16),
                 ("_pad", ctypes.c_uint16)]
@@ -457,17 +491,15 @@ class UringEgress:
         sc = np.ascontiguousarray(ssrc, np.uint32)
         assert seq.ndim == 2 and seq.shape == ts.shape == sc.shape
         assert seq.shape[1] >= len(dests)
-        t0 = TRACER.begin()
+        opened = _egress_open(self._lib, "native.egress", trace_id,
+                              ops=n_ops, backend="io_uring")
         r = self._lib.ed_uring_send_multi(
             self._h, _u8(ring_data),
             _i32(np.ascontiguousarray(ring_len, np.int32)),
             ring_data.shape[0], ring_data.shape[1],
             _u32(seq), _u32(ts), _u32(sc), seq.shape[0], seq.shape[1],
             dests, len(dests), ops, n_ops)
-        span_args = {"ops": n_ops, "sent": int(r), "backend": "io_uring"}
-        if trace_id is not None:
-            span_args["trace_id"] = trace_id
-        TRACER.end("native.egress", t0, cat="native", **span_args)
+        _egress_close(self._lib, opened, r)
         return int(r)
 
     def stream_send(self, fd: int, ring_data: np.ndarray,
@@ -482,18 +514,15 @@ class UringEgress:
         assert ring_data.dtype == np.uint8 and ring_data.flags.c_contiguous
         slots32 = np.ascontiguousarray(slots, np.int32)
         partial = ctypes.c_int32(0)
-        t0 = TRACER.begin()
+        opened = _egress_open(self._lib, "native.stream_egress", trace_id,
+                              ops=int(len(slots32)), backend="io_uring")
         r = self._lib.ed_uring_stream_send(
             self._h, fd, _u8(ring_data),
             _i32(np.ascontiguousarray(ring_len, np.int32)),
             ring_data.shape[0], ring_data.shape[1],
             seq_off & 0xFFFFFFFF, ts_off & 0xFFFFFFFF, ssrc & 0xFFFFFFFF,
             channel, _i32(slots32), len(slots32), ctypes.byref(partial))
-        span_args = {"ops": int(len(slots32)), "sent": int(r),
-                     "backend": "io_uring"}
-        if trace_id is not None:
-            span_args["trace_id"] = trace_id
-        TRACER.end("native.stream_egress", t0, cat="native", **span_args)
+        _egress_close(self._lib, opened, r)
         return int(r), partial.value
 
     def stream_write(self, fd: int, data) -> int:
@@ -523,17 +552,14 @@ def stream_send(fd: int, ring_data: np.ndarray, ring_len: np.ndarray,
     assert ring_data.dtype == np.uint8 and ring_data.flags.c_contiguous
     slots32 = np.ascontiguousarray(slots, np.int32)
     partial = ctypes.c_int32(0)
-    t0 = TRACER.begin()
+    opened = _egress_open(lib, "native.stream_egress", trace_id,
+                          ops=int(len(slots32)), backend="writev")
     r = lib.ed_stream_send(
         fd, _u8(ring_data), _i32(np.ascontiguousarray(ring_len, np.int32)),
         ring_data.shape[0], ring_data.shape[1],
         seq_off & 0xFFFFFFFF, ts_off & 0xFFFFFFFF, ssrc & 0xFFFFFFFF,
         channel, _i32(slots32), len(slots32), ctypes.byref(partial))
-    span_args = {"ops": int(len(slots32)), "sent": int(r),
-                 "backend": "writev"}
-    if trace_id is not None:
-        span_args["trace_id"] = trace_id
-    TRACER.end("native.stream_egress", t0, cat="native", **span_args)
+    _egress_close(lib, opened, r)
     return int(r), partial.value
 
 
@@ -742,17 +768,15 @@ def fanout_send_multi(fd: int, ring_data: np.ndarray, ring_len: np.ndarray,
     # the param row may be wider than the dest table (fewer real sockets
     # than logical subscribers); ops only reference outs < len(dests)
     assert seq.shape[1] >= len(dests)
-    t0 = TRACER.begin()
+    # gso: 0 = plain sendmmsg, 1 = GSO, 2 = scalar sendto rung
+    opened = _egress_open(lib, "native.egress", trace_id, ops=n_ops,
+                          gso=int(use_gso))
     r = lib.ed_fanout_send_multi(
         fd, _u8(ring_data), _i32(np.ascontiguousarray(ring_len, np.int32)),
         ring_data.shape[0], ring_data.shape[1],
         _u32(seq), _u32(ts), _u32(sc), seq.shape[0], seq.shape[1],
         dests, len(dests), ops, n_ops, int(use_gso))
-    # 0 = plain sendmmsg, 1 = GSO, 2 = scalar sendto rung
-    span_args = {"ops": n_ops, "sent": int(r), "gso": int(use_gso)}
-    if trace_id is not None:
-        span_args["trace_id"] = trace_id
-    TRACER.end("native.egress", t0, cat="native", **span_args)
+    _egress_close(lib, opened, r)
     return r
 
 

@@ -30,6 +30,20 @@ RELAY_INGEST_TO_WIRE = REGISTRY.histogram(
     "packet, by egress engine (native sendmmsg/GSO, device batch-header, "
     "scalar oracle)",
     labels=("engine",), buckets=TIME_BUCKETS)
+#: TIME_BUCKETS with the 10 ms … 1 s regime densified: where the delay a
+#: wake adds beyond the declared hold lives (ISSUE 25), so a p95 read
+#: off the ladder resolves to tens of ms, not to (0.25, 0.5]
+DELAY_BUCKETS = tuple(sorted(set(TIME_BUCKETS) | {
+    0.02, 0.03, 0.04, 0.075, 0.15, 0.2, 0.3, 0.4, 0.6, 0.75}))
+RELAY_DUE_TO_WIRE = REGISTRY.histogram(
+    "relay_due_to_wire_seconds",
+    "Ingest->wire latency net of the output bucket's declared hold "
+    "(bucket index x bucket_delay_ms), clamped at 0, per relayed "
+    "(packet, subscriber) and by egress engine: what the server added "
+    "beyond the delay it was configured to add.  Observed with "
+    "relay_ingest_to_wire_seconds over the same deliveries, so the two "
+    "always have the same count",
+    labels=("engine",), buckets=DELAY_BUCKETS)
 
 # ------------------------------------------------------- phase attribution
 #: per-pass stage decomposition of the relay hot path (obs/profile.py):
@@ -73,6 +87,29 @@ PUMP_DEFERRED_TOTAL = REGISTRY.counter(
     "(megabatch dispatch skipped at the in-flight cap, HLS requant AUs "
     "shed at the admission gate, ...), by work class",
     labels=("work_class",))
+#: the pump loop's own two states (ISSUE 25), from the same clock reads
+#: as the ``pump.wake`` / ``pump.sleep`` spans (obs/trace.py)
+PUMP_LOOP_SECONDS = REGISTRY.counter(
+    "pump_loop_seconds_total",
+    "Wall seconds the pump coroutine spent in each state: wake = from "
+    "_reflect_all's first line to the end of that wake's maintenance "
+    "block, sleep = waiting for ingest or a timer.  The pump is one "
+    "coroutine on the server's event-loop thread, which runs RTSP "
+    "ingest and the REST handlers while the pump waits: wake over the "
+    "sum is the share of that thread's time the pump holds, and sleep "
+    "is the pump waiting, not the thread idle", labels=("state",))
+PUMP_WAKE_SECONDS = REGISTRY.histogram(
+    "pump_wake_seconds",
+    "Duration of one pump wake (the pump.wake span): every stream's "
+    "step, the megabatch harvest and dispatch, deadline scheduling and "
+    "the 1 Hz maintenance block when it ran", buckets=DELAY_BUCKETS)
+PUMP_WAKES = REGISTRY.counter(
+    "pump_wakes_total",
+    "Pump wakes by what ended the sleep before them: ingest (a pusher's "
+    "packet set the wake event), timer (a bucket release or RTO deadline "
+    "on the wheel came due), interval (the reflect interval ran out); a "
+    "pump that never catches up finds the event already set, so its "
+    "timer share falls to 0", labels=("cause",))
 
 # -------------------------------------------------------------- SLO watchdog
 SLO_VIOLATIONS = REGISTRY.counter(
@@ -93,6 +130,14 @@ TPU_PASS_SECONDS = REGISTRY.histogram(
     labels=("stage",), buckets=TIME_BUCKETS)
 TPU_PASSES = REGISTRY.counter(
     "tpu_passes_total", "TpuFanoutEngine.step passes executed")
+ENGINE_OUTPUTS_WALKED = REGISTRY.counter(
+    "engine_outputs_walked_total",
+    "Outputs (subscribers) an engine step looked at, added once per step")
+ENGINE_OUTPUTS_DUE = REGISTRY.counter(
+    "engine_outputs_due_total",
+    "Of the outputs a step looked at, those with at least one packet "
+    "past its bucket's hold and not yet sent (due / walked = the share "
+    "of a wake's per-output work that had anything to do)")
 TPU_PACKETS_SENT = REGISTRY.counter(
     "tpu_packets_sent_total",
     "(packet, subscriber) sends completed by the TPU fan-out engine")

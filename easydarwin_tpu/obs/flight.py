@@ -76,25 +76,41 @@ class FlightRecorder:
 
     # -- span correlation --------------------------------------------
     @staticmethod
-    def _span_summaries(trace_id: str | None, limit: int = 256) -> list[dict]:
+    def _span_index() -> dict[str, list]:
+        """The span ring's records by trace id, oldest first: ONE pass
+        over the ring, shared by every session a ``dump_path`` freezes
+        (257 sessions each scanning the ring made an SLO flag cost the
+        pump thread 0.3 s at the ring's ISSUE 25 size)."""
+        index: dict[str, list] = {}
+        for rec in TRACER.records():
+            args = rec[5]
+            if args and "trace_id" in args:
+                index.setdefault(args["trace_id"], []).append(rec)
+        return index
+
+    @classmethod
+    def _span_summaries(cls, trace_id: str | None, limit: int = 256,
+                        index: dict | None = None) -> list[dict]:
         """Chrome-trace-style summaries of every ring span stamped with
         this session's trace id (newest ``limit``)."""
         if not trace_id:
             return []
+        if index is None:
+            index = cls._span_index()
         out = []
-        for name, cat, t0, dur, tid, args in TRACER.records():
-            if args and args.get("trace_id") == trace_id:
-                s = {"name": name, "cat": cat, "ts_us": t0 / 1000.0,
-                     "dur_us": dur / 1000.0, "tid": tid}
-                extra = {k: v for k, v in args.items() if k != "trace_id"}
-                if extra:
-                    s["args"] = extra
-                out.append(s)
-        return out[-limit:]
+        for name, cat, t0, dur, tid, args in index.get(trace_id, ())[-limit:]:
+            s = {"name": name, "cat": cat, "ts_us": t0 / 1000.0,
+                 "dur_us": dur / 1000.0, "tid": tid}
+            extra = {k: v for k, v in args.items() if k != "trace_id"}
+            if extra:
+                s["args"] = extra
+            out.append(s)
+        return out
 
     # -- dumping ------------------------------------------------------
     def _doc(self, session_id: str, box: _Box, reason: str | None,
-             events: list | None = None) -> dict:
+             events: list | None = None,
+             span_index: dict | None = None) -> dict:
         """``events`` must be a snapshot taken under ``self._lock`` when
         the box is still live (on_event appends concurrently; iterating
         the deque unlocked raises 'deque mutated during iteration')."""
@@ -111,11 +127,11 @@ class FlightRecorder:
             "fence": NODE["fence"],
             "meta": box.meta,
             "events": list(box.ring) if events is None else events,
-            "spans": self._span_summaries(box.trace_id),
+            "spans": self._span_summaries(box.trace_id, index=span_index),
         }
 
-    def dump(self, session_id: str, *, reason: str,
-             keep_live: bool = False) -> dict | None:
+    def dump(self, session_id: str, *, reason: str, keep_live: bool = False,
+             span_index: dict | None = None) -> dict | None:
         """Freeze a session's black box.  Returns the document (None for
         an unregistered session).
 
@@ -153,7 +169,7 @@ class FlightRecorder:
                 return prior
         if box is None:
             return None
-        doc = self._doc(session_id, box, reason, events)
+        doc = self._doc(session_id, box, reason, events, span_index)
         path = None
         node_tag = f"{NODE['id']}_" if NODE["id"] else ""
         try:
@@ -192,9 +208,10 @@ class FlightRecorder:
         with self._lock:
             sids = [sid for sid, box in self._live.items()
                     if box.meta.get("path") == path]
+        index = self._span_index() if sids else None
         return [sid for sid in sids
-                if self.dump(sid, reason=reason,
-                             keep_live=True) is not None]
+                if self.dump(sid, reason=reason, keep_live=True,
+                             span_index=index) is not None]
 
     # -- retrieval ----------------------------------------------------
     def lookup(self, session_id: str) -> dict | None:

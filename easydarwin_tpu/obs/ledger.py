@@ -49,6 +49,7 @@ from collections import deque
 import numpy as np
 
 from .metrics import TIME_BUCKETS, bucket_quantile
+from .trace import TRACER
 
 #: the closed work-class vocabulary (the ``work_class`` label of the
 #: pump families; metrics_lint pins it).  One class per unit the pump
@@ -70,6 +71,9 @@ from .metrics import TIME_BUCKETS, bucket_quantile
 #: ==============  ======================================================
 WORK_CLASSES = ("live_relay", "megabatch", "vod_fill", "dvr_spill",
                 "hls_requant", "fec_parity", "checkpoint", "cluster_tick")
+
+#: each class's span (``obs.trace.SPANS``): a unit is ``pump.<class>``
+_SPAN_OF = {wc: f"pump.{wc}" for wc in WORK_CLASSES}
 
 #: the classes whose units put RTP on the wire — the only consumers of
 #: ``note_queue_age`` (nested fec/requant units closing between a send
@@ -118,6 +122,8 @@ class WorkLedger:
         # stamp and the ledger clock must share an epoch
         self.enabled = os.environ.get("EDTPU_PROFILE", "1") != "0"
         self._clock = clock_ns
+        #: the spans' clock is the ledger's: a unit's two reads serve both
+        self._span_clock = clock_ns is time.perf_counter_ns
         if wait_hist is None or service_hist is None \
                 or deferred_counter is None:
             from . import families
@@ -174,24 +180,33 @@ class WorkLedger:
         self._open = {"t0": now, "dur_ns": 0, "classes": {},
                       "redis_ops": 0, "redis_ns": 0}
 
-    def unit_start(self):
-        """Stamp a unit's start; returns the opaque token ``unit_end``
-        needs, or ``None`` when disabled (``unit_end(None, ...)`` is a
-        no-op, so call sites need no branches of their own)."""
+    def unit_start(self, work_class: str, **span_args):
+        """Open a unit of ``work_class``; returns the opaque token
+        ``unit_end`` needs (it carries the class), or ``None`` when
+        disabled (``unit_end(None)`` is a no-op, so call sites need no
+        branches of their own).  The unit is also the span
+        ``pump.<work_class>`` (``obs.trace``): one bracket, and on the
+        default clock the ledger's service time and the span share its
+        two reads."""
         if not self.enabled:
             return None
-        return (self._clock(), self._nested_acc)
+        span = TRACER.open(_SPAN_OF[work_class], "pump", **span_args)
+        t0 = (span.t0 if span is not None and self._span_clock
+              else self._clock())
+        return (t0, self._nested_acc, span, work_class)
 
-    def unit_end(self, token, work_class: str, *, items: int = 1,
-                 trace_id=None, wait_ns: int | None = None) -> None:
+    def unit_end(self, token, *, items: int = 1, trace_id=None,
+                 wait_ns: int | None = None) -> None:
         """Close a unit: service = elapsed minus any nested class's
         service recorded since ``token``; wait defaults to start minus
         the wake's enqueue stamp (``wait_ns`` overrides for units that
         know their own schedule, e.g. the cluster tick's due time)."""
         if token is None:
             return
-        now = self._clock()
-        t0, nested0 = token
+        t0, nested0, span, work_class = token
+        end = TRACER.close(span, items=items)
+        now = end if span is not None and self._span_clock \
+            else self._clock()
         svc = (now - t0) - (self._nested_acc - nested0)
         if svc < 0:
             svc = 0

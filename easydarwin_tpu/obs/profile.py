@@ -32,9 +32,11 @@ Components:
   ``snapshot()`` ranks the top sessions by wire bytes and by p99
   latency contribution.  Served live at ``admin command=top`` and
   ``GET /api/v1/profile``.
-* **Compile capture** — the first trace of a jitted step notes its
-  compile wall time (and, opportunistically, XLA cost analysis) so a
-  latency spike at t=0 is attributable to compilation, not the wire.
+* **Compiles stay out** — a bracket across which
+  ``jax_executables_built_total`` grew (``builds()``; fed by
+  ``jax.monitoring``, exact) is a compile or a cache load, not a pass:
+  the engines drop it from the phase histograms, so a spike at t=0 is
+  read off ``jax_executable_build_seconds_total``, not off a phase p99.
 * **pprof export** — ``build_pprof()`` folds the existing span ring
   into a gzipped pprof ``Profile`` proto (samples = span count + wall
   ns, stacks = span name under its category), served at
@@ -136,8 +138,6 @@ class PhaseProfiler:
         self.drift_checks = 0
         self.drift_violations = 0
         self.last_drift: dict | None = None
-        #: name → {"compile_s": …, "cost": {...}} (first-trace capture)
-        self.compiles: dict[str, dict] = {}
 
     # -- hot path ----------------------------------------------------------
     def observe(self, phase: str, engine: str, dur_ns: int) -> None:
@@ -211,15 +211,6 @@ class PhaseProfiler:
         st.last_seen = time.time()
         return st
 
-    # -- compile capture ---------------------------------------------------
-    def note_compile(self, name: str, compile_s: float,
-                     cost: dict | None = None) -> None:
-        """First-trace capture: compile wall time + optional XLA cost
-        analysis (flops/bytes) for one jitted step."""
-        if name not in self.compiles:
-            self.compiles[name] = {"compile_s": round(compile_s, 6),
-                                   **({"cost": cost} if cost else {})}
-
     # -- read side ---------------------------------------------------------
     def top_offender(self, max_age_s: float = 120.0) -> str | None:
         """Session path with the worst attributed p99 latency among
@@ -243,7 +234,7 @@ class PhaseProfiler:
     def snapshot(self, top_n: int = 5) -> dict:
         """The live ``command=top`` / ``GET /api/v1/profile`` document:
         per-phase summaries (by engine) + top sessions by wire bytes and
-        by p99 latency contribution + drift/compile notes."""
+        by p99 latency contribution + drift notes."""
         phases: dict[str, dict] = {}
         # dict() snapshot: a concurrent pass may add a label child
         for key, st in sorted(dict(self._hist._states).items()):
@@ -284,7 +275,6 @@ class PhaseProfiler:
             "drift": {"checks": self.drift_checks,
                       "violations": self.drift_violations,
                       "last": self.last_drift},
-            "compiles": self.compiles,
         }
 
     def clear(self) -> None:
@@ -292,11 +282,43 @@ class PhaseProfiler:
             self._sessions.clear()
         self.drift_checks = self.drift_violations = 0
         self.last_drift = None
-        self.compiles.clear()
 
 
 #: process-wide profiler every instrumented engine records into
 PROFILER = PhaseProfiler()
+
+
+def builds() -> float:
+    """Executables this process has built so far (compiled, or loaded
+    from the persistent cache).  A bracket across which this grew held a
+    build and stays out of the phase histograms."""
+    return families.JAX_EXECUTABLES_BUILT.total()
+
+
+def observe_wire(engine: str, lat_s: np.ndarray, runs,
+                 delay_ms: int) -> None:
+    """One pass's delivered (packet, subscriber) latencies into both
+    wire histograms.  ``lat_s`` is ingest→wire in delivery order;
+    ``runs`` is ``(count, bucket index)`` per run of consecutive
+    deliveries to one output, whose declared hold is bucket index ×
+    ``delay_ms``.  The ONE place the pair is observed, so
+    ``relay_due_to_wire_seconds`` and ``relay_ingest_to_wire_seconds``
+    always have the same count.  ``lat_s`` is CONSUMED: the hold comes
+    off it in place, one slice per run of equal buckets and no second
+    array of its size — call this after its other readers."""
+    families.RELAY_INGEST_TO_WIRE.observe_many(lat_s, engine=engine)
+    step = delay_ms / 1e3
+    lo = hi = cur = 0
+    for n, b in runs:
+        if b != cur:
+            if cur:
+                lat_s[lo:hi] -= cur * step
+            lo, cur = hi, b
+        hi += n
+    if cur:
+        lat_s[lo:hi] -= cur * step
+    np.maximum(lat_s, 0.0, out=lat_s)
+    families.RELAY_DUE_TO_WIRE.observe_many(lat_s, engine=engine)
 
 
 # ---------------------------------------------------------------- pprof

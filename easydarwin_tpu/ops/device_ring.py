@@ -38,34 +38,42 @@ def init_ring(capacity: int, width: int = PARSE_PREFIX) -> RingState:
         head=jnp.zeros((), dtype=jnp.int32))
 
 
+# Each served jitted step has a name that is its own, as the program's
+# name and as a ``jax.named_scope`` around its body: a profiler trace
+# says ``device_ring_append``, not ``append`` (ISSUE 25).
+
 @functools.partial(jax.jit, donate_argnums=(0,))
-def append(state: RingState, new_prefix: jnp.ndarray,
-           new_length: jnp.ndarray, new_arrival: jnp.ndarray,
-           n_new: jnp.ndarray) -> RingState:
+def device_ring_append(state: RingState, new_prefix: jnp.ndarray,
+                       new_length: jnp.ndarray, new_arrival: jnp.ndarray,
+                       n_new: jnp.ndarray) -> RingState:
     """Append up to ``new_prefix.shape[0]`` packets (first ``n_new`` valid).
 
     The batch is written at ``head % C`` with wraparound handled by a double
     dynamic_update_slice (split at the seam).  Donated: XLA reuses the HBM
     buffers in place.
     """
-    C = state.prefix.shape[0]
-    B = new_prefix.shape[0]
-    pos = state.head % C
-    idx = (pos + jnp.arange(B, dtype=jnp.int32)) % C
-    keep = jnp.arange(B, dtype=jnp.int32) < n_new
-    # scatter rows (B is small; scatter handles the seam uniformly)
-    prefix = state.prefix.at[idx].set(
-        jnp.where(keep[:, None], new_prefix, state.prefix[idx]))
-    length = state.length.at[idx].set(
-        jnp.where(keep, new_length, state.length[idx]))
-    arrival = state.arrival.at[idx].set(
-        jnp.where(keep, new_arrival, state.arrival[idx]))
-    return RingState(prefix, length, arrival, state.head + n_new)
+    with jax.named_scope("device_ring_append"):
+        C = state.prefix.shape[0]
+        B = new_prefix.shape[0]
+        pos = state.head % C
+        idx = (pos + jnp.arange(B, dtype=jnp.int32)) % C
+        keep = jnp.arange(B, dtype=jnp.int32) < n_new
+        # scatter rows (B is small; scatter handles the seam uniformly)
+        prefix = state.prefix.at[idx].set(
+            jnp.where(keep[:, None], new_prefix, state.prefix[idx]))
+        length = state.length.at[idx].set(
+            jnp.where(keep, new_length, state.length[idx]))
+        arrival = state.arrival.at[idx].set(
+            jnp.where(keep, new_arrival, state.arrival[idx]))
+        return RingState(prefix, length, arrival, state.head + n_new)
+
+
+append = device_ring_append
 
 
 @jax.jit
-def query(state: RingState, out_state: jnp.ndarray,
-          now_ms: jnp.ndarray) -> dict:
+def device_ring_query(state: RingState, out_state: jnp.ndarray,
+                      now_ms: jnp.ndarray) -> dict:
     """Run the affine relay step over the resident window.
 
     Returns the ``relay_affine_step`` outputs plus the newest keyframe as an
@@ -74,15 +82,19 @@ def query(state: RingState, out_state: jnp.ndarray,
     """
     from .fanout import relay_affine_step
 
-    C = state.prefix.shape[0]
-    res = relay_affine_step(state.prefix, state.length, out_state)
-    # slot index → absolute id: ids in [head-C, head); slot s holds id
-    # head - ((head - s - 1) % C) - 1
-    slots = jnp.arange(C, dtype=jnp.int32)
-    abs_id = state.head - ((state.head - slots - 1) % C) - 1
-    valid = (abs_id >= 0) & (abs_id < state.head) & (state.length > 0)
-    kf = res["keyframe_first"] & valid
-    newest_kf_abs = jnp.max(jnp.where(kf, abs_id, -1))
-    age = jnp.asarray(now_ms, jnp.int32) - state.arrival
-    return {**res, "abs_id": abs_id, "valid": valid,
-            "newest_keyframe_abs": newest_kf_abs, "age_ms": age}
+    with jax.named_scope("device_ring_query"):
+        C = state.prefix.shape[0]
+        res = relay_affine_step(state.prefix, state.length, out_state)
+        # slot index → absolute id: ids in [head-C, head); slot s holds id
+        # head - ((head - s - 1) % C) - 1
+        slots = jnp.arange(C, dtype=jnp.int32)
+        abs_id = state.head - ((state.head - slots - 1) % C) - 1
+        valid = (abs_id >= 0) & (abs_id < state.head) & (state.length > 0)
+        kf = res["keyframe_first"] & valid
+        newest_kf_abs = jnp.max(jnp.where(kf, abs_id, -1))
+        age = jnp.asarray(now_ms, jnp.int32) - state.arrival
+        return {**res, "abs_id": abs_id, "valid": valid,
+                "newest_keyframe_abs": newest_kf_abs, "age_ms": age}
+
+
+query = device_ring_query

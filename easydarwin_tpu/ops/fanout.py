@@ -186,11 +186,12 @@ def relay_affine_step_window(window: jnp.ndarray,
     ``out_state``: [N_SRC, S, STATE_COLS] uint32 — subscriber state, kept
     device-resident by the caller (it changes on subscribe/unsubscribe, not
     per window, so it should never ride the per-window upload)."""
-    prefix = window[:, :, :96]
-    lb = window[:, :, 96:].astype(jnp.uint32)
-    length = (lb[..., 0] | (lb[..., 1] << 8) | (lb[..., 2] << 16)
-              | (lb[..., 3] << 24)).astype(jnp.int32)
-    return relay_affine_step_packed(prefix, length, out_state)
+    with jax.named_scope("relay_affine_step_window"):
+        prefix = window[:, :, :96]
+        lb = window[:, :, 96:].astype(jnp.uint32)
+        length = (lb[..., 0] | (lb[..., 1] << 8) | (lb[..., 2] << 16)
+                  | (lb[..., 3] << 24)).astype(jnp.int32)
+        return relay_affine_step_packed(prefix, length, out_state)
 
 
 def unpack_affine(packed, n_sub: int):
@@ -219,16 +220,18 @@ def relay_batch_step(prefix: jnp.ndarray, length: jnp.ndarray,
     from .gop import newest_keyframe
     from .parse import parse_packets
 
-    fields = parse_packets(prefix, length)
-    headers = fanout_headers(prefix[:, :2], fields["seq"], fields["timestamp"],
-                             out_state)
-    mask = eligibility(age_ms, bucket_of_output, bucket_delay_ms)
-    valid = (length > 0)
-    sendable = (length >= 12)      # runts are never relayed (skipped host-side)
-    return {
-        "headers": headers,
-        "mask": mask & sendable[None, :],
-        "keyframe_first": fields["keyframe_first"],
-        "newest_keyframe": newest_keyframe(fields["keyframe_first"], valid),
-        "frame_last": fields["frame_last"],
-    }
+    with jax.named_scope("relay_batch_step"):
+        fields = parse_packets(prefix, length)
+        headers = fanout_headers(prefix[:, :2], fields["seq"],
+                                 fields["timestamp"], out_state)
+        mask = eligibility(age_ms, bucket_of_output, bucket_delay_ms)
+        valid = (length > 0)
+        sendable = (length >= 12)  # runts are never relayed (skipped host-side)
+        return {
+            "headers": headers,
+            "mask": mask & sendable[None, :],
+            "keyframe_first": fields["keyframe_first"],
+            "newest_keyframe": newest_keyframe(fields["keyframe_first"],
+                                               valid),
+            "frame_last": fields["frame_last"],
+        }

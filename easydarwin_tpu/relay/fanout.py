@@ -21,7 +21,9 @@ import time
 import numpy as np
 
 from .. import obs
-from ..obs import PROFILER, TRACER
+from ..device import listen_builds
+from ..obs import PROFILER, TRACER, t0_of
+from ..obs.profile import builds
 from ..ops import device_ring
 from ..ops import fanout as fanout_ops
 from ..ops import parse as parse_ops
@@ -180,13 +182,19 @@ class TpuFanoutEngine:
         # reports the merged dict once per engine
         self._pass_phases: dict[tuple[str, str], int] = {}
         self._pass_wire_bytes = 0
-        # first-trace latches PER JIT SHAPE: a cold pass's compile goes
-        # to the profiler's compile notes, NOT the phase histograms —
-        # one 100 ms+ outlier would own every phase mean/p99 forever.
-        # Keyed by the padded shapes because jax re-traces when a
-        # session grows past a power-of-two pad, and that recompile is
-        # just as much compile as the first one
-        self._traced_shapes: set[tuple] = set()
+        #: outputs this pass walked / those of them with a packet past
+        #: its hold (engine_outputs_*_total, added once per step)
+        self._pass_walked = 0
+        self._pass_due = 0
+        self._profiled = False
+        #: ``trace_id`` of the stream being stepped, on every child span
+        self._span_args: dict = {}
+        # a bracket that held an XLA build (a cold pass's compile, or a
+        # re-trace when a session grows past a power-of-two pad) stays
+        # out of the phase histograms — one 100 ms+ outlier would own
+        # every phase mean/p99 forever.  jax_executables_built_total
+        # (obs.profile.builds) says exactly when that happened
+        listen_builds()
 
     # -- helpers -----------------------------------------------------------
     def _native_ok(self) -> bool:
@@ -321,16 +329,39 @@ class TpuFanoutEngine:
         key = (engine, phase)
         self._pass_phases[key] = self._pass_phases.get(key, 0) + dur_ns
 
+    def _open(self, name: str, **args):
+        """Open one child span of ``engine.step`` (``obs.trace``)."""
+        return TRACER.open(name, "tpu", **self._span_args, **args)
+
+    def _close(self, span, phase: str | None = None, engine: str = "native",
+               built0: float | None = None, **args) -> int:
+        """Close a child span and file its interval as ``phase``: the
+        span and the phase histogram share the bracket's two clock
+        reads.  ``built0``: ``builds()`` at open — a bracket that held a
+        build files no phase.  Returns the end instant."""
+        end = TRACER.close(span, **args)
+        if (phase is not None and span is not None and self._profiled
+                and (built0 is None or builds() == built0)):
+            self._phase_add(phase, end - span.t0, engine)
+        return end
+
     def step(self, stream: RelayStream, now_ms: int) -> int:
-        t0 = time.perf_counter_ns()
+        self._span_args = ({} if stream.trace_id is None
+                           else {"trace_id": stream.trace_id})
+        step_span = self._open("engine.step")
+        t0 = t0_of(step_span)
         ring = stream.rtp_ring
         flat = self._flat_outputs(stream)
         if not flat or len(ring) == 0:
+            TRACER.close(step_span, outputs=len(flat), sent=0)
             return 0
-        profiled = PROFILER.enabled
+        profiled = self._profiled = PROFILER.enabled
         self._pass_phases = {}
         self._pass_wire_bytes = 0
+        self._pass_walked = self._pass_due = 0
+        tok = self._open("engine.prime")
         self._prime(stream, flat, now_ms)
+        TRACER.close(tok)
         fast: list[tuple[RelayOutput, int]] = []
         tcp: list[tuple[RelayOutput, int]] = []
         slow: list[tuple[RelayOutput, int]] = []
@@ -348,10 +379,11 @@ class TpuFanoutEngine:
         if slow:
             sent += self._batch_header_step(stream, slow, now_ms)
         # RTCP relay + SR origination, identical to the scalar path
-        if profiled:
-            pr = time.perf_counter_ns()
-            stream.relay_rtcp(now_ms)
-            dt = time.perf_counter_ns() - pr
+        tok = self._open("engine.rtcp")
+        stream.relay_rtcp(now_ms)
+        end = TRACER.close(tok)
+        if profiled and tok is not None:
+            dt = end - tok.t0
             # file one slice per engine actually exercised this pass,
             # splitting the bracket so a mixed pass neither hides the
             # batch path's share under "native" nor double-counts the
@@ -366,14 +398,16 @@ class TpuFanoutEngine:
                                 dt - share * (len(engines) - 1)
                                 if i == len(engines) - 1 else share,
                                 engine=e)
-        else:
-            stream.relay_rtcp(now_ms)
         stream.stats.packets_out += sent
         self.steps += 1
         self.packets_sent += sent
-        dur = time.perf_counter_ns() - t0
+        dur = TRACER.close(step_span, sent=sent, outputs=len(flat),
+                           due_outputs=self._pass_due) - t0
         obs.TPU_PASS_SECONDS.observe(dur / 1e9, stage="engine_step")
         obs.TPU_PASSES.inc()
+        obs.ENGINE_OUTPUTS_WALKED.inc(self._pass_walked)
+        if self._pass_due:
+            obs.ENGINE_OUTPUTS_DUE.inc(self._pass_due)
         if sent:
             obs.TPU_PACKETS_SENT.inc(sent)
         if profiled and self._pass_phases:
@@ -387,10 +421,6 @@ class TpuFanoutEngine:
                     wire_bytes=self._pass_wire_bytes if first_slice else 0,
                     count_pass=first_slice)
                 first_slice = False
-        span_args = {"sent": sent, "outputs": len(flat)}
-        if stream.trace_id is not None:
-            span_args["trace_id"] = stream.trace_id
-        TRACER.add("engine.step", t0, dur, cat="tpu", **span_args)
         return sent
 
     # -- native fast path --------------------------------------------------
@@ -404,7 +434,10 @@ class TpuFanoutEngine:
 
     def _ring_sync(self, ring, now_ms: int) -> None:
         """Append packets the device ring has not seen yet (O(new) H2D,
-        async dispatch — nothing blocks until a params refresh fetches)."""
+        async dispatch — nothing blocks until a params refresh fetches).
+        Staging + the async append dispatch are the pass's host-side
+        H2D cost (the device-side copy overlaps later phases): callers
+        bracket it as ``engine.ring_sync`` / ``h2d``."""
         if self._dring is None:
             self._dring = device_ring.init_ring(ring.capacity)
             self._dring_appended = self._dring_base = max(
@@ -419,7 +452,6 @@ class TpuFanoutEngine:
         n_new = ring.head - self._dring_appended
         if n_new <= 0:
             return
-        t_h2d = time.perf_counter_ns() if PROFILER.enabled else 0
         ids, lengths, _f = ring.window_meta(self._dring_appended, n_new)
         b_pad = _pow2(len(ids), 16)
         prefix = np.zeros((b_pad, self.prefix_width), np.uint8)
@@ -437,16 +469,6 @@ class TpuFanoutEngine:
         self.dring_appends += 1
         self.h2d_appended_bytes += b_pad * (self.prefix_width + 8)
         obs.TPU_H2D_BYTES.inc(b_pad * (self.prefix_width + 8))
-        if t_h2d:
-            # staging + async append dispatch — the pass's host-side H2D
-            # cost (the device-side copy overlaps later phases)
-            dur = time.perf_counter_ns() - t_h2d
-            shape_key = ("append", b_pad)
-            if shape_key not in self._traced_shapes:
-                self._traced_shapes.add(shape_key)
-                PROFILER.note_compile("device_ring.append", dur / 1e9)
-            else:
-                self._phase_add("h2d", dur)
 
     def _device_params(self, fast, ring, now_ms: int):
         """Affine egress params from the device step over the RESIDENT
@@ -485,9 +507,14 @@ class TpuFanoutEngine:
             # fallback.  The resident ring was not synced this pass
             # (the scheduler owns staging), so catch it up lazily first.
             obs.MEGABATCH_FALLBACK.inc()
+            tok = self._open("engine.ring_sync")
+            built0 = builds()
             self._ring_sync(ring, now_ms)
-        t0 = time.perf_counter_ns()
+            self._close(tok, "h2d", built0=built0)
         S = len(fast)
+        tok = self._open("engine.params", outputs=S)
+        t0 = t0_of(tok)
+        built0 = builds()
         s_pad = _pow2(S, 8)
         state = np.zeros((s_pad, fanout_ops.STATE_COLS), np.uint32)
         state[:S] = np.asarray(
@@ -505,16 +532,10 @@ class TpuFanoutEngine:
         ssrc = np.asarray(res["ssrc"])[None, :S]
         chan = np.asarray(res["chan"])[None, :S]
         kf_abs = int(res["newest_keyframe_abs"])
-        t_d2h = time.perf_counter_ns()
-        if PROFILER.enabled:
-            shape_key = ("query", s_pad)
-            if shape_key not in self._traced_shapes:
-                self._traced_shapes.add(shape_key)
-                PROFILER.note_compile("device_ring.query",
-                                      (t_d2h - t0) / 1e9)
-            else:
-                self._phase_add("device_step", t_dev - t0)
-                self._phase_add("d2h", t_d2h - t_dev)
+        t_d2h = TRACER.close(tok)
+        if PROFILER.enabled and builds() == built0:
+            self._phase_add("device_step", t_dev - t0)
+            self._phase_add("d2h", t_d2h - t_dev)
         self.last_newest_keyframe = (self._dring_base + kf_abs
                                      if kf_abs >= 0 else -1)
         self._params = (np.ascontiguousarray(seq_off),
@@ -539,25 +560,27 @@ class TpuFanoutEngine:
         ONE device param pass (the affine rewrite plus the interleave
         channel column ride the same query)."""
         ring = stream.rtp_ring
-        t_win = time.perf_counter_ns() if PROFILER.enabled else 0
+        # extracting the host window view is part of staging it: one
+        # h2d bracket over the view and the device-ring append
+        tok = self._open("engine.ring_sync")
+        built0 = builds()
         combined = fast + tcp
         start = min(o.bookmark for o, _ in combined)
         ids, lengths, _flags = ring.window_meta(start, ring.head - start)
         if len(ids) == 0:
+            TRACER.close(tok)
             return 0
         start = int(ids[0])                 # window_meta clamps to tail
         idx = (ids % ring.capacity).astype(np.int32)
         arrivals = ring.arrival[idx]        # nondecreasing (ingest clock)
         valid = lengths >= 12
-        if t_win:
-            # extracting the host window view is part of staging it
-            self._phase_add("h2d", time.perf_counter_ns() - t_win)
         if not self.megabatch_owned:
             # scheduler-owned streams skip the per-wake device append:
             # the megabatch's stacked staging replaces it (the resident
             # ring catches up lazily if a per-stream query is ever
             # needed again)
             self._ring_sync(ring, now_ms)
+        self._close(tok, "h2d", built0=built0)
         # counterfactual H2D of a design that re-stages the device's full
         # classification window every pass (what keeping the window fresh
         # without a resident ring costs); h2d_appended_bytes is the O(new)
@@ -588,10 +611,11 @@ class TpuFanoutEngine:
         # wire — per-output span selection, the scatter op list, and the
         # native sendmmsg/GSO calls — is the egress stage (leaving the
         # op-list numpy unphased put Σ(phases) ~15% under the pass total)
-        t_egress = time.perf_counter_ns() if PROFILER.enabled else 0
+        egress = self._open("engine.egress")
         # per-output eligible spans (numpy slices, no per-op Python)
         per_out = []                        # (out, hi, pids, slots, lens)
         total = 0
+        due = 0                             # outputs with a packet to send
         for s, (out, b_idx) in enumerate(fast):
             lo = max(out.bookmark - start, 0)
             hi = int(np.searchsorted(arrivals, now_ms - b_idx * delay,
@@ -599,14 +623,19 @@ class TpuFanoutEngine:
             if hi <= lo:
                 per_out.append((out, None, None, None, None))
                 continue
+            due += 1
             sel = valid[lo:hi]
             per_out.append((out, hi, ids[lo:hi][sel], idx[lo:hi][sel],
                             lengths[lo:hi][sel]))
             total += int(sel.sum())
+        self._pass_walked += len(fast)
+        self._pass_due += due
         if total == 0:
             for out, hi, _p, _s, _l in per_out:
                 if hi is not None:          # runt-only span: skip past it
                     out.bookmark = start + hi
+            TRACER.close(egress, outputs=len(fast), due_outputs=due,
+                         sent=0)
             return 0
         ops_np = np.empty((total, 2), np.int32)
         pos = 0
@@ -702,20 +731,21 @@ class TpuFanoutEngine:
         # the packets are ON THE WIRE here: latency stamps below use this
         # instant, not a fresh read after the accounting walk (which
         # would bill our own bookkeeping to the network)
-        wire_ns = time.perf_counter_ns()
-        if t_egress:
-            # every native send this pass (op-list build, backend try,
-            # lower-rung fallback, GSO remainder retry) — the Python-side
-            # bracket; csrc's ed_stats.send_ns carries the in-library
-            # half.  Filed under the BACKEND's phase so per-pass egress
-            # cost is comparable across rungs on one dashboard
-            self._phase_add("egress_io_uring"
-                            if used_backend == "io_uring"
-                            else "egress_native", wire_ns - t_egress)
+        # every native send this pass (op-list build, backend try,
+        # lower-rung fallback, GSO remainder retry) — the Python-side
+        # bracket; csrc's ed_stats.send_ns carries the in-library
+        # half.  Filed under the BACKEND's phase so per-pass egress
+        # cost is comparable across rungs on one dashboard
+        wire_ns = self._close(
+            egress, "egress_io_uring" if used_backend == "io_uring"
+            else "egress_native", outputs=len(fast), due_outputs=due,
+            sent=int(r))
         # bookmark/stat accounting, exact under partial (EAGAIN) sends
+        account = self._open("engine.account")
         taken = 0
         hard_consumed = False
         sent_slots: list[np.ndarray] = []   # → ingest→wire histogram
+        hold_runs: list[tuple] = []         # (deliveries, bucket) of each
         # audience aggregates (obs/audience.py): assembled inside this
         # existing accounting walk, applied as ONE vectorized column
         # pass below; disabled = one attribute check
@@ -727,7 +757,8 @@ class TpuFanoutEngine:
         a_first: list[int] = []
         a_last: list[int] = []
         a_slots: list[np.ndarray] = []
-        for (out, hi, pids, slots, lens), n in zip(per_out, counts):
+        for (out, hi, pids, slots, lens), n, (_o, b_idx) in zip(
+                per_out, counts, fast):
             k = min(max(r - taken, 0), n)
             taken += n
             if n == 0:
@@ -754,6 +785,7 @@ class TpuFanoutEngine:
                 out.payload_octets += sent_bytes - 12 * k
                 self._pass_wire_bytes += sent_bytes
                 sent_slots.append(slots[:k])
+                hold_runs.append((k, b_idx))
                 if ablk is not None:
                     row = getattr(out, "audience_row", -1)
                     if row >= 0:
@@ -776,12 +808,14 @@ class TpuFanoutEngine:
             all_slots = (sent_slots[0] if len(sent_slots) == 1
                          else np.concatenate(sent_slots))
             lat_s = (wire_ns - ring.arrival_ns[all_slots]) / 1e9
-            obs.RELAY_INGEST_TO_WIRE.observe_many(lat_s, engine="native")
             if obs.LEDGER.enabled:
                 obs.LEDGER.note_queue_age(float(lat_s.max()), lat_s.size)
             # per-session attribution (top-by-p99 in command=top)
             PROFILER.account_latency(stream.session_path, lat_s)
+            # last: it takes the hold off lat_s in place
+            obs.observe_wire("native", lat_s, hold_runs, delay)
         self.native_sent += r
+        TRACER.close(account)
         return int(r)
 
     # -- interleaved-TCP fast path (ISSUE 14) ------------------------------
@@ -848,10 +882,12 @@ class TpuFanoutEngine:
         from .. import native
         ring = stream.rtp_ring
         delay = stream.settings.bucket_delay_ms
-        t_egress = time.perf_counter_ns() if PROFILER.enabled else 0
+        egress = self._open("engine.egress")
         backend = self.stream_backend()
         sent = 0
+        due = 0
         sent_slots: list[np.ndarray] = []
+        hold_runs: list[tuple] = []         # (deliveries, bucket) of each
         # audience aggregates — same ONE-vectorized-pass discipline as
         # the UDP scatter (obs/audience.py)
         aud = obs.AUDIENCE
@@ -884,6 +920,7 @@ class TpuFanoutEngine:
                                      side="right"))
             if hi <= lo:
                 continue
+            due += 1
             sel = valid[lo:hi]
             pids = ids[lo:hi][sel]
             slots = np.ascontiguousarray(idx[lo:hi][sel])
@@ -955,6 +992,7 @@ class TpuFanoutEngine:
                 self._pass_wire_bytes += nbytes
                 sent += k
                 sent_slots.append(slots[:k])
+                hold_runs.append((k, b_idx))
                 obs.TCP_EGRESS_PACKETS.inc(k, backend=used)
                 obs.TCP_EGRESS_BYTES.inc(nbytes + 4 * k, backend=used)
                 if ablk is not None:
@@ -966,25 +1004,29 @@ class TpuFanoutEngine:
                         a_first.append(int(pids[0]))
                         a_last.append(int(pids[k - 1]))
                         a_slots.append(slots[:k])
-        wire_ns = time.perf_counter_ns()
+        self._pass_walked += len(tcp)
+        self._pass_due += due
+        wire_ns = self._close(
+            egress, "egress_io_uring" if backend == "io_uring"
+            else "egress_native", outputs=len(tcp), due_outputs=due,
+            sent=sent)
+        account = self._open("engine.account")
         if a_rows:
             a_cat = (a_slots[0] if len(a_slots) == 1
                      else np.concatenate(a_slots))
             aud.note_pass(ablk, a_rows, a_pkts, a_byts, a_first, a_last,
                           (wire_ns - ring.arrival_ns[a_cat]) / 1e9,
                           wire_ns)
-        if t_egress:
-            self._phase_add("egress_io_uring" if backend == "io_uring"
-                            else "egress_native", wire_ns - t_egress)
         if sent_slots:
             all_slots = (sent_slots[0] if len(sent_slots) == 1
                          else np.concatenate(sent_slots))
             lat_s = (wire_ns - ring.arrival_ns[all_slots]) / 1e9
-            obs.RELAY_INGEST_TO_WIRE.observe_many(lat_s, engine="native")
             if obs.LEDGER.enabled:
                 obs.LEDGER.note_queue_age(float(lat_s.max()), lat_s.size)
             PROFILER.account_latency(stream.session_path, lat_s)
+            obs.observe_wire("native", lat_s, hold_runs, delay)
         self.native_sent += sent
+        TRACER.close(account)
         return sent
 
     # -- batch-header path (TCP/meta/thinned outputs) ----------------------
@@ -998,7 +1040,7 @@ class TpuFanoutEngine:
         ids, data, lengths, _flags = ring.window_arrays(start, ring.head - start)
         if len(ids) == 0:
             return 0
-        t_h2d = time.perf_counter_ns() if PROFILER.enabled else 0
+        stage = self._open("engine.ring_sync")
         idx = ids % ring.capacity
         n = len(ids)
         # pow2-pad the window axis (the ONE bucket-shape rounding rule):
@@ -1018,27 +1060,21 @@ class TpuFanoutEngine:
         age[:n] = (now_ms - ring.arrival[idx]).astype(np.int32)
         state = fanout_ops.pack_output_state([o for o, _ in flat])
         buckets = np.array([b for _, b in flat], dtype=np.int32)
+        self._close(stage, "h2d", engine="batch")
 
-        t_dev = time.perf_counter_ns() if t_h2d else 0
+        # relay_batch_step re-traces per (window, outputs) shape: a
+        # bracket that held the build files no phase
+        tok = self._open("engine.params", outputs=len(flat))
+        built0 = builds()
         res = fanout_ops.relay_batch_step(
             prefix, lens_p, age, state, buckets,
             np.int32(stream.settings.bucket_delay_ms))
-        t_d2h = time.perf_counter_ns() if t_h2d else 0
+        t_d2h = time.perf_counter_ns()
         headers = np.asarray(res["headers"])     # blocks: the D2H wait
-        if t_h2d:
-            self._phase_add("h2d", t_dev - t_h2d, engine="batch")
-            shape_key = ("batch", p_pad, self.prefix_width, len(flat))
-            if shape_key not in self._traced_shapes:
-                # relay_batch_step re-traces per (window, outputs) shape
-                self._traced_shapes.add(shape_key)
-                PROFILER.note_compile(
-                    "relay_batch_step",
-                    (time.perf_counter_ns() - t_dev) / 1e9)
-            else:
-                self._phase_add("device_step", t_d2h - t_dev,
-                                engine="batch")
-                self._phase_add("d2h", time.perf_counter_ns() - t_d2h,
-                                engine="batch")
+        end = TRACER.close(tok)
+        if self._profiled and tok is not None and builds() == built0:
+            self._phase_add("device_step", t_d2h - tok.t0, engine="batch")
+            self._phase_add("d2h", end - t_d2h, engine="batch")
         # the whole PADDED window's prefixes+metadata crossed to the
         # device and the [S, P_pad, 12] header block crossed back; only
         # the n real rows count as rendered headers (padding rows are
@@ -1048,8 +1084,11 @@ class TpuFanoutEngine:
         obs.TPU_D2H_BYTES.inc(headers.nbytes)
         obs.TPU_HEADERS_RENDERED.inc(headers.shape[0] * n)
 
+        egress = self._open("engine.egress")
         sent = 0
+        due = 0
         lat_ns: list[int] = []
+        hold_runs: list[tuple] = []         # (deliveries, bucket) per output
         delay = stream.settings.bucket_delay_ms
         # audience aggregates — assembled in the existing walk, ONE
         # vectorized column pass at the bottom (obs/audience.py)
@@ -1080,6 +1119,8 @@ class TpuFanoutEngine:
                 # (break holds the bookmark), runt-skip second (advance)
                 if int(ring.arrival[slot]) > deadline:
                     break
+                if pid == out.bookmark:
+                    due += 1                # its first packet is past hold
                 if ring.length[slot] < 12:
                     pid += 1
                     continue
@@ -1112,6 +1153,8 @@ class TpuFanoutEngine:
                         o_last = pid - 1
                         a_lat.append(stamp)
             out.bookmark = pid
+            if tcp_ok:
+                hold_runs.append((tcp_ok, b_idx))
             if o_sent:
                 a_rows.append(o_row)
                 a_pkts.append(o_sent)
@@ -1124,16 +1167,19 @@ class TpuFanoutEngine:
                 # across the whole ladder, engine rungs AND fallback
                 obs.TCP_EGRESS_PACKETS.inc(tcp_ok, backend="buffered")
                 obs.TCP_EGRESS_BYTES.inc(tcp_bytes, backend="buffered")
+        self._pass_walked += len(flat)
+        self._pass_due += due
+        now_ns = TRACER.close(egress, outputs=len(flat), due_outputs=due,
+                              sent=sent)
         if lat_ns:
-            now_ns = time.perf_counter_ns()
             lat_s = (now_ns - np.asarray(lat_ns, dtype=np.int64)) / 1e9
             if a_rows:
                 aud.note_pass(
                     ablk, a_rows, a_pkts, a_byts, a_first, a_last,
                     (now_ns - np.asarray(a_lat, np.int64)) / 1e9,
                     now_ns)
-            obs.RELAY_INGEST_TO_WIRE.observe_many(lat_s, engine="batch")
             if obs.LEDGER.enabled:
                 obs.LEDGER.note_queue_age(float(lat_s.max()), lat_s.size)
             PROFILER.account_latency(stream.session_path, lat_s)
+            obs.observe_wire("batch", lat_s, hold_runs, delay)
         return sent

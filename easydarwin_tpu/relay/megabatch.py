@@ -78,10 +78,12 @@ import time
 import numpy as np
 
 from .. import obs
+from ..device import listen_builds
 from ..models.relay_pipeline import (megabatch_window_step,
                                      scatter_affine_segments,
                                      sharded_megabatch_step)
 from ..obs import PROFILER, TRACER
+from ..obs.profile import builds
 from ..ops import staging
 from ..ops.fanout import STATE_COLS, pack_output_state
 from ..resilience.inject import INJECTOR
@@ -178,7 +180,9 @@ class MegabatchScheduler:
         # the host gathers the next wake into a fresh/recycled one
         # (steady state: two buffers per hot shape)
         self._free: dict[tuple, list[np.ndarray]] = {}
-        self._traced_shapes: set[tuple] = set()
+        # a bracket that held an XLA build (a bucket-growth retrace) is
+        # never a phase sample: obs.profile.builds() tells
+        listen_builds()
         self.wakes = 0
         self.passes = 0
         self.sharded_passes = 0            # mesh-dispatched buckets
@@ -214,7 +218,6 @@ class MegabatchScheduler:
 
     def end_wake(self, pairs, now_ms: int) -> None:
         """Collect, bucket, stage and dispatch the next stacked pass."""
-        t0 = time.perf_counter_ns()
         # prune dead streams BEFORE any early return: a torn-down
         # stream's id() can be recycled by a new RelayStream, and a
         # stale staged-head surviving a saturated wake would silently
@@ -231,8 +234,10 @@ class MegabatchScheduler:
             from ..obs.ledger import LEDGER
             LEDGER.defer("megabatch", len(pairs))
             return
+        span = TRACER.open("megabatch.dispatch", "tpu")
         work = self._collect(pairs)
         if not work:
+            TRACER.close(span, buckets=0, streams=0)
             return
         buckets: dict[tuple, list] = {}
         for item in work:
@@ -245,11 +250,9 @@ class MegabatchScheduler:
             g, h = self._dispatch_bucket(entries, p_pad, s_pad)
             gather_ns += g
             h2d_ns += h
-        total = time.perf_counter_ns() - t0
-        phases = {"stage_gather": gather_ns, "h2d": h2d_ns}
-        PROFILER.account_pass("megabatch", total, phases)
-        TRACER.add("megabatch.dispatch", t0, total, cat="tpu",
-                   buckets=len(buckets), streams=len(work))
+        total = TRACER.lap(span, buckets=len(buckets), streams=len(work))
+        PROFILER.account_pass("megabatch", total,
+                              {"stage_gather": gather_ns, "h2d": h2d_ns})
 
     # ------------------------------------------------------------- prime
     def _prime_stale(self, pairs, now_ms: int) -> None:
@@ -280,7 +283,7 @@ class MegabatchScheduler:
             return
         import jax
 
-        t0 = time.perf_counter_ns()
+        span = TRACER.open("megabatch.prime", "tpu", streams=len(stale))
         buckets: dict[int, list] = {}
         for item in stale:
             buckets.setdefault(_pow2(len(item[1]), 8), []).append(item)
@@ -293,6 +296,7 @@ class MegabatchScheduler:
             state = np.zeros((b_pad, s_pad, STATE_COLS), np.uint32)
             for i, (_eng, fast, _key) in enumerate(items):
                 state[i, :len(fast)] = np.asarray(pack_output_state(fast))
+            built0 = builds()
             t_h = time.perf_counter_ns()
             res = megabatch_window_step(jax.device_put(win), state)
             t_d = time.perf_counter_ns()
@@ -300,21 +304,14 @@ class MegabatchScheduler:
             t_f = time.perf_counter_ns()         # scatter is host work,
             segs = scatter_affine_segments(      # NOT d2h — unphased
                 packed, [len(f) for (_e, f, _k) in items])
-            shape = (b_pad, 16, s_pad)
-            if shape not in self._traced_shapes:
-                self._traced_shapes.add(shape)
-                PROFILER.note_compile(
-                    f"megabatch.step[{b_pad}x16x{s_pad}]",
-                    (t_f - t_h) / 1e9)
-            else:
+            if builds() == built0:
                 PROFILER.account_pass(
                     "megabatch", t_f - t_h,
                     {"device_step": t_d - t_h, "d2h": t_f - t_d})
             for (eng, _fast, key), seg in zip(items, segs):
                 self._install_segment(eng, key, seg)
             self._note_pass(len(items), win.nbytes + state.nbytes)
-        TRACER.add("megabatch.prime", t0, time.perf_counter_ns() - t0,
-                   cat="tpu", streams=len(stale))
+        TRACER.close(span)
 
     # ------------------------------------------------------------- collect
     def _collect(self, pairs) -> list:
@@ -414,7 +411,9 @@ class MegabatchScheduler:
         if self._sharded_step is not None:
             return self._dispatch_bucket_mesh(entries, p_pad, s_pad)
         b_pad = _pow2(len(entries), 1)
-        t_g = time.perf_counter_ns()
+        bucket = f"{b_pad}x{p_pad}x{s_pad}"
+        tok = TRACER.open("megabatch.gather", "tpu", streams=len(entries),
+                          bucket=bucket)
         win = self._buffer(b_pad, p_pad)
         state = np.zeros((b_pad, s_pad, STATE_COLS), np.uint32)
         recs = []
@@ -425,19 +424,16 @@ class MegabatchScheduler:
             recs.append((stream, eng, key, len(fast), base, -1))
         if b_pad > len(entries):
             win[len(entries):] = 0         # bucket padding rows
-        gather_ns = time.perf_counter_ns() - t_g
-        t_h = time.perf_counter_ns()
+        gather_ns = TRACER.lap(tok)
+        built0 = builds()
+        tok = TRACER.open("megabatch.h2d", "tpu", streams=len(entries),
+                          bucket=bucket)
         dwin = jax.device_put(win)
         res = megabatch_window_step(dwin, state)
         res.copy_to_host_async()
-        h2d_ns = time.perf_counter_ns() - t_h
-        shape = (b_pad, p_pad, s_pad)
-        if shape not in self._traced_shapes:
-            # bucket-growth retrace: the cold trace is a compile note,
-            # never a phase sample (PR 3 latch discipline)
-            self._traced_shapes.add(shape)
-            PROFILER.note_compile(
-                f"megabatch.step[{b_pad}x{p_pad}x{s_pad}]", h2d_ns / 1e9)
+        h2d_ns = TRACER.lap(tok)
+        if builds() != built0:
+            # bucket-growth retrace: a build is never a phase sample
             h2d_ns = 0
         self._inflight.append(
             _InFlight(res, recs, win, time.perf_counter_ns()))
@@ -462,7 +458,9 @@ class MegabatchScheduler:
         n_dev = len(self._mesh_devices)
         rows_per = staging.rows_per_shard(len(entries), n_dev)
         b_pad = rows_per * n_dev
-        t_g = time.perf_counter_ns()
+        bucket = f"{b_pad}x{p_pad}x{s_pad}"
+        tok = TRACER.open("megabatch.gather", "tpu", streams=len(entries),
+                          bucket=bucket)
         shard_bufs = [self._buffer(rows_per, p_pad) for _ in range(n_dev)]
         state = np.zeros((b_pad, s_pad, STATE_COLS), np.uint32)
         recs = []
@@ -478,8 +476,10 @@ class MegabatchScheduler:
         for k, buf in enumerate(shard_bufs):
             if filled[k] < rows_per:
                 buf[filled[k]:] = 0        # shard/bucket padding rows
-        gather_ns = time.perf_counter_ns() - t_g
-        t_h = time.perf_counter_ns()
+        gather_ns = TRACER.lap(tok)
+        built0 = builds()
+        tok = TRACER.open("megabatch.h2d", "tpu", streams=len(entries),
+                          bucket=bucket)
         win_s = NamedSharding(self.mesh, P("src", None, None))
         arrs = []
         for k, buf in enumerate(shard_bufs):
@@ -493,14 +493,9 @@ class MegabatchScheduler:
         dstate = jax.device_put(state, win_s)
         res = self._sharded_step(dwin, dstate)
         res.copy_to_host_async()
-        h2d_ns = time.perf_counter_ns() - t_h
-        shape = ("mesh", b_pad, p_pad, s_pad)
-        if shape not in self._traced_shapes:
-            self._traced_shapes.add(shape)
-            PROFILER.note_compile(
-                f"megabatch.step[mesh{n_dev}:{b_pad}x{p_pad}x{s_pad}]",
-                h2d_ns / 1e9)
-            h2d_ns = 0
+        h2d_ns = TRACER.lap(tok)
+        if builds() != built0:
+            h2d_ns = 0                     # a build is never a phase sample
         self._inflight.append(
             _InFlight(res, recs, shard_bufs, time.perf_counter_ns(),
                       rows_per=rows_per))
@@ -561,7 +556,8 @@ class MegabatchScheduler:
     def _harvest(self, *, force: bool = False) -> int:
         if not self._inflight:
             return 0
-        t0 = time.perf_counter_ns()
+        span = TRACER.open("megabatch.harvest", "tpu",
+                           inflight=len(self._inflight))
         keep: list[_InFlight] = []
         installed = 0
         overlap_ns = 0
@@ -572,13 +568,15 @@ class MegabatchScheduler:
             if not (ready or force or age >= self.FORCE_FETCH_NS):
                 keep.append(inf)           # never stall the wake on it
                 continue
+            tok = TRACER.open("megabatch.fetch", "tpu",
+                              streams=len(inf.entries), ready=int(ready))
             if inf.rows_per is not None:
                 got, fetch_ns = self._consume_mesh(inf, ready)
                 installed += got
+                TRACER.close(tok)
             else:
-                t_f = time.perf_counter_ns()
                 packed = np.asarray(inf.result)
-                fetch_ns = time.perf_counter_ns() - t_f
+                fetch_ns = TRACER.lap(tok)
                 obs.TPU_D2H_BYTES.inc(packed.nbytes)
                 segs = scatter_affine_segments(
                     packed, [n for (_s, _e, _k, n, _b, _sh)
@@ -601,9 +599,10 @@ class MegabatchScheduler:
                 self._recycle(b)
             self.harvests += 1
         self._inflight = keep
+        total = TRACER.lap(span, installed=installed)
         if overlap_ns or d2h_ns:
             PROFILER.account_pass(
-                "megabatch", time.perf_counter_ns() - t0,
+                "megabatch", total,
                 {"h2d_overlap": overlap_ns, "d2h": d2h_ns})
         return installed
 

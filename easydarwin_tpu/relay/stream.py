@@ -298,6 +298,7 @@ class RelayStream:
         sent = 0
         bytes_out = 0
         lat_ns: list[int] = []          # ingest stamps of delivered packets
+        hold_runs: list[tuple] = []     # (deliveries, bucket) per output
         # audience aggregates (obs/audience.py): per-OUTPUT figures
         # assembled inside the existing walk, applied as ONE vectorized
         # column pass below; disabled costs one attribute check
@@ -323,6 +324,7 @@ class RelayStream:
                          if ablk is not None else -1)
                 o_sent = o_byts = 0
                 o_first = o_last = -1
+                sent0 = sent
                 while pid < ring.head:
                     if ring.get_arrival(pid) > deadline:
                         break
@@ -351,6 +353,8 @@ class RelayStream:
                             o_last = pid - 1
                             a_lat.append(stamp)
                 out.bookmark = pid
+                if sent > sent0:
+                    hold_runs.append((sent - sent0, b_idx))
                 if o_sent:
                     a_rows.append(o_row)
                     a_pkts.append(o_sent)
@@ -367,13 +371,15 @@ class RelayStream:
                     ablk, a_rows, a_pkts, a_byts, a_first, a_last,
                     (wire_ns - np.asarray(a_lat, np.int64)) / 1e9,
                     wire_ns)
-            obs.RELAY_INGEST_TO_WIRE.observe_many(lat_s, engine="scalar")
             if obs.LEDGER.enabled:
                 obs.LEDGER.note_queue_age(float(lat_s.max()), lat_s.size)
             # per-session attribution (command=top) works on the scalar
             # oracle too — small fan-outs are still sessions operators ask
             # about, and the SLO watchdog's offender lookup reads this
             obs.PROFILER.account_latency(self.session_path, lat_s)
+            # last: it takes the hold off lat_s in place
+            obs.observe_wire("scalar", lat_s, hold_runs,
+                             self.settings.bucket_delay_ms)
             if self.session_path is not None:
                 obs.PROFILER.account_pass("scalar", 0, {},
                                           path=self.session_path,
@@ -414,9 +420,9 @@ class RelayStream:
             # Ledger-bracketed (ISSUE 16): parity windows run nested in
             # the live-relay pass — charge fec_parity its own service so
             # live_relay's figure stays conserved.
-            _tok = obs.LEDGER.unit_start()
+            _tok = obs.LEDGER.unit_start("fec_parity")
             self.fec.tick(now_ms)
-            obs.LEDGER.unit_end(_tok, "fec_parity")
+            obs.LEDGER.unit_end(_tok)
         rring = self.rtcp_ring
         if len(rring) == 0 and now_ms < self._next_sr_due_ms:
             return                  # hot path: nothing buffered, none due

@@ -17,6 +17,8 @@ from __future__ import annotations
 import asyncio
 import time
 
+from .. import obs
+from ..obs import PROFILER, TRACER, t0_of
 from ..relay.fanout import TpuFanoutEngine
 from ..relay.session import SessionRegistry, now_ms
 from .config import ServerConfig
@@ -102,6 +104,13 @@ class StreamingServer:
         #: first un-serviced wake's perf stamp — the wake→pass queueing
         #: delay phase (obs/profile.py); None = no wake pending
         self._wake_ns: int | None = None
+        #: the wake in progress: (pump.wake span, its first clock read,
+        #: wake→pass µs); closed by _wake_close after the maintenance
+        #: block, or by the next _reflect_all (direct callers)
+        self._wake_open_rec: tuple | None = None
+        self._wake_seq = 0
+        self._wake_streams = 0
+        self._wake_sent = 0
         #: SLO watchdog over the obs families; the pump's 1 Hz
         #: maintenance block ticks it, violations flag flight recorders
         from ..obs import PROFILER, SloWatchdog
@@ -1193,17 +1202,47 @@ class StreamingServer:
         eng.tcp_fast_enabled = self.config.tcp_engine_enabled
         return eng
 
-    def _reflect_all(self) -> int:
-        t = now_ms()
-        wake_ns, self._wake_ns = self._wake_ns, None
-        from ..obs import LEDGER
+    def _wake_open(self, wake_ns: int | None) -> None:
+        """First line of a wake: number it, open ``pump.wake`` (every
+        span opened until ``_wake_close`` carries the number) and file
+        the wake→pass queueing delay from the same clock read."""
+        if self._wake_open_rec is not None:
+            self._wake_close()          # a direct caller's previous wake
+        self._wake_seq += 1
+        TRACER.wake = self._wake_seq
+        span = TRACER.open("pump.wake", "pump")
+        t0 = t0_of(span)
+        w2p_us = 0
         if wake_ns is not None:
             # wake→pass queueing delay: ingest set the event at wake_ns,
             # the loop got scheduled and reached the pass now — event-loop
             # lag the per-pass phases cannot see but players feel
-            from ..obs import PROFILER
-            PROFILER.observe("wake_to_pass", "pump",
-                             time.perf_counter_ns() - wake_ns)
+            PROFILER.observe("wake_to_pass", "pump", t0 - wake_ns)
+            w2p_us = (t0 - wake_ns) // 1000
+        self._wake_open_rec = (span, t0, w2p_us)
+        self._wake_streams = self._wake_sent = 0
+
+    def _wake_close(self) -> None:
+        """Last line of a wake: close the ledger's record and
+        ``pump.wake``; the span's two clock reads are also the wake's
+        sample in ``pump_wake_seconds`` and its share of
+        ``pump_loop_seconds_total``."""
+        if self._wake_open_rec is None:
+            return
+        span, t0, w2p_us = self._wake_open_rec
+        self._wake_open_rec = None
+        obs.LEDGER.end_wake()
+        end = TRACER.close(span, streams=self._wake_streams,
+                           sent=self._wake_sent, wake_to_pass_us=w2p_us)
+        TRACER.wake = None
+        obs.PUMP_WAKE_SECONDS.observe((end - t0) / 1e9)
+        obs.PUMP_LOOP_SECONDS.inc((end - t0) / 1e9, state="wake")
+
+    def _reflect_all(self) -> int:
+        wake_ns, self._wake_ns = self._wake_ns, None
+        self._wake_open(wake_ns)
+        LEDGER = obs.LEDGER             # (tests put a private one there)
+        t = now_ms()
         # wake ledger (ISSUE 16): one record per wake, every unit below
         # tagged with its work class.  The record stays open through the
         # 1 Hz maintenance block in _pump_loop (end_wake there); direct
@@ -1228,27 +1267,27 @@ class StreamingServer:
         # VOD service, never the pump.
         vod_pairs = []
         if self.vod_pacer is not None and self.vod_pacer.sessions:
-            _u = LEDGER.unit_start()
+            _u = LEDGER.unit_start("vod_fill")
             try:
                 vod_pairs = self.vod_pacer.tick(t)
             except Exception as e:
                 vod_pairs = []
                 if self.error_log:
                     self.error_log.warning(f"vod pacer: {e!r}")
-            LEDGER.unit_end(_u, "vod_fill", items=max(len(vod_pairs), 1))
+            LEDGER.unit_end(_u, items=max(len(vod_pairs), 1))
         # DVR window spill (ISSUE 12): snapshot any live ring window the
         # head completed since the last wake (an integer compare per
         # armed stream when nothing did).  Runs BEFORE the reflect pass
         # so a time-shift cursor parked at the spill/ring seam sees the
         # freshest cold tail.  Failures degrade recording, not relaying.
         if self.dvr is not None and self.dvr._armed:
-            _u = LEDGER.unit_start()
+            _u = LEDGER.unit_start("dvr_spill")
             try:
                 self.dvr.tick(t)
             except Exception as e:
                 if self.error_log:
                     self.error_log.warning(f"dvr spill: {e!r}")
-            LEDGER.unit_end(_u, "dvr_spill")
+            LEDGER.unit_end(_u)
         mega_pairs = []
         lad = self.ladder
         if use_tpu and self.config.megabatch_enabled:
@@ -1269,7 +1308,7 @@ class StreamingServer:
                     from ..relay.megabatch import MegabatchScheduler
                     self.megabatch = MegabatchScheduler(
                         mesh=self.megabatch_mesh)
-                _u = LEDGER.unit_start()
+                _u = LEDGER.unit_start("megabatch", part="harvest")
                 try:
                     self.megabatch.begin_wake(mega_pairs, t)
                 except Exception as e:
@@ -1279,26 +1318,25 @@ class StreamingServer:
                     mega_pairs = []
                     if self.error_log:
                         self.error_log.warning(f"megabatch harvest: {e!r}")
-                LEDGER.unit_end(_u, "megabatch",
-                                items=max(len(mega_pairs), 1))
+                LEDGER.unit_end(_u, items=max(len(mega_pairs), 1))
             else:
                 mega_pairs = []
         if not mega_pairs and self.megabatch is not None:
             # scheduler built but not engaged this wake (mass teardown,
             # megabatch disabled): keep harvesting in-flight passes so
             # they can't pin torn-down streams and staging buffers
-            _u = LEDGER.unit_start()
+            _u = LEDGER.unit_start("megabatch", part="idle")
             try:
                 self.megabatch.idle_wake()
             except Exception as e:
                 if self.error_log:
                     self.error_log.warning(f"megabatch idle: {e!r}")
-            LEDGER.unit_end(_u, "megabatch")
+            LEDGER.unit_end(_u)
         mega_ids = {id(s) for s, _ in mega_pairs}
         # live relay pass: ONE ledger unit covering every live stream's
         # step/reflect; the slowest stream's trace_id rides the record
         # (the critical-path correlation a p99 sample decomposes by)
-        _lu = LEDGER.unit_start()
+        _lu = LEDGER.unit_start("live_relay")
         _n_live = 0
         _worst_ns, _worst_trace = -1, None
         for sess in list(self.registry.sessions.values()):
@@ -1356,13 +1394,13 @@ class StreamingServer:
                     _el = time.perf_counter_ns() - _s0
                     if _el > _worst_ns:
                         _worst_ns, _worst_trace = _el, stream.trace_id
-        LEDGER.unit_end(_lu, "live_relay", items=max(_n_live, 1),
+        LEDGER.unit_end(_lu, items=max(_n_live, 1),
                         trace_id=_worst_trace)
         # paced VOD streams: same per-stream guard discipline as live.
         # The device gate ignores tpu_min_outputs — a VOD subscriber is
         # one output by construction, and its device cost is a bucket
         # row in the stacked pass, not a per-stream dispatch
-        _vu = LEDGER.unit_start() if vod_pairs else None
+        _vu = LEDGER.unit_start("vod_fill") if vod_pairs else None
         for stream, eng in vod_pairs:
             pre_stalls = stream.stats.stalls
             try:
@@ -1386,9 +1424,9 @@ class StreamingServer:
             stream._last_pass_stalled = \
                 stream.stats.stalls > pre_stalls
         if _vu is not None:
-            LEDGER.unit_end(_vu, "vod_fill", items=len(vod_pairs))
+            LEDGER.unit_end(_vu, items=len(vod_pairs))
         if mega_pairs:
-            _u = LEDGER.unit_start()
+            _u = LEDGER.unit_start("megabatch", part="stage")
             try:
                 self.megabatch.end_wake(mega_pairs, t)
             except Exception as e:
@@ -1397,7 +1435,9 @@ class StreamingServer:
                         [s.session_path for s, _ in mega_pairs])
                 if self.error_log:
                     self.error_log.warning(f"megabatch stage: {e!r}")
-            LEDGER.unit_end(_u, "megabatch", items=len(mega_pairs))
+            LEDGER.unit_end(_u, items=len(mega_pairs))
+        self._wake_streams = _n_live + len(vod_pairs)
+        self._wake_sent = sent
         return sent
 
     def _make_pump_wheel(self):
@@ -1443,10 +1483,21 @@ class StreamingServer:
                 nd = wheel.next_deadline(now_ms())
                 if nd >= 0:
                     timeout = min(interval, max(nd, 1) / 1000.0)
+            # pump.sleep: what ended it is the wake's cause — ingest (a
+            # pusher set the event), timer (a wheel deadline shortened
+            # the wait and ran out) or interval (the full tick ran out)
+            span = TRACER.open("pump.sleep", "pump",
+                               timeout_ms=round(timeout * 1e3, 3))
+            t_sleep = t0_of(span)
             try:
                 await asyncio.wait_for(self._pump_event.wait(), timeout)
+                cause = "ingest"
             except asyncio.TimeoutError:
-                pass
+                cause = "timer" if timeout < interval else "interval"
+            t_woke = TRACER.close(span, cause=cause)
+            obs.PUMP_LOOP_SECONDS.inc((t_woke - t_sleep) / 1e9,
+                                      state="sleep")
+            obs.PUMP_WAKES.inc(cause=cause)
             self._pump_event.clear()
             self._reflect_all()
             if self.config.slo_enabled:
@@ -1456,13 +1507,16 @@ class StreamingServer:
             if wheel is not None:
                 # advance and schedule against the SAME clock sample, or
                 # timers fire early by the reflect-pass duration
+                tok = TRACER.open("pump.deadlines", "pump")
                 t = now_ms()
                 for key in wheel.advance(t):
                     self._wheel_sched.pop(key, None)
                 self._schedule_stream_deadlines(wheel, t)
+                TRACER.close(tok, streams=self._wake_streams)
             now = time.monotonic()
             if now - last_prune >= 1.0:
                 last_prune = now
+                maint = TRACER.open("pump.maintenance", "pump")
                 t = now_ms()
                 for sess in list(self.registry.sessions.values()):
                     sess.prune(t)
@@ -1499,8 +1553,7 @@ class StreamingServer:
                         if self.error_log:
                             self.error_log.warning(f"ladder tick: {e!r}")
                 if self.checkpoint is not None:
-                    from ..obs import LEDGER
-                    _u = LEDGER.unit_start()
+                    _u = obs.LEDGER.unit_start("checkpoint")
                     try:
                         wrote = self.checkpoint.maybe_write(self.registry)
                         if wrote and self.vod_cache is not None:
@@ -1508,7 +1561,7 @@ class StreamingServer:
                     except Exception as e:
                         if self.error_log:
                             self.error_log.warning(f"checkpoint: {e!r}")
-                    LEDGER.unit_end(_u, "checkpoint")
+                    obs.LEDGER.unit_end(_u)
                 if self.presence is not None:
                     self.presence.set_load(sum(
                         s.num_outputs
@@ -1517,12 +1570,12 @@ class StreamingServer:
                         await self.presence.sync_streams(self.registry.paths())
                     except Exception:
                         pass
-            # close this wake's ledger record AFTER the maintenance
-            # block: the 1 Hz duties ran on the same wake's thread time,
-            # so their service belongs to the record a queued packet's
-            # wait decomposes against
-            from ..obs import LEDGER
-            LEDGER.end_wake()
+                TRACER.close(maint)
+            # close this wake's ledger record (and pump.wake) AFTER the
+            # maintenance block: the 1 Hz duties ran on the same wake's
+            # thread time, so their service belongs to the record a
+            # queued packet's wait decomposes against
+            self._wake_close()
 
     def _ladder_maintenance(self) -> None:
         """1 Hz ladder duties: evaluate recovery/SLO pressure, then shed

@@ -296,7 +296,7 @@ class RtspConnection:
             self._reply(rtsp.RtspResponse(403), req.cseq)
             return
         self._last_response = None
-        t0 = TRACER.begin()
+        t0 = time.perf_counter_ns()
         errored = False
         try:
             await handler(req)
@@ -308,7 +308,7 @@ class RtspConnection:
                         trace_id=self.trace_id, method=req.method,
                         status=e.status)
         finally:
-            TRACER.end(f"rtsp.{req.method.lower()}", t0, cat="rtsp",
+            TRACER.add(f"rtsp.{req.method.lower()}", t0, cat="rtsp",
                        trace_id=self.trace_id)
         if (not errored and req.method in self._EVENT_METHODS
                 and self._last_response is not None):

@@ -104,13 +104,13 @@ def test_nested_service_telescopes_to_wake_duration():
     t = [1_000_000_000]
     led, _, _, _ = _private_ledger(lambda: t[0])
     led.begin_wake()
-    lu = led.unit_start()
+    lu = led.unit_start("live_relay")
     t[0] += 2_000_000                 # 2 ms of relay work…
-    fu = led.unit_start()
+    fu = led.unit_start("fec_parity")
     t[0] += 5_000_000                 # …5 ms inside nested FEC…
-    led.unit_end(fu, "fec_parity")
+    led.unit_end(fu)
     t[0] += 3_000_000                 # …3 ms more relay work
-    led.unit_end(lu, "live_relay")
+    led.unit_end(lu)
     led.end_wake()
     snap = led.snapshot()
     lr = snap["classes"]["live_relay"]
@@ -133,22 +133,22 @@ def test_queue_age_attributed_to_wire_class_and_item_weighted():
         enq = t[0]
         t[0] += 1_000_000
         led.begin_wake(enq)
-        u = led.unit_start()
+        u = led.unit_start("live_relay")
         t[0] += 500_000
         led.note_queue_age(0.002, 5)
-        led.unit_end(u, "live_relay")
+        led.unit_end(u)
         led.end_wake()
     # the backlog wake: 500 queued packets drained, oldest 8.1 s old
     enq = t[0]
     t[0] += 1_000_000
     led.begin_wake(enq)
-    u = led.unit_start()
-    fu = led.unit_start()
+    u = led.unit_start("live_relay")
+    fu = led.unit_start("fec_parity")
     t[0] += 200_000
     led.note_queue_age(8.1, 500)
-    led.unit_end(fu, "fec_parity")    # non-wire: must not consume
+    led.unit_end(fu)    # non-wire: must not consume
     t[0] += 800_000
-    led.unit_end(u, "live_relay", trace_id="tr-burst")
+    led.unit_end(u, trace_id="tr-burst")
     led.end_wake()
     snap = led.snapshot()
     lr = snap["classes"]["live_relay"]
@@ -167,9 +167,9 @@ def test_deferred_counts_fold_and_feed_counter():
     led, _, _, dfr = _private_ledger(lambda: t[0])
     led.defer("megabatch", 3)         # no wake open → pending
     led.begin_wake()
-    u = led.unit_start()
+    u = led.unit_start("megabatch")
     t[0] += 1_000_000
-    led.unit_end(u, "megabatch")
+    led.unit_end(u)
     led.defer("hls_requant")          # open-wake path
     led.end_wake()
     snap = led.snapshot()
@@ -185,8 +185,8 @@ def test_profile_off_is_noop(monkeypatch):
     led, wait, _, _ = _private_ledger()
     assert led.enabled is False
     led.begin_wake()
-    assert led.unit_start() is None
-    led.unit_end(None, "live_relay")  # None token: no-op, no branch
+    assert led.unit_start("live_relay") is None
+    led.unit_end(None)  # None token: no-op, no branch
     led.note_queue_age(9.0, 100)
     led.defer("megabatch")
     led.record("cluster_tick", service_ns=1_000_000)
@@ -202,9 +202,9 @@ def test_standalone_cluster_tick_redis_rollup_and_suspects():
     t = [1_000_000_000]
     led, _, _, _ = _private_ledger(lambda: t[0])
     led.begin_wake()                  # one cheap relay wake for contrast
-    u = led.unit_start()
+    u = led.unit_start("live_relay")
     t[0] += 1_000_000
-    led.unit_end(u, "live_relay")
+    led.unit_end(u)
     led.end_wake()
     for _ in range(4):                # tick coroutine: NO wake open
         led.record("cluster_tick", service_ns=80_000_000,
@@ -226,13 +226,13 @@ def test_blame_doc_ranks_rows_and_conserves():
     enq = t[0]
     t[0] += 1_000_000
     led.begin_wake(enq)
-    u = led.unit_start()
+    u = led.unit_start("live_relay")
     led.note_queue_age(6.0, 50)
     t[0] += 2_000_000
-    led.unit_end(u, "live_relay")
-    u = led.unit_start()
+    led.unit_end(u)
+    u = led.unit_start("dvr_spill")
     t[0] += 500_000
-    led.unit_end(u, "dvr_spill")
+    led.unit_end(u)
     led.end_wake()
     doc = blame_doc(led.snapshot(), measured_p99_ms=7000.0,
                     baseline_p50_ms=10.0)
@@ -298,17 +298,17 @@ def test_slow_subscriber_latency_spike_blames_live_relay(monkeypatch,
         st.push_rtp(vid_pkt(i), 1000)
     injector.arm(FaultPlan(seed=3, slow_sub_every=1))
     led.begin_wake()
-    u = led.unit_start()
+    u = led.unit_start("live_relay")
     st.reflect(1000)                  # every write blocks: nothing out
-    led.unit_end(u, "live_relay")
+    led.unit_end(u)
     led.end_wake()
     assert out.stalls > 0 and not out.rtp_packets
     injector.disarm()
     time.sleep(0.7)                   # the queued packets age for real
     led.begin_wake()
-    u = led.unit_start()
+    u = led.unit_start("live_relay")
     st.reflect(1000)                  # catch-up drain: 8 aged packets
-    led.unit_end(u, "live_relay")
+    led.unit_end(u)
     led.end_wake()
     assert len(out.rtp_packets) == 8
     snap = led.snapshot()
@@ -397,8 +397,8 @@ async def test_rest_ledger_and_blame_surfaces():
     from easydarwin_tpu.server.rest import RestApi
     # feed the process ledger one wake so the documents are non-trivial
     obs.LEDGER.begin_wake()
-    u = obs.LEDGER.unit_start()
-    obs.LEDGER.unit_end(u, "live_relay")
+    u = obs.LEDGER.unit_start("live_relay")
+    obs.LEDGER.unit_end(u)
     obs.LEDGER.end_wake()
     api = RestApi(ServerConfig(), None)
     st, body, ctype = await api.route("GET", "/api/v1/ledger", {}, b"")
@@ -432,9 +432,9 @@ async def test_status_monitor_surfaces_ledger_summary(monkeypatch):
         led, _, _, _ = _private_ledger()
         monkeypatch.setattr(obs, "LEDGER", led)
         led.begin_wake()
-        u = led.unit_start()
+        u = led.unit_start("hls_requant")
         time.sleep(0.002)
-        led.unit_end(u, "hls_requant")
+        led.unit_end(u)
         led.end_wake()
         d = StatusMonitor(app).sample()
         assert d["ledger_top_wait_class"] == "hls_requant"
@@ -530,12 +530,12 @@ def test_ledger_overhead_bound_on_cpu_engine(monkeypatch):
             o.rtp_packets.clear()
         c0 = time.perf_counter()
         led.begin_wake()
-        u = led.unit_start()
+        u = led.unit_start("live_relay")
         eng.step(st, 10_000)
-        led.unit_end(u, "live_relay", items=64)
+        led.unit_end(u, items=64)
         for cls in ("vod_fill", "dvr_spill", "checkpoint"):
-            tok = led.unit_start()
-            led.unit_end(tok, cls)
+            tok = led.unit_start(cls)
+            led.unit_end(tok)
         led.end_wake()
         return time.perf_counter() - c0
 

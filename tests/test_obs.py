@@ -15,6 +15,7 @@ import pathlib
 import re
 import socket
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -268,10 +269,8 @@ def test_native_stats_count_scalar_baseline():
 # ------------------------------------------------------------------ trace
 def test_tracer_records_and_dumps_chrome_format():
     tr = SpanTracer(capacity=16)
-    with tr.span("pass", cat="tpu", n=3):
-        pass
-    t0 = tr.begin()
-    tr.end("egress", t0, cat="native")
+    tr.close(tr.open("pass", cat="tpu", n=3))
+    tr.add("egress", time.perf_counter_ns(), cat="native")
     doc = json.loads(json.dumps(tr.dump()))   # must be JSON-serializable
     assert set(doc) == {"traceEvents", "displayTimeUnit"}
     evs = doc["traceEvents"]
@@ -295,14 +294,21 @@ def test_tracer_ring_is_bounded():
     assert len(tr) == 0 and tr.dropped_hint == 0
 
 
-def test_tracer_span_records_on_exception_path():
+def test_tracer_span_on_the_exception_path():
     tr = SpanTracer(capacity=8)
     with pytest.raises(ValueError):
-        with tr.span("boom", cat="test", n=1):
-            raise ValueError("nope")
+        tok = tr.open("lost", cat="test")
+        raise ValueError("nope")
+    # a span an exception unwound past never reaches the ring ...
+    assert tr.dump()["traceEvents"] == []
+    tok = tr.open("boom", cat="test", n=1)
+    try:
+        raise ValueError("nope")
+    except ValueError as e:
+        # ... unless its owner closes it, tagged for trace queries
+        tr.close(tok, error=type(e).__name__)
     evs = tr.dump()["traceEvents"]
     assert len(evs) == 1 and evs[0]["name"] == "boom"
-    # the failed span is tagged with the error class for trace queries
     assert evs[0]["args"] == {"n": 1, "error": "ValueError"}
 
 
@@ -430,10 +436,10 @@ def test_flight_dump_correlates_spans_by_trace_id(tmp_path):
     from easydarwin_tpu.obs import TRACER
     fr = FlightRecorder(dump_dir=str(tmp_path))
     fr.register("s9", trace_id="deadbeef")
-    TRACER.end("engine.step", TRACER.begin(), cat="tpu",
-               trace_id="deadbeef", sent=3)
-    TRACER.end("engine.step", TRACER.begin(), cat="tpu",
-               trace_id="someone-else")
+    TRACER.close(TRACER.open("engine.step", "tpu", trace_id="deadbeef"),
+                 sent=3)
+    TRACER.close(TRACER.open("engine.step", "tpu",
+                             trace_id="someone-else"))
     doc = fr.dump("s9", reason="exception: Boom")
     assert [s["name"] for s in doc["spans"]] == ["engine.step"]
     assert doc["spans"][0]["args"] == {"sent": 3}

@@ -835,6 +835,82 @@ def lint_ledger(registry) -> list[str]:
     return errs
 
 
+#: how a span gets its name at a call site: the tracer's open/close
+#: form and its post-hoc ``add``, the engine's and the native module's
+#: wrappers, and the ledger's units (``pump.<work_class>``)
+SPAN_SITE_RE = re.compile(
+    r"""\b(?:TRACER\.(?:open|add)|_open|_egress_open|unit_start)"""
+    r"""\(\s*(?:(?:self\._lib|lib)\s*,\s*)?(f?)['"]([^'"]+)['"]""")
+PUMP_STATES = ("wake", "sleep")
+WAKE_CAUSES = ("ingest", "timer", "interval")
+
+
+def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
+    """The span contract (ISSUE 25): ``obs.trace.SPANS`` is CLOSED —
+    dotted lower-case names, one ``pump.<work_class>`` per ledger class,
+    and every span name written at a call site under ``root`` (the
+    package) is in it (an f-string site by its literal prefix, against
+    ``SPAN_PREFIXES``) — and the families read off the same brackets
+    exist with their closed label sets: ``pump_loop_seconds_total
+    {state}``, ``pump_wakes_total{cause}``, ``pump_wake_seconds``,
+    ``relay_due_to_wire_seconds{engine}`` on a ladder covering
+    TIME_BUCKETS, ``engine_outputs_walked_total`` / ``_due_total``."""
+    from easydarwin_tpu.obs.ledger import WORK_CLASSES
+    from easydarwin_tpu.obs.metrics import TIME_BUCKETS
+    from easydarwin_tpu.obs.trace import SPAN_PREFIXES, SPANS
+    errs: list[str] = []
+    if len(set(SPANS)) != len(SPANS):
+        errs.append("span vocabulary has duplicates")
+    for name in SPANS:
+        if not EVENT_NAME_RE.match(name):
+            errs.append(f"span {name!r}: not dotted snake_case")
+    for wc in WORK_CLASSES:
+        if f"pump.{wc}" not in SPANS:
+            errs.append(f"span pump.{wc} missing: every ledger work class "
+                        "is a span")
+    for py in sorted(root.rglob("*.py")) if root else ():
+        text = py.read_text(encoding="utf-8", errors="replace")
+        for m in SPAN_SITE_RE.finditer(text):
+            line_no = text.count("\n", 0, m.start()) + 1
+            is_f, name = m.group(1), m.group(2)
+            if "unit_start" in m.group(0):
+                name = f"pump.{name}"
+            if is_f:
+                if not name.split("{")[0].startswith(SPAN_PREFIXES):
+                    errs.append(f"{py.name}:{line_no}: f-string span "
+                                f"{name!r} outside {SPAN_PREFIXES}")
+            elif name not in SPANS:
+                errs.append(f"{py.name}:{line_no}: span {name!r} outside "
+                            "the closed vocabulary obs.trace.SPANS")
+    want = {"pump_loop_seconds_total": (("state",), PUMP_STATES),
+            "pump_wakes_total": (("cause",), WAKE_CAUSES),
+            "pump_wake_seconds": ((), ()),
+            "relay_due_to_wire_seconds": (("engine",), ()),
+            "engine_outputs_walked_total": ((), ()),
+            "engine_outputs_due_total": ((), ())}
+    for fam_name, (labels, closed) in want.items():
+        try:
+            fam = registry.get(fam_name)
+        except KeyError:
+            errs.append(f"span-side family {fam_name} missing from the "
+                        "registry")
+            continue
+        if tuple(fam.label_names) != labels:
+            errs.append(f"{fam_name}: labels must be {labels}, got "
+                        f"{tuple(fam.label_names)}")
+        elif closed:
+            for (v,) in getattr(fam, "_values", {}):
+                if v not in closed:
+                    errs.append(f"{fam_name}: observed {labels[0]} {v!r} "
+                                f"outside the closed set {closed}")
+        bounds = getattr(fam, "bounds", None)
+        if bounds is not None and (bounds[0] > TIME_BUCKETS[0]
+                                   or bounds[-1] < TIME_BUCKETS[-1]):
+            errs.append(f"{fam_name}: bucket bounds do not cover the "
+                        "TIME_BUCKETS range")
+    return errs
+
+
 def lint_audience(registry, schema: dict | None = None) -> list[str]:
     """The audience observatory's contract (ISSUE 18): the four
     ``audience_*`` families exist with exactly the declared labels,
@@ -1050,6 +1126,10 @@ def main() -> int:
     # vocabulary), the [0, 1] QoE bucket ladder and the stall-storm
     # event declaration
     errs += lint_audience(obs.REGISTRY, ev.SCHEMA)
+    # the span vocabulary (ISSUE 25): obs.trace.SPANS closed over every
+    # call site in the package + the pump_loop / pump_wakes /
+    # due_to_wire / engine_outputs families read off the same brackets
+    errs += lint_spans(obs.REGISTRY, pkg)
     for e in errs:
         print(f"metrics_lint: {e}", file=sys.stderr)
     if not errs:

@@ -1,0 +1,66 @@
+#!/usr/bin/env python3
+"""Where a pump wake's time goes, from the program's own spans.
+
+    python tools/span_breakdown.py <trace dir | x.xplane.pb | ring.json>
+
+Reads the host plane of a profiler trace (``benchmark/server_child.py
+--trace-dir D``, or ``benchmark_out/<cell>/trace`` after a ``--trace 1``
+run) through ``benchmark/reduce_trace.load_xplane``, or the span ring as
+``GET /api/v1/admin?command=trace`` dumps it, and prints per span of
+``obs.trace.SPANS``: count, seconds, ms per ``pump.wake`` and share of
+the time the pump loop spent awake or asleep.  Spans nest, so a child's
+ms are inside its parent's.  Holds no chip: run it with
+``JAX_PLATFORMS=cpu``."""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+OURS = ("pump.", "engine.", "megabatch.", "native.", "pipeline.")
+
+
+def host_rows(path: str) -> list:
+    """``[name, start_ns, duration_ns]`` rows of the program's spans."""
+    if path.endswith(".json"):
+        with open(path) as f:
+            evs = json.load(f)["traceEvents"]
+        return [[e["name"], e["ts"] * 1e3, e["dur"] * 1e3] for e in evs]
+    sys.path.insert(0, ROOT)
+    from benchmark import reduce_trace
+    if os.path.isdir(path):
+        path = reduce_trace.newest_xplane(path) or path
+    return reduce_trace.load_xplane(path)["host"]
+
+
+def breakdown(rows: list) -> dict:
+    spans: dict[str, list] = {}
+    for name, _start, dur in rows:
+        if name.startswith(OURS):
+            c = spans.setdefault(name, [0, 0.0])
+            c[0] += 1
+            c[1] += dur / 1e9
+    wakes = spans.get("pump.wake", [0, 0.0])[0]
+    loop_s = sum(spans.get(n, [0, 0.0])[1]
+                 for n in ("pump.wake", "pump.sleep"))
+    return {"wakes": wakes, "loop_s": loop_s, "spans": {
+        name: {"count": n, "seconds": s,
+               "ms_per_wake": 1e3 * s / wakes if wakes else None,
+               "loop_pct": 100.0 * s / loop_s if loop_s else None}
+        for name, (n, s) in sorted(spans.items())}}
+
+
+def main(argv) -> int:
+    doc = breakdown(host_rows(argv[1]))
+    print(f"{doc['wakes']} wakes, {doc['loop_s']:.3f} s of pump loop")
+    for name, row in doc["spans"].items():
+        print(f"{name:22s} {row['count']:7d} {row['seconds']:10.4f} s "
+              f"{row['ms_per_wake'] or 0:10.3f} ms/wake "
+              f"{row['loop_pct'] or 0:6.2f} %")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv))
