@@ -138,6 +138,12 @@ ENGINE_OUTPUTS_DUE = REGISTRY.counter(
     "Of the outputs a step looked at, those with at least one packet "
     "past its bucket's hold and not yet sent (due / walked = the share "
     "of a wake's per-output work that had anything to do)")
+ENGINE_PLAN_REBUILDS = REGISTRY.counter(
+    "engine_plan_rebuilds_total",
+    "Per-stream output plans rebuilt (the fast list, params key, dest "
+    "table and bucket cohorts an engine steps from): one per stream "
+    "whose plan epoch moved — a join, a leave, a latch, a bookmark "
+    "written from outside the engine — and none on a steady wake")
 TPU_PACKETS_SENT = REGISTRY.counter(
     "tpu_packets_sent_total",
     "(packet, subscriber) sends completed by the TPU fan-out engine")

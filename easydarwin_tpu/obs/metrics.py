@@ -239,6 +239,21 @@ class Gauge(_Family):
         return {",".join(k): v for k, v in sorted(self._values.items())}
 
 
+def bin_weighted(bounds: np.ndarray, values: np.ndarray,
+                 weights=None) -> tuple[np.ndarray, float, int]:
+    """``values`` binned against ``bounds`` (``le`` buckets plus +Inf):
+    ``(counts, sum, count)``, value i taken ``weights[i]`` times where
+    whole-number ``weights`` are given — the one binning every bulk
+    observer shares."""
+    idx = np.searchsorted(bounds, values, side="left")
+    if weights is None:
+        return (np.bincount(idx, minlength=len(bounds) + 1),
+                float(values.sum()), int(values.size))
+    return (np.bincount(idx, weights=weights,
+                        minlength=len(bounds) + 1).astype(np.int64),
+            float(values @ weights), int(np.sum(weights)))
+
+
 class _HistState:
     __slots__ = ("counts", "sum", "count")
 
@@ -284,21 +299,24 @@ class Histogram(_Family):
             st.sum += value * n
             st.count += n
 
-    def observe_many(self, values: np.ndarray, **labels) -> None:
+    def observe_many(self, values: np.ndarray, weights=None,
+                     **labels) -> None:
         """Vectorized bulk observe — the relay hot paths record one call
-        per PASS, not per packet."""
+        per PASS, not per packet.  ``weights`` (whole numbers, one per
+        value) observes ``values[i]`` ``weights[i]`` times without
+        expanding the array: a cohort's deliveries carry the same few
+        latencies once per subscriber."""
         values = np.asarray(values, dtype=np.float64).ravel()
         if values.size == 0:
             return
-        idx = np.searchsorted(self._bounds_arr, values, side="left")
-        binned = np.bincount(idx, minlength=len(self.bounds) + 1)
+        binned, total, n = bin_weighted(self._bounds_arr, values, weights)
         with self._mu:
             st = self._state(labels)
             for i, c in enumerate(binned):
                 if c:
                     st.counts[i] += int(c)
-            st.sum += float(values.sum())
-            st.count += int(values.size)
+            st.sum += total
+            st.count += n
 
     def count(self, **labels) -> int:
         st = self._states.get(self._key(labels))

@@ -55,7 +55,7 @@ from collections import OrderedDict
 import numpy as np
 
 from . import families
-from .metrics import TIME_BUCKETS, bucket_quantile
+from .metrics import TIME_BUCKETS, bin_weighted, bucket_quantile
 from .trace import TRACER
 
 #: the CLOSED phase vocabulary (tools/metrics_lint.py rejects children of
@@ -182,22 +182,23 @@ class PhaseProfiler:
                     if ns > 0:
                         st.phase_ns[ph] = st.phase_ns.get(ph, 0) + ns
 
-    def account_latency(self, path: str | None, values_s) -> None:
+    def account_latency(self, path: str | None, values_s,
+                        weights=None) -> None:
         """Fold one pass's delivered-packet latencies (seconds, array)
         into the session's attribution histogram — one searchsorted +
-        bincount per PASS, mirroring ``Histogram.observe_many``."""
+        bincount per PASS, mirroring ``Histogram.observe_many``
+        (``weights`` as there: value i counts ``weights[i]`` times)."""
         if not self.enabled or path is None:
             return
         values = np.asarray(values_s, dtype=np.float64).ravel()
         if values.size == 0:
             return
-        idx = np.searchsorted(self._bounds, values, side="left")
-        binned = np.bincount(idx, minlength=len(self._bounds) + 1)
+        binned, total, n = bin_weighted(self._bounds, values, weights)
         with self._lock:
             st = self._session(path)
             st.lat_counts += binned
-            st.lat_sum += float(values.sum())
-            st.lat_count += int(values.size)
+            st.lat_sum += total
+            st.lat_count += n
 
     def _session(self, path: str) -> _SessionStat:
         """Caller holds ``self._lock``."""
@@ -296,17 +297,20 @@ def builds() -> float:
 
 
 def observe_wire(engine: str, lat_s: np.ndarray, runs,
-                 delay_ms: int) -> None:
+                 delay_ms: int, weights=None) -> None:
     """One pass's delivered (packet, subscriber) latencies into both
     wire histograms.  ``lat_s`` is ingest→wire in delivery order;
     ``runs`` is ``(count, bucket index)`` per run of consecutive
-    deliveries to one output, whose declared hold is bucket index ×
-    ``delay_ms``.  The ONE place the pair is observed, so
+    entries of one bucket, whose declared hold is bucket index ×
+    ``delay_ms``.  With ``weights`` an entry stands for that many
+    deliveries (the cohort step: one row of latencies per cohort, not
+    per subscriber).  The ONE place the pair is observed, so
     ``relay_due_to_wire_seconds`` and ``relay_ingest_to_wire_seconds``
     always have the same count.  ``lat_s`` is CONSUMED: the hold comes
     off it in place, one slice per run of equal buckets and no second
     array of its size — call this after its other readers."""
-    families.RELAY_INGEST_TO_WIRE.observe_many(lat_s, engine=engine)
+    families.RELAY_INGEST_TO_WIRE.observe_many(lat_s, weights,
+                                               engine=engine)
     step = delay_ms / 1e3
     lo = hi = cur = 0
     for n, b in runs:
@@ -318,7 +322,7 @@ def observe_wire(engine: str, lat_s: np.ndarray, runs,
     if cur:
         lat_s[lo:hi] -= cur * step
     np.maximum(lat_s, 0.0, out=lat_s)
-    families.RELAY_DUE_TO_WIRE.observe_many(lat_s, engine=engine)
+    families.RELAY_DUE_TO_WIRE.observe_many(lat_s, weights, engine=engine)
 
 
 # ---------------------------------------------------------------- pprof

@@ -54,7 +54,8 @@ SPANS = (
     "pump.live_relay", "pump.megabatch", "pump.vod_fill", "pump.dvr_spill",
     "pump.hls_requant", "pump.fec_parity", "pump.checkpoint",
     "pump.cluster_tick",
-    "engine.step", "engine.prime", "engine.ring_sync", "engine.params",
+    "engine.step", "engine.plan", "engine.prime", "engine.ring_sync",
+    "engine.params",
     "engine.egress", "engine.account", "engine.rtcp",
     "megabatch.harvest", "megabatch.fetch", "megabatch.prime",
     "megabatch.dispatch", "megabatch.gather", "megabatch.h2d",

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import errno as errno_mod
 import time
+import weakref
 
 import numpy as np
 
@@ -84,6 +85,90 @@ def _native_mod():
     return native if native.available() else None
 
 
+class _Plan:
+    """One stream's output tables as one engine steps them, built once
+    per membership epoch (``RelayStream.plan_epoch``) and not once per
+    wake.  The unit of a step is the **cohort**: the native-fast UDP
+    outputs of one bucket that share a bookmark.  ``out.bookmark`` stays
+    the truth; a cohort's mark is a copy, written back to the outputs
+    in the step that moves it."""
+
+    __slots__ = ("epoch", "native_ok", "n_outputs", "udp", "udp_key",
+                 "dests", "aud_rows", "b_arr", "cohorts",
+                 "unprimed", "other", "fast", "key", "tcp", "slow")
+
+    def __init__(self):
+        self.epoch = -1
+        self.native_ok = False
+        self.n_outputs = 0
+        #: native-fast UDP outputs in canonical (bucket-major) order: an
+        #: output's index here is its column in ``params_key``, the dest
+        #: table, the device state matrix and the op list
+        self.udp: list[RelayOutput] = []
+        self.udp_key: tuple = ()
+        self.dests = None               # native dest table, built on use
+        self.aud_rows = np.zeros(0, np.int64)   # audience row per column
+        #: bucket index of each entry of ``cohorts``
+        self.b_arr = np.zeros(0, np.int64)
+        #: per bucket with a fast output: {bookmark: int32 columns,
+        #: ascending}
+        self.cohorts: list[dict[int, np.ndarray]] = []
+        #: residue walked per output every wake, because what decides it
+        #: changes without notice: outputs not yet bookmarked or latched
+        #: (``_prime``'s work) ...
+        self.unprimed: list[tuple[RelayOutput, int]] = []
+        #: ... and every output that is not native-fast UDP (TCP, meta,
+        #: thinning, no address): ``engine_writable()`` / ``passthrough()``
+        self.other: list[tuple[RelayOutput, int]] = []
+        # this wake's view (``TpuFanoutEngine.plan`` refreshes it from
+        # ``other``): fast = udp + the TCP outputs writable now
+        self.fast: list[RelayOutput] = []
+        self.key: tuple = ()
+        self.tcp: list[tuple[RelayOutput, int]] = []
+        self.slow: list[tuple[RelayOutput, int]] = []
+
+    def tables(self) -> tuple:
+        """Everything the plan caches, in a form two plans compare by
+        (tests: a plan built from scratch equals the cached one)."""
+        return (self.native_ok, self.n_outputs,
+                [id(o) for o in self.udp], self.udp_key,
+                self.aud_rows.tolist(),
+                [(b, sorted((m, c.tolist()) for m, c in co.items()))
+                 for b, co in zip(self.b_arr.tolist(), self.cohorts)],
+                [(id(o), b) for o, b in self.unprimed],
+                [(id(o), b) for o, b in self.other])
+
+
+def _join(cohorts: dict, mark: int, cols: np.ndarray) -> None:
+    """Put ``cols`` under ``mark``, merging with the cohort already
+    there (a straggler that caught up to its bucket's mark)."""
+    have = cohorts.get(mark)
+    cohorts[mark] = cols if have is None else np.sort(
+        np.concatenate((have, cols)))
+
+
+def _file(co: dict, fast: list, cols: np.ndarray, mark: int) -> None:
+    """``cols`` now stand at ``mark`` with nothing else to account: write
+    it back to the outputs (the truth) and file them as one cohort."""
+    for c in cols.tolist():
+        fast[c]._bookmark = mark
+    _join(co, mark, cols)
+
+
+def _column_runs(parts: list) -> list:
+    """Several due cohorts of ONE bucket, as units in column order: a
+    unit is a run of consecutive due columns of one cohort, so the op
+    rows come out in fast-list order whatever the cohorts' shapes (one
+    cohort a bucket, the common case, never comes here)."""
+    cols = np.concatenate([p[0] for p in parts])
+    owner = np.repeat(np.arange(len(parts)), [len(p[0]) for p in parts])
+    order = np.argsort(cols, kind="stable")
+    cols, owner = cols[order], owner[order]
+    cuts = (np.flatnonzero(owner[1:] != owner[:-1]) + 1).tolist()
+    return [(cols[lo:hi],) + parts[owner[lo]][1:]
+            for lo, hi in zip([0] + cuts, cuts + [len(cols)])]
+
+
 class TpuFanoutEngine:
     """Batched fan-out for one stream.  Stateless between steps apart from
     jit caches; all mutable relay state stays in the stream/outputs.
@@ -147,6 +232,11 @@ class TpuFanoutEngine:
         self._params = None           # ([1,S] seq_off, ts_off, ssrc, chan)
         self._dests_key = None
         self._dests = None
+        #: stream -> its ``_Plan`` (weak: a torn-down stream's tables go
+        #: with it); ``plan()`` is the one way in
+        self._plans: weakref.WeakKeyDictionary = weakref.WeakKeyDictionary()
+        #: outputs a rebuild walked since the last step filed its count
+        self._rebuild_walked = 0
         # HBM-resident GOP ring (SURVEY §5 long-context analogue): the
         # classification window lives on the device; each pass APPENDS
         # only the new packets' prefixes (async dispatch, no sync), so
@@ -241,9 +331,9 @@ class TpuFanoutEngine:
         """Native fast-path predicate — the ONE definition step() and the
         megabatch scheduler share, so the scheduler stages params for
         exactly the output set the engine will send through sendmmsg."""
-        return (native_ok and out.bookmark is not None
+        return (native_ok and out._bookmark is not None
                 and getattr(out, "native_addr", None) is not None
-                and out.meta_field_ids is None
+                and out._meta_field_ids is None
                 and out.thinning.passthrough())
 
     def _tcp_eligible(self, out, native_ok: bool) -> bool:
@@ -258,55 +348,141 @@ class TpuFanoutEngine:
         return (self.tcp_fast_enabled
                 and _native_mod() is not None
                 and self.egress_backend != "scalar"
-                and out.bookmark is not None
+                and out._bookmark is not None
                 and getattr(out, "interleave_chan", None) is not None
                 and getattr(out, "stream_fd", -1) >= 0
-                and out.meta_field_ids is None
+                and out._meta_field_ids is None
                 and out.thinning.passthrough()
                 and out.engine_writable())
 
-    def fast_from_flat(self, flat) -> list:
-        """Canonical fast-list order over one output scan: every
-        UDP-fast output first, then every TCP-fast output.  BOTH the
-        engine and the megabatch scheduler build ``params_key`` and the
-        device state matrix in this order, so a scheduler-staged pass
-        lands on exactly the columns the engine will consume."""
+    # -- the output plan ---------------------------------------------------
+    def plan(self, stream: RelayStream, now_ms: int) -> _Plan:
+        """This stream's output plan, current as of this wake: the ONE
+        place the engine and the megabatch scheduler get the fast list
+        (every UDP-fast output, bucket-major, then every TCP-fast one)
+        and ``params_key`` from, so a scheduler-staged pass lands on
+        exactly the columns the engine will consume.
+
+        Per call: ``_prime``'s placement and latch over the un-primed
+        residue, one epoch comparison, and the TCP / meta / thinning
+        residue's predicates.  A moved epoch (``RelayStream.plan_epoch``
+        — membership, a bookmark written from outside the engine, a
+        rewrite field, a thinning level, ``meta_field_ids``) or a change
+        of ``_native_ok()`` rebuilds the tables."""
+        p = self._plans.get(stream)
         ok = self._native_ok()
-        udp = [o for o, _ in flat if self._fast_eligible(o, ok)]
-        tcp = [o for o, _ in flat if self._tcp_eligible(o, ok)]
-        return udp + tcp
+        if p is not None and p.native_ok == ok:
+            if p.unprimed:
+                tok = self._open("engine.prime")
+                self._prime(stream, p.unprimed, now_ms)
+                TRACER.close(tok)
+            if (p.epoch == stream.plan_epoch
+                    and self._split_residue(p, stream.rtp_ring, ok)):
+                return p
+        t0 = time.perf_counter_ns()
+        p = self._plans[stream] = self._build_plan(stream, now_ms, ok)
+        self._rebuild_walked += p.n_outputs
+        obs.ENGINE_PLAN_REBUILDS.inc()
+        if TRACER.enabled:              # ring only: rare, and post hoc
+            args = dict(self._span_args, outputs=p.n_outputs,
+                        fast=len(p.udp))
+            if TRACER.wake is not None:
+                args["wake"] = TRACER.wake
+            TRACER.add("engine.plan", t0, cat="tpu", **args)
+        return p
 
-    def fast_outputs(self, stream: RelayStream) -> list:
-        """This stream's native-fast outputs in fast-list order (the
-        order ``params_key`` and the dest table are built in)."""
-        return self.fast_from_flat(self._flat_outputs(stream))
+    def _build_plan(self, stream: RelayStream, now_ms: int,
+                    ok: bool) -> _Plan:
+        """One walk over every output: prime, classify, group the UDP
+        fast list's columns by (bucket, bookmark)."""
+        flat = [(out, b_idx) for b_idx, bucket in enumerate(stream.buckets)
+                for out in bucket]
+        self._prime(stream, [pair for pair in flat
+                             if pair[0]._bookmark is None
+                             or pair[0].rewrite.base_src_seq < 0], now_ms)
+        p = _Plan()
+        p.native_ok = ok
+        p.n_outputs = len(flat)
+        cur_b = -1
+        b_list: list[int] = []
+        marks: dict[int, list[int]] = {}
+        for pair in flat:
+            out, b_idx = pair
+            if out._bookmark is None or out.rewrite.base_src_seq < 0:
+                p.unprimed.append(pair)
+            if not self._fast_eligible(out, ok):
+                p.other.append(pair)
+                continue
+            if b_idx != cur_b:
+                cur_b = b_idx
+                marks = {}
+                b_list.append(b_idx)
+                p.cohorts.append(marks)
+            marks.setdefault(out._bookmark, []).append(len(p.udp))
+            p.udp.append(out)
+        for co in p.cohorts:
+            for mark, cols in co.items():
+                co[mark] = np.asarray(cols, np.int32)
+        p.b_arr = np.asarray(b_list, np.int64)
+        p.udp_key = params_key(p.udp)
+        p.aud_rows = np.asarray(
+            [getattr(o, "audience_row", -1) for o in p.udp], np.int64)
+        # the epoch as the walk leaves it: _prime's own writes moved it
+        p.epoch = stream.plan_epoch
+        p.fast, p.key = p.udp, p.udp_key
+        # cannot ask for another rebuild: nothing in ``other`` is fast
+        self._split_residue(p, stream.rtp_ring, ok)
+        return p
 
-    def _flat_outputs(self, stream: RelayStream):
-        flat: list[tuple[RelayOutput, int]] = []
-        for b_idx, bucket in enumerate(stream.buckets):
-            for out in bucket:
-                flat.append((out, b_idx))
-        return flat
+    def _split_residue(self, p: _Plan, ring, ok: bool) -> bool:
+        """This wake's TCP-fast and batch-header lists out of the
+        residue, and the fast list / key they make with the cached UDP
+        part.  False where a residue output has turned UDP-fast (its
+        thinning filter went back to pass-through): rebuild."""
+        if not p.other:
+            return True                 # fast / key are the UDP tables
+        tail = ring.tail
+        tcp, slow = [], []
+        for pair in p.other:
+            out = pair[0]
+            bm = out._bookmark
+            if bm is not None and bm < tail:
+                out._bookmark = tail    # evicted from under a stalled output
+            if self._fast_eligible(out, ok):
+                return False
+            (tcp if self._tcp_eligible(out, ok) else slow).append(pair)
+        p.tcp, p.slow = tcp, slow
+        if tcp:
+            outs = [o for o, _ in tcp]
+            p.fast, p.key = p.udp + outs, p.udp_key + params_key(outs)
+        else:
+            p.fast, p.key = p.udp, p.udp_key
+        return True
 
-    def _prime(self, stream: RelayStream, flat, now_ms: int) -> None:
-        """New-output placement + seq/ts rebase priming.
+    def _prime(self, stream: RelayStream, pairs, now_ms: int) -> None:
+        """New-output placement + seq/ts rebase priming, over the
+        outputs that still need either.
 
         The scalar oracle latches the rebase origin exactly once, inside the
         first ``write_rtp`` *attempt* (``RewriteState.base_src_seq < 0``
         check — even a WOULD_BLOCK'd attempt latches).  Mirror that: latch
         only if unlatched, from the first ring packet this output would
         attempt this pass (bookmark advanced past runts, and only if that
-        packet is bucket-eligible now)."""
+        packet is bucket-eligible now).  A placement or a latch moves the
+        stream's plan epoch: the output changes lists, or its key."""
         ring = stream.rtp_ring
         delay = stream.settings.bucket_delay_ms
-        for out, b_idx in flat:
-            if out.bookmark is None:
-                out.bookmark = stream.first_packet_for_new_output(now_ms)
-            if out.bookmark is not None and out.bookmark < ring.tail:
-                out.bookmark = ring.tail
-            if out.rewrite.base_src_seq >= 0 or out.bookmark is None:
+        moved = False
+        for out, b_idx in pairs:
+            if out._bookmark is None:
+                out._bookmark = stream.first_packet_for_new_output(now_ms)
+                moved = moved or out._bookmark is not None
+            if out._bookmark is not None and out._bookmark < ring.tail:
+                out._bookmark = ring.tail
+                moved = True            # it may sit in a cohort
+            if out.rewrite.base_src_seq >= 0 or out._bookmark is None:
                 continue
-            pid = out.bookmark
+            pid = out._bookmark
             while pid < ring.head and ring.length[ring.slot(pid)] < 12:
                 pid += 1               # runts are skipped, never latched
             if pid >= ring.head:
@@ -315,6 +491,9 @@ class TpuFanoutEngine:
             if now_ms - int(ring.arrival[s]) >= b_idx * delay:
                 out.rewrite.base_src_seq = int(ring.seq[s])
                 out.rewrite.base_src_ts = int(ring.timestamp[s])
+                moved = True
+        if moved:
+            stream.touch_plan()
 
     # -- the batch pass ----------------------------------------------------
     def _phase_add(self, phase: str, dur_ns: int,
@@ -351,31 +530,18 @@ class TpuFanoutEngine:
         step_span = self._open("engine.step")
         t0 = t0_of(step_span)
         ring = stream.rtp_ring
-        flat = self._flat_outputs(stream)
-        if not flat or len(ring) == 0:
-            TRACER.close(step_span, outputs=len(flat), sent=0)
+        if not stream.num_outputs or len(ring) == 0:
+            TRACER.close(step_span, outputs=stream.num_outputs, sent=0)
             return 0
         profiled = self._profiled = PROFILER.enabled
         self._pass_phases = {}
         self._pass_wire_bytes = 0
         self._pass_walked = self._pass_due = 0
-        tok = self._open("engine.prime")
-        self._prime(stream, flat, now_ms)
-        TRACER.close(tok)
-        fast: list[tuple[RelayOutput, int]] = []
-        tcp: list[tuple[RelayOutput, int]] = []
-        slow: list[tuple[RelayOutput, int]] = []
-        native_ok = self._native_ok()
-        for out, b_idx in flat:
-            if self._fast_eligible(out, native_ok):
-                fast.append((out, b_idx))
-            elif self._tcp_eligible(out, native_ok):
-                tcp.append((out, b_idx))
-            else:
-                slow.append((out, b_idx))
+        plan = self.plan(stream, now_ms)
+        fast, tcp, slow = plan.udp, plan.tcp, plan.slow
         sent = 0
         if fast or tcp:
-            sent += self._native_step(stream, fast, tcp, now_ms)
+            sent += self._native_step(stream, plan, now_ms)
         if slow:
             sent += self._batch_header_step(stream, slow, now_ms)
         # RTCP relay + SR origination, identical to the scalar path
@@ -401,11 +567,15 @@ class TpuFanoutEngine:
         stream.stats.packets_out += sent
         self.steps += 1
         self.packets_sent += sent
-        dur = TRACER.close(step_span, sent=sent, outputs=len(flat),
+        dur = TRACER.close(step_span, sent=sent, outputs=plan.n_outputs,
                            due_outputs=self._pass_due) - t0
         obs.TPU_PASS_SECONDS.observe(dur / 1e9, stage="engine_step")
         obs.TPU_PASSES.inc()
-        obs.ENGINE_OUTPUTS_WALKED.inc(self._pass_walked)
+        # output objects this step touched: the due cohorts' members, the
+        # residue it sent for, and a rebuild's walk where there was one
+        obs.ENGINE_OUTPUTS_WALKED.inc(self._pass_walked
+                                      + self._rebuild_walked)
+        self._rebuild_walked = 0
         if self._pass_due:
             obs.ENGINE_OUTPUTS_DUE.inc(self._pass_due)
         if sent:
@@ -424,13 +594,15 @@ class TpuFanoutEngine:
         return sent
 
     # -- native fast path --------------------------------------------------
-    def _dests_for(self, fast):
-        from .. import native
-        key = tuple(o.native_addr for o, _ in fast)
-        if key != self._dests_key:
-            self._dests = native.make_dests(list(key))
-            self._dests_key = key
-        return self._dests
+    def _dests_for(self, plan: _Plan):
+        if plan.dests is None:
+            from .. import native
+            key = tuple(o.native_addr for o in plan.udp)
+            if key != self._dests_key:
+                self._dests = native.make_dests(list(key))
+                self._dests_key = key
+            plan.dests = self._dests
+        return plan.dests
 
     def _ring_sync(self, ring, now_ms: int) -> None:
         """Append packets the device ring has not seen yet (O(new) H2D,
@@ -470,7 +642,7 @@ class TpuFanoutEngine:
         self.h2d_appended_bytes += b_pad * (self.prefix_width + 8)
         obs.TPU_H2D_BYTES.inc(b_pad * (self.prefix_width + 8))
 
-    def _device_params(self, fast, ring, now_ms: int):
+    def _device_params(self, fast, key, ring, now_ms: int):
         """Affine egress params from the device step over the RESIDENT
         window (``ops.device_ring``) — no window re-staging.
 
@@ -490,7 +662,6 @@ class TpuFanoutEngine:
                 self._params_key = None
                 self.megabatch_params = None
             INJECTOR.device_dispatch("fanout.device_params")
-        key = params_key([o for o, _ in fast])
         if key == self._params_key:
             return self._params
         mb = self.megabatch_params
@@ -517,8 +688,7 @@ class TpuFanoutEngine:
         built0 = builds()
         s_pad = _pow2(S, 8)
         state = np.zeros((s_pad, fanout_ops.STATE_COLS), np.uint32)
-        state[:S] = np.asarray(
-            fanout_ops.pack_output_state([o for o, _ in fast]))
+        state[:S] = np.asarray(fanout_ops.pack_output_state(fast))
         res = device_ring.query(self._dring, state,
                                 np.int32(now_ms - self._dring_epoch))
         # phase split: dispatching the fused query is device_step; the
@@ -552,27 +722,61 @@ class TpuFanoutEngine:
                                      stage="device_params")
         return self._params
 
-    def _native_step(self, stream: RelayStream, fast, tcp,
+    def _native_step(self, stream: RelayStream, plan: _Plan,
                      now_ms: int) -> int:
         """Send every eligible (packet, output) pair through the native
         senders — ONE sendmmsg/GSO scatter for the UDP set, one framed
         writev/io_uring batch per interleaved-TCP connection — all from
         ONE device param pass (the affine rewrite plus the interleave
-        channel column ride the same query)."""
+        channel column ride the same query).
+
+        Due selection is bucket-major: one ``searchsorted`` over the
+        buckets' deadlines, then one comparison per cohort.  A stream
+        with nothing due returns here, before the device params and
+        before any output object is touched."""
         ring = stream.rtp_ring
+        tcp = plan.tcp
         # extracting the host window view is part of staging it: one
         # h2d bracket over the view and the device-ring append
         tok = self._open("engine.ring_sync")
         built0 = builds()
-        combined = fast + tcp
-        start = min(o.bookmark for o, _ in combined)
-        ids, lengths, _flags = ring.window_meta(start, ring.head - start)
+        head = ring.head
+        start = head
+        for co in plan.cohorts:
+            for mark in co:
+                if mark < start:
+                    start = mark
+        if start < ring.tail:
+            start = self._clamp_cohorts(plan, ring.tail)
+        for o, _ in tcp:
+            if o._bookmark < start:
+                start = o._bookmark
+        if start >= head:                   # everyone has caught up
+            TRACER.close(tok)
+            return 0
+        ids, lengths, _flags = ring.window_meta(start, head - start)
         if len(ids) == 0:
             TRACER.close(tok)
             return 0
         start = int(ids[0])                 # window_meta clamps to tail
         idx = (ids % ring.capacity).astype(np.int32)
         arrivals = ring.arrival[idx]        # nondecreasing (ingest clock)
+        # (bucket entry, mark, window index its hold has released up to)
+        due: list[tuple[int, int, int]] = []
+        if plan.cohorts:
+            his = np.searchsorted(
+                arrivals,
+                now_ms - plan.b_arr * stream.settings.bucket_delay_ms,
+                side="right").tolist()
+            for bi, co in enumerate(plan.cohorts):
+                hi = his[bi]
+                if hi:
+                    for mark in co:
+                        if start + hi > mark:
+                            due.append((bi, mark, hi))
+        if not due and not tcp:
+            TRACER.close(tok)
+            return 0
         valid = lengths >= 12
         if not self.megabatch_owned:
             # scheduler-owned streams skip the per-wake device append:
@@ -587,67 +791,91 @@ class TpuFanoutEngine:
         # actual.  The ratio is the device-ring saving (VERDICT r2 item 6).
         live_window = ring.head - max(ring.tail, ring.head - ring.capacity)
         self.h2d_window_equiv_bytes += live_window * (self.prefix_width + 8)
-        seq_off, ts_off, ssrc, chan = self._device_params(combined, ring,
-                                                          now_ms)
+        seq_off, ts_off, ssrc, chan = self._device_params(
+            plan.fast, plan.key, ring, now_ms)
         sent = 0
-        if fast:
-            sent += self._udp_scatter(stream, fast, start, ids, idx,
-                                      arrivals, valid, lengths,
-                                      seq_off, ts_off, ssrc, now_ms)
+        if due:
+            try:
+                sent += self._udp_scatter(stream, plan, due, start, ids,
+                                          idx, valid, lengths, seq_off,
+                                          ts_off, ssrc)
+            except BaseException:
+                stream.touch_plan()     # due cohorts may be half settled
+                raise
         if tcp:
-            sent += self._tcp_scatter(stream, tcp, len(fast), start, ids,
-                                      idx, arrivals, valid, lengths,
+            sent += self._tcp_scatter(stream, tcp, len(plan.udp), start,
+                                      ids, idx, arrivals, valid, lengths,
                                       seq_off, ts_off, ssrc, chan, now_ms)
         self.native_passes += 1
         return sent
 
-    def _udp_scatter(self, stream: RelayStream, fast, start, ids, idx,
-                     arrivals, valid, lengths, seq_off, ts_off, ssrc,
-                     now_ms: int) -> int:
+    def _clamp_cohorts(self, plan: _Plan, tail: int) -> int:
+        """The ring evicted past a stalled cohort: it resumes at the
+        tail, as ``_prime`` has it for one output.  Returns the tail."""
+        for co in plan.cohorts:
+            for mark in [m for m in co if m < tail]:
+                _file(co, plan.udp, co.pop(mark), tail)
+        return tail
+
+    def _udp_scatter(self, stream: RelayStream, plan: _Plan, due, start,
+                     ids, idx, valid, lengths, seq_off, ts_off,
+                     ssrc) -> int:
         from .. import native
         ring = stream.rtp_ring
         delay = stream.settings.bucket_delay_ms
+        fast = plan.udp
+        cohorts = plan.cohorts
         # egress_native starts HERE: everything from params-in-hand to
-        # wire — per-output span selection, the scatter op list, and the
-        # native sendmmsg/GSO calls — is the egress stage (leaving the
+        # wire — span selection, the scatter op list, and the native
+        # sendmmsg/GSO calls — is the egress stage (leaving the
         # op-list numpy unphased put Σ(phases) ~15% under the pass total)
         egress = self._open("engine.egress")
-        # per-output eligible spans (numpy slices, no per-op Python)
-        per_out = []                        # (out, hi, pids, slots, lens)
+        # one unit per due cohort: (bucket entry, columns, bookmark once
+        # sent, pids, slots, lens) — numpy slices of the window, shared
+        # by every output of the cohort
+        units: list[tuple] = []
         total = 0
-        due = 0                             # outputs with a packet to send
-        for s, (out, b_idx) in enumerate(fast):
-            lo = max(out.bookmark - start, 0)
-            hi = int(np.searchsorted(arrivals, now_ms - b_idx * delay,
-                                     side="right"))
-            if hi <= lo:
-                per_out.append((out, None, None, None, None))
-                continue
-            due += 1
-            sel = valid[lo:hi]
-            per_out.append((out, hi, ids[lo:hi][sel], idx[lo:hi][sel],
-                            lengths[lo:hi][sel]))
-            total += int(sel.sum())
-        self._pass_walked += len(fast)
-        self._pass_due += due
+        n_due = 0                           # outputs with a packet to send
+        i = 0
+        while i < len(due):
+            bi = due[i][0]
+            parts = []
+            while i < len(due) and due[i][0] == bi:
+                _bi, mark, hi = due[i]
+                i += 1
+                lo = max(mark - start, 0)
+                sel = valid[lo:hi]
+                parts.append((cohorts[bi][mark], start + hi,
+                              ids[lo:hi][sel], idx[lo:hi][sel],
+                              lengths[lo:hi][sel]))
+            if len(parts) > 1:
+                parts = _column_runs(parts)
+            for part in parts:
+                units.append((bi,) + part)
+                n_due += len(part[0])
+                total += len(part[0]) * len(part[2])
+        self._pass_walked += n_due
+        self._pass_due += n_due
         if total == 0:
-            for out, hi, _p, _s, _l in per_out:
-                if hi is not None:          # runt-only span: skip past it
-                    out.bookmark = start + hi
-            TRACER.close(egress, outputs=len(fast), due_outputs=due,
+            for bi, mark, _hi in due:
+                del cohorts[bi][mark]
+            for bi, cols, hi_abs, _p, _s, _l in units:
+                _file(cohorts[bi], fast, cols, hi_abs)  # runt-only: skip
+            TRACER.close(egress, outputs=len(fast), due_outputs=n_due,
                          sent=0)
             return 0
+        # the SAME op list, row for row, as one span per output would
+        # build: columns ascend (fast order is bucket-major) and a
+        # cohort's columns each carry its slots
         ops_np = np.empty((total, 2), np.int32)
         pos = 0
-        counts = []
-        for s, (out, hi, pids, slots, lens) in enumerate(per_out):
-            n = 0 if pids is None else len(pids)
-            counts.append(n)
+        for _bi, cols, _hi, pids, slots, _l in units:
+            n = len(cols) * len(pids)
             if n:
-                ops_np[pos:pos + n, 0] = slots
-                ops_np[pos:pos + n, 1] = s
+                ops_np[pos:pos + n, 0] = np.tile(slots, len(cols))
+                ops_np[pos:pos + n, 1] = np.repeat(cols, len(pids))
                 pos += n
-        dests = self._dests_for(fast)
+        dests = self._dests_for(plan)
         ops = native.ops_from_numpy(ops_np)
         trace_id = stream.trace_id
         backend = self.effective_backend()
@@ -738,82 +966,113 @@ class TpuFanoutEngine:
         # cost is comparable across rungs on one dashboard
         wire_ns = self._close(
             egress, "egress_io_uring" if used_backend == "io_uring"
-            else "egress_native", outputs=len(fast), due_outputs=due,
+            else "egress_native", outputs=len(fast), due_outputs=n_due,
             sent=int(r))
-        # bookmark/stat accounting, exact under partial (EAGAIN) sends
+        # bookmark/stat accounting by cohort, exact under partial
+        # (EAGAIN) sends: a partial send splits the cohort at its
+        # boundary, and a straggler is a cohort of one
         account = self._open("engine.account")
-        taken = 0
-        hard_consumed = False
-        sent_slots: list[np.ndarray] = []   # → ingest→wire histogram
-        hold_runs: list[tuple] = []         # (deliveries, bucket) of each
-        # audience aggregates (obs/audience.py): assembled inside this
-        # existing accounting walk, applied as ONE vectorized column
-        # pass below; disabled = one attribute check
+        # one entry per piece — outputs of a cohort that got the same
+        # packets: its delivered slots, and its size as their weight
+        lat_slots: list[np.ndarray] = []    # → both wire histograms
+        lat_w: list[int] = []
+        hold_runs: list[tuple] = []         # (slots, bucket) of each piece
+        # audience aggregates (obs/audience.py): assembled per piece,
+        # applied as ONE vectorized column pass below; disabled = one
+        # attribute check
         aud = obs.AUDIENCE
         ablk = stream.audience if aud.enabled else None
-        a_rows: list[int] = []
-        a_pkts: list[int] = []
-        a_byts: list[int] = []
-        a_first: list[int] = []
-        a_last: list[int] = []
-        a_slots: list[np.ndarray] = []
-        for (out, hi, pids, slots, lens), n, (_o, b_idx) in zip(
-                per_out, counts, fast):
-            k = min(max(r - taken, 0), n)
-            taken += n
-            if n == 0:
-                if hi is not None:
-                    out.bookmark = start + hi
+        a_parts: list[tuple] = []   # (rows, k, bytes, first, last, piece)
+
+        def settle(co, b_idx, cols, k, mark, pids, slots, lens) -> None:
+            """``cols`` each got the unit's first ``k`` packets and now
+            stand at ``mark``: write that back to the outputs (the
+            truth) and file them as one cohort."""
+            if not k:
+                return _file(co, fast, cols, mark)
+            nbytes = int(lens[:k].sum())
+            for c in cols.tolist():
+                out = fast[c]
+                out._bookmark = mark
+                out.packets_sent += k
+                out.bytes_sent += nbytes
+                out.payload_octets += nbytes - 12 * k
+            self._pass_wire_bytes += nbytes * len(cols)
+            if ablk is not None:
+                rows = plan.aud_rows[cols]
+                rows = rows[rows >= 0]
+                if rows.size:
+                    a_parts.append((rows, k, nbytes, int(pids[0]),
+                                    int(pids[k - 1]), len(lat_slots)))
+            lat_slots.append(slots[:k])
+            lat_w.append(len(cols))
+            hold_runs.append((k, b_idx))
+            _join(co, mark, cols)
+
+        for bi, mark, _hi in due:           # every member gets a new mark
+            del cohorts[bi][mark]
+        taken = 0
+        hard_consumed = False
+        for bi, cols, hi_abs, pids, slots, lens in units:
+            co, b_idx = cohorts[bi], int(plan.b_arr[bi])
+            n, m = len(cols), len(pids)
+            if m == 0:                      # runt-only span: skip past it
+                settle(co, b_idx, cols, 0, hi_abs, pids, slots, lens)
                 continue
-            if k == n:
-                out.bookmark = start + hi
-            elif hard and not hard_consumed:
+            got = min(max(r - taken, 0), n * m)
+            taken += n * m
+            full, k = divmod(got, m)        # whole outputs; the next one's
+            if full:
+                settle(co, b_idx, cols[:full], m, hi_abs, pids, slots, lens)
+            if full == n:
+                continue
+            edge = fast[int(cols[full])]    # where the send stopped
+            if hard and not hard_consumed:
                 # the datagram at the boundary failed hard (unroutable/
                 # rejected destination): drop this output's remainder for
                 # the pass so it cannot starve the outputs behind it
                 hard_consumed = True
-                out.bookmark = start + hi
-                self.send_errors += n - k
+                mark = hi_abs
+                self.send_errors += m - k
             else:
-                out.bookmark = int(pids[k])  # first unsent packet
-                out.stalls += 1
+                mark = int(pids[k])         # first unsent packet
+                edge.stalls += 1
                 stream.stats.stalls += 1
-            if k:
-                out.packets_sent += k
-                sent_bytes = int(lens[:k].sum())
-                out.bytes_sent += sent_bytes
-                out.payload_octets += sent_bytes - 12 * k
-                self._pass_wire_bytes += sent_bytes
-                sent_slots.append(slots[:k])
-                hold_runs.append((k, b_idx))
-                if ablk is not None:
-                    row = getattr(out, "audience_row", -1)
-                    if row >= 0:
-                        a_rows.append(row)
-                        a_pkts.append(k)
-                        a_byts.append(sent_bytes)
-                        a_first.append(int(pids[0]))
-                        a_last.append(int(pids[k - 1]))
-                        a_slots.append(slots[:k])
-        if a_rows:
-            a_cat = (a_slots[0] if len(a_slots) == 1
-                     else np.concatenate(a_slots))
-            aud.note_pass(ablk, a_rows, a_pkts, a_byts, a_first, a_last,
-                          (wire_ns - ring.arrival_ns[a_cat]) / 1e9,
-                          wire_ns)
-        if sent_slots:
+            settle(co, b_idx, cols[full:full + 1], k, mark, pids, slots,
+                   lens)
+            rest = cols[full + 1:]
+            if len(rest):                   # nothing of theirs went out
+                for c in rest.tolist():
+                    fast[c].stalls += 1
+                stream.stats.stalls += len(rest)
+                settle(co, b_idx, rest, 0, int(pids[0]), pids, slots, lens)
+        if lat_slots:
             # one vectorized observe per pass: perf_counter stamp at
             # push_rtp minus the send-return instant, per delivered
-            # (packet, subscriber) pair
-            all_slots = (sent_slots[0] if len(sent_slots) == 1
-                         else np.concatenate(sent_slots))
+            # (packet, piece) pair, weighted by the piece's outputs
+            sizes = [len(x) for x in lat_slots]
+            all_slots = (lat_slots[0] if len(lat_slots) == 1
+                         else np.concatenate(lat_slots))
             lat_s = (wire_ns - ring.arrival_ns[all_slots]) / 1e9
+            weights = np.repeat(np.asarray(lat_w, np.int64), sizes)
+            if a_parts:
+                offs = np.cumsum([0] + sizes).tolist()
+                cnt = [p[0].size for p in a_parts]
+                aud.note_pass(
+                    ablk, np.concatenate([p[0] for p in a_parts]),
+                    *(np.repeat(np.asarray([p[j] for p in a_parts],
+                                           np.int64), cnt)
+                      for j in (1, 2, 3, 4)),
+                    np.concatenate([
+                        np.tile(lat_s[offs[p[5]]:offs[p[5]] + p[1]],
+                                p[0].size) for p in a_parts]),
+                    wire_ns)
             if obs.LEDGER.enabled:
-                obs.LEDGER.note_queue_age(float(lat_s.max()), lat_s.size)
+                obs.LEDGER.note_queue_age(float(lat_s.max()), int(r))
             # per-session attribution (top-by-p99 in command=top)
-            PROFILER.account_latency(stream.session_path, lat_s)
+            PROFILER.account_latency(stream.session_path, lat_s, weights)
             # last: it takes the hold off lat_s in place
-            obs.observe_wire("native", lat_s, hold_runs, delay)
+            obs.observe_wire("native", lat_s, hold_runs, delay, weights)
         self.native_sent += r
         TRACER.close(account)
         return int(r)
@@ -903,19 +1162,19 @@ class TpuFanoutEngine:
             # deep-backlog shed BEFORE building the span: a reader this
             # far behind gets whole AUs dropped (resume at the newest
             # keyframe) instead of a doomed mega-writev
-            behind = ring.head - out.bookmark
+            behind = ring.head - out._bookmark
             if behind > ring.capacity // 2:
                 kf = stream.keyframe_id
-                if kf is None or kf <= out.bookmark:
+                if kf is None or kf <= out._bookmark:
                     kf = ring.head - ring.capacity // 4
-                shed = int(kf - out.bookmark)
+                shed = int(kf - out._bookmark)
                 if shed > 0:
-                    out.bookmark = int(kf)
+                    out._bookmark = int(kf)
                     out.stalls += 1
                     stream.stats.stalls += 1
                     obs.TCP_EGRESS_BACKPRESSURE_SHEDS.inc(
                         shed, backend=backend)
-            lo = max(out.bookmark - start, 0)
+            lo = max(out._bookmark - start, 0)
             hi = int(np.searchsorted(arrivals, now_ms - b_idx * delay,
                                      side="right"))
             if hi <= lo:
@@ -926,7 +1185,7 @@ class TpuFanoutEngine:
             slots = np.ascontiguousarray(idx[lo:hi][sel])
             lens = lengths[lo:hi][sel]
             if len(pids) == 0:
-                out.bookmark = start + hi   # runt-only span: skip past it
+                out._bookmark = start + hi   # runt-only span: skip past it
                 continue
             ch = int(chan[0, col]) & 0xFF
             args = (out.stream_fd, ring.data, ring.length,
@@ -953,7 +1212,7 @@ class TpuFanoutEngine:
                 else:
                     # hard connection error: ERROR semantics — skip the
                     # span so a dead socket cannot starve the pass
-                    out.bookmark = start + hi
+                    out._bookmark = start + hi
                     self.send_errors += len(pids)
                 continue
             k = int(r)
@@ -975,14 +1234,14 @@ class TpuFanoutEngine:
                     # stall, or the torn packet would be re-sent in
                     # full on a socket that already carries its prefix
                     dead = True
-                    out.bookmark = start + hi
+                    out._bookmark = start + hi
                     self.send_errors += len(pids) - k
             if dead:
                 pass                        # span skipped above
             elif k == len(pids):
-                out.bookmark = start + hi
+                out._bookmark = start + hi
             else:
-                out.bookmark = int(pids[k])  # first unsent packet
+                out._bookmark = int(pids[k])  # first unsent packet
                 out.stalls += 1
                 stream.stats.stalls += 1
             if k:
@@ -1033,7 +1292,7 @@ class TpuFanoutEngine:
     def _batch_header_step(self, stream: RelayStream, flat,
                            now_ms: int) -> int:
         ring = stream.rtp_ring
-        starts = [o.bookmark for o, _ in flat if o.bookmark is not None]
+        starts = [o._bookmark for o, _ in flat if o._bookmark is not None]
         if not starts:
             return 0
         start = min(starts)
@@ -1101,7 +1360,7 @@ class TpuFanoutEngine:
         a_last: list[int] = []
         a_lat: list[int] = []
         for s, (out, b_idx) in enumerate(flat):
-            pid = out.bookmark
+            pid = out._bookmark
             if pid is None:
                 continue
             deadline = now_ms - b_idx * delay
@@ -1119,7 +1378,7 @@ class TpuFanoutEngine:
                 # (break holds the bookmark), runt-skip second (advance)
                 if int(ring.arrival[slot]) > deadline:
                     break
-                if pid == out.bookmark:
+                if pid == out._bookmark:
                     due += 1                # its first packet is past hold
                 if ring.length[slot] < 12:
                     pid += 1
@@ -1152,7 +1411,7 @@ class TpuFanoutEngine:
                             o_first = pid - 1
                         o_last = pid - 1
                         a_lat.append(stamp)
-            out.bookmark = pid
+            out._bookmark = pid
             if tcp_ok:
                 hold_runs.append((tcp_ok, b_idx))
             if o_sent:

@@ -87,7 +87,7 @@ from ..obs.profile import builds
 from ..ops import staging
 from ..ops.fanout import STATE_COLS, pack_output_state
 from ..resilience.inject import INJECTOR
-from .fanout import _pow2, params_key
+from .fanout import _pow2
 
 
 def _host_affine_params(key) -> tuple:
@@ -167,12 +167,6 @@ class MegabatchScheduler:
         #: is already paid every wake; skips the O(S) python pack loop
         #: on unchanged membership
         self._state_cache: dict[int, tuple] = {}
-        #: id(stream) → (fast, key) computed by this WAKE's prime scan;
-        #: _collect reuses it instead of re-walking the outputs (the
-        #: pump loop is single-threaded, so membership cannot change
-        #: between begin_wake and end_wake; a stale entry would merely
-        #: stage params for a key the engine ignores)
-        self._wake_fast: dict[int, tuple] = {}
         self._inflight: list[_InFlight] = []
         # double-buffered staging: a free pool per (b_pad, p_pad) shape;
         # a buffer leaves the pool at dispatch and returns at harvest,
@@ -235,7 +229,7 @@ class MegabatchScheduler:
             LEDGER.defer("megabatch", len(pairs))
             return
         span = TRACER.open("megabatch.dispatch", "tpu")
-        work = self._collect(pairs)
+        work = self._collect(pairs, now_ms)
         if not work:
             TRACER.close(span, buckets=0, streams=0)
             return
@@ -258,20 +252,20 @@ class MegabatchScheduler:
     def _prime_stale(self, pairs, now_ms: int) -> None:
         """Synchronous stacked param pass for key-stale streams.
 
-        Runs the engine's own deterministic bookmark/rebase latch first
-        (idempotent — the engine's step re-runs it as a no-op with the
-        same wake timestamp), so the key computed here is the key the
-        engine will check moments later in the same wake.  The affine
+        Reads each engine's output plan (``TpuFanoutEngine.plan``): its
+        deterministic bookmark/rebase latch runs first, over the
+        un-primed residue only (idempotent — the engine's step re-runs
+        it as a no-op with the same wake timestamp), so the key read
+        here is the key the engine will check moments later in the same
+        wake.  The affine
         params depend only on that rewrite state, so the windows staged
         here are all-zero padding: no packet bytes ride the prime."""
         stale = []
-        self._wake_fast.clear()
         for stream, eng in pairs:
-            flat = eng._flat_outputs(stream)     # one scan: prime + filter
-            eng._prime(stream, flat, now_ms)
-            fast = eng.fast_from_flat(flat)
-            key = params_key(fast) if fast else None
-            self._wake_fast[id(stream)] = (fast, key)
+            # the engine's own tables: the un-primed residue is latched,
+            # nothing else is walked on an unchanged epoch
+            p = eng.plan(stream, now_ms)
+            fast, key = p.fast, p.key
             if not fast:
                 continue
             if key == eng._params_key or (
@@ -314,16 +308,12 @@ class MegabatchScheduler:
         TRACER.close(span)
 
     # ------------------------------------------------------------- collect
-    def _collect(self, pairs) -> list:
+    def _collect(self, pairs, now_ms: int) -> list:
         work = []
         for stream, eng in pairs:
             ring = stream.rtp_ring
-            cached = self._wake_fast.get(id(stream))
-            if cached is not None:
-                fast, key = cached
-            else:                          # end_wake without a prime scan
-                fast = eng.fast_outputs(stream)
-                key = params_key(fast) if fast else None
+            p = eng.plan(stream, now_ms)
+            fast, key = p.fast, p.key
             if not fast:
                 self._tracked[id(stream)] = ring.head
                 continue

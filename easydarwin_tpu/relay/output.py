@@ -16,7 +16,7 @@ An output is one subscriber's view of one relayed track.  It owns:
 from __future__ import annotations
 
 import enum
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 from ..protocol import rtcp, rtp
 from ..resilience.inject import INJECTOR
@@ -39,6 +39,17 @@ class RewriteState:
     #: output-side origins (what base_src maps to)
     out_seq_start: int = 0
     out_ts_start: int = 0
+    #: the output these parameters rewrite for.  The engine caches
+    #: ``params_key`` per stream (``relay.fanout``: the output plan), so
+    #: a write to any field here moves the owner's stream's plan epoch
+    owner: "RelayOutput | None" = field(default=None, repr=False,
+                                        compare=False)
+
+    def __setattr__(self, name, value):
+        object.__setattr__(self, name, value)
+        owner = self.__dict__.get("owner")
+        if owner is not None:
+            owner.touch_plan()
 
     def map_seq(self, src_seq: int) -> int:
         return (src_seq - self.base_src_seq + self.out_seq_start) & 0xFFFF
@@ -50,17 +61,20 @@ class RewriteState:
 class RelayOutput:
     """One subscriber × one track. Subclasses implement ``send_bytes``."""
 
+    #: the owning stream's plan-epoch cell (``RelayStream.add_output``
+    #: sets it, ``remove_output`` clears it): one shared ``[int]`` and
+    #: no reference to the stream itself
+    _plan_cell: list | None = None
+    _bookmark: int | None = None
+    _meta_field_ids: dict | None = None
+
     def __init__(self, *, ssrc: int = 0, out_seq_start: int = 1,
                  out_ts_start: int = 0):
         from .quality import ThinningFilter
-        self.bookmark: int | None = None      # next ring id; None = not primed
         self.rewrite = RewriteState(ssrc=ssrc, out_seq_start=out_seq_start,
-                                    out_ts_start=out_ts_start)
+                                    out_ts_start=out_ts_start, owner=self)
         self.thinning = ThinningFilter()
-        #: negotiated x-RTP-Meta-Info {field: compressed id} (SETUP header;
-        #: None = plain RTP).  Wrapping covers both the scalar write_rtp
-        #: path and the TPU engine's send_rewritten path.
-        self.meta_field_ids: dict[str, int] | None = None
+        self.thinning.controller.owner = self
         self.packets_sent = 0
         self.bytes_sent = 0
         #: RTP payload octets only (no 12-byte header, no meta-info wrap) —
@@ -70,6 +84,40 @@ class RelayOutput:
         #: monotonic ms of the last SR this output received (relayed or
         #: originated) — drives the 5 s origination cadence
         self.last_sr_ms = 0
+
+    # -- what the engine's output plan derives from -----------------------
+    def touch_plan(self) -> None:
+        """Move the owning stream's plan epoch (``relay.fanout``: the
+        engine steps cohorts from tables cached per epoch).  Every
+        write below, of a ``RewriteState`` field and of the thinning
+        level lands here; the engine's own cohort step writes
+        ``_bookmark`` directly and does not."""
+        cell = self._plan_cell
+        if cell is not None:
+            cell[0] += 1
+
+    @property
+    def bookmark(self) -> int | None:
+        """Next ring id this output needs; None = not primed.  The truth
+        every reader sees: a cohort's mark is a copy of it."""
+        return self._bookmark
+
+    @bookmark.setter
+    def bookmark(self, pid: int | None) -> None:
+        self._bookmark = pid
+        self.touch_plan()
+
+    @property
+    def meta_field_ids(self) -> dict[str, int] | None:
+        """Negotiated x-RTP-Meta-Info {field: compressed id} (SETUP
+        header; None = plain RTP).  Wrapping covers both the scalar
+        write_rtp path and the TPU engine's send_rewritten path."""
+        return self._meta_field_ids
+
+    @meta_field_ids.setter
+    def meta_field_ids(self, ids: dict[str, int] | None) -> None:
+        self._meta_field_ids = ids
+        self.touch_plan()
 
     def on_receiver_report(self, fraction_lost: float) -> int:
         """RTCP RR feedback → quality level (FlowControl role input)."""

@@ -54,6 +54,17 @@ class QualityController:
     _clean_reports: int = 0
     thins: int = 0
     thickens: int = 0
+    #: the RelayOutput this controller thins for: a change of ``level``
+    #: decides whether the engine may batch it (``passthrough``), so it
+    #: moves the owner's stream's plan epoch (``relay.fanout``)
+    owner: object = field(default=None, repr=False, compare=False)
+
+    def __setattr__(self, name, value):
+        object.__setattr__(self, name, value)
+        if name == "level":
+            owner = self.__dict__.get("owner")
+            if owner is not None:
+                owner.touch_plan()
 
     def on_receiver_report(self, fraction_lost: float) -> int:
         """Feed one RR's loss fraction (0..1); returns the new level."""

@@ -242,6 +242,10 @@ class ReliableUdpOutput(RelayOutput):
         self.transport = transport
         self.rewrite = transport.rewrite        # shared rebase state
         self.thinning = transport.thinning
+        # this wrapper is the output a stream holds: writes to the shared
+        # state move ITS stream's plan epoch
+        self.rewrite.owner = self
+        self.thinning.controller.owner = self
         self.meta_field_ids = transport.meta_field_ids
         self.tracker = BandwidthTracker()
         if window_kb is not None:

@@ -85,6 +85,12 @@ class RelayStream:
         self.trace_id: str | None = None
         self.session_path: str | None = None
         self.buckets: list[list[RelayOutput]] = []
+        #: the plan epoch, in a cell every output of this stream shares
+        #: (``RelayOutput.touch_plan``): the engine's per-stream output
+        #: plan (``relay.fanout``) is valid while it has not moved.
+        #: Moved by membership (below) and by every write of what the
+        #: plan derives from outside the engine's own cohort step
+        self._plan_cell = [0]
         #: this stream's audience column block (obs/audience.py) — set
         #: by AUDIENCE.register on the first subscriber; None keeps the
         #: egress hooks to one attribute check per pass
@@ -215,6 +221,13 @@ class RelayStream:
         return self.rtcp_ring.push(packet, now_ms, is_rtcp=True)
 
     # -- output management -------------------------------------------------
+    @property
+    def plan_epoch(self) -> int:
+        return self._plan_cell[0]
+
+    def touch_plan(self) -> None:
+        self._plan_cell[0] += 1
+
     def add_output(self, output: RelayOutput, *,
                    bucket: int | None = None) -> None:
         """Place in the first bucket with a free slot, growing the bucket
@@ -241,6 +254,8 @@ class RelayStream:
                     break
             else:
                 self.buckets.append([output])
+        output._plan_cell = self._plan_cell
+        self.touch_plan()
         obs.AUDIENCE.register(self, output)
         obs.EVENTS.emit("stream.output_add", stream=self.session_path,
                         trace_id=self.trace_id,
@@ -255,6 +270,8 @@ class RelayStream:
         for bucket in self.buckets:
             if output in bucket:
                 bucket.remove(output)
+                output._plan_cell = None
+                self.touch_plan()
                 obs.AUDIENCE.unregister(output)
                 obs.EVENTS.emit(
                     "stream.output_remove", stream=self.session_path,
@@ -523,7 +540,7 @@ class RelayStream:
             if b_idx == 0:
                 continue               # bucket 0 has no stagger delay
             for out in bucket:
-                bm = out.bookmark
+                bm = out._bookmark      # a read: past the property
                 if bm is None or bm >= ring.head:
                     continue
                 if bm < ring.tail:
@@ -545,7 +562,8 @@ class RelayStream:
     def prune(self, now_ms: int) -> int:
         """Age-based eviction with bookmark + keyframe pinning
         (``RemoveOldPackets`` cpp:1242-1291)."""
-        pins = [o.bookmark for o in self.outputs if o.bookmark is not None]
+        pins = [o._bookmark for b in self.buckets for o in b
+                if o._bookmark is not None]
         if self.keyframe_id is not None:
             pins.append(self.keyframe_id)
         pin = min(pins) if pins else None

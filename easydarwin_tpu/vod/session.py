@@ -737,7 +737,7 @@ class VodPacerGroup:
                        if self.engine_for is not None else None)
                 pairs.append((tr.stream, eng))
         if self._unprimed:
-            self._prime_joined()
+            self._prime_joined(now_ms)
         if now_ms - self._last_prune_ms >= 1000:
             self._last_prune_ms = now_ms
             for sess in self.sessions:
@@ -746,7 +746,7 @@ class VodPacerGroup:
         return pairs
 
     # --------------------------------------------------- device-side prime
-    def _prime_joined(self) -> None:
+    def _prime_joined(self, now_ms: int) -> None:
         """Affine prime for just-joined subscribers from the CACHE's
         HBM-resident windows: one stacked ``megabatch_window_step`` per
         padded window shape over device-side row stacks — zero H2D (the
@@ -760,16 +760,15 @@ class VodPacerGroup:
         if sched is None or not self.device_prime \
                 or self.engine_for is None:
             return
-        from ..relay.fanout import params_key
         groups: dict[int, list] = {}
         for sess, tr in pending:
             if sess.stopped or sess.done or tr.window is None:
                 continue
             eng = self.engine_for(tr.stream)
-            fast = eng.fast_outputs(tr.stream)
+            p = eng.plan(tr.stream, now_ms)
+            fast, key = p.fast, p.key
             if not fast:
                 continue                 # TCP/meta output: no affine set
-            key = params_key(fast)
             mb = eng.megabatch_params
             if key == eng._params_key or (mb is not None
                                           and mb[0] == key):

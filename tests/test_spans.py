@@ -227,16 +227,19 @@ def test_span_breakdown_reads_a_ring_dump(tmp_path):
 
 # ------------------------------------------------------ (b) due → wire
 class _Recorder:
-    """Keep every array a histogram's ``observe_many`` is given."""
+    """Keep every array a histogram's ``observe_many`` is given, one
+    entry per delivery (a weighted value repeated by its weight)."""
 
     def __init__(self, monkeypatch, hist):
         self.seen: list[tuple[np.ndarray, str]] = []
         inner = hist.observe_many
 
-        def observe_many(values, **labels):
-            self.seen.append((np.array(values, dtype=np.float64),
+        def observe_many(values, weights=None, **labels):
+            vals = np.array(values, dtype=np.float64)
+            self.seen.append((vals if weights is None
+                              else np.repeat(vals, weights),
                               labels["engine"]))
-            inner(values, **labels)
+            inner(values, weights, **labels)
         monkeypatch.setattr(hist, "observe_many", observe_many)
 
 
@@ -288,7 +291,8 @@ def test_due_to_wire_is_ingest_to_wire_net_of_the_hold(monkeypatch, engine):
     assert len(lat.seen) == len(due.seen) == 1
     (lat_s, e1), (due_s, e2) = lat.seen[0], due.seen[0]
     assert e1 == e2 == engine and lat_s.shape == due_s.shape == (sent,)
-    # deliveries go out output by output, in bucket order
+    # deliveries go out output by output, in bucket order; the cohort
+    # step files a bucket's packets once, each weighted by its 16 outputs
     bucket = np.repeat(np.arange(n_out) // 16, n_pkt)
     assert np.allclose(due_s, np.maximum(lat_s - bucket * 0.073, 0.0),
                        atol=1e-12)

@@ -854,7 +854,9 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
     exist with their closed label sets: ``pump_loop_seconds_total
     {state}``, ``pump_wakes_total{cause}``, ``pump_wake_seconds``,
     ``relay_due_to_wire_seconds{engine}`` on a ladder covering
-    TIME_BUCKETS, ``engine_outputs_walked_total`` / ``_due_total``."""
+    TIME_BUCKETS, ``engine_outputs_walked_total`` / ``_due_total``,
+    ``engine_plan_rebuilds_total`` (the ring-only ``engine.plan`` span's
+    counter: one per rebuilt output plan, ISSUE 27)."""
     from easydarwin_tpu.obs.ledger import WORK_CLASSES
     from easydarwin_tpu.obs.metrics import TIME_BUCKETS
     from easydarwin_tpu.obs.trace import SPAN_PREFIXES, SPANS
@@ -887,7 +889,8 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
             "pump_wake_seconds": ((), ()),
             "relay_due_to_wire_seconds": (("engine",), ()),
             "engine_outputs_walked_total": ((), ()),
-            "engine_outputs_due_total": ((), ())}
+            "engine_outputs_due_total": ((), ()),
+            "engine_plan_rebuilds_total": ((), ())}
     for fam_name, (labels, closed) in want.items():
         try:
             fam = registry.get(fam_name)
