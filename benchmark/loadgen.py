@@ -213,14 +213,24 @@ def udp_socket(ip: str, port: int = 0) -> socket.socket:
     return s
 
 
+def has_successor(port: int) -> bool:
+    """Whether ``port + 1`` exists, to carry the RTCP of an RTP ``port``.
+    The kernel does hand out 65535 as an ephemeral port."""
+    return port + 1 <= 65535
+
+
 def udp_pair(ip: str) -> tuple[socket.socket, socket.socket]:
-    """An (RTP, RTCP) socket pair on adjacent ports of ``ip``."""
+    """An (RTP, RTCP) socket pair on adjacent ports of ``ip``.  A drawn
+    port whose successor is taken, or does not exist, is drawn again."""
     for _ in range(64):
         a = udp_socket(ip)
-        try:
-            return a, udp_socket(ip, a.getsockname()[1] + 1)
-        except OSError:
-            a.close()
+        port = a.getsockname()[1]
+        if has_successor(port):
+            try:
+                return a, udp_socket(ip, port + 1)
+            except (OSError, OverflowError):
+                pass
+        a.close()
     raise LoadgenError(f"no adjacent UDP port pair on {ip}")
 
 
@@ -297,7 +307,7 @@ class BulkDrains:
             probe = udp_socket("127.0.0.1")
             port = probe.getsockname()[1]
             probe.close()
-            if port % 2 or port + 1 > 65535:
+            if port % 2 or not has_successor(port):
                 continue
             made: list[socket.socket] = []
             try:

@@ -201,8 +201,7 @@ class Run:
             keys["resilience_fault_plan"] = args.control[len("fault:"):]
         self.server = Server(self.out_dir, keys, args.child_script,
                              self.trace_dir)
-        self.lg = loadgen.Loadgen(self.cfg, self.traffic, args.seed,
-                                  args.seconds, self.n_src, self.n_sub)
+        self.lg: loadgen.Loadgen | None = None      # built in run()
         self.harness: dict[str, float] = {}
         self.compared: dict[str, list] = {}     # name -> [value, limit]
         self.notes: list[str] = []
@@ -485,12 +484,16 @@ class Run:
         sys.setswitchinterval(0.0005)
         a = self.args
         log(f"cell {a.workload}: {self.n_src} sources x {self.n_sub} "
-            f"players at {self.lg.fps:g} fps/source, seed {a.seed}, "
-            f"{a.seconds:g} s, trace {a.trace}")
-        self.lg.start_receivers()
-        self.server.start()
+            f"players at {float(self.traffic['fps_per_source']):g} "
+            f"fps/source, seed {a.seed}, {a.seconds:g} s, trace {a.trace}")
         rc, err = None, None
         try:
+            # the harness's own set-up fails as the server's does: no
+            # result, and whatever was started is torn down
+            self.lg = loadgen.Loadgen(self.cfg, self.traffic, a.seed,
+                                      a.seconds, self.n_src, self.n_sub)
+            self.lg.start_receivers()
+            self.server.start()
             log("server boot: " + self.server.wait_boot())
             info = self.server.info()
             self.device = {"platform": info.get("Platform", ""),
@@ -505,7 +508,8 @@ class Run:
         except Exception as e:
             err = f"run failed: {e!r}"
         finally:
-            self.lg.stop_receivers()
+            if self.lg is not None:
+                self.lg.stop_receivers()
             rc = self.server.terminate()
         if err is not None:
             log(f"NO RESULT: {err}")

@@ -6,6 +6,7 @@ whole run at a debug size on the CPU prints the contract's last line.
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q -p no:cacheprovider
 """
 
+import errno
 import importlib
 import json
 import os
@@ -19,7 +20,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, ROOT)
 
-from benchmark import kernels, reduce_trace, reference, stats  # noqa: E402
+from benchmark import (kernels, loadgen, reduce_trace, reference,  # noqa: E402
+                       stats)
+from benchmark.run import EXIT_NO_DEVICE  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -190,6 +193,80 @@ def test_a_session_that_announced_nothing_is_not_correct():
     assert got["unannounced"] == 1 and got["missing"] == 5
 
 
+# ------------------------------------------- the receivers' port pairs
+class ScriptedPorts:
+    """``loadgen.udp_socket`` with the kernel's part scripted: ephemeral
+    binds draw from ``draws`` in turn (the last one for good), a bind to
+    a port in ``taken`` is EADDRINUSE, and one past 65535 is refused as
+    ``socket.bind`` refuses it.  No real port is drawn."""
+
+    class Sock:
+        def __init__(self, port):
+            self.port, self.closed = port, False
+
+        def getsockname(self):
+            return ("127.0.0.1", self.port)
+
+        def close(self):
+            self.closed = True
+
+    def __init__(self, draws, taken=()):
+        self.draws, self.taken = list(draws), set(taken)
+        self.made = []
+
+    def __call__(self, ip, port=0):
+        if port == 0:
+            port = self.draws.pop(0) if len(self.draws) > 1 else self.draws[0]
+        elif port > 65535:
+            raise OverflowError("bind(): port must be 0-65535.")
+        elif port in self.taken:
+            raise OSError(errno.EADDRINUSE, "Address already in use")
+        self.made.append(self.Sock(port))
+        return self.made[-1]
+
+    def open_ports(self):
+        return [s.port for s in self.made if not s.closed]
+
+
+@pytest.mark.parametrize("draws, taken, pair", [
+    ([65535, 40000], (), (40000, 40001)),       # no successor: drawn again
+    ([40000, 40002], (40001,), (40002, 40003)),     # successor taken
+    ([65535, 65535, 40001], (), (40001, 40002)),    # RTP parity stays free
+], ids=["top_of_range", "successor_taken", "twice_the_top"])
+def test_udp_pair_draws_again(monkeypatch, draws, taken, pair):
+    ports = ScriptedPorts(draws, taken)
+    monkeypatch.setattr(loadgen, "udp_socket", ports)
+    a, b = loadgen.udp_pair("127.0.0.1")
+    assert (a.port, b.port) == pair
+    # every rejected socket was closed; the pair is all that is open
+    assert ports.open_ports() == list(pair)
+
+
+def test_udp_pair_gives_up_as_a_loadgen_error(monkeypatch):
+    ports = ScriptedPorts([65535])
+    monkeypatch.setattr(loadgen, "udp_socket", ports)
+    with pytest.raises(loadgen.LoadgenError):    # and no OverflowError
+        loadgen.udp_pair("127.0.0.1")
+    assert len(ports.made) == 64 and ports.open_ports() == []
+
+
+@pytest.mark.parametrize("port, fits", [
+    (65535, False), (65534, True), (40000, True)])
+def test_both_receiver_kinds_reject_the_same_ports(monkeypatch, port, fits):
+    """``udp_pair`` and ``_open_port_group`` (which wants an even RTP
+    port besides) share the one predicate."""
+    assert loadgen.has_successor(port) is fits
+    ports = ScriptedPorts([port, 40002])
+    monkeypatch.setattr(loadgen, "udp_socket", ports)
+    assert loadgen.udp_pair("127.0.0.1")[0].port == (port if fits else 40002)
+    ports = ScriptedPorts([port, 40002])
+    monkeypatch.setattr(loadgen, "udp_socket", ports)
+    bulk = object.__new__(loadgen.BulkDrains)
+    bulk.rtp, bulk.rtcp = [], []
+    assert bulk._open_port_group() == (port if fits else 40002)
+    assert len(bulk.rtp) == len(bulk.rtcp) == loadgen.N_IP
+
+
 # ---------------------------------------------------------- whole runs
 def _run(*extra):
     """run.py at a debug size on the CPU by name; returns (exit code,
@@ -237,6 +314,33 @@ def test_full_size_run_refuses_a_cpu():
     assert r.returncode != 0
     assert "NO RESULT" in r.stdout
     assert not r.stdout.strip().splitlines()[-1].startswith("{")
+
+
+def test_a_harness_that_cannot_set_up_prints_no_result():
+    """The harness's own sockets fail for good: NO RESULT and the exit
+    code of a run without a device, no traceback's exit 1, no JSON line,
+    and nothing left running in the run's process group."""
+    code = (
+        "import errno, sys\n"
+        f"sys.path.insert(0, {ROOT!r})\n"
+        "from benchmark import loadgen, run\n"
+        "def refused(ip, port=0):\n"
+        "    raise OSError(errno.EADDRINUSE, 'scripted: no port for good')\n"
+        "loadgen.udp_socket = refused\n"
+        "sys.exit(run.main())\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.Popen(
+        [sys.executable, "-c", code, "--workload", "relay-1x64.live",
+         "--seed", str(2**31 + 29), "--seconds", "1", "--trace", "0",
+         "--debug-size", "1x2"], stdout=subprocess.PIPE,
+        stderr=subprocess.PIPE, text=True, env=env, start_new_session=True)
+    out, err = p.communicate(timeout=600)
+    assert p.returncode == EXIT_NO_DEVICE, out[-2000:] + err[-2000:]
+    assert "NO RESULT" in out and "scripted: no port for good" in out
+    assert "Traceback" not in err, err[-2000:]
+    assert not any(ln.startswith("{") for ln in out.splitlines())
+    with pytest.raises(ProcessLookupError):     # the group is empty
+        os.killpg(p.pid, 0)
 
 
 @pytest.mark.parametrize("control", [
