@@ -158,10 +158,13 @@ def udp_pair(ip: str) -> tuple[socket.socket, socket.socket]:
     """An (RTP, RTCP) socket pair on adjacent ports of ``ip``."""
     for _ in range(64):
         a = _udp_socket(ip)
-        try:
-            return a, _udp_socket(ip, a.getsockname()[1] + 1)
-        except OSError:
-            a.close()
+        port = a.getsockname()[1]
+        if port < 65535:            # the kernel does hand out 65535
+            try:
+                return a, _udp_socket(ip, port + 1)
+            except OSError:
+                pass
+        a.close()
     raise SmokeFailure(f"no adjacent UDP port pair on {ip}")
 
 

@@ -144,6 +144,13 @@ ENGINE_PLAN_REBUILDS = REGISTRY.counter(
     "table and bucket cohorts an engine steps from): one per stream "
     "whose plan epoch moved — a join, a leave, a latch, a bookmark "
     "written from outside the engine — and none on a steady wake")
+ENGINE_STEPS = REGISTRY.counter(
+    "engine_steps_total",
+    "Per-stream engine steps by what they found: idle (no output, an "
+    "empty ring, or no cohort past its hold: the step cost its fixed "
+    "part and sent nothing) or worked (idle / all = the share of a "
+    "wake's per-stream loop spent finding nothing to do)",
+    labels=("result",))
 TPU_PACKETS_SENT = REGISTRY.counter(
     "tpu_packets_sent_total",
     "(packet, subscriber) sends completed by the TPU fan-out engine")
@@ -196,6 +203,12 @@ MEGABATCH_STREAMS = REGISTRY.counter(
     "megabatch_streams_total",
     "Streams coalesced into megabatch passes (streams_total / passes_total "
     "= mean streams per stacked pass)")
+MEGABATCH_CELLS = REGISTRY.counter(
+    "megabatch_cells_total",
+    "(packet row, subscriber column) cells of dispatched stacked passes: "
+    "real (new packets x subscribers, summed over a pass's streams) and "
+    "staged (b_pad x p_pad x s_pad, what the program computes); real / "
+    "staged = how much of a pass is not padding", labels=("kind",))
 MEGABATCH_FALLBACK = REGISTRY.counter(
     "megabatch_fallback_total",
     "Per-stream device param queries taken while a stream was megabatch-"
@@ -356,6 +369,15 @@ INGEST_BYTES = REGISTRY.counter(
 INGEST_OVERSIZE_DROPPED = REGISTRY.counter(
     "ingest_oversize_dropped_total",
     "Datagrams dropped at ingest because they exceed the ring slot")
+INGEST_INTERLEAVED_PACKETS = REGISTRY.counter(
+    "ingest_interleaved_packets_total",
+    "RTP/RTCP packets pushed over a pusher's RTSP connection "
+    "(TCP-interleaved RECORD) into its relay's rings")
+INGEST_INTERLEAVED_SECONDS = REGISTRY.counter(
+    "ingest_interleaved_seconds_total",
+    "Wall time inside ingest.read: one socket read's worth of "
+    "interleaved packets, from the first packet's module hooks to the "
+    "last ring push (seconds / packets = host cost a pushed packet)")
 INGEST_BUSY_SECONDS = REGISTRY.counter(
     "ingest_busy_seconds_total",
     "Cumulative wall time spent inside the native recvmmsg ring ingest "

@@ -59,6 +59,7 @@ SPANS = (
     "engine.egress", "engine.account", "engine.rtcp",
     "megabatch.harvest", "megabatch.fetch", "megabatch.prime",
     "megabatch.dispatch", "megabatch.gather", "megabatch.h2d",
+    "ingest.read",
     "native.egress", "native.stream_egress",
     "pipeline.step", "jax.build")
 #: span families whose last part is data: ``rtsp.<method>``

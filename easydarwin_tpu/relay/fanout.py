@@ -532,6 +532,7 @@ class TpuFanoutEngine:
         ring = stream.rtp_ring
         if not stream.num_outputs or len(ring) == 0:
             TRACER.close(step_span, outputs=stream.num_outputs, sent=0)
+            obs.ENGINE_STEPS.inc(result="idle")
             return 0
         profiled = self._profiled = PROFILER.enabled
         self._pass_phases = {}
@@ -571,6 +572,9 @@ class TpuFanoutEngine:
                            due_outputs=self._pass_due) - t0
         obs.TPU_PASS_SECONDS.observe(dur / 1e9, stage="engine_step")
         obs.TPU_PASSES.inc()
+        # idle: no cohort was past its hold and nothing else was sent
+        obs.ENGINE_STEPS.inc(
+            result="worked" if sent or self._pass_due else "idle")
         # output objects this step touched: the due cohorts' members, the
         # residue it sent for, and a rebuild's walk where there was one
         obs.ENGINE_OUTPUTS_WALKED.inc(self._pass_walked

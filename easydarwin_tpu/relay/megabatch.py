@@ -8,10 +8,12 @@ shape-bucketed stacked pass per wake**:
 
 * **collect** — each megabatch-owned stream contributes its ring window
   tail (the packets not yet staged) and its fast-output rewrite state;
-* **bucket** — streams are grouped by pow2-padded (window, subscriber)
-  shape so jit specializations stay latched per bucket shape, reusing
-  the PR 3 compile-note discipline (a bucket-growth retrace files a
-  compile note, never a phase sample);
+* **bucket** — streams are grouped by padded (window, subscriber)
+  shape.  The pads come from three ladders (``_stream_pad``,
+  ``PACKET_PADS``, ``_sub_pad``), so the programs the handed pairs can
+  reach are a small CLOSED set (``MegabatchScheduler.members``) that
+  ``begin_wake`` traces and loads when the pairs first reach it — not
+  when a wake first needs one, with packets queued behind the build;
 * **stage** — each bucket's windows are gathered into ONE contiguous
   upload buffer (``csrc ed_stage_gather`` when native, numpy otherwise)
   in the fused ``pack_window`` layout — a single H2D transfer per
@@ -88,6 +90,37 @@ from ..ops import staging
 from ..ops.fanout import STATE_COLS, pack_output_state
 from ..resilience.inject import INJECTOR
 from .fanout import _pow2
+
+
+#: packet rows a stream stages in one row of a stacked pass.  A stream
+#: with more new packets than the widest pad (it fell behind: a frozen
+#: host, a burst) rides FURTHER ROWS of that pad, never a wider program
+PACKET_PADS = (16, 64)
+
+
+def _stream_pad(n: int) -> int:
+    """Stream rows of a stacked pass: 1, 4, 16, 64, 256, … — powers of
+    four, half the rungs of a pow2 ladder.  The device is idle; a pad
+    row costs host zeros and H2D bytes (``megabatch_cells_total``)."""
+    p = 1
+    while p < n:
+        p <<= 2
+    return p
+
+
+def _packet_pad(n: int) -> int:
+    return PACKET_PADS[0] if n <= PACKET_PADS[0] else PACKET_PADS[-1]
+
+
+#: the narrowest subscriber pad: every thin stream (a camera and its few
+#: viewers) rides it, whatever its audience
+SUB_FLOOR = 8
+
+
+def _sub_pad(n: int) -> int:
+    """Subscriber columns: the power of two from ``SUB_FLOOR`` up, the
+    pad the per-stream engine stages its own state at."""
+    return _pow2(n, SUB_FLOOR)
 
 
 def _host_affine_params(key) -> tuple:
@@ -174,6 +207,9 @@ class MegabatchScheduler:
         # the host gathers the next wake into a fresh/recycled one
         # (steady state: two buffers per hot shape)
         self._free: dict[tuple, list[np.ndarray]] = {}
+        #: (b_pad, p_pad, s_pad) of every stacked-pass program this
+        #: process has traced and loaded
+        self._built: set[tuple] = set()
         # a bracket that held an XLA build (a bucket-growth retrace) is
         # never a phase sample: obs.profile.builds() tells
         listen_builds()
@@ -236,14 +272,20 @@ class MegabatchScheduler:
         buckets: dict[tuple, list] = {}
         for item in work:
             _stream, _eng, fast, _key, _base, n_new = item
-            shape = (_pow2(max(n_new, 1), 16), _pow2(len(fast), 8))
+            shape = (_packet_pad(n_new), _sub_pad(len(fast)))
             buckets.setdefault(shape, []).append(item)
         gather_ns = 0
         h2d_ns = 0
         for (p_pad, s_pad), entries in sorted(buckets.items()):
-            g, h = self._dispatch_bucket(entries, p_pad, s_pad)
-            gather_ns += g
-            h2d_ns += h
+            # further rows of a stream that fell behind may outnumber
+            # the streams riding this pad: no pass is taller than their
+            # rung, the tallest ``members`` holds for the pad
+            top = _stream_pad(len({id(e[0]) for e in entries}))
+            for i in range(0, len(entries), top):
+                g, h = self._dispatch_bucket(entries[i:i + top], p_pad,
+                                             s_pad)
+                gather_ns += g
+                h2d_ns += h
         total = TRACER.lap(span, buckets=len(buckets), streams=len(work))
         PROFILER.account_pass("megabatch", total,
                               {"stage_gather": gather_ns, "h2d": h2d_ns})
@@ -259,20 +301,34 @@ class MegabatchScheduler:
         here is the key the engine will check moments later in the same
         wake.  The affine
         params depend only on that rewrite state, so the windows staged
-        here are all-zero padding: no packet bytes ride the prime."""
+        here are all-zero padding: no packet bytes ride the prime.  The
+        same walk counts the streams riding each subscriber pad for
+        ``_build_ahead``: a stream with media rides its fast list's; a
+        thin stream still waiting for its first packet will ride the
+        floor.  A fatter one is not counted until its first packet — its
+        audience walks through every pad on the way up while players
+        join, and loading each would cost the join six programs a pad."""
         stale = []
+        riders: dict[int, int] = {}
+        live = False                       # media flows on a handed pair
         for stream, eng in pairs:
             # the engine's own tables: the un-primed residue is latched,
             # nothing else is walked on an unchanged epoch
             p = eng.plan(stream, now_ms)
             fast, key = p.fast, p.key
             if not fast:
+                if p.n_outputs <= SUB_FLOOR:
+                    riders[SUB_FLOOR] = riders.get(SUB_FLOOR, 0) + 1
                 continue
+            live = True
+            s_pad = _sub_pad(len(fast))
+            riders[s_pad] = riders.get(s_pad, 0) + 1
             if key == eng._params_key or (
                     eng.megabatch_params is not None
                     and eng.megabatch_params[0] == key):
                 continue
             stale.append((eng, fast, key))
+        self._build_ahead(riders, live)
         if not stale:
             return
         import jax
@@ -280,13 +336,15 @@ class MegabatchScheduler:
         span = TRACER.open("megabatch.prime", "tpu", streams=len(stale))
         buckets: dict[int, list] = {}
         for item in stale:
-            buckets.setdefault(_pow2(len(item[1]), 8), []).append(item)
+            buckets.setdefault(_sub_pad(len(item[1])), []).append(item)
+        p_pad = PACKET_PADS[0]
         for s_pad, items in sorted(buckets.items()):
-            b_pad = _pow2(len(items), 1)
+            b_pad = _stream_pad(len(items))
+            self._built.add((b_pad, p_pad, s_pad))
             # fresh zeros, never a recycled buffer: a stale le32 length
             # row would resurrect a previous wake's packets into the
             # keyframe scan
-            win = np.zeros((b_pad, 16, staging.ROW_STRIDE), np.uint8)
+            win = np.zeros((b_pad, p_pad, staging.ROW_STRIDE), np.uint8)
             state = np.zeros((b_pad, s_pad, STATE_COLS), np.uint32)
             for i, (_eng, fast, _key) in enumerate(items):
                 state[i, :len(fast)] = np.asarray(pack_output_state(fast))
@@ -304,8 +362,49 @@ class MegabatchScheduler:
                     {"device_step": t_d - t_h, "d2h": t_f - t_d})
             for (eng, _fast, key), seg in zip(items, segs):
                 self._install_segment(eng, key, seg)
-            self._note_pass(len(items), win.nbytes + state.nbytes)
+            self._note_pass(len(items), win.nbytes + state.nbytes,
+                            0, b_pad * p_pad * s_pad)
         TRACER.close(span)
+
+    # ------------------------------------------------------- the closed set
+    @staticmethod
+    def members(riders: dict) -> set:
+        """Every (b_pad, p_pad, s_pad) a wake can dispatch for handed
+        pairs of which ``riders[s_pad]`` ride each subscriber pad: the
+        stream rungs up to that count's own, each packet pad."""
+        out = set()
+        for s_pad, n in riders.items():
+            b_pad = 1
+            while True:
+                out.update((b_pad, p_pad, s_pad) for p_pad in PACKET_PADS)
+                if b_pad >= n:
+                    break
+                b_pad <<= 2
+        return out
+
+    def _build_ahead(self, riders: dict, live: bool) -> None:
+        """Trace and load the members the handed pairs can reach and
+        this process has not built, when a pair joins past a rung or
+        brings a new subscriber pad — not at the wake that first stacks
+        that many streams.  Before any media (players join before their
+        camera's first packet) nothing waits behind a build and every
+        missing member loads now; once media flows (``live``) one a
+        wake, so a relayed packet waits behind at most one.  A zero pass
+        per member, fetched; nothing is staged or installed.  The mesh
+        path keeps building at first use."""
+        if self._sharded_step is not None:
+            return
+        missing = sorted(self.members(riders) - self._built)
+        if not missing:
+            return
+        import jax
+
+        for b_pad, p_pad, s_pad in missing[:1] if live else missing:
+            np.asarray(megabatch_window_step(
+                jax.device_put(np.zeros(
+                    (b_pad, p_pad, staging.ROW_STRIDE), np.uint8)),
+                np.zeros((b_pad, s_pad, STATE_COLS), np.uint32)))
+            self._built.add((b_pad, p_pad, s_pad))
 
     # ------------------------------------------------------------- collect
     def _collect(self, pairs, now_ms: int) -> list:
@@ -327,6 +426,11 @@ class MegabatchScheduler:
                                     and eng.megabatch_params[0] == key))
             if n_new <= 0 and not need_params:
                 continue                   # idle stream: zero device work
+            while n_new > PACKET_PADS[-1]:
+                # fell behind: further rows of the widest pad
+                work.append((stream, eng, fast, key, base, PACKET_PADS[-1]))
+                base += PACKET_PADS[-1]
+                n_new -= PACKET_PADS[-1]
             work.append((stream, eng, fast, key, base, n_new))
         return work
 
@@ -374,12 +478,18 @@ class MegabatchScheduler:
                                            base + kf)
         return True
 
-    def _note_pass(self, n_streams: int, h2d_bytes: int) -> None:
+    def _note_pass(self, n_streams: int, h2d_bytes: int, real: int,
+                   staged: int) -> None:
+        """One dispatched pass: ``real`` (new packet, subscriber) cells
+        of the ``staged`` = b_pad × p_pad × s_pad its program computes."""
         self.passes += 1
         self.streams_coalesced += n_streams
         obs.MEGABATCH_PASSES.inc()
         obs.MEGABATCH_STREAMS.inc(n_streams)
         obs.TPU_H2D_BYTES.inc(h2d_bytes)
+        if real:
+            obs.MEGABATCH_CELLS.inc(real, kind="real")
+        obs.MEGABATCH_CELLS.inc(staged, kind="staged")
 
     def _packed_state(self, stream, fast, key) -> np.ndarray:
         cached = self._state_cache.get(id(stream))
@@ -400,18 +510,21 @@ class MegabatchScheduler:
             INJECTOR.device_dispatch("megabatch.dispatch")
         if self._sharded_step is not None:
             return self._dispatch_bucket_mesh(entries, p_pad, s_pad)
-        b_pad = _pow2(len(entries), 1)
+        b_pad = _stream_pad(len(entries))
+        self._built.add((b_pad, p_pad, s_pad))
         bucket = f"{b_pad}x{p_pad}x{s_pad}"
         tok = TRACER.open("megabatch.gather", "tpu", streams=len(entries),
                           bucket=bucket)
         win = self._buffer(b_pad, p_pad)
         state = np.zeros((b_pad, s_pad, STATE_COLS), np.uint32)
         recs = []
+        real = 0
         for i, (stream, eng, fast, key, base, n_new) in enumerate(entries):
             staging.gather_window(stream.rtp_ring, base, n_new, win[i])
             state[i, :len(fast)] = self._packed_state(stream, fast, key)
             self._tracked[id(stream)] = base + n_new
             recs.append((stream, eng, key, len(fast), base, -1))
+            real += n_new * len(fast)
         if b_pad > len(entries):
             win[len(entries):] = 0         # bucket padding rows
         gather_ns = TRACER.lap(tok)
@@ -427,7 +540,9 @@ class MegabatchScheduler:
             h2d_ns = 0
         self._inflight.append(
             _InFlight(res, recs, win, time.perf_counter_ns()))
-        self._note_pass(len(entries), win.nbytes + state.nbytes)
+        self._note_pass(len({id(e[0]) for e in entries}),
+                        win.nbytes + state.nbytes, real,
+                        b_pad * p_pad * s_pad)
         return gather_ns, h2d_ns
 
     def _dispatch_bucket_mesh(self, entries, p_pad: int,
@@ -454,8 +569,10 @@ class MegabatchScheduler:
         shard_bufs = [self._buffer(rows_per, p_pad) for _ in range(n_dev)]
         state = np.zeros((b_pad, s_pad, STATE_COLS), np.uint32)
         recs = []
+        real = 0
         filled = [0] * n_dev
         for i, (stream, eng, fast, key, base, n_new) in enumerate(entries):
+            real += n_new * len(fast)
             k, r = divmod(i, rows_per)
             staging.gather_window(stream.rtp_ring, base, n_new,
                                   shard_bufs[k][r])
@@ -494,8 +611,9 @@ class MegabatchScheduler:
             if n:                          # pad-only shards count nothing
                 obs.MEGABATCH_DEVICE_PASSES.inc(device=str(k))
                 obs.MEGABATCH_DEVICE_STREAMS.inc(n, device=str(k))
-        self._note_pass(len(entries),
-                        sum(b.nbytes for b in shard_bufs) + state.nbytes)
+        self._note_pass(len({id(e[0]) for e in entries}),
+                        sum(b.nbytes for b in shard_bufs) + state.nbytes,
+                        real, b_pad * p_pad * s_pad)
         return gather_ns, h2d_ns
 
     def _consume_mesh(self, inf: _InFlight, ready: bool) -> tuple[int, int]:
@@ -614,6 +732,7 @@ class MegabatchScheduler:
             "inflight": len(self._inflight),
             "harvests": self.harvests,
             "mismatches": self.mismatches,
+            "programs": len(self._built),
         }
 
 

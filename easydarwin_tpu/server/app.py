@@ -1202,6 +1202,13 @@ class StreamingServer:
         eng.tcp_fast_enabled = self.config.tcp_engine_enabled
         return eng
 
+    def _on_device(self, stream) -> bool:
+        """Whether the device path serves ``stream``: the engine tier is
+        on and the stream has ``tpu_min_outputs`` outputs (a deployment
+        of thin streams states 1; under it the scalar loop serves)."""
+        return (self.config.tpu_fanout
+                and stream.num_outputs >= self.config.tpu_min_outputs)
+
     def _wake_open(self, wake_ns: int | None) -> None:
         """First line of a wake: number it, open ``pump.wake`` (every
         span opened until ``_wake_close`` carries the number) and file
@@ -1293,7 +1300,7 @@ class StreamingServer:
         if use_tpu and self.config.megabatch_enabled:
             for sess in list(self.registry.sessions.values()):
                 for stream in sess.streams.values():
-                    if (stream.num_outputs >= self.config.tpu_min_outputs
+                    if (self._on_device(stream)
                             and (lad is None
                                  or lad.allows_megabatch(sess.path))):
                         mega_pairs.append((stream,
@@ -1351,8 +1358,7 @@ class StreamingServer:
                 # retry-backoff window — serves via the CPU oracle,
                 # the mandatory fallback the north star requires
                 mode = 0 if lad is None else lad.engine_mode(sess.path)
-                device = (use_tpu and stream.num_outputs
-                          >= self.config.tpu_min_outputs and mode <= 1)
+                device = self._on_device(stream) and mode <= 1
                 try:
                     if device:
                         eng = self._engine_for(stream)

@@ -20,7 +20,8 @@ import secrets
 import time
 from dataclasses import dataclass, field
 
-from ..obs import EVENTS, FLIGHT, TRACER
+from ..obs import (EVENTS, FLIGHT, INGEST_INTERLEAVED_PACKETS,
+                   INGEST_INTERLEAVED_SECONDS, TRACER, t0_of)
 from ..protocol import rtsp, sdp
 from ..relay.session import RelaySession, SessionRegistry, now_ms
 from .config import ServerConfig
@@ -155,11 +156,30 @@ class RtspConnection:
         self.wire.feed(data)
 
     async def _drain_events(self) -> None:
+        """One socket read's events.  The pushed packets among them sit
+        in one ``ingest.read`` span (a request between two of them ends
+        it: nothing awaits inside a span)."""
+        span, t0, pushed = None, 0, 0       # t0 == 0: no bracket open
         for ev in self.wire.events():
             if isinstance(ev, rtsp.InterleavedPacket):
-                self._on_interleaved(ev)
-            else:
-                await self._dispatch(ev)
+                if not t0 and self.relay is not None:
+                    span = TRACER.open("ingest.read", "ingest")
+                    t0 = t0_of(span)
+                pushed += self._on_interleaved(ev)
+                continue
+            if t0:
+                self._ingest_close(span, t0, pushed)
+                t0 = pushed = 0
+            await self._dispatch(ev)
+        if t0:
+            self._ingest_close(span, t0, pushed)
+
+    @staticmethod
+    def _ingest_close(span, t0: int, pushed: int) -> None:
+        end = TRACER.close(span, packets=pushed)
+        if pushed:
+            INGEST_INTERLEAVED_PACKETS.inc(pushed)
+            INGEST_INTERLEAVED_SECONDS.inc((end - t0) / 1e9)
 
     # ------------------------------------------------ HTTP on the RTSP port
     async def _run_http(self, first: bytes) -> None:
@@ -975,8 +995,9 @@ class RtspConnection:
         await self.close()
 
     # -------------------------------------------------------- media paths
-    def _on_interleaved(self, pkt: rtsp.InterleavedPacket) -> None:
-        """Pushed media (RECORD mode) or player RTCP feedback."""
+    def _on_interleaved(self, pkt: rtsp.InterleavedPacket) -> int:
+        """Pushed media (RECORD mode) or player RTCP feedback; 1 where
+        the packet was pushed into the relay."""
         m = self.channel_map.get(pkt.channel)
         if m is not None and self.relay is not None:
             track_id, is_rtcp = m
@@ -986,9 +1007,10 @@ class RtspConnection:
             self.relay.push(track_id, pkt.data, is_rtcp=is_rtcp)
             self.server.stats["packets_in"] += 1
             self.server.wake_pump()
-            return
+            return 1
         if self.player_tracks and pkt.channel % 2 == 1:
             self.server.on_client_rtcp(self, pkt.data)
+        return 0
 
     def send_interleaved(self, channel: int, data: bytes) -> None:
         """Write one $-framed packet on this connection (server→client)."""

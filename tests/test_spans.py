@@ -139,7 +139,7 @@ async def test_served_spans_land_on_the_profilers_host_plane(tmp_path):
         assert host.get(name), f"{name} is not on the host plane"
     # the program's own names and nothing else of its making
     ours = {n for n in host if n.startswith(
-        ("pump.", "engine.", "megabatch.", "native."))}
+        ("pump.", "engine.", "megabatch.", "native.", "ingest."))}
     assert ours <= set(SPANS)
     # each child inside its parent's interval, on the profiler's clock
     for child, parent in (("engine.egress", "engine.step"),

@@ -843,6 +843,8 @@ SPAN_SITE_RE = re.compile(
     r"""\(\s*(?:(?:self\._lib|lib)\s*,\s*)?(f?)['"]([^'"]+)['"]""")
 PUMP_STATES = ("wake", "sleep")
 WAKE_CAUSES = ("ingest", "timer", "interval")
+STEP_RESULTS = ("idle", "worked")
+CELL_KINDS = ("real", "staged")
 
 
 def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
@@ -856,7 +858,11 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
     ``relay_due_to_wire_seconds{engine}`` on a ladder covering
     TIME_BUCKETS, ``engine_outputs_walked_total`` / ``_due_total``,
     ``engine_plan_rebuilds_total`` (the ring-only ``engine.plan`` span's
-    counter: one per rebuilt output plan, ISSUE 27)."""
+    counter: one per rebuilt output plan, ISSUE 27), and the camera
+    wall's three (ISSUE 30): ``engine_steps_total{result}`` at
+    ``engine.step``'s exits, ``megabatch_cells_total{kind}`` per
+    dispatched pass, ``ingest_interleaved_packets_total`` /
+    ``_seconds_total`` off ``ingest.read``."""
     from easydarwin_tpu.obs.ledger import WORK_CLASSES
     from easydarwin_tpu.obs.metrics import TIME_BUCKETS
     from easydarwin_tpu.obs.trace import SPAN_PREFIXES, SPANS
@@ -890,7 +896,11 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
             "relay_due_to_wire_seconds": (("engine",), ()),
             "engine_outputs_walked_total": ((), ()),
             "engine_outputs_due_total": ((), ()),
-            "engine_plan_rebuilds_total": ((), ())}
+            "engine_plan_rebuilds_total": ((), ()),
+            "engine_steps_total": (("result",), STEP_RESULTS),
+            "megabatch_cells_total": (("kind",), CELL_KINDS),
+            "ingest_interleaved_packets_total": ((), ()),
+            "ingest_interleaved_seconds_total": ((), ())}
     for fam_name, (labels, closed) in want.items():
         try:
             fam = registry.get(fam_name)

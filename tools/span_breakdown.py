@@ -19,7 +19,8 @@ import os
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-OURS = ("pump.", "engine.", "megabatch.", "native.", "pipeline.")
+OURS = ("pump.", "engine.", "megabatch.", "native.", "pipeline.",
+        "ingest.")
 
 
 def host_rows(path: str) -> list:
