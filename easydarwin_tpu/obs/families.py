@@ -110,6 +110,23 @@ PUMP_WAKES = REGISTRY.counter(
     "on the wheel came due), interval (the reflect interval ran out); a "
     "pump that never catches up finds the event already set, so its "
     "timer share falls to 0", labels=("cause",))
+#: the pump's bounded drain between its wait and its wake (ISSUE 31):
+#: both counted where ``pump.sleep`` closes, from its two new arguments
+PUMP_DRAIN_ROUNDS = REGISTRY.counter(
+    "pump_drain_rounds_total",
+    "Rounds the pump yielded to the event loop between its wait's "
+    "return and its wake's start, two loop iterations each (select and "
+    "the transports' reads, then the connection tasks those resumed); "
+    "another round follows one in which ingest arrived, up to a fixed "
+    "ceiling (rounds / pump_wakes_total: 1 = the readers were dry)")
+PUMP_DRAIN_PACKETS = REGISTRY.counter(
+    "pump_drain_packets_total",
+    "Packets the RTSP pushers pushed into the rings between the pump's "
+    "wait returning and its wake starting: read by the wake that "
+    "follows, where they would have queued behind it in the event "
+    "loop's ready queue and waited for the one after.  What was pushed "
+    "while the pump still waited on its event is not counted: a pump "
+    "that is not at the head of the queue is woken behind the batch")
 
 # -------------------------------------------------------------- SLO watchdog
 SLO_VIOLATIONS = REGISTRY.counter(

@@ -1478,6 +1478,40 @@ class StreamingServer:
                     wheel.cancel(cur[0])
                 self._wheel_sched[key] = (wheel.schedule(d, key), due)
 
+    #: the most rounds ``_drain_readers`` yields before a wake: a pusher
+    #: that never pauses (a backlog, a REST storm) holds the pump out for
+    #: eight loop iterations and no longer
+    _DRAIN_ROUNDS_MAX = 4
+
+    async def _drain_readers(self) -> tuple[int, int]:
+        """Let the readers run dry before a wake starts; returns (rounds
+        yielded, packets the RTSP pushers pushed meanwhile).
+
+        The loop's ready queue is FIFO and a stream read takes two
+        iterations (the transport's read, then the connection task it
+        resumed), so a pump that went from its wait straight into a
+        blocking wake ran a whole wake in front of each stage of every
+        batch ``select`` found (ARCHITECTURE.md "Why the pump yields
+        before it works"; ``tests/test_pump_drain.py`` holds the order).
+        One round = clear the event and yield for two iterations; a
+        round in which ingest arrived is followed by another, up to
+        ``_DRAIN_ROUNDS_MAX``.  ``_wake_ns`` keeps the first ingest's
+        instant, so ``wake_to_pass`` includes the drain, and
+        ``_reflect_all`` reads its clock after it: everything the drain
+        pushed is due in this wake."""
+        stats = self.rtsp.stats
+        before = stats["packets_in"]
+        rounds = 0
+        while rounds < self._DRAIN_ROUNDS_MAX:
+            self._pump_event.clear()
+            rounds += 1
+            await asyncio.sleep(0)
+            await asyncio.sleep(0)
+            if not self._pump_event.is_set():
+                break
+        self._pump_event.clear()
+        return rounds, stats["packets_in"] - before
+
     async def _pump_loop(self) -> None:
         interval = self.config.reflect_interval_ms / 1000.0
         last_prune = 0.0
@@ -1500,11 +1534,16 @@ class StreamingServer:
                 cause = "ingest"
             except asyncio.TimeoutError:
                 cause = "timer" if timeout < interval else "interval"
-            t_woke = TRACER.close(span, cause=cause)
+            # the drain is the pump waiting while the thread serves
+            # ingest, which is what pump.sleep means: it closes after it
+            rounds, packets = await self._drain_readers()
+            t_woke = TRACER.close(span, cause=cause, drain_rounds=rounds,
+                                  drain_packets=packets)
             obs.PUMP_LOOP_SECONDS.inc((t_woke - t_sleep) / 1e9,
                                       state="sleep")
             obs.PUMP_WAKES.inc(cause=cause)
-            self._pump_event.clear()
+            obs.PUMP_DRAIN_ROUNDS.inc(rounds)
+            obs.PUMP_DRAIN_PACKETS.inc(packets)
             self._reflect_all()
             if self.config.slo_enabled:
                 # a wake that compiled (and the one after) stays out of

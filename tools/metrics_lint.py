@@ -862,7 +862,9 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
     wall's three (ISSUE 30): ``engine_steps_total{result}`` at
     ``engine.step``'s exits, ``megabatch_cells_total{kind}`` per
     dispatched pass, ``ingest_interleaved_packets_total`` /
-    ``_seconds_total`` off ``ingest.read``."""
+    ``_seconds_total`` off ``ingest.read``; and the drain's two (ISSUE
+    31), counted where ``pump.sleep`` closes: ``pump_drain_rounds_total``
+    / ``pump_drain_packets_total``."""
     from easydarwin_tpu.obs.ledger import WORK_CLASSES
     from easydarwin_tpu.obs.metrics import TIME_BUCKETS
     from easydarwin_tpu.obs.trace import SPAN_PREFIXES, SPANS
@@ -893,6 +895,8 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
     want = {"pump_loop_seconds_total": (("state",), PUMP_STATES),
             "pump_wakes_total": (("cause",), WAKE_CAUSES),
             "pump_wake_seconds": ((), ()),
+            "pump_drain_rounds_total": ((), ()),
+            "pump_drain_packets_total": ((), ()),
             "relay_due_to_wire_seconds": (("engine",), ()),
             "engine_outputs_walked_total": ((), ()),
             "engine_outputs_due_total": ((), ()),
