@@ -632,36 +632,15 @@ def multi_source_latency(addrs, *, n_src=16, n_sub=16, seconds=6.0):
     own dispatch counters: per-stream = ring appends + param queries;
     megabatch = stacked bucket passes + fallback queries."""
     from easydarwin_tpu.obs import phase_breakdown, phase_snapshot
-    from easydarwin_tpu.protocol import sdp as sdp_mod
-    from easydarwin_tpu.relay.fanout import TpuFanoutEngine
+    from easydarwin_tpu.parallel.megabench import _mk_streams
+    from easydarwin_tpu.relay import pump
     from easydarwin_tpu.relay.megabatch import MegabatchScheduler
-    from easydarwin_tpu.relay.output import CollectingOutput
-    from easydarwin_tpu.relay.stream import RelayStream, StreamSettings
-
-    sdp_txt = ("v=0\r\ns=b\r\nt=0 0\r\nm=video 0 RTP/AVP 96\r\n"
-               "a=rtpmap:96 H264/90000\r\na=control:trackID=1\r\n")
-
-    def build_set():
-        rng = np.random.default_rng(11)
-        streams, engines = [], []
-        for s in range(n_src):
-            st = RelayStream(sdp_mod.parse(sdp_txt).streams[0],
-                             StreamSettings(bucket_delay_ms=0))
-            for i in range(n_sub):
-                o = CollectingOutput(
-                    ssrc=int(rng.integers(0, 2**32)),
-                    out_seq_start=int(rng.integers(0, 2**16)))
-                o.native_addr = addrs[(s * n_sub + i) % len(addrs)]
-                st.add_output(o)
-            streams.append(st)
-            engines.append(TpuFanoutEngine(egress_fd=send_fd))
-        return streams, engines
 
     send_sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
     send_sock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 1 << 22)
     send_fd = send_sock.fileno()
-    set_mb = build_set()
-    set_ps = build_set()
+    set_mb = _mk_streams(n_src, n_sub, addrs, send_fd, 11)
+    set_ps = _mk_streams(n_src, n_sub, addrs, send_fd, 11)
     sched = MegabatchScheduler()
     pkt = bytes([0x80, 96]) + bytes(10) + bytes(PKT_BYTES - 12)
     BURST = 4
@@ -674,16 +653,10 @@ def multi_source_latency(addrs, *, n_src=16, n_sub=16, seconds=6.0):
         return seq + BURST
 
     def step_mb(t):
-        pairs = list(zip(*set_mb))
-        sched.begin_wake(pairs, t)
-        for st, eng in pairs:
-            eng.step(st, t)
-        sched.end_wake(pairs, t)
+        pump.wake(list(zip(*set_mb)), sched, t)
 
     def step_ps(t):
-        for st, eng in zip(*set_ps):
-            eng.megabatch_owned = False
-            eng.step(st, t)
+        pump.wake(list(zip(*set_ps)), None, t)
 
     # prime both paths (compile + GSO probe) outside the timed loop
     t = int(time.monotonic() * 1000)
@@ -1119,6 +1092,7 @@ def vod_section(addrs, *, n_subs=8, n_assets=2, seconds=8.0) -> dict:
     import tempfile
 
     from easydarwin_tpu import obs
+    from easydarwin_tpu.relay import pump
     from easydarwin_tpu.relay.fanout import TpuFanoutEngine
     from easydarwin_tpu.relay.megabatch import MegabatchScheduler
     from easydarwin_tpu.relay.output import RelayOutput, WriteResult
@@ -1177,19 +1151,11 @@ def vod_section(addrs, *, n_subs=8, n_assets=2, seconds=8.0) -> dict:
     mm_base = obs.MEGABATCH_WIRE_MISMATCH.value()
 
     def hot_window() -> tuple[int, float]:
-        engines = {}
-
-        def engine_for(st):
-            e = engines.get(id(st))
-            if e is None:
-                e = engines[id(st)] = TpuFanoutEngine(
-                    egress_fd=send.fileno())
-            return e
-
+        engines = pump.Pump(new_engine=lambda: TpuFanoutEngine(
+            egress_fd=send.fileno()))
         sched = MegabatchScheduler()
-        pacer = VodPacerGroup(cache, engine_for=engine_for,
-                              engine_drop=lambda s: engines.pop(
-                                  id(s), None),
+        pacer = VodPacerGroup(cache, engine_for=engines.engine_for,
+                              engine_drop=engines.engine_drop,
                               scheduler=lambda: sched,
                               lookahead_ms=10_000, device_prime=True)
         outs = []
@@ -1206,14 +1172,7 @@ def vod_section(addrs, *, n_subs=8, n_assets=2, seconds=8.0) -> dict:
         deadline = t0 + 30.0
         while time.perf_counter() < deadline:
             t = int(time.monotonic() * 1000)
-            pairs = pacer.tick(t)
-            if len(pairs) >= 2:
-                sched.begin_wake(pairs, t)
-            for st, e in pairs:
-                e.megabatch_owned = len(pairs) >= 2
-                e.step(st, t)
-            if len(pairs) >= 2:
-                sched.end_wake(pairs, t)
+            pump.wake(pacer.tick(t), sched, t, min_streams=2)
             live = False
             for i, rec in enumerate(state):
                 sess, asset, seeks = rec
@@ -1327,6 +1286,7 @@ def dvr_section(addrs, *, record_frames=900, window_pkts=64) -> dict:
     from easydarwin_tpu.protocol import nalu
     from easydarwin_tpu.relay.fanout import TpuFanoutEngine
     from easydarwin_tpu.relay.output import RelayOutput, WriteResult
+    from easydarwin_tpu.relay.pump import Pump
     from easydarwin_tpu.relay.session import SessionRegistry, now_ms
     from easydarwin_tpu.vod.cache import SegmentCache, pack_window
     from easydarwin_tpu.vod.session import VodPacerGroup
@@ -1342,16 +1302,10 @@ def dvr_section(addrs, *, record_frames=900, window_pkts=64) -> dict:
     send.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 1 << 22)
     registry = SessionRegistry()
     cache = SegmentCache(budget_bytes=128 << 20, device=False)
-    engines: dict = {}
-
-    def engine_for(st):
-        e = engines.get(id(st))
-        if e is None:
-            e = engines[id(st)] = TpuFanoutEngine(egress_fd=send.fileno())
-        return e
-
-    pacer = VodPacerGroup(cache, engine_for=engine_for,
-                          engine_drop=lambda s: engines.pop(id(s), None),
+    engines = Pump(new_engine=lambda: TpuFanoutEngine(
+        egress_fd=send.fileno()))
+    pacer = VodPacerGroup(cache, engine_for=engines.engine_for,
+                          engine_drop=engines.engine_drop,
                           lookahead_ms=10_000, device_prime=False)
     tmp = tempfile.mkdtemp(prefix="edtpu_dvrbench_")
     dvr = DvrManager(tmp, cache, pacer, registry,
@@ -1365,7 +1319,7 @@ def dvr_section(addrs, *, record_frames=900, window_pkts=64) -> dict:
     out_live.native_addr = addrs[0]
     sess.add_output(1, out_live)
     dvr.arm(sess, SDP)
-    eng = engine_for(sess.streams[1])
+    eng = engines.engine_for(sess.streams[1])
     seq = 0
     spill_s = 0.0
     t0 = time.perf_counter()
@@ -1380,7 +1334,6 @@ def dvr_section(addrs, *, record_frames=900, window_pkts=64) -> dict:
         s0 = time.perf_counter()
         dvr.tick(t)
         spill_s += time.perf_counter() - s0
-        eng.megabatch_owned = False
         eng.step(sess.streams[1], t)
     live_s = time.perf_counter() - t0
     live_pkts = out_live.packets_sent
@@ -1405,7 +1358,6 @@ def dvr_section(addrs, *, record_frames=900, window_pkts=64) -> dict:
         while not shift.done and time.perf_counter() < deadline:
             t = now_ms()
             for st, e in pacer.tick(t):
-                e.megabatch_owned = False
                 e.step(st, t)
         ts_s = time.perf_counter() - t1
         ts_pkts = out_shift.packets_sent
@@ -1697,8 +1649,7 @@ def tcp_delivery_section(*, n_outputs: int = 16, n_new: int = 64,
     st_e, taps_e = build(True)
     st_b, taps_b = build(False)
     eng_e = TpuFanoutEngine()
-    eng_b = TpuFanoutEngine()
-    eng_b.tcp_fast_enabled = False      # the per-session baseline rung
+    eng_b = TpuFanoutEngine()           # fast=False sinks: baseline rung
     # phase 1: socket-level framing identity over one mixed-size window
     push_burst(st_e, 0, n_new)
     push_burst(st_b, 0, n_new)

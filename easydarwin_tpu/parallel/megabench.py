@@ -116,6 +116,7 @@ def measure_mesh_throughput(n_devices: int, *, n_streams: int = 16,
     Returns the ``extra.multichip`` schema; ``n_devices: 1`` with a
     ``note`` when no mesh could be built (1-device box) — the caller
     still gets valid single-device numbers."""
+    from ..relay import pump
     from ..relay.megabatch import MegabatchScheduler
     from .mesh import make_megabatch_mesh
 
@@ -148,11 +149,7 @@ def measure_mesh_throughput(n_devices: int, *, n_streams: int = 16,
 
     def step(mode, t):
         (streams, engines), sched = sets[mode]
-        pairs = list(zip(streams, engines))
-        sched.begin_wake(pairs, t)
-        for st, eng in pairs:
-            eng.step(st, t)
-        sched.end_wake(pairs, t)
+        pump.wake(list(zip(streams, engines)), sched, t)
 
     def drain_recv():
         if recv is None:

@@ -163,8 +163,9 @@ class _InFlight:
 
 
 class MegabatchScheduler:
-    """One per server; the pump calls ``begin_wake`` before the
-    per-stream step loop and ``end_wake`` after it."""
+    """One per server; the pump (``relay/pump.py: serve``) marks the
+    engines it hands over ``megabatch_owned`` and calls ``begin_wake``
+    before the per-stream step loop and ``end_wake`` after it."""
 
     #: never stage more than this many packets per stream per pass (a
     #: burst beyond it restages from the newest tail, mirroring the
@@ -222,14 +223,11 @@ class MegabatchScheduler:
 
     # ------------------------------------------------------------- wake API
     def begin_wake(self, pairs, now_ms: int) -> None:
-        """Harvest any finished stacked pass, mark ownership so the
-        engines skip their per-stream device work this wake, and prime
-        params for streams whose membership changed — ONE stacked pass
-        for every joined/rebased stream instead of one per-stream query
-        each (the mass-join case the per-stream path serves linearly)."""
+        """Harvest any finished stacked pass and prime params for
+        streams whose membership changed — ONE stacked pass for every
+        joined/rebased stream instead of one per-stream query each (the
+        mass-join case the per-stream path serves linearly)."""
         self.wakes += 1
-        for _stream, eng in pairs:
-            eng.megabatch_owned = True
         self._harvest()
         self._prime_stale(pairs, now_ms)
 

@@ -119,9 +119,6 @@ class DegradationLadder:
                 return LEVEL_CPU
         return h.level
 
-    def allows_megabatch(self, path: str | None) -> bool:
-        return self.engine_mode(path) == LEVEL_FULL
-
     def worst_level(self) -> int:
         return max((h.level for h in self._streams.values()), default=0)
 

@@ -6,10 +6,10 @@ session registry → listeners (RTSP + REST service port) → relay pump
 ingest and ticking at ``reflect_interval_ms``) → timeout sweeper (15 s
 granularity, ``TimeoutTask.h:66``) → optional cluster presence task.
 
-The pump chooses per stream between the scalar CPU fan-out and the TPU
-batch engine (``relay.fanout.TpuFanoutEngine``) based on config and the
-subscriber count — the "module loaded / unloaded with CPU fallback"
-behavior the north star requires.
+The pump (``relay.pump``) chooses per stream between the scalar CPU
+fan-out and the TPU batch engine (``relay.fanout.TpuFanoutEngine``) from
+config, subscriber count and ladder rung — the "module loaded / unloaded
+with CPU fallback" behavior the north star requires.
 """
 
 from __future__ import annotations
@@ -20,6 +20,7 @@ import time
 from .. import obs
 from ..obs import PROFILER, TRACER, t0_of
 from ..relay.fanout import TpuFanoutEngine
+from ..relay.pump import Pump
 from ..relay.session import SessionRegistry, now_ms
 from .config import ServerConfig
 from .rest import RestApi
@@ -109,8 +110,6 @@ class StreamingServer:
         #: block, or by the next _reflect_all (direct callers)
         self._wake_open_rec: tuple | None = None
         self._wake_seq = 0
-        self._wake_streams = 0
-        self._wake_sent = 0
         #: SLO watchdog over the obs families; the pump's 1 Hz
         #: maintenance block ticks it, violations flag flight recorders
         from ..obs import PROFILER, SloWatchdog
@@ -145,22 +144,23 @@ class StreamingServer:
         self._running = False
         self._restart_requested = False
         self.restart_event = asyncio.Event()
-        self._engines: dict[int, TpuFanoutEngine] = {}
         #: io_uring egress ring over the shared UDP pair (ISSUE 8);
         #: built in start() by the probe ladder, None = GSO/scalar rung
         self.uring_egress = None
         #: the rung the probe ladder landed on ("io_uring"/"gso"/
         #: "scalar") — mirrored into egress_backend_info{backend}
         self.egress_backend_effective = "gso"
+        #: the normalized ``egress_backend`` the probe ladder started from
+        self._egress_backend_choice = "auto"
         #: pusher RTP sockets get multishot io_uring ingest when True
         self.uring_ingest_enabled = False
-        #: cross-stream megabatch scheduler (relay/megabatch.py) — built
-        #: lazily on the first wake with enough engine-eligible streams
-        self.megabatch = None
-        #: the megabatch serving mesh (megabatch_devices > 1), built in
-        #: start() so a bad device config fails loudly at boot, not on
-        #: the first busy wake; None = single-device dispatch
-        self.megabatch_mesh = None
+        #: the wake (relay/pump.py): routes every stream, owns the
+        #: engines, the megabatch scheduler and its serving mesh
+        #: (``pump.mesh``: built in start() so a bad device config fails
+        #: loudly at boot, not on the first busy wake)
+        self.pump = Pump(self.config, on_device=self._on_device,
+                         new_engine=self._new_engine, ladder=self.ladder,
+                         error_log=self.error_log)
         #: ``device.resolve`` result ({"platform","kind","count"}) —
         #: filled by start() when tpu_fanout is on, BEFORE any listener
         #: opens; None = the engine tier is off and JAX was not touched
@@ -297,23 +297,24 @@ class StreamingServer:
             from ..device import note_swallowed
             from ..parallel.mesh import make_megabatch_mesh
             want = self.config.megabatch_devices
+            mesh = None
             try:
-                self.megabatch_mesh = make_megabatch_mesh(want)
-                if self.megabatch_mesh is None and want > 1:
+                mesh = make_megabatch_mesh(want)
+                if mesh is None and want > 1:
                     raise RuntimeError(
                         f"megabatch_devices={want} but only "
                         f"{self.device_info['count']} device(s) present")
             except Exception as e:
-                self.megabatch_mesh = None
+                mesh = None
                 note_swallowed("megabatch_mesh", e)
                 if self.error_log:
                     self.error_log.warning(
                         f"megabatch mesh unavailable, serving "
                         f"single-device: {e!r}")
-            if self.megabatch_mesh is not None and self.error_log:
+            self.pump.mesh = mesh
+            if mesh is not None and self.error_log:
                 from ..parallel.distributed import process_span
-                self.error_log.info(
-                    f"megabatch mesh: {process_span(self.megabatch_mesh)}")
+                self.error_log.info(f"megabatch mesh: {process_span(mesh)}")
         if self.config.vod_cache_enabled:
             from ..vod.cache import SegmentCache
             from ..vod.session import VodPacerGroup
@@ -323,9 +324,9 @@ class StreamingServer:
                 device=self.config.vod_cache_device)
             self.vod_pacer = VodPacerGroup(
                 self.vod_cache,
-                engine_for=self._engine_for,
-                engine_drop=lambda s: self._engines.pop(id(s), None),
-                scheduler=lambda: self.megabatch,
+                engine_for=self.pump.engine_for,
+                engine_drop=self.pump.engine_drop,
+                scheduler=lambda: self.pump.megabatch,
                 settings=self.config.stream_settings(),
                 lookahead_ms=self.config.vod_cache_lookahead_ms,
                 device_prime=(self.config.vod_cache_device
@@ -1189,18 +1190,14 @@ class StreamingServer:
                                    if self.uring_egress else ""))
 
     # ---------------------------------------------------------- pump loop
-    def _engine_for(self, stream) -> TpuFanoutEngine:
-        eng = self._engines.get(id(stream))
-        if eng is None:
-            eng = self._engines[id(stream)] = TpuFanoutEngine(
-                egress_backend=getattr(self, "_egress_backend_choice",
-                                       None) or "auto",
-                uring=self.uring_egress)
+    def _new_engine(self) -> TpuFanoutEngine:
+        """The pump builds one per stream, on its first device wake:
+        ``start()`` has settled the egress pair, ring and backend."""
         egress = self.rtsp.shared_egress
-        eng.egress_fd = egress.fileno() if egress is not None else None
-        eng.uring = self.uring_egress
-        eng.tcp_fast_enabled = self.config.tcp_engine_enabled
-        return eng
+        return TpuFanoutEngine(
+            egress_fd=egress.fileno() if egress is not None else None,
+            uring=self.uring_egress,
+            egress_backend=self._egress_backend_choice)
 
     def _on_device(self, stream) -> bool:
         """Whether the device path serves ``stream``: the engine tier is
@@ -1227,7 +1224,6 @@ class StreamingServer:
             PROFILER.observe("wake_to_pass", "pump", t0 - wake_ns)
             w2p_us = (t0 - wake_ns) // 1000
         self._wake_open_rec = (span, t0, w2p_us)
-        self._wake_streams = self._wake_sent = 0
 
     def _wake_close(self) -> None:
         """Last line of a wake: close the ledger's record and
@@ -1239,8 +1235,8 @@ class StreamingServer:
         span, t0, w2p_us = self._wake_open_rec
         self._wake_open_rec = None
         obs.LEDGER.end_wake()
-        end = TRACER.close(span, streams=self._wake_streams,
-                           sent=self._wake_sent, wake_to_pass_us=w2p_us)
+        end = TRACER.close(span, streams=self.pump.streams,
+                           sent=self.pump.sent, wake_to_pass_us=w2p_us)
         TRACER.wake = None
         obs.PUMP_WAKE_SECONDS.observe((end - t0) / 1e9)
         obs.PUMP_LOOP_SECONDS.inc((end - t0) / 1e9, state="wake")
@@ -1256,22 +1252,12 @@ class StreamingServer:
         # callers (tests, bench) are covered by begin_wake folding any
         # unclosed predecessor.
         LEDGER.begin_wake(wake_ns)
-        led_on = LEDGER.enabled
-        sent = 0
-        use_tpu = self.config.tpu_fanout
-        # megabatch: coalesce every engine-eligible stream's device work
-        # into one shape-bucketed stacked pass per wake (ISSUE 4).  The
-        # scheduler harvests the previous wake's in-flight pass here,
-        # the per-stream steps below consume the installed params, and
-        # end_wake stages+dispatches the next pass after the loop.  Any
-        # scheduler failure degrades to per-stream stepping, never to a
-        # halted pump.
         # VOD group pacer (ISSUE 10): fill every hot session's rings up
         # to the lookahead horizon and collect its (stream, engine)
         # pairs — paced VOD subscribers are first-class relay streams
-        # the pump steps below and the megabatch scheduler coalesces
-        # with live streams.  Any pacer failure degrades THIS wake's
-        # VOD service, never the pump.
+        # the pump steps and the megabatch scheduler coalesces with live
+        # streams.  Any pacer failure degrades THIS wake's VOD service,
+        # never the pump.
         vod_pairs = []
         if self.vod_pacer is not None and self.vod_pacer.sessions:
             _u = LEDGER.unit_start("vod_fill")
@@ -1295,156 +1281,7 @@ class StreamingServer:
                 if self.error_log:
                     self.error_log.warning(f"dvr spill: {e!r}")
             LEDGER.unit_end(_u)
-        mega_pairs = []
-        lad = self.ladder
-        if use_tpu and self.config.megabatch_enabled:
-            for sess in list(self.registry.sessions.values()):
-                for stream in sess.streams.values():
-                    if (self._on_device(stream)
-                            and (lad is None
-                                 or lad.allows_megabatch(sess.path))):
-                        mega_pairs.append((stream,
-                                           self._engine_for(stream)))
-            # paced VOD streams are always megabatch-eligible when the
-            # engine tier is on: the affine rewrite is content-
-            # independent, and a 1-subscriber VOD stream costs one
-            # bucket row, not a device pass
-            mega_pairs.extend(vod_pairs)
-            if len(mega_pairs) >= self.config.megabatch_min_streams:
-                if self.megabatch is None:
-                    from ..relay.megabatch import MegabatchScheduler
-                    self.megabatch = MegabatchScheduler(
-                        mesh=self.megabatch_mesh)
-                _u = LEDGER.unit_start("megabatch", part="harvest")
-                try:
-                    self.megabatch.begin_wake(mega_pairs, t)
-                except Exception as e:
-                    if lad is not None:
-                        lad.note_scheduler_error(
-                            [s.session_path for s, _ in mega_pairs])
-                    mega_pairs = []
-                    if self.error_log:
-                        self.error_log.warning(f"megabatch harvest: {e!r}")
-                LEDGER.unit_end(_u, items=max(len(mega_pairs), 1))
-            else:
-                mega_pairs = []
-        if not mega_pairs and self.megabatch is not None:
-            # scheduler built but not engaged this wake (mass teardown,
-            # megabatch disabled): keep harvesting in-flight passes so
-            # they can't pin torn-down streams and staging buffers
-            _u = LEDGER.unit_start("megabatch", part="idle")
-            try:
-                self.megabatch.idle_wake()
-            except Exception as e:
-                if self.error_log:
-                    self.error_log.warning(f"megabatch idle: {e!r}")
-            LEDGER.unit_end(_u)
-        mega_ids = {id(s) for s, _ in mega_pairs}
-        # live relay pass: ONE ledger unit covering every live stream's
-        # step/reflect; the slowest stream's trace_id rides the record
-        # (the critical-path correlation a p99 sample decomposes by)
-        _lu = LEDGER.unit_start("live_relay")
-        _n_live = 0
-        _worst_ns, _worst_trace = -1, None
-        for sess in list(self.registry.sessions.values()):
-            for stream in sess.streams.values():
-                _s0 = time.perf_counter_ns() if led_on else 0
-                _n_live += 1
-                # per-stream guard: one bad output (broken socket, buggy
-                # transcoder tap) must never halt fan-out for the rest
-                pre_stalls = stream.stats.stalls
-                # ladder rung (resilience/ladder.py): ≤1 keeps the
-                # device engine (0 = megabatch-coalesced); ≥2 — or a
-                # retry-backoff window — serves via the CPU oracle,
-                # the mandatory fallback the north star requires
-                mode = 0 if lad is None else lad.engine_mode(sess.path)
-                device = self._on_device(stream) and mode <= 1
-                try:
-                    if device:
-                        eng = self._engine_for(stream)
-                        eng.megabatch_owned = id(stream) in mega_ids
-                        sent += eng.step(stream, t)
-                        if lad is not None:
-                            lad.note_device_ok(sess.path)
-                    else:
-                        sent += stream.reflect(t)
-                except Exception as e:
-                    if device and lad is not None:
-                        # the DEVICE path failed: bounded retry with
-                        # backoff first, rung change only past the
-                        # budget.  Oracle-path failures (one broken
-                        # output) are logged only — they are not device
-                        # health and must not move the ladder
-                        lad.note_device_error(sess.path)
-                    if self.error_log:
-                        self.error_log.warning(
-                            f"reflect error on {sess.path}: {e!r}")
-                try:
-                    for out in stream.tickable_outputs:
-                        # reliable-UDP retransmit sweep (RTO-expired
-                        # packets; RTPPacketResender resend-on-interval)
-                        sent += out.tick(t)
-                except Exception as e:
-                    # one buggy output's sweep must neither halt fan-out
-                    # nor masquerade as a device error
-                    if self.error_log:
-                        self.error_log.warning(
-                            f"tick error on {sess.path}: {e!r}")
-                # wheel hint: a due-but-held bucket release on a
-                # NON-stalled stream just matured mid-pass and may be
-                # armed immediately; a stalled stream must not be (a
-                # time wake cannot unblock a full socket)
-                stream._last_pass_stalled = \
-                    stream.stats.stalls > pre_stalls
-                if led_on:
-                    _el = time.perf_counter_ns() - _s0
-                    if _el > _worst_ns:
-                        _worst_ns, _worst_trace = _el, stream.trace_id
-        LEDGER.unit_end(_lu, items=max(_n_live, 1),
-                        trace_id=_worst_trace)
-        # paced VOD streams: same per-stream guard discipline as live.
-        # The device gate ignores tpu_min_outputs — a VOD subscriber is
-        # one output by construction, and its device cost is a bucket
-        # row in the stacked pass, not a per-stream dispatch
-        _vu = LEDGER.unit_start("vod_fill") if vod_pairs else None
-        for stream, eng in vod_pairs:
-            pre_stalls = stream.stats.stalls
-            try:
-                if use_tpu and eng is not None:
-                    eng.megabatch_owned = id(stream) in mega_ids
-                    sent += eng.step(stream, t)
-                else:
-                    sent += stream.reflect(t)
-            except Exception as e:
-                if self.error_log:
-                    self.error_log.warning(
-                        f"vod reflect error on {stream.session_path}: "
-                        f"{e!r}")
-            try:
-                for out in stream.tickable_outputs:
-                    sent += out.tick(t)
-            except Exception as e:
-                if self.error_log:
-                    self.error_log.warning(
-                        f"vod tick error on {stream.session_path}: {e!r}")
-            stream._last_pass_stalled = \
-                stream.stats.stalls > pre_stalls
-        if _vu is not None:
-            LEDGER.unit_end(_vu, items=len(vod_pairs))
-        if mega_pairs:
-            _u = LEDGER.unit_start("megabatch", part="stage")
-            try:
-                self.megabatch.end_wake(mega_pairs, t)
-            except Exception as e:
-                if lad is not None:
-                    lad.note_scheduler_error(
-                        [s.session_path for s, _ in mega_pairs])
-                if self.error_log:
-                    self.error_log.warning(f"megabatch stage: {e!r}")
-            LEDGER.unit_end(_u, items=len(mega_pairs))
-        self._wake_streams = _n_live + len(vod_pairs)
-        self._wake_sent = sent
-        return sent
+        return self.pump.wake(self.registry.sessions, vod_pairs, t)
 
     def _make_pump_wheel(self):
         """1 ms native timer wheel pacing the pump below the fixed tick
@@ -1462,21 +1299,25 @@ class StreamingServer:
 
     def _schedule_stream_deadlines(self, wheel, t: int) -> None:
         """``t`` must be the time the wheel was last advanced to, so
-        relative deadlines land on the right tick."""
-        for sess in self.registry.sessions.values():
-            for stream in sess.streams.values():
-                allow_due = not getattr(stream, "_last_pass_stalled", False)
-                d = stream.next_deadline_ms(t, allow_due=allow_due)
-                key = id(stream)
-                cur = self._wheel_sched.get(key)
-                if d < 0:
-                    continue
-                due = t + d
-                if cur is not None and cur[1] <= due and cur[1] >= t:
-                    continue            # an earlier-or-equal timer pends
-                if cur is not None:
-                    wheel.cancel(cur[0])
-                self._wheel_sched[key] = (wheel.schedule(d, key), due)
+        relative deadlines land on the right tick.  Reads the wake's
+        own live streams (no ``await`` lies between), less any whose
+        session a step removed."""
+        sessions = self.registry.sessions
+        for path, stream, _eng, _route in self.pump.live:
+            if path not in sessions:
+                continue
+            d = stream.next_deadline_ms(
+                t, allow_due=not stream._last_pass_stalled)
+            if d < 0:
+                continue
+            key = id(stream)
+            cur = self._wheel_sched.get(key)
+            due = t + d
+            if cur is not None and cur[1] <= due and cur[1] >= t:
+                continue                # an earlier-or-equal timer pends
+            if cur is not None:
+                wheel.cancel(cur[0])
+            self._wheel_sched[key] = (wheel.schedule(d, key), due)
 
     #: the most rounds ``_drain_readers`` yields before a wake: a pusher
     #: that never pauses (a backlog, a REST storm) holds the pump out for
@@ -1557,7 +1398,7 @@ class StreamingServer:
                 for key in wheel.advance(t):
                     self._wheel_sched.pop(key, None)
                 self._schedule_stream_deadlines(wheel, t)
-                TRACER.close(tok, streams=self._wake_streams)
+                TRACER.close(tok, streams=self.pump.streams)
             now = time.monotonic()
             if now - last_prune >= 1.0:
                 last_prune = now
@@ -1744,15 +1585,15 @@ class StreamingServer:
         # design zeroed whichever reader came second in a tick)
         d = self.status.snapshot()
         mesh_info = {}
-        if self.megabatch_mesh is not None:
+        if self.pump.mesh is not None:
             # the mesh→process mapping, live (previously only the
             # multichip dryrun could see process_span)
             try:
                 from ..parallel.distributed import mesh_summary
-                mesh_info = mesh_summary(self.megabatch_mesh)
-                if self.megabatch is not None:
+                mesh_info = mesh_summary(self.pump.mesh)
+                if self.pump.megabatch is not None:
                     mesh_info["MeshShardedPasses"] = str(
-                        self.megabatch.sharded_passes)
+                        self.pump.megabatch.sharded_passes)
             except Exception:
                 mesh_info = {}
         return {

@@ -112,13 +112,6 @@ class ServerConfig:
     # degrades to gso with ONE egress.backend_fallback event); "scalar"
     # forces the per-datagram sendto baseline
     egress_backend: str = "auto"
-    # first-class TCP/HTTP delivery (ISSUE 14): interleaved-RTSP
-    # subscribers ride the engine's framed writev/io_uring stream path
-    # (vectorized $-framing in the same affine device pass as the UDP
-    # rewrite).  Off → TCP outputs serve from the per-session
-    # batch-header rung, the pre-ISSUE-14 behavior (also the bench's
-    # honest baseline).
-    tcp_engine_enabled: bool = True
     # x-Retransmit (reliable UDP) negotiation in SETUP — the reference's
     # reliable_udp pref (QTSServerPrefs; RTPStream.cpp:448 gate)
     reliable_udp: bool = True

@@ -18,6 +18,7 @@ import pytest
 from easydarwin_tpu import native, obs
 from easydarwin_tpu.protocol import rtp, sdp
 from easydarwin_tpu.relay import megabatch as mb
+from easydarwin_tpu.relay import pump
 from easydarwin_tpu.relay.fanout import TpuFanoutEngine
 from easydarwin_tpu.relay.megabatch import (PACKET_PADS, MegabatchScheduler,
                                             _packet_pad, _stream_pad,
@@ -158,8 +159,8 @@ def test_thin_streams_on_the_device_path_equal_the_scalar_loop(n_out):
             assert first.seq == o_dev.rewrite.out_seq_start
         # the device path did it: stacked passes, no scalar delivery from
         # the server's streams, no per-stream query behind the scheduler
-        assert app.megabatch is not None and app.megabatch.passes > 0
-        assert app.megabatch.mismatches == 0
+        assert app.pump.megabatch is not None and app.pump.megabatch.passes > 0
+        assert app.pump.megabatch.mismatches == 0
         assert not any(o.rtp_packets for o in dev_outs)
         assert obs.MEGABATCH_FALLBACK.total() == fallback0
         assert (obs.RELAY_INGEST_TO_WIRE.count(engine="scalar")
@@ -254,6 +255,9 @@ def test_the_shape_set_is_closed_and_built_ahead(monkeypatch):
                 seq = _push(streams[0], 150, seq, t)     # fell behind
             b0, m0 = built(), len(sched._built)
             complete = sched._built == members
+            # asserts between the scheduler's phases: drives them itself
+            for _st, eng in pairs:
+                eng.megabatch_owned = True
             sched.begin_wake(pairs, t)
             ahead = len(sched._built) - m0
             for st, eng in pairs:
@@ -300,10 +304,7 @@ def test_a_stream_that_fell_behind_rides_more_passes_and_counts_once():
                 seq = _push(streams[0], behind, seq, t)
                 _push(streams[1], 20, seq, t)
                 passes, coalesced = sched.passes, sched.streams_coalesced
-            sched.begin_wake(pairs, t)
-            for st, eng in pairs:
-                eng.step(st, t)
-            sched.end_wake(pairs, t)
+            pump.wake(pairs, sched, t)
             sched.drain()
         # 64 + 64 + 22 of one stream and 20 of the other: four rows of
         # the wide pad in one pass of the pair's rung, two streams
@@ -337,8 +338,8 @@ def test_the_set_is_built_while_players_join_before_the_first_packet():
         fat.add_output(o)
     app._reflect_all()
     app._wake_close()
-    assert app.megabatch._built == MegabatchScheduler.members({8: 20})
-    assert app.megabatch.passes == 0        # nothing staged, nothing primed
+    assert app.pump.megabatch._built == MegabatchScheduler.members({8: 20})
+    assert app.pump.megabatch.passes == 0        # nothing staged, nothing primed
 
 
 # ------------------------------------------------ (3) the counters add up
@@ -359,6 +360,8 @@ def test_engine_steps_and_megabatch_cells_add_up():
                    for _ in streams]
         sched = MegabatchScheduler()
         pairs = list(zip(streams, engines))
+        for _st, eng in pairs:      # counts inside the step loop: drives
+            eng.megabatch_owned = True      # the phases itself
         steps0 = {r: obs.ENGINE_STEPS.value(result=r)
                   for r in ("idle", "worked")}
         cells0 = {k: obs.MEGABATCH_CELLS.value(kind=k)

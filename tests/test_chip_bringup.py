@@ -216,7 +216,7 @@ async def test_megabatch_mesh_failure_is_counted(monkeypatch, tmp_path):
     before = _swallowed("megabatch_mesh")
     await app.start()
     try:
-        assert app.megabatch_mesh is None
+        assert app.pump.mesh is None
         assert _swallowed("megabatch_mesh") == before + 1
         assert app.device_info["platform"] == "cpu"
         info = app.server_info()

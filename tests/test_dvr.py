@@ -316,7 +316,6 @@ def _step(stream, engines, t):
             from easydarwin_tpu.relay.fanout import TpuFanoutEngine
             eng = engines[id(stream)] = TpuFanoutEngine(
                 egress_fd=engines["_fd"])
-        eng.megabatch_owned = False
         eng.step(stream, t)
 
 

@@ -16,6 +16,7 @@ import pytest
 
 from easydarwin_tpu import native, obs
 from easydarwin_tpu.protocol import sdp
+from easydarwin_tpu.relay import pump
 from easydarwin_tpu.relay.fanout import TpuFanoutEngine, params_key
 from easydarwin_tpu.relay.megabatch import (MegabatchScheduler,
                                             _host_affine_params)
@@ -107,13 +108,7 @@ def _run_scenario(use_megabatch: bool, wire: _Wire, send_fd: int):
                 s.push_rtp(vid_pkt(seq, seq * 90,
                                    nal_type=5 if seq % 25 == 0 else 1), t)
                 seq += 1
-        if sched is not None:
-            sched.begin_wake(pairs, t)
-        for s, eng in pairs:
-            eng.megabatch_owned = sched is not None
-            eng.step(s, t)
-        if sched is not None:
-            sched.end_wake(pairs, t)
+        pump.wake(pairs, sched, t)
         wire.drain()
         t += 20
     if sched is not None:
@@ -169,14 +164,7 @@ def test_megabatch_collecting_outputs_identical_to_per_stream():
                 for _ in range(6):
                     st.push_rtp(vid_pkt(seq, seq * 90), t)
                     seq += 1
-            pairs = list(zip(streams, engines))
-            if sched is not None:
-                sched.begin_wake(pairs, t)
-            for st, eng in pairs:
-                eng.megabatch_owned = sched is not None
-                eng.step(st, t)
-            if sched is not None:
-                sched.end_wake(pairs, t)
+            pump.wake(list(zip(streams, engines)), sched, t)
             t += 20
         return [[o.rtp_packets for o in st.outputs] for st in streams]
 
@@ -296,15 +284,12 @@ def test_idle_wake_drains_inflight_after_mass_teardown():
                 for _ in range(4):
                     st.push_rtp(vid_pkt(seq, seq * 90), t)
                     seq += 1
-            sched.begin_wake(pairs, t)
-            for st, eng in pairs:
-                eng.step(st, t)
-            sched.end_wake(pairs, t)
+            pump.wake(pairs, sched, t)
             t += 20
         # mass teardown: the pump now sees zero eligible streams and
         # calls idle_wake instead of begin/end_wake
         for _ in range(50):
-            sched.idle_wake()
+            pump.wake([], sched, t)
             if not sched._inflight and not sched._tracked:
                 break
             time.sleep(0.01)
@@ -325,7 +310,7 @@ def test_server_reflect_all_wires_the_scheduler():
                        access_log_enabled=False)
     app = StreamingServer(cfg)
     app._reflect_all()                     # no streams: scheduler stays off
-    assert app.megabatch is None
+    assert app.pump.megabatch is None
     for path, seed in (("/live/a", 1), ("/live/b", 2)):
         sess = app.registry.find_or_create(path, VIDEO_SDP)
         st = sess.streams[1]
@@ -335,8 +320,8 @@ def test_server_reflect_all_wires_the_scheduler():
             st.add_output(o)
         st.push_rtp(vid_pkt(seed, seed * 90), 1000)
     app._reflect_all()
-    assert app.megabatch is not None
-    assert app.megabatch.wakes >= 1
+    assert app.pump.megabatch is not None
+    assert app.pump.megabatch.wakes >= 1
     # packets actually moved through the engines under the scheduler
     assert all(o.rtp_packets
                for sess in app.registry.sessions.values()

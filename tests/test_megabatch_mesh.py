@@ -18,6 +18,7 @@ import pytest
 
 from easydarwin_tpu import native, obs
 from easydarwin_tpu.parallel.mesh import make_megabatch_mesh
+from easydarwin_tpu.relay import pump
 from easydarwin_tpu.relay.fanout import TpuFanoutEngine
 from easydarwin_tpu.relay.megabatch import MegabatchScheduler
 from test_megabatch import VIDEO_SDP, _Wire, _mk_stream, vid_pkt
@@ -65,13 +66,7 @@ def _run_mesh_scenario(mesh, wire: _Wire, send_fd: int):
                 s.push_rtp(vid_pkt(seq, seq * 90,
                                    nal_type=5 if seq % 25 == 0 else 1), t)
                 seq += 1
-        if sched is not None:
-            sched.begin_wake(pairs, t)
-        for s, eng in pairs:
-            eng.megabatch_owned = sched is not None
-            eng.step(s, t)
-        if sched is not None:
-            sched.end_wake(pairs, t)
+        pump.wake(pairs, sched, t)
         wire.drain()
         t += 20
     if sched is not None:
@@ -133,14 +128,7 @@ def test_mesh_uneven_stream_count_pad_masked():
                 for _ in range(3):
                     s.push_rtp(vid_pkt(seq, seq * 90), t)
                     seq += 1
-            pairs = list(zip(streams, engines))
-            if sched is not None:
-                sched.begin_wake(pairs, t)
-            for s, eng in pairs:
-                eng.megabatch_owned = sched is not None
-                eng.step(s, t)
-            if sched is not None:
-                sched.end_wake(pairs, t)
+            pump.wake(list(zip(streams, engines)), sched, t)
             wire.drain()
             t += 20
         if sched is not None:
@@ -284,7 +272,7 @@ async def test_server_builds_mesh_and_surfaces_span():
     app = StreamingServer(cfg)
     await app.start()
     try:
-        assert app.megabatch_mesh is not None
+        assert app.pump.mesh is not None
         for path, seed in (("/live/a", 1), ("/live/b", 2)):
             sess = app.registry.find_or_create(path, VIDEO_SDP)
             st = sess.streams[1]
@@ -294,8 +282,8 @@ async def test_server_builds_mesh_and_surfaces_span():
                 st.add_output(o)
             st.push_rtp(vid_pkt(seed, seed * 90), 1000)
         app._reflect_all()
-        assert app.megabatch is not None
-        assert app.megabatch.mesh is app.megabatch_mesh
+        assert app.pump.megabatch is not None
+        assert app.pump.megabatch.mesh is app.pump.mesh
         info = app.server_info()
         assert info["MeshDevices"] == "8"
         assert info["MeshShape"] == "src=8,sub=1,win=1"

@@ -118,7 +118,7 @@ async def test_native_egress_64_udp_players_bit_identical():
         assert len(ssrcs) == N_PLAYERS          # unique SSRC per player
 
         # the packets actually went through the native scatter path
-        engines = list(app._engines.values())
+        engines = list(app.pump.engines.values())
         native_sent = sum(e.native_sent for e in engines)
         assert native_sent >= N_PLAYERS * N_PKTS, native_sent
         assert all(e.device_param_refreshes >= 1 for e in engines

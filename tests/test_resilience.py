@@ -25,6 +25,7 @@ from easydarwin_tpu import native, obs
 from easydarwin_tpu.obs.events import EventLog
 from easydarwin_tpu.obs.metrics import Counter, Gauge
 from easydarwin_tpu.protocol import sdp
+from easydarwin_tpu.relay import pump
 from easydarwin_tpu.relay.fanout import TpuFanoutEngine
 from easydarwin_tpu.relay.megabatch import MegabatchScheduler
 from easydarwin_tpu.relay.output import CollectingOutput, WriteResult
@@ -331,7 +332,7 @@ def test_ladder_bounded_retry_before_rung_change():
     assert evs == ["ladder.degrade"]
     rec = events.tail()[0]
     assert rec["rung"] == "device" and rec["from_rung"] == "megabatch"
-    assert not lad.allows_megabatch(path)
+    assert lad.engine_mode(path) == LEVEL_DEVICE   # own engine, unowned
 
 
 def test_ladder_interleaved_successes_do_not_reset_budget():
@@ -615,11 +616,7 @@ def test_kill_restore_resumes_byte_identical_16x16():
                     for _ in range(2):
                         st.push_rtp(vid_pkt(state["seq"]), state["t"])
                         state["seq"] += 1
-                pairs = list(zip(streams, engines))
-                sched.begin_wake(pairs, state["t"])
-                for st, eng in pairs:
-                    eng.step(st, state["t"])
-                sched.end_wake(pairs, state["t"])
+                pump.wake(list(zip(streams, engines)), sched, state["t"])
                 wire.drain()
                 state["t"] += 20
 
@@ -642,11 +639,7 @@ def test_kill_restore_resumes_byte_identical_16x16():
         wakes(PHASE_B)
         sched.drain()
         # a final no-ingest wake flushes params harvested in flight
-        pairs = list(zip(streams, engines))
-        sched.begin_wake(pairs, state["t"])
-        for st, eng in pairs:
-            eng.step(st, state["t"])
-        sched.end_wake(pairs, state["t"])
+        pump.wake(list(zip(streams, engines)), sched, state["t"])
         wire.drain()
         return mark, [list(r) for r in wire.rx]
 

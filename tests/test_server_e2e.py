@@ -364,7 +364,7 @@ async def test_tpu_fanout_engine_serves_players_end_to_end():
                 assert rtp.RtpPacket.parse(g).payload in payloads
             assert pl.stats.lost == 0 and pl.stats.duplicates == 0
         # the engine actually ran (device batch, not the scalar loop)
-        assert app._engines, "TpuFanoutEngine was never instantiated"
+        assert app.pump.engines, "TpuFanoutEngine was never instantiated"
         for pl in players:
             await pl.close()
         await pusher.close()

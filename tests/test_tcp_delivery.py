@@ -283,6 +283,7 @@ def test_megabatch_stages_tcp_framing_params():
     """The cross-stream scheduler stages interleave channel columns in
     the SAME stacked pass as the UDP affine params; every install rides
     the host-oracle check and the wire stays byte-identical."""
+    from easydarwin_tpu.relay import pump
     from easydarwin_tpu.relay.megabatch import MegabatchScheduler
     streams_a, streams_b, taps_a, taps_b = [], [], [], []
     for s in range(3):
@@ -295,12 +296,7 @@ def test_megabatch_stages_tcp_framing_params():
     now = 1000 + 50 + 5000
     sched = MegabatchScheduler()
     engines = [TpuFanoutEngine() for _ in streams_a]
-    pairs = list(zip(streams_a, engines))
-    sched.begin_wake(pairs, now)
-    for st, eng in pairs:
-        eng.megabatch_owned = True
-        eng.step(st, now)
-    sched.end_wake(pairs, now)
+    pump.wake(list(zip(streams_a, engines)), sched, now)
     for st_b in streams_b:
         TpuFanoutEngine().step(st_b, now)
     assert sched.mismatches == 0

@@ -22,6 +22,7 @@ import pytest
 
 from easydarwin_tpu import obs
 from easydarwin_tpu.protocol import rtp
+from easydarwin_tpu.relay import pump
 from easydarwin_tpu.relay.output import RelayOutput, WriteResult
 from easydarwin_tpu.vod.cache import (SegmentCache, StagedPacketRing,
                                       pack_window, tracks_by_no)
@@ -218,13 +219,7 @@ def _run_hot(path, rx_v, rx_a, tx, *, start_npt=0.0, level=0,
     deadline = time.time() + 20
     while not sess.done and time.time() < deadline:
         t = int(time.monotonic() * 1000)
-        pairs = pacer.tick(t)
-        for st, e in pairs:
-            if e is not None:
-                e.megabatch_owned = False
-                e.step(st, t)
-            else:
-                st.reflect(t)
+        pump.wake(pacer.tick(t), None, t)
         time.sleep(0.001)
     assert sess.done, "hot session never finished"
     pacer.close()
@@ -342,14 +337,7 @@ def test_vod_streams_ride_megabatch_with_device_prime(fixture_mp4):
     deadline = time.time() + 20
     while any(not s.done for s in sessions) and time.time() < deadline:
         t = int(time.monotonic() * 1000)
-        pairs = pacer.tick(t)
-        if len(pairs) >= 2:
-            sched.begin_wake(pairs, t)
-        for st, e in pairs:
-            e.megabatch_owned = len(pairs) >= 2
-            e.step(st, t)
-        if len(pairs) >= 2:
-            sched.end_wake(pairs, t)
+        pump.wake(pacer.tick(t), sched, t, min_streams=2)
         time.sleep(0.001)
     sched.drain()
     assert all(s.done for s in sessions)

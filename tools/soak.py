@@ -377,13 +377,9 @@ def multi_source_section(n_sources: int, seconds: float = 2.0,
     src-axis device mesh (ISSUE 7) and additionally fails on zero
     SHARDED passes — a mesh run that silently fell back to
     single-device dispatch proves nothing about the mesh path."""
-    import numpy as np
-
-    from easydarwin_tpu.protocol import sdp as sdp_mod
-    from easydarwin_tpu.relay.fanout import TpuFanoutEngine
+    from easydarwin_tpu.parallel.megabench import _mk_streams, _precompile
+    from easydarwin_tpu.relay import pump
     from easydarwin_tpu.relay.megabatch import MegabatchScheduler
-    from easydarwin_tpu.relay.output import CollectingOutput
-    from easydarwin_tpu.relay.stream import RelayStream, StreamSettings
 
     errs: list[str] = []
     mesh = None
@@ -395,25 +391,13 @@ def multi_source_section(n_sources: int, seconds: float = 2.0,
                     "devices; set XLA_FLAGS="
                     "--xla_force_host_platform_device_count)"]
     OUTS_PER_STREAM = 8
-    sdp_txt = ("v=0\r\ns=m\r\nt=0 0\r\nm=video 0 RTP/AVP 96\r\n"
-               "a=rtpmap:96 H264/90000\r\na=control:trackID=1\r\n")
     recv = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
     recv.bind(("127.0.0.1", 0))
     recv.setblocking(False)
     recv.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 1 << 22)
     send = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
-    rng = np.random.default_rng(5)
-    streams, engines = [], []
-    for s in range(n_sources):
-        st = RelayStream(sdp_mod.parse(sdp_txt).streams[0],
-                         StreamSettings(bucket_delay_ms=0))
-        for _ in range(OUTS_PER_STREAM):
-            o = CollectingOutput(ssrc=int(rng.integers(0, 2**32)),
-                                 out_seq_start=int(rng.integers(0, 2**16)))
-            o.native_addr = recv.getsockname()
-            st.add_output(o)
-        streams.append(st)
-        engines.append(TpuFanoutEngine(egress_fd=send.fileno()))
+    streams, engines = _mk_streams(n_sources, OUTS_PER_STREAM,
+                                   [recv.getsockname()], send.fileno(), 5)
     sched = MegabatchScheduler(mesh=mesh)
     pkt = bytes([0x80, 96]) + bytes(10) + bytes(188)
     # pre-compile the stacked step for the shapes this section uses,
@@ -421,7 +405,6 @@ def multi_source_section(n_sources: int, seconds: float = 2.0,
     # a live backlog turns compile time into real ingest→wire latency
     # and burns the SLO budget the soak asserts on (the burst of 3
     # below pads to the same 16-row window the harness traces)
-    from easydarwin_tpu.parallel.megabench import _precompile
     _precompile(sched, n_sources, OUTS_PER_STREAM, burst=3)
     t = int(time.monotonic() * 1000)
     seq = 0
@@ -432,11 +415,7 @@ def multi_source_section(n_sources: int, seconds: float = 2.0,
                 st.push_rtp(pkt[:2] + (seq & 0xFFFF).to_bytes(2, "big")
                             + pkt[4:], t)
                 seq += 1
-        pairs = list(zip(streams, engines))
-        sched.begin_wake(pairs, t)
-        for st, eng in pairs:
-            eng.step(st, t)
-        sched.end_wake(pairs, t)
+        pump.wake(list(zip(streams, engines)), sched, t)
         try:                               # keep the receiver queue empty
             while True:
                 recv.recv(65536)
@@ -1286,7 +1265,7 @@ async def soak(seconds: float, n_sources: int = 0,
             failures.append(
                 f"reliable window never drains: {rel_out.resender.in_flight}")
         if not chaos:
-            for eng in app._engines.values():
+            for eng in app.pump.engines.values():
                 if eng.send_errors:
                     failures.append(f"engine send errors: {eng.send_errors}")
         if lossy:
