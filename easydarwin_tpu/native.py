@@ -968,7 +968,14 @@ class TimerWheel:
     def cancel(self, timer_id: int) -> bool:
         return bool(self._lib.ed_wheel_cancel(self._w, timer_id))
 
-    def advance(self, now_ms: int, max_out: int = 1024) -> list[int]:
+    def advance(self, now_ms: int, max_out: int | None = None) -> list[int]:
+        """Keys of the timers due by ``now_ms``.  Room for every pending
+        timer by default: the C walk stops at ``max_out`` yet moves its
+        clock to ``now_ms``, so a timer it had no room for would fire a
+        revolution (4,096 ms) late — and the pump readies a stream by
+        its timer."""
+        if max_out is None:
+            max_out = max(self.pending, 1)
         out = np.zeros(max_out, dtype=np.int64)
         n = self._lib.ed_wheel_advance(self._w, now_ms, _i64(out), max_out)
         return out[:n].tolist()

@@ -127,6 +127,25 @@ PUMP_DRAIN_PACKETS = REGISTRY.counter(
     "loop's ready queue and waited for the one after.  What was pushed "
     "while the pump still waited on its event is not counted: a pump "
     "that is not at the head of the queue is woken behind the batch")
+#: the wake's ready set (ISSUE 33; ``relay.pump``): the first two counted
+#: once a wake in ``Pump.wake``, the third by the 1 Hz ``Pump.audit``
+PUMP_ROSTER_STREAMS = REGISTRY.counter(
+    "pump_roster_streams_total",
+    "Live streams on the wake's roster, summed over wakes (the VOD "
+    "pacer's are not counted: they are stepped whenever it hands them in)")
+PUMP_STEPPED_STREAMS = REGISTRY.counter(
+    "pump_stepped_streams_total",
+    "Live roster entries the wake handed to its step loop: the streams "
+    "marked ready since the last wake by ingest, a plan move, a route "
+    "change, a wheel timer (bucket release, RTO, SR) or the step's own "
+    "carry-over (stepped / pump_roster_streams_total: the share of the "
+    "roster that had something to do)")
+PUMP_READY_MISSED = REGISTRY.counter(
+    "pump_ready_missed_total",
+    "Streams the 1 Hz audit found in need of a step (relay.pump."
+    "needs_step) that the last wake skipped and nothing had marked: a "
+    "write that bypassed the marks.  Each is stepped in the next wake; "
+    "anything but 0 is a fault to find")
 
 # -------------------------------------------------------------- SLO watchdog
 SLO_VIOLATIONS = REGISTRY.counter(

@@ -28,6 +28,54 @@ class WriteResult(enum.Enum):
     ERROR = 2
 
 
+class PlanCell:
+    """What one stream shares with its outputs and with the pump that
+    serves it: the plan epoch (``relay.fanout``: the engine's output
+    plan is valid while it has not moved) and, once a pump has rostered
+    the stream, that pump's ready set and the stream's key in it
+    (``relay.pump``: the wake steps the streams that were marked).  An
+    output holds the cell and no reference to the stream itself.
+
+    The rest is the pump's own record of the stream's last step, which
+    ``relay.pump.needs_step`` reads: the two rings' heads and the epoch
+    as the step left them, the route it took, whether it has to be
+    retried whatever happens, and the wheel timer armed for it."""
+
+    __slots__ = ("epoch", "ready", "key", "rtp_head", "rtcp_head",
+                 "stepped_epoch", "route", "retry", "due", "timer")
+
+    #: ``due`` with no timer pending
+    NEVER = 1 << 62
+
+    def __init__(self):
+        self.epoch = 0
+        self.ready: set | None = None
+        self.key = 0
+        self.rtp_head = self.rtcp_head = self.stepped_epoch = -1
+        self.route = -1
+        self.retry = False
+        self.due = self.NEVER
+        self.timer = 0
+
+    def install(self, ready: set, key: int) -> None:
+        """A pump rosters the stream for the first time: its marks land
+        in ``ready`` under ``key`` from here on, it has no step on
+        record (``needs_step`` holds) and no timer."""
+        self.ready, self.key = ready, key
+        self.rtp_head = -1
+        self.due, self.timer = self.NEVER, 0
+        ready.add(key)
+
+    def mark(self) -> None:
+        """The stream has something to do in the next wake."""
+        if self.ready is not None:
+            self.ready.add(self.key)
+
+    def touch(self) -> None:
+        self.epoch += 1
+        self.mark()
+
+
 @dataclass
 class RewriteState:
     """Per-output header-rewrite parameters (device-friendly: 3 ints)."""
@@ -61,10 +109,9 @@ class RewriteState:
 class RelayOutput:
     """One subscriber × one track. Subclasses implement ``send_bytes``."""
 
-    #: the owning stream's plan-epoch cell (``RelayStream.add_output``
-    #: sets it, ``remove_output`` clears it): one shared ``[int]`` and
-    #: no reference to the stream itself
-    _plan_cell: list | None = None
+    #: the owning stream's ``PlanCell`` (``RelayStream.add_output`` sets
+    #: it, ``remove_output`` clears it)
+    _plan_cell: PlanCell | None = None
     _bookmark: int | None = None
     _meta_field_ids: dict | None = None
 
@@ -94,7 +141,7 @@ class RelayOutput:
         ``_bookmark`` directly and does not."""
         cell = self._plan_cell
         if cell is not None:
-            cell[0] += 1
+            cell.touch()
 
     @property
     def bookmark(self) -> int | None:

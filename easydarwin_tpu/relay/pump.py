@@ -6,10 +6,22 @@ The server's ``_reflect_all`` calls ``Pump.wake``; a caller with no
 server hands ``wake`` its ``(stream, engine)`` pairs.  Both run ``serve``
 over a roster of ``(session path, stream, engine | None, route)``, the
 engine ``None`` exactly when the route is ``SCALAR``.
+
+**Which streams a wake steps.**  The scheduler sees every owned pair of
+the roster every wake (its closed shape set counts them); ``_step`` is
+handed the live streams that have something to do — ``needs_step`` is
+the definition, for every route alike.  ``Pump`` does not evaluate it
+over the roster: it keeps a ready set, marked where the state
+``needs_step`` reads is written (``PlanCell.mark`` / ``touch`` from
+ingest and every plan move, the wheel's fired timers, ``_step``'s own
+carry-over), and audits the marks against the rule once a second
+(``Pump.audit``).  The VOD roster and a caller with no wheel step all
+they hand in.
 """
 
 from __future__ import annotations
 
+import itertools
 import time
 import weakref
 
@@ -22,16 +34,50 @@ from .fanout import TpuFanoutEngine
 SCALAR, DEVICE, OWNED = 0, 1, 2
 
 
+def needs_step(stream, t: int, route: int | None = None) -> bool:
+    """Whether ``stream`` has something to do in a wake at ``t``, from
+    its own state and the record its last step left in its cell:
+
+    1. ingest — either ring's head moved (``push_rtp``, the native
+       drain, the chaos injector's held packet, ``push_rtcp``);
+    2. its plan epoch moved (a join, a leave, a bookmark or rewrite
+       field written from outside the engine, a thinning level) or
+       ``route`` is not the route it last took (a ladder move);
+
+    and, for a stream with outputs and a ring that is not empty (with
+    neither a step leaves by its first exit):
+
+    3. the timer armed for it has run out — a held cohort's release, a
+       reliable-UDP RTO, the next SR (``next_deadline_ms``);
+    4. its last step stalled, raised or left an output un-latched: it
+       is retried every wake;
+    5. it carries a per-pass hook no deadline stands for (``fec.tick``
+       rides ``relay_rtcp``)."""
+    c = stream._plan_cell
+    ring = stream.rtp_ring
+    if (ring.head != c.rtp_head or stream.rtcp_ring.head != c.rtcp_head
+            or c.epoch != c.stepped_epoch
+            or (route is not None and route != c.route)):
+        return True
+    if not len(ring) or not stream.num_outputs:
+        return False
+    return c.retry or c.due <= t or stream.fec is not None
+
+
 def _step(entries, t: int, ladder, log, label: str, timed: bool):
     """Step every entry once; returns (packets sent, the slowest
     stream's trace id when ``timed``).  ``ladder`` hears how the DEVICE
     path fared; an oracle-path failure (one broken output) is logged
-    only — it is not device health and must not move a rung."""
+    only — it is not device health and must not move a rung.  Leaves in
+    each stream's cell what ``needs_step`` compares the next wake's
+    state with, and in its pump's ready set the mark of a stream that is
+    to be stepped again whatever happens."""
     sent = 0
     worst_ns, worst_trace = -1, None
-    for path, stream, eng, _route in entries:
+    for path, stream, eng, route in entries:
         s0 = time.perf_counter_ns() if timed else 0
         pre_stalls = stream.stats.stalls
+        raised = False
         # per-stream guard: one bad output (broken socket, buggy
         # transcoder tap) must never halt fan-out for the rest
         try:
@@ -42,6 +88,7 @@ def _step(entries, t: int, ladder, log, label: str, timed: bool):
             else:
                 sent += stream.reflect(t)
         except Exception as e:
+            raised = True
             if eng is not None and ladder is not None:
                 # bounded retry with backoff; a rung only past the budget
                 ladder.note_device_error(path)
@@ -56,7 +103,25 @@ def _step(entries, t: int, ladder, log, label: str, timed: bool):
         # wheel hint: a due-but-held release on a NON-stalled stream may
         # be armed at once; a stalled one must not be (a time wake
         # cannot unblock a full socket)
-        stream._last_pass_stalled = stream.stats.stalls > pre_stalls
+        stalled = stream._last_pass_stalled = (stream.stats.stalls
+                                               > pre_stalls)
+        # the record for needs_step.  What the step itself wrote (a
+        # scalar pass's bookmarks, a latch) marked the stream: that mark
+        # goes, and the carry-over (rules 4 and 5) is put in its place.
+        # An SR due at t is an un-latched output's "re-check every pass"
+        c = stream._plan_cell
+        c.rtp_head = stream.rtp_ring.head
+        c.rtcp_head = stream.rtcp_ring.head
+        c.stepped_epoch = c.epoch
+        c.route = route
+        live = len(stream.rtp_ring) > 0 and stream.num_outputs > 0
+        c.retry = live and (stalled or raised
+                            or stream._next_sr_due_ms <= t)
+        if c.ready is not None:
+            if c.retry or (live and stream.fec is not None):
+                c.ready.add(c.key)
+            else:
+                c.ready.discard(c.key)
         if timed:
             el = time.perf_counter_ns() - s0
             if el > worst_ns:
@@ -65,10 +130,12 @@ def _step(entries, t: int, ladder, log, label: str, timed: bool):
 
 
 def serve(live, vod, sched, t: int, *, min_streams: int = 1, ladder=None,
-          log=None) -> int:
+          log=None, stepped=None) -> int:
     """One wake over a built roster: ``live`` entries inside the
-    ``live_relay`` ledger unit, ``vod`` entries (they neither consult
-    nor move the ladder) inside ``vod_fill``.  ``OWNED`` falls to
+    ``live_relay`` ledger unit — ``stepped`` of them where the caller
+    keeps a ready set, the scheduler still handed every owned pair —
+    and ``vod`` entries (they neither consult nor move the ladder)
+    inside ``vod_fill``.  ``OWNED`` falls to
     ``DEVICE`` for the whole wake without a ``sched``, under
     ``min_streams`` owned entries, or when the harvest raises: a
     scheduler failure degrades to per-stream stepping, never to a halted
@@ -110,9 +177,11 @@ def serve(live, vod, sched, t: int, *, min_streams: int = 1, ladder=None,
         LEDGER.unit_end(_u)
     # the slowest stream's trace_id rides the unit's record (the
     # critical-path correlation a p99 sample decomposes by)
+    if stepped is None:
+        stepped = live
     _u = LEDGER.unit_start("live_relay")
-    sent, worst = _step(live, t, ladder, log, "", LEDGER.enabled)
-    LEDGER.unit_end(_u, items=max(len(live), 1), trace_id=worst)
+    sent, worst = _step(stepped, t, ladder, log, "", LEDGER.enabled)
+    LEDGER.unit_end(_u, items=max(len(stepped), 1), trace_id=worst)
     if vod:
         _u = LEDGER.unit_start("vod_fill")
         sent += _step(vod, t, None, log, "vod ", False)[0]
@@ -144,7 +213,8 @@ class Pump:
     """The server's wake.  Owns the engines — weakly keyed by stream, so
     a torn-down stream's engine, HBM ring and strike counters go with it
     and a new stream never inherits them through a recycled ``id()`` —
-    and the megabatch scheduler."""
+    and the megabatch scheduler — and the ready set, with the wheel whose
+    timers feed it (``_pump_loop`` builds the wheel and sleeps by it)."""
 
     def __init__(self, config=None, *, on_device=None,
                  new_engine=TpuFanoutEngine, ladder=None, error_log=None):
@@ -158,9 +228,19 @@ class Pump:
         self.megabatch = None
         #: the serving mesh ``start()`` built, None = one device
         self.mesh = None
-        #: the last wake: its live entries (the deadlines pass reads
-        #: them), the entries it served and the packets it sent
+        #: the 1 ms native timer wheel, None = no timer source: every
+        #: live stream is stepped every wake
+        self.wheel = None
+        #: keys (``PlanCell.key``) of the streams marked since the last
+        #: wake took its streams; the wheel's timers carry the same keys
+        self.ready: set[int] = set()
+        self._keys = itertools.count(1)
+        #: the last wake: its clock, its live roster, the entries of it
+        #: that were stepped (the deadlines pass re-arms those), how many
+        #: entries it served in all and the packets it sent
+        self.t = 0
         self.live: list = []
+        self.stepped: list = []
         self.streams = self.sent = 0
 
     def engine_for(self, stream) -> TpuFanoutEngine:
@@ -193,15 +273,28 @@ class Pump:
         once) and every ``(stream, engine | None)`` pair of the VOD
         pacer.  The scheduler is built on the first wake that has
         ``megabatch_min_streams`` owned entries."""
-        live, vod = [], []
+        ready, wheel = self.ready, self.wheel
+        if wheel is not None:
+            # before the wake picks its streams, against its own clock
+            ready.update(wheel.advance(t))
+        live, stepped, vod = [], [], []
         n_owned = 0
         for sess in sessions.values():
             path = sess.path
             for stream in sess.streams.values():
                 r = self.route(stream, path)
                 n_owned += r == OWNED
-                live.append((path, stream,
-                             self.engine_for(stream) if r else None, r))
+                entry = (path, stream,
+                         self.engine_for(stream) if r else None, r)
+                live.append(entry)
+                c = stream._plan_cell
+                if c.ready is not ready:        # first rostered here
+                    c.install(ready, next(self._keys))
+                if wheel is None or c.key in ready or r != c.route:
+                    stepped.append(entry)
+        # marks made from here on are the next wake's (and a torn-down
+        # stream's key goes with the rest)
+        ready.clear()
         for stream, eng in vod_pairs:
             path = stream.session_path
             r = SCALAR if eng is None else self.route(stream, path, vod=True)
@@ -211,8 +304,54 @@ class Pump:
         if self.megabatch is None and n_owned and n_owned >= min_streams:
             from .megabatch import MegabatchScheduler
             self.megabatch = MegabatchScheduler(mesh=self.mesh)
-        self.live, self.streams = live, len(live) + len(vod)
+        self.t, self.live, self.stepped = t, live, stepped
+        self.streams = len(live) + len(vod)
+        obs.PUMP_ROSTER_STREAMS.inc(len(live))
+        obs.PUMP_STEPPED_STREAMS.inc(len(stepped))
         self.sent = serve(live, vod, self.megabatch, t,
                           min_streams=min_streams, ladder=self.ladder,
-                          log=self.error_log)
+                          log=self.error_log, stepped=stepped)
         return self.sent
+
+    def arm(self, sessions) -> None:
+        """The deadlines pass: one wheel timer a stepped stream, at the
+        earliest of what ``next_deadline_ms`` reports, relative to the
+        wake's clock — the time the wheel was advanced to (no ``await``
+        lies between).  A stream that was not stepped has the bookmarks,
+        the head and so the timer it had; one whose session a step
+        removed is skipped."""
+        wheel, t = self.wheel, self.t
+        for path, stream, _eng, _route in self.stepped:
+            if path not in sessions:
+                continue
+            c = stream._plan_cell
+            if c.due <= t:                      # it fired into this wake
+                c.due, c.timer = c.NEVER, 0
+            d = stream.next_deadline_ms(
+                t, allow_due=not stream._last_pass_stalled)
+            if d < 0 or c.due <= t + d:
+                continue                # an earlier-or-equal timer pends
+            if c.timer:
+                wheel.cancel(c.timer)
+            c.timer, c.due = wheel.schedule(d, c.key), t + d
+
+    def audit(self) -> int:
+        """Hold the marks against the rule: a stream the last wake
+        skipped for which ``needs_step`` is true at that wake's clock and
+        which nothing has marked since is stepped next wake and counted
+        in ``pump_ready_missed_total`` — a missed mark costs the second
+        between two audits, not a stream."""
+        if self.wheel is None:
+            return 0
+        ready, t = self.ready, self.t
+        stepped = {id(e[1]) for e in self.stepped}
+        missed = 0
+        for _path, stream, _eng, route in self.live:
+            c = stream._plan_cell
+            if (id(stream) not in stepped and c.key not in ready
+                    and needs_step(stream, t, route)):
+                ready.add(c.key)
+                missed += 1
+        if missed:
+            obs.PUMP_READY_MISSED.inc(missed)
+        return missed

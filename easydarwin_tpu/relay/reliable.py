@@ -287,6 +287,10 @@ class ReliableUdpOutput(RelayOutput):
         for seq in parse_ack(app):
             if self.resender.ack(seq, now):
                 n += 1
+        if n and self._plan_cell is not None:
+            # an ack moves the RTO estimate and opens the window: the
+            # stream's armed timer no longer stands for its next RTO
+            self._plan_cell.mark()
         return n
 
     def tick(self, now_ms: int | None = None) -> int:

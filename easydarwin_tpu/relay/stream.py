@@ -27,7 +27,7 @@ from .. import obs
 from ..protocol import rtcp as rtcp_mod
 from ..protocol.sdp import StreamInfo
 from ..resilience.inject import INJECTOR
-from .output import RelayOutput, WriteResult
+from .output import PlanCell, RelayOutput, WriteResult
 from .ring import DEFAULT_CAPACITY, PacketFlags, PacketRing
 
 #: SR origination / upstream-RR cadence (``ReflectorStream.h:341``
@@ -89,8 +89,10 @@ class RelayStream:
         #: (``RelayOutput.touch_plan``): the engine's per-stream output
         #: plan (``relay.fanout``) is valid while it has not moved.
         #: Moved by membership (below) and by every write of what the
-        #: plan derives from outside the engine's own cohort step
-        self._plan_cell = [0]
+        #: plan derives from outside the engine's own cohort step.  A
+        #: move, and every ingest, also marks the stream in the ready
+        #: set of the pump that serves it (``relay.pump``)
+        self._plan_cell = PlanCell()
         #: this stream's audience column block (obs/audience.py) — set
         #: by AUDIENCE.register on the first subscriber; None keeps the
         #: egress hooks to one attribute check per pass
@@ -152,6 +154,7 @@ class RelayStream:
         ring = self.rtp_ring
         s = ring.slot(pid)
         n = int(ring.length[s])
+        self._plan_cell.mark()
         self.stats.packets_in += 1
         self.stats.bytes_in += n
         if n >= 12:
@@ -218,15 +221,16 @@ class RelayStream:
         return n
 
     def push_rtcp(self, packet: bytes, now_ms: int) -> int:
+        self._plan_cell.mark()
         return self.rtcp_ring.push(packet, now_ms, is_rtcp=True)
 
     # -- output management -------------------------------------------------
     @property
     def plan_epoch(self) -> int:
-        return self._plan_cell[0]
+        return self._plan_cell.epoch
 
     def touch_plan(self) -> None:
-        self._plan_cell[0] += 1
+        self._plan_cell.touch()
 
     def add_output(self, output: RelayOutput, *,
                    bucket: int | None = None) -> None:
@@ -520,10 +524,12 @@ class RelayStream:
     def next_deadline_ms(self, now_ms: int, *, allow_due: bool = False
                          ) -> int:
         """ms until this stream next needs a pump pass without new ingest:
-        the earliest bucket-delay release among held-back packets, or the
-        earliest future reliable-UDP RTO.  -1 = nothing scheduled.  Feeds
-        the 1 ms timer wheel that paces the pump (vs the reference's
-        10 ms scheduler floor, ``Task.cpp:334``).
+        the earliest bucket-delay release among held-back packets, the
+        earliest future reliable-UDP RTO, or the next SR ``relay_rtcp``
+        owes a latched output.  -1 = nothing scheduled.  Feeds the 1 ms
+        timer wheel that paces the pump (vs the reference's 10 ms
+        scheduler floor, ``Task.cpp:334``) and readies the stream
+        (``relay.pump``: a stream nothing marked is not stepped).
 
         ``allow_due`` controls already-due bucket releases: a caller that
         knows the last pass did NOT stall may arm them at 1 ms (the
@@ -532,10 +538,16 @@ class RelayStream:
         blocked socket writable, and re-arming 0/1 ms timers would spin
         the pump until the client drains.  Future RTOs are always
         reported; due RTOs never are (the tick that just ran handled
-        them)."""
+        them).  An SR that is due now is not reported either: it is an
+        un-latched output's "re-check every pass", which the pump
+        carries over from the step and no timer paces."""
         best = -1
         ring = self.rtp_ring
         delay = self.settings.bucket_delay_ms
+        if self.buckets and len(ring):
+            d = self._next_sr_due_ms - now_ms
+            if d > 0 and self.num_outputs:
+                best = d
         for b_idx, bucket in enumerate(self.buckets):
             if b_idx == 0:
                 continue               # bucket 0 has no stagger delay

@@ -1236,6 +1236,7 @@ class StreamingServer:
         self._wake_open_rec = None
         obs.LEDGER.end_wake()
         end = TRACER.close(span, streams=self.pump.streams,
+                           stepped=len(self.pump.stepped),
                            sent=self.pump.sent, wake_to_pass_us=w2p_us)
         TRACER.wake = None
         obs.PUMP_WAKE_SECONDS.observe((end - t0) / 1e9)
@@ -1286,9 +1287,11 @@ class StreamingServer:
     def _make_pump_wheel(self):
         """1 ms native timer wheel pacing the pump below the fixed tick
         (``csrc ed_wheel``; the reference's scheduler has a 10 ms floor,
-        ``Task.cpp:334-335``).  Streams post their earliest bucket-delay
-        release / reliable-UDP RTO here; the pump sleeps until the wheel's
-        next deadline instead of a full reflect interval."""
+        ``Task.cpp:334-335``).  ``Pump.arm`` posts each stepped stream's
+        earliest bucket-delay release / reliable-UDP RTO / SR here; the
+        pump sleeps until the wheel's next deadline instead of a full
+        reflect interval, and the wake readies the streams whose timers
+        ran out."""
         from .. import native
         if not native.available():
             return None
@@ -1296,28 +1299,6 @@ class StreamingServer:
             return native.TimerWheel(now_ms())
         except RuntimeError:
             return None
-
-    def _schedule_stream_deadlines(self, wheel, t: int) -> None:
-        """``t`` must be the time the wheel was last advanced to, so
-        relative deadlines land on the right tick.  Reads the wake's
-        own live streams (no ``await`` lies between), less any whose
-        session a step removed."""
-        sessions = self.registry.sessions
-        for path, stream, _eng, _route in self.pump.live:
-            if path not in sessions:
-                continue
-            d = stream.next_deadline_ms(
-                t, allow_due=not stream._last_pass_stalled)
-            if d < 0:
-                continue
-            key = id(stream)
-            cur = self._wheel_sched.get(key)
-            due = t + d
-            if cur is not None and cur[1] <= due and cur[1] >= t:
-                continue                # an earlier-or-equal timer pends
-            if cur is not None:
-                wheel.cancel(cur[0])
-            self._wheel_sched[key] = (wheel.schedule(d, key), due)
 
     #: the most rounds ``_drain_readers`` yields before a wake: a pusher
     #: that never pauses (a backlog, a REST storm) holds the pump out for
@@ -1356,8 +1337,7 @@ class StreamingServer:
     async def _pump_loop(self) -> None:
         interval = self.config.reflect_interval_ms / 1000.0
         last_prune = 0.0
-        wheel = self._make_pump_wheel()
-        self._wheel_sched: dict[int, tuple[int, int]] = {}
+        wheel = self.pump.wheel = self._make_pump_wheel()
         while self._running:
             timeout = interval
             if wheel is not None and wheel.pending:
@@ -1391,18 +1371,19 @@ class StreamingServer:
                 # the latency objective — SloWatchdog.note_wake
                 self.slo.note_wake()
             if wheel is not None:
-                # advance and schedule against the SAME clock sample, or
-                # timers fire early by the reflect-pass duration
+                # the wake advanced the wheel to its own clock sample
+                # before it picked its streams; the timers of those it
+                # stepped are armed against the same sample
                 tok = TRACER.open("pump.deadlines", "pump")
-                t = now_ms()
-                for key in wheel.advance(t):
-                    self._wheel_sched.pop(key, None)
-                self._schedule_stream_deadlines(wheel, t)
-                TRACER.close(tok, streams=self.pump.streams)
+                self.pump.arm(self.registry.sessions)
+                TRACER.close(tok, streams=len(self.pump.stepped))
             now = time.monotonic()
             if now - last_prune >= 1.0:
                 last_prune = now
                 maint = TRACER.open("pump.maintenance", "pump")
+                # first, while the skipped streams are as the wake left
+                # them: the marks against the rule
+                self.pump.audit()
                 t = now_ms()
                 for sess in list(self.registry.sessions.values()):
                     sess.prune(t)

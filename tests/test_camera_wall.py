@@ -92,12 +92,18 @@ def _outputs(rng: random.Random, n: int, addrs=None) -> list:
 
 # ------------------------------------- (1) the device path == the scalar loop
 @needs_native
+@pytest.mark.parametrize("ready_set", [False, True],
+                         ids=["every_stream", "ready_set"])
 @pytest.mark.parametrize("n_out", [1, 2, 3, 4])
-def test_thin_streams_on_the_device_path_equal_the_scalar_loop(n_out):
+def test_thin_streams_on_the_device_path_equal_the_scalar_loop(n_out,
+                                                               ready_set):
     """12 cameras x ``n_out`` UDP viewers, un-locked phases, through
     ``_reflect_all`` with ``tpu_min_outputs = 1``, against the same
     pushes through ``RelayStream.reflect``: every output's wire bytes
-    equal, in order, sequence numbers and SSRC as announced."""
+    equal, in order, sequence numbers and SSRC as announced.
+    ``ready_set``: with the pump's wheel, as ``_pump_loop`` runs it — a
+    wake steps the cameras that pushed (a quarter of them), the
+    scheduler is still handed all twelve."""
     from easydarwin_tpu.server import ServerConfig, StreamingServer
     n_src, wakes = 12, 40
     wire = _Sockets(n_src * n_out)
@@ -107,6 +113,10 @@ def test_thin_streams_on_the_device_path_equal_the_scalar_loop(n_out):
                            access_log_enabled=False)
         app = StreamingServer(cfg)
         app.rtsp.shared_egress = wire.send
+        if ready_set:
+            app.pump.wheel = native.TimerWheel(now_ms())
+        roster0 = obs.PUMP_ROSTER_STREAMS.value()
+        stepped0 = obs.PUMP_STEPPED_STREAMS.value()
         dev, ref, dev_outs, ref_outs = [], [], [], []
         for k in range(n_src):
             st = app.registry.find_or_create(f"/wall/cam{k}",
@@ -143,6 +153,9 @@ def test_thin_streams_on_the_device_path_equal_the_scalar_loop(n_out):
                     dev[k].push_rtp(p, t)
                     ref[k].push_rtp(p, t)
             app._reflect_all()
+            if ready_set:
+                app.pump.arm(app.registry.sessions)
+                assert app.pump.audit() == 0
             app._wake_close()
             for twin in ref:
                 twin.reflect(now_ms())
@@ -152,6 +165,12 @@ def test_thin_streams_on_the_device_path_equal_the_scalar_loop(n_out):
             app._wake_close()
             wire.drain()
         assert sum(len(r) for r in wire.rx) == pushed
+        roster = obs.PUMP_ROSTER_STREAMS.value() - roster0
+        stepped = obs.PUMP_STEPPED_STREAMS.value() - stepped0
+        assert roster == n_src * (wakes + 3)
+        # all twelve in the first wake, then the three that pushed and
+        # any whose SR came due
+        assert stepped == roster if not ready_set else stepped < roster / 3
         for i, (o_dev, o_ref) in enumerate(zip(dev_outs, ref_outs)):
             assert wire.rx[i] == o_ref.rtp_packets, f"output {i}"
             first = rtp.RtpPacket.parse(wire.rx[i][0])

@@ -22,6 +22,7 @@ from easydarwin_tpu.relay.megabatch import (MegabatchScheduler,
                                             _host_affine_params)
 from easydarwin_tpu.relay.output import CollectingOutput
 from easydarwin_tpu.relay.stream import RelayStream, StreamSettings
+from test_pump_ready import ReadyPump
 
 VIDEO_SDP = ("v=0\r\nm=video 0 RTP/AVP 96\r\na=rtpmap:96 H264/90000\r\n"
              "a=control:trackID=1\r\n")
@@ -79,17 +80,22 @@ def _mk_stream(n_outputs: int, addrs, seed: int) -> RelayStream:
     return st
 
 
-def _run_scenario(use_megabatch: bool, wire: _Wire, send_fd: int):
+def _run_scenario(use_megabatch: bool, wire: _Wire, send_fd: int,
+                  ready_set: bool = False):
     """Deterministic multi-stream relay scenario.  Exercises: mixed
     window/subscriber shapes, a mid-run output join (rebase latch +
     params-key change), a mid-run stream teardown, and bucket growth
-    (the eligible stream count crosses a pow2 boundary)."""
+    (the eligible stream count crosses a pow2 boundary).  ``ready_set``:
+    through the server's ``Pump.wake`` with a wheel, so each wake steps
+    the streams that were marked."""
     shapes = [(5, 3, 0), (9, 4, 100), (17, 5, 200)]  # (S, burst, seed)
     streams = [_mk_stream(s, wire.addrs, seed) for s, _, seed in shapes]
     engines = [TpuFanoutEngine(egress_fd=send_fd) for _ in streams]
     sched = MegabatchScheduler() if use_megabatch else None
     live = [streams[0]]                    # bucket growth: 1 → 2 → 3
     t, seq = 1000, 0
+    serve = (ReadyPump(sched, t).wake if ready_set
+             else lambda pairs, t: pump.wake(pairs, sched, t))
     for wake in range(24):
         if wake == 4:
             live.append(streams[1])
@@ -108,7 +114,7 @@ def _run_scenario(use_megabatch: bool, wire: _Wire, send_fd: int):
                 s.push_rtp(vid_pkt(seq, seq * 90,
                                    nal_type=5 if seq % 25 == 0 else 1), t)
                 seq += 1
-        pump.wake(pairs, sched, t)
+        serve(pairs, t)
         wire.drain()
         t += 20
     if sched is not None:
@@ -118,13 +124,15 @@ def _run_scenario(use_megabatch: bool, wire: _Wire, send_fd: int):
 
 
 @needs_native
-def test_megabatch_wire_bytes_identical_to_per_stream():
+@pytest.mark.parametrize("ready_set", [False, True],
+                         ids=["server_free", "ready_set"])
+def test_megabatch_wire_bytes_identical_to_per_stream(ready_set):
     send = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
     wire_a, wire_b = _Wire(6), _Wire(6)
     try:
         _run_scenario(False, wire_a, send.fileno())
         streams_b, engines_b, sched = _run_scenario(
-            True, wire_b, send.fileno())
+            True, wire_b, send.fileno(), ready_set)
         # byte-identical per destination, in order — headers AND payloads
         assert [len(r) for r in wire_a.rx] == [len(r) for r in wire_b.rx]
         for ra, rb in zip(wire_a.rx, wire_b.rx):
@@ -145,11 +153,15 @@ def test_megabatch_wire_bytes_identical_to_per_stream():
 
 
 @needs_native
-def test_megabatch_collecting_outputs_identical_to_per_stream():
+@pytest.mark.parametrize("ready_set", [False, True],
+                         ids=["server_free", "ready_set"])
+def test_megabatch_collecting_outputs_identical_to_per_stream(ready_set):
     """The batch-header (slow) sub-path under a megabatch wake: streams
     whose outputs are not native-addressed still deliver byte-identical
-    packets — the scheduler must never perturb the fallback path."""
-    def run(use_megabatch):
+    packets — the scheduler must never perturb the fallback path.  With
+    the ready set, the second stream is pushed every other wake only and
+    is stepped in those."""
+    def run(use_megabatch, ready_set=False):
         streams = []
         for seed, n in ((1, 4), (2, 11)):
             st = _mk_stream(n, [None], seed)
@@ -159,16 +171,23 @@ def test_megabatch_collecting_outputs_identical_to_per_stream():
         engines = [TpuFanoutEngine() for _ in streams]
         sched = MegabatchScheduler() if use_megabatch else None
         t, seq = 1000, 0
+        rp = ReadyPump(sched, t) if ready_set else None
+        stepped = 0
         for wake in range(8):
-            for st in streams:
+            for st in streams[:1 + (wake % 2 == 0)]:
                 for _ in range(6):
                     st.push_rtp(vid_pkt(seq, seq * 90), t)
                     seq += 1
-            pump.wake(list(zip(streams, engines)), sched, t)
+            if rp is not None:
+                rp.wake(list(zip(streams, engines)), t)
+                stepped += len(rp.pump.stepped)
+            else:
+                pump.wake(list(zip(streams, engines)), sched, t)
             t += 20
+        assert stepped in (0, 12)           # 8 + 4 of the 16 entries
         return [[o.rtp_packets for o in st.outputs] for st in streams]
 
-    assert run(False) == run(True)
+    assert run(False) == run(True, ready_set)
 
 
 @needs_native

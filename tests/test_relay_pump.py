@@ -4,10 +4,15 @@ with their stream.
 
 Stub engines and a stub scheduler record what the wake did to them; the
 streams, the registry, the ladder and (for the deadlines pass) the
-server are the real ones.  Nothing here touches JAX.
+server are the real ones.  Every pump here has a wheel (a stub that
+records), so the ready set is engaged: a stream is stepped in the wake
+that first rosters it and after that when something marked it
+(``tests/test_pump_ready.py`` holds the rule itself).  Nothing here
+touches JAX.
 """
 
 import gc
+import itertools
 import types
 import weakref
 
@@ -88,6 +93,36 @@ class _Ladder:
         self.sched_errors.append(sorted(paths))
 
 
+class _Wheel:
+    """``native.TimerWheel``'s surface, in Python, recording."""
+
+    def __init__(self):
+        self.now, self.timers, self.ids = 0, {}, itertools.count(1)
+
+    @property
+    def pending(self):
+        return len(self.timers)
+
+    def advance(self, t):
+        self.now = t
+        fired = [i for i, (due, _k) in self.timers.items() if due <= t]
+        return [self.timers.pop(i)[1] for i in fired]
+
+    def schedule(self, d, key):
+        i = next(self.ids)
+        self.timers[i] = (self.now + d, key)
+        return i
+
+    def cancel(self, i):
+        return self.timers.pop(i, None) is not None
+
+
+def _push(stream, t, seq=0):
+    """One keyframe packet through the ingest choke point."""
+    stream.push_rtp(bytes([0x80, 96, 0, seq]) + bytes(8)
+                    + bytes([(3 << 5) | 5]) + bytes(20), t)
+
+
 def _registry(n_streams, n_outputs=2):
     reg = SessionRegistry()
     for k in range(n_streams):
@@ -107,6 +142,7 @@ def _pump(cfg, log, ladder=None, fail=()):
     p = Pump(cfg, new_engine=new_engine, ladder=ladder,
              on_device=lambda s: (cfg.tpu_fanout and s.num_outputs
                                   >= cfg.tpu_min_outputs))
+    p.wheel = _Wheel()
     p.made = made
     return p
 
@@ -351,20 +387,20 @@ def test_the_servers_engines_are_built_once_from_what_start_settled():
         tx.close()
 
 
-def test_the_deadlines_pass_sees_exactly_the_streams_the_wake_served():
+def test_the_deadlines_pass_arms_exactly_the_streams_the_wake_stepped():
     from easydarwin_tpu.server import ServerConfig, StreamingServer
     app = StreamingServer(ServerConfig(access_log_enabled=False,
                                        bucket_delay_ms=40))
     asked = []
+    streams = []
     for k in range(3):
         st = app.registry.find_or_create(f"/live/s{k}",
                                          VIDEO_SDP).streams[1]
         st.add_output(CollectingOutput(ssrc=k))
         st.next_deadline_ms = (lambda t, allow_due=True, _p=st.session_path:
                                asked.append(_p) or 7)
-    wheel = types.SimpleNamespace(
-        schedule=lambda d, key: (d, key), cancel=lambda tok: None)
-    app._wheel_sched = {}
+        streams.append(st)
+    wheel = app.pump.wheel = _Wheel()
     walked = []
     sessions = app.registry.sessions
 
@@ -374,15 +410,61 @@ def test_the_deadlines_pass_sees_exactly_the_streams_the_wake_served():
             return dict.values(self)
 
     app.registry.sessions = _Counting(sessions)
-    app._reflect_all()
+    app._reflect_all()              # first rostered: all three stepped
     # a session that joins after the wake waits for the next one; one a
     # step removed is skipped
     app.registry.find_or_create("/live/late", VIDEO_SDP)
     app.registry.remove("/live/s1")
-    app._schedule_stream_deadlines(wheel, 1000)
+    app.pump.arm(app.registry.sessions)
     app._wake_close()
     assert asked == ["/live/s0", "/live/s2"]
     assert [p for p, *_ in app.pump.live] == ["/live/s0", "/live/s1",
                                               "/live/s2"]
-    assert len(app._wheel_sched) == 2
+    assert app.pump.stepped == app.pump.live
+    assert wheel.pending == 2
     assert walked == [1]            # one walk of the registry a wake
+    # the next wake: only what was marked is stepped and re-armed; the
+    # stream nothing touched keeps the timer it had
+    del asked[:]
+    _push(streams[2], app.pump.t)
+    app._reflect_all()
+    app.pump.arm(app.registry.sessions)
+    app._wake_close()
+    assert [p for p, *_ in app.pump.stepped] == ["/live/s2", "/live/late"]
+    assert asked == ["/live/s2"]    # (the late one has no stub: real, -1)
+    assert wheel.pending == 2 and app.pump.streams == 3
+
+
+def test_a_ladder_move_steps_a_stream_nothing_else_marked():
+    log = []
+    reg = _registry(2)
+    lad = _Ladder(0)
+    p = _pump(_cfg(megabatch_min_streams=1), log, lad)
+    p.megabatch = _Sched(log)
+    p.wake(reg.sessions, [], 1000)
+    assert [s[1] for s in _steps(log)] == ["/live/s0", "/live/s1"]
+    del log[:]
+    p.wake(reg.sessions, [], 1020)              # nothing marked, no move
+    assert _steps(log) == [] and log[0][0] == "begin"
+    lad.modes["/live/s1"] = 1                   # OWNED -> DEVICE
+    p.wake(reg.sessions, [], 1040)
+    assert _steps(log) == [("step", "/live/s1", False)]
+    assert [e[3] for e in p.stepped] == [DEVICE]
+
+
+def test_a_timer_that_ran_out_readies_its_stream_and_no_other():
+    log = []
+    reg = _registry(3)
+    p = _pump(_cfg(megabatch_enabled=False), log)
+    streams = [reg.find(f"/live/s{k}").streams[1] for k in range(3)]
+    p.wake(reg.sessions, [], 1000)
+    streams[1].next_deadline_ms = lambda t, allow_due=True: 30
+    streams[1].touch_plan()
+    p.wake(reg.sessions, [], 1010)
+    p.arm(reg.sessions)                         # s1: a timer due at 1040
+    del log[:]
+    p.wake(reg.sessions, [], 1039)
+    assert _steps(log) == [] and p.wheel.pending == 1
+    p.wake(reg.sessions, [], 1040)
+    assert [s[1] for s in _steps(log)] == ["/live/s1"]
+    assert p.wheel.pending == 0

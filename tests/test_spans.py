@@ -155,9 +155,16 @@ async def test_served_spans_land_on_the_profilers_host_plane(tmp_path):
             assert w[1] >= s[1] + s[2] or s[1] >= w[1] + w[2]
 
     # tools/span_breakdown.py reads the same plane: a wake's decomposition
-    doc = _load("tools/span_breakdown.py", "span_breakdown").breakdown(
-        events["host"])
+    tool = _load("tools/span_breakdown.py", "span_breakdown")
+    doc = tool.breakdown(events["host"])
     assert doc["wakes"] == len(host["pump.wake"]) >= 6
+    # and what each wake carries: the roster and how much of it the ready
+    # set had it step (two streams, pushed in six of the wakes)
+    carried = tool.wake_args(str(tmp_path))
+    assert len(carried) == doc["wakes"]
+    mean = tool.per_wake(carried)
+    assert mean["streams"] == 2 and 0 < mean["stepped"] <= 2
+    assert mean["sent"] * doc["wakes"] == pytest.approx(2 * 32 * 24)
     per_wake = {n: r["ms_per_wake"] for n, r in doc["spans"].items()}
     assert (per_wake["pump.wake"] >= per_wake["pump.live_relay"]
             >= per_wake["engine.step"] >= per_wake["engine.egress"]
@@ -211,7 +218,8 @@ def test_span_breakdown_reads_a_ring_dump(tmp_path):
     for wake in range(4):
         t0 = wake * 10_000_000
         tr.add("pump.sleep", t0, 6_000_000, cat="pump")
-        tr.add("pump.wake", t0 + 6_000_000, 4_000_000, cat="pump")
+        tr.add("pump.wake", t0 + 6_000_000, 4_000_000, cat="pump",
+               streams=256, stepped=wake, sent=8 * wake)
         tr.add("pump.live_relay", t0 + 6_000_000, 3_000_000, cat="pump")
         tr.add("rtsp.options", t0, 1_000, cat="rtsp")
     path = tmp_path / "ring.json"
@@ -223,6 +231,9 @@ def test_span_breakdown_reads_a_ring_dump(tmp_path):
     assert doc["spans"]["pump.live_relay"]["ms_per_wake"] == \
         pytest.approx(3.0)
     assert doc["spans"]["pump.wake"]["loop_pct"] == pytest.approx(40.0)
+    assert tool.per_wake(tool.wake_args(str(path))) == {
+        "streams": 256.0, "stepped": 1.5, "sent": 12.0}
+    assert tool.per_wake([{"streams": 3}]) == {"streams": 3.0}  # a parent
 
 
 # ------------------------------------------------------ (b) due → wire

@@ -279,12 +279,16 @@ def test_deep_backlog_sheds_whole_aus():
     assert st.rtp_ring.head - o.bookmark < behind_before
 
 
-def test_megabatch_stages_tcp_framing_params():
+@pytest.mark.parametrize("ready_set", [False, True],
+                         ids=["server_free", "ready_set"])
+def test_megabatch_stages_tcp_framing_params(ready_set):
     """The cross-stream scheduler stages interleave channel columns in
     the SAME stacked pass as the UDP affine params; every install rides
-    the host-oracle check and the wire stays byte-identical."""
+    the host-oracle check and the wire stays byte-identical — through
+    the server-free wake and through ``Pump.wake`` with the ready set."""
     from easydarwin_tpu.relay import pump
     from easydarwin_tpu.relay.megabatch import MegabatchScheduler
+    from test_pump_ready import ReadyPump
     streams_a, streams_b, taps_a, taps_b = [], [], [], []
     for s in range(3):
         st_a, pa = _build(fast=True, seed=10 + s, n=50, n_out=2)
@@ -296,7 +300,15 @@ def test_megabatch_stages_tcp_framing_params():
     now = 1000 + 50 + 5000
     sched = MegabatchScheduler()
     engines = [TpuFanoutEngine() for _ in streams_a]
-    pump.wake(list(zip(streams_a, engines)), sched, now)
+    if ready_set:
+        rp = ReadyPump(sched, now)
+        rp.wake(list(zip(streams_a, engines)), now)
+        assert len(rp.pump.stepped) == 3
+        # nothing marked since: the next wake harvests and steps nobody
+        rp.wake(list(zip(streams_a, engines)), now + 20)
+        assert rp.pump.stepped == []
+    else:
+        pump.wake(list(zip(streams_a, engines)), sched, now)
     for st_b in streams_b:
         TpuFanoutEngine().step(st_b, now)
     assert sched.mismatches == 0

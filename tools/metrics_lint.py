@@ -864,7 +864,9 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
     dispatched pass, ``ingest_interleaved_packets_total`` /
     ``_seconds_total`` off ``ingest.read``; and the drain's two (ISSUE
     31), counted where ``pump.sleep`` closes: ``pump_drain_rounds_total``
-    / ``pump_drain_packets_total``."""
+    / ``pump_drain_packets_total``; and the ready set's three (ISSUE 33):
+    ``pump_roster_streams_total`` / ``pump_stepped_streams_total`` once a
+    wake, ``pump_ready_missed_total`` by the 1 Hz audit."""
     from easydarwin_tpu.obs.ledger import WORK_CLASSES
     from easydarwin_tpu.obs.metrics import TIME_BUCKETS
     from easydarwin_tpu.obs.trace import SPAN_PREFIXES, SPANS
@@ -897,6 +899,9 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
             "pump_wake_seconds": ((), ()),
             "pump_drain_rounds_total": ((), ()),
             "pump_drain_packets_total": ((), ()),
+            "pump_roster_streams_total": ((), ()),
+            "pump_stepped_streams_total": ((), ()),
+            "pump_ready_missed_total": ((), ()),
             "relay_due_to_wire_seconds": (("engine",), ()),
             "engine_outputs_walked_total": ((), ()),
             "engine_outputs_due_total": ((), ()),

@@ -8,9 +8,10 @@ Reads the host plane of a profiler trace (``benchmark/server_child.py
 run) through ``benchmark/reduce_trace.load_xplane``, or the span ring as
 ``GET /api/v1/admin?command=trace`` dumps it, and prints per span of
 ``obs.trace.SPANS``: count, seconds, ms per ``pump.wake`` and share of
-the time the pump loop spent awake or asleep.  Spans nest, so a child's
-ms are inside its parent's.  Holds no chip: run it with
-``JAX_PLATFORMS=cpu``."""
+the time the pump loop spent awake or asleep, then what ``pump.wake``
+carries per wake: the streams it served and, where the program has a
+ready set, how many of them it stepped.  Spans nest, so a child's ms are
+inside its parent's.  Holds no chip: run it with ``JAX_PLATFORMS=cpu``."""
 
 from __future__ import annotations
 
@@ -34,6 +35,35 @@ def host_rows(path: str) -> list:
     if os.path.isdir(path):
         path = reduce_trace.newest_xplane(path) or path
     return reduce_trace.load_xplane(path)["host"]
+
+
+def wake_args(path: str) -> list[dict]:
+    """The arguments of every ``pump.wake`` span (``streams``, ``sent``
+    and, where the wake has a ready set, ``stepped``)."""
+    if path.endswith(".json"):
+        with open(path) as f:
+            return [e.get("args", {}) for e in json.load(f)["traceEvents"]
+                    if e["name"] == "pump.wake"]
+    sys.path.insert(0, ROOT)
+    from benchmark import reduce_trace
+    from jax.profiler import ProfileData
+    if os.path.isdir(path):
+        path = reduce_trace.newest_xplane(path) or path
+    return [dict(e.stats) for plane in ProfileData.from_file(path).planes
+            if plane.name == reduce_trace.HOST_PLANE
+            for line in plane.lines for e in line.events
+            if e.name == "pump.wake" and e.duration_ns > 0]
+
+
+def per_wake(args: list[dict]) -> dict:
+    """Mean of each numeric ``pump.wake`` argument over the wakes that
+    carry it."""
+    out = {}
+    for key in ("streams", "stepped", "sent"):
+        vals = [float(a[key]) for a in args if key in a]
+        if vals:
+            out[key] = sum(vals) / len(vals)
+    return out
 
 
 def breakdown(rows: list) -> dict:
@@ -60,6 +90,14 @@ def main(argv) -> int:
         print(f"{name:22s} {row['count']:7d} {row['seconds']:10.4f} s "
               f"{row['ms_per_wake'] or 0:10.3f} ms/wake "
               f"{row['loop_pct'] or 0:6.2f} %")
+    mean = per_wake(wake_args(argv[1]))
+    if "streams" in mean:
+        line = f"a wake: streams {mean['streams']:.1f}"
+        if "stepped" in mean:
+            line += (f", stepped {mean['stepped']:.2f} "
+                     f"({100 * mean['stepped'] / mean['streams']:.1f} %)"
+                     if mean["streams"] else ", stepped 0")
+        print(line + f", sent {mean.get('sent', 0):.1f}")
     return 0
 
 
