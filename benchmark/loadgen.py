@@ -26,6 +26,11 @@ SDP = ("v=0\r\no=- 1 1 IN IP4 127.0.0.1\r\ns=benchmark\r\nt=0 0\r\n"
        "a=control:trackID=1\r\n")
 RCVBUF = 1 << 24
 N_IP, N_PORT = 64, 4        # bulk destinations: 64 loopback IPs x 4 ports
+#: a traffic file's ``frame_phase``: "spread" (and no key) puts source i's
+#: frames i/n of a period after source 0's, cameras on their own clocks;
+#: "locked" puts every source's frame f at one instant, sources genlocked
+#: to one house clock.  GOP phases stay spread either way.
+FRAME_PHASES = {"spread": 1, "locked": 0}
 
 
 class LoadgenError(Exception):
@@ -406,6 +411,12 @@ class Loadgen:
 
     def __init__(self, cfg: dict, traffic: dict, seed: int, seconds: float,
                  n_sources: int, n_players: int):
+        phase = traffic.get("frame_phase", "spread")
+        if phase not in FRAME_PHASES:       # before a socket is opened
+            raise LoadgenError(f"frame_phase {phase!r} is none of "
+                               f"{sorted(FRAME_PHASES)}")
+        #: how much of i/n of a period source i's frames trail source 0's
+        self.phase_spread = FRAME_PHASES[phase]
         self.fps = float(traffic["fps_per_source"])
         self.warm_frames = int(traffic["warm_frames"])
         self.n_src, self.n_sub = n_sources, n_players
@@ -499,25 +510,32 @@ class Loadgen:
         await asyncio.gather(*(one_source(s) for s in self.sources))
 
     # -- media -------------------------------------------------------------
-    async def push_frames(self, lo: int, hi_of, t0_ns: int) -> None:
-        """Push frames ``lo`` onward of every source at the mix's pace,
-        every source on its OWN phase (cameras are not frame-locked).
-        Frame f of source i is due at ``t0 + ((f - lo) + i/n) / fps``;
-        ``hi_of(due_s)`` says whether a frame due then is still pushed.
-        How late each frame left is recorded."""
+    def frame_plan(self, lo: int, hi_of) -> list[tuple[float, int, int]]:
+        """``(due_s, source idx, frame)`` of frames ``lo`` onward, in the
+        order they are pushed.  Frame f of source i is due ``((f - lo) +
+        spread * i/n) / fps`` after t0, ``spread`` 1 with every source on
+        its OWN phase and 0 with all on one (``FRAME_PHASES``): frames due
+        at one instant leave back to back in source order.  ``hi_of(due_s)``
+        says whether a frame due then is still pushed."""
         n = self.n_src
         plan = []
         for s in self.sources:
             f = lo
             while f < len(s.frame_start) - 1:
-                due = ((f - lo) + s.idx / n) / self.fps
+                due = ((f - lo) + self.phase_spread * s.idx / n) / self.fps
                 if not hi_of(due):
                     break
                 if s.frame_start[f + 1] > s.frame_start[f]:
                     plan.append((due, s.idx, f))
                 f += 1
         plan.sort()
-        for due, i, f in plan:
+        return plan
+
+    async def push_frames(self, lo: int, hi_of, t0_ns: int) -> None:
+        """Push frames ``lo`` onward of every source at the mix's pace,
+        as ``frame_plan`` orders them.  How late each frame left is
+        recorded."""
+        for due, i, f in self.frame_plan(lo, hi_of):
             due_ns = t0_ns + int(due * 1e9)
             delay = (due_ns - time.perf_counter_ns()) / 1e9
             if delay > 0:
