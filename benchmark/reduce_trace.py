@@ -7,14 +7,16 @@ Two steps, so that the second can be checked on a small recorded trace:
   ``ProfileData``) into plain ``events``: the traced window and, per
   device plane, its module executions and its ops as ``[name, start_ns,
   duration_ns]``, plus the host's events.
-* ``reduce(events)`` gives the summary the readers use: ``window_s``,
-  ``busy_s`` (union of device-op intervals, averaged over chips),
+* ``reduce(events, chips)`` gives the summary the readers use:
+  ``window_s``, ``busy_s`` (union of device-op intervals, averaged over
+  the cell's chips: one that ran nothing in the window counts as idle),
   ``device_ops`` (top 10 by time), ``idle_gaps`` (the 10 longest, named
   by what the host was doing in them and the module that ended them)
   and ``modules`` (per program: executions, seconds, shapes where the
   trace names them).
 
-Run as a program it prints the summary of the newest trace under a
+Run as a program (``reduce_trace.py <trace dir> [<events.json>]
+--chips N``) it prints the summary of the newest trace under a
 directory as one JSON object.  The benchmark's parent never imports JAX;
 it runs this file as a child, on the CPU by name, once the server child
 has gone.
@@ -22,6 +24,7 @@ has gone.
 
 from __future__ import annotations
 
+import argparse
 import bisect
 import glob
 import json
@@ -124,10 +127,16 @@ def note_shapes(modules: dict, mods: list, ops: list) -> None:
         prog["shapes"] = shapes
 
 
-def reduce(events: dict) -> dict:
+def reduce(events: dict, chips: int = 1) -> dict:
+    """``chips`` is what the cell asks for.  A chip that ran nothing in
+    the traced window has no XLA line and so no plane in ``events``: it
+    is idle, not absent, and ``busy_s`` is the mean over it too.  (Every
+    caller here names it; the default serves ``tests/test_spans.py``'s
+    one-chip call, which a benchmark PR may not edit.)"""
     window_ns = events.get("window_ns") or 0
     devs = events.get("devices", {})
-    out: dict = {"window_s": window_ns / 1e9, "chips": len(devs),
+    n_chips = max(len(devs), chips)
+    out: dict = {"window_s": window_ns / 1e9, "chips": n_chips,
                  "busy_s": None, "device_ops": [], "idle_gaps": [],
                  "modules": {}}
     if not devs or not window_ns:
@@ -158,7 +167,7 @@ def reduce(events: dict) -> dict:
         _, runs = union_ns((r[1], r[2]) for r in (mods or rows))
         for (_, a_end), (b_start, _) in zip(runs, runs[1:]):
             gaps.append((b_start - a_end, a_end, b_start, mods))
-    out["busy_s"] = busy_total / len(devs) / 1e9
+    out["busy_s"] = busy_total / n_chips / 1e9
     # programs first, then single ops under their short HLO names
     by_mod = sorted(((n, m["seconds"]) for n, m in out["modules"].items()),
                     key=lambda kv: -kv[1])[:5]
@@ -185,15 +194,22 @@ def newest_xplane(trace_dir: str) -> str | None:
 
 
 def main(argv) -> int:
-    path = newest_xplane(argv[1])
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("trace_dir")
+    ap.add_argument("events_json", nargs="?",
+                    help="keep the events here, for a look")
+    ap.add_argument("--chips", type=int, required=True,
+                    help="the chips the cell asks for")
+    args = ap.parse_args(argv[1:])
+    path = newest_xplane(args.trace_dir)
     if path is None:
-        print(json.dumps({"error": f"no xplane.pb under {argv[1]}"}))
+        print(json.dumps({"error": f"no xplane.pb under {args.trace_dir}"}))
         return 1
     events = load_xplane(path)
-    if len(argv) > 2:                       # keep the events for a look
-        with open(argv[2], "w") as f:
+    if args.events_json:
+        with open(args.events_json, "w") as f:
             json.dump(events, f)
-    print(json.dumps(reduce(events)))
+    print(json.dumps(reduce(events, args.chips)))
     return 0
 
 

@@ -470,7 +470,8 @@ class Run:
             return None
         r = subprocess.run(
             [sys.executable, os.path.join(HERE, "reduce_trace.py"),
-             self.trace_dir], capture_output=True, text=True, timeout=240,
+             self.trace_dir, "--chips", str(self.cell["chips"])],
+            capture_output=True, text=True, timeout=240,
             env=dict(os.environ, JAX_PLATFORMS="cpu"))
         try:
             return json.loads(r.stdout.strip().splitlines()[-1])

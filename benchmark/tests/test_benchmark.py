@@ -7,84 +7,30 @@ whole run at a debug size on the CPU prints the contract's last line.
 """
 
 import errno
-import importlib
+import hashlib
 import json
 import os
-import re
 import subprocess
 import sys
 
 import pytest
 
+import file_checks
+from file_checks import ROOT, UNIT, load
+
+from benchmark import kernels, loadgen, reduce_trace, reference, stats
+from benchmark.run import EXIT_NO_DEVICE
+
 HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(os.path.dirname(HERE))
-sys.path.insert(0, ROOT)
-
-from benchmark import (kernels, loadgen, reduce_trace, reference,  # noqa: E402
-                       stats)
-from benchmark.run import EXIT_NO_DEVICE  # noqa: E402
-
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-
-
-def load(path):
-    with open(os.path.join(ROOT, path)) as f:
-        return json.load(f)
-
-
-BENCH = load("BENCHMARK.json")
-CELLS = [w["name"] for w in BENCH["workloads"]]
-
-
-def reports(metric, cell):
-    return cell in metric.get("workloads", CELLS)
 
 
 # ------------------------------------------------------------ the files
-@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
-def test_workload_resolves_to_files(cell):
-    conf = next(c for c in BENCH["configs"] if c["name"] == cell["config"])
-    cfg = load(conf["file"])
-    assert cfg["name"] == conf["name"] and cfg["reduced"] == conf["reduced"]
-    assert set(cfg["guarantees"]) == set(reference.GUARANTEES)
-    traffic = load(f"benchmark/traffic/{cell['traffic']}.json")
-    assert traffic["fps_per_source"] > 0 and traffic["warm_frames"] > 0
-    assert cell["chips"] == 1 and len(cell["why"]) <= 200
-    for name in (cell["name"], cell["config"], cell["traffic"]):
-        assert NAME.match(name), name
-    e2e = [m for m in BENCH["end_to_end"] if reports(m, cell["name"])]
-    assert "setup_s" in [m["name"] for m in e2e] and len(e2e) >= 2
-    assert any(reports(m, cell["name"]) for m in BENCH["per_layer"])
-
-
-@pytest.mark.parametrize("metric", BENCH["end_to_end"] + BENCH["per_layer"],
-                         ids=lambda m: m["name"])
-def test_metric_names_units_and_moves(metric):
-    assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
-    assert metric["better"] in ("lower", "higher")
-    assert set(metric.get("workloads", [])) <= set(CELLS)
-    if "moves" not in metric:               # end to end
-        assert 0.01 <= metric["bound"] <= 0.25
-        assert metric["source"] in ("host_clock", "device_trace")
-        return
-    # per layer: moves an end-to-end metric that each of its cells reports
-    moved = next(m for m in BENCH["end_to_end"] if m["name"] == metric["moves"])
-    for cell in metric.get("workloads", CELLS):
-        assert reports(moved, cell), (metric["name"], cell)
-    spec = load(f"benchmark/layer_metrics/{metric['name']}.json")
-    assert spec["name"] == metric["name"]
-    reader = importlib.import_module(
-        f"benchmark.readers.{spec['reader']['kind']}")
-    assert callable(reader.read)
-
-
-def test_a_reader_with_nothing_to_read_returns_nothing():
-    from benchmark import readers
-    ctx = {"m0": {}, "m1": {}, "harness": {}, "trace": None, "peaks": None}
-    for m in BENCH["per_layer"]:
-        spec = load(f"benchmark/layer_metrics/{m['name']}.json")
-        assert readers.read(spec, ctx) is None, m["name"]
+@pytest.mark.parametrize("check", file_checks.params("files"))
+def test_the_committed_files_hang_together(check):
+    """Each cell resolves to its files, the four-chip cap holds, each
+    metric has its file, its reader and the metric it moves, and no
+    metric file is left without an entry (file_checks.py)."""
+    check()
 
 
 # ------------------------------------------------------- the arithmetic
@@ -123,7 +69,7 @@ def test_delay_and_pdv_on_hand_made_stamps():
 # ------------------------------------------------- the trace reduction
 def test_trace_reduction_on_the_recorded_trace():
     events = load("benchmark/tests/data/recorded_trace.json")
-    r = reduce_trace.reduce(events)
+    r = reduce_trace.reduce(events, chips=1)
     assert r["chips"] == 1
     assert r["window_s"] == pytest.approx(0.94330042)
     assert r["busy_s"] == pytest.approx(2.4909e-05)
@@ -149,6 +95,152 @@ def test_trace_reduction_on_the_recorded_trace():
                                  "hbm_bytes_per_s")
     assert need == pytest.approx((23688 + 189504) / 819e9)
     assert 0 < 100 * need / mod["seconds"] < 100
+
+
+#: sha256 of ``json.dumps`` of the parent's (35dcbeb) one-chip reduction
+PARENT_REDUCTION = ("7fbb119fc0ee01363287c2d39d00b388322573917b75ea3f60ab5905"
+                    "e9dac600", 1291)
+
+
+def test_a_one_chip_reduction_is_the_parents_byte_for_byte():
+    events = load("benchmark/tests/data/recorded_trace.json")
+    out = json.dumps(reduce_trace.reduce(events, chips=1))
+    assert (hashlib.sha256(out.encode()).hexdigest(),
+            len(out)) == PARENT_REDUCTION
+
+
+SHARD = (4, 16, 256)            # a quarter of a 16 x 16 x 256 pass
+#: per plane: (start of the sharded pass, its three ops' durations, the
+#: start of the small program that follows it); plane 2 is busy longest
+PLANES = {0: (1_000_000, (600, 400, 5000), 400_000_000),
+          1: (1_000_900, (600, 400, 5000), 300_000_000),
+          2: (1_001_800, (600, 400, 29000), 200_000_000),
+          3: (1_002_700, (600, 400, 5000), 100_000_000)}
+TAIL_NS = 700                   # the small program's one op
+
+
+def four_planes(ran=(0, 1, 2, 3), keep_empty=False):
+    """A hand-made trace of one sharded ``megabatch_window_step``: the
+    program runs once on each plane in ``ran`` with the per-shard shapes
+    in its ops' names, then a small program of one op; a plane not in
+    ``ran`` ran nothing inside the window (left out, or kept with its
+    lines empty)."""
+    b, p, s = SHARD
+    devices = {}
+    for k, (t0, durs, t1) in PLANES.items():
+        if k not in ran:
+            if keep_empty:
+                devices[f"/device:TPU:{k}"] = {"ops": [], "modules": [],
+                                               "async": []}
+            continue
+        names = (
+            f"%copy-done = u8[{b},{p},100]{{2,1,0:T(8,128)(4,1)S(1)}} "
+            f"copy-done(u8[{b},{p},100]{{2,1,0:T(8,128)(4,1)}} %window.1)",
+            f"%copy-done.1 = u32[{b},{s},6]{{1,0,2:T(2,128)S(1)}} "
+            f"copy-done(u32[{b},{s},6]{{1,0,2:T(2,128)}} %out_state.1)",
+            f"%fusion.1 = u32[{b},{4 * s + 1}]{{1,0:T(2,128)}} fusion("
+            f"u32[{b},{s},6]{{1,0,2:T(2,128)S(1)}} %copy-done.1)")
+        ops, at = [], t0
+        for name, d in zip(names, durs):
+            ops.append([name, at, d])
+            at += d
+        ops.append(["%convert.1 = s32[8]{0} convert(u8[8]{0} %p)", t1,
+                    TAIL_NS])
+        devices[f"/device:TPU:{k}"] = {
+            "ops": ops, "async": [],
+            "modules": [
+                ["jit_megabatch_window_step(4242)", t0, sum(durs),
+                 {"program_id": "4242"}],
+                ["jit_convert_element_type(77)", t1, TAIL_NS,
+                 {"program_id": "77"}]]}
+    host = [["pump.wake", 900_000, 200_000],
+            ["pump.sleep", 50_000_000, 340_000_000]]
+    return {"window_ns": 1_000_000_000, "devices": devices, "host": host}
+
+
+@pytest.mark.parametrize("ran, keep_empty", [
+    ((0, 1, 2, 3), False), ((0, 1, 2), False), ((0, 1, 2), True)],
+    ids=["all_four_ran", "one_ran_nothing", "one_ran_nothing_lines_empty"])
+def test_a_trace_of_four_chips_reduces_right(ran, keep_empty):
+    r = reduce_trace.reduce(four_planes(ran, keep_empty), chips=4)
+    assert r["chips"] == 4 and r["window_s"] == 1.0
+    # busy: every plane's own union, summed, over FOUR chips: the chip
+    # that ran nothing counts as idle and stays in the average
+    busy_ns = sum(sum(PLANES[k][1]) + TAIL_NS for k in ran)
+    assert r["busy_s"] == pytest.approx(busy_ns / 4 / 1e9)
+    mod = r["modules"]["megabatch_window_step"]
+    assert mod["count"] == len(ran) and list(mod["programs"]) == ["4242"]
+    assert mod["seconds"] == pytest.approx(
+        sum(sum(PLANES[k][1]) for k in ran) / 1e9)
+    assert kernels.megabatch_shape(
+        mod["programs"]["4242"]["shapes"]) == SHARD
+    assert r["device_ops"][0] == ["program megabatch_window_step",
+                                  pytest.approx(mod["seconds"])]
+    # the roofline of a sharded pass is that of the same work: four
+    # shards of 4 x 16 x 256 need the bytes of one 16 x 16 x 256 pass
+    need = kernels.least_seconds("megabatch_window_step", mod,
+                                 {"hbm_bytes_per_s": 819e9},
+                                 "hbm_bytes_per_s")
+    assert need * 819e9 == pytest.approx(
+        len(ran) * kernels.megabatch_window_step_bytes(*SHARD))
+    if len(ran) == 4:
+        assert need * 819e9 == pytest.approx(
+            kernels.megabatch_window_step_bytes(16, 16, 256))
+    assert 0 < 100 * need / mod["seconds"] < 100
+    # idle gaps are taken per plane: one a plane that ran, between its
+    # two programs, the longest first; none spans two planes (plane 1's
+    # pass starts 900 ns after plane 0's and is no gap of 0 ns)
+    gaps = {k: PLANES[k][2] - (PLANES[k][0] + sum(PLANES[k][1]))
+            for k in ran}
+    assert [g[1] for g in r["idle_gaps"]] == [
+        pytest.approx(ns / 1e9) for ns in sorted(gaps.values(),
+                                                 reverse=True)]
+    assert [g[0] for g in r["idle_gaps"]] == [
+        "pump.sleep -> convert_element_type"] * len(ran)
+
+
+def test_the_recorded_trace_of_four_chips():
+    """A trimmed recording of the mesh path's rehearsal on a four-chip
+    host (PR 35: ``relay-16x256`` with ``megabatch_devices: 4`` under
+    ``genlock``; 0.91 s holding a frame instant's two passes and the
+    next instant's first).  Every plane runs every pass, pad-only
+    shards too, and the sharded step is a program of another name."""
+    events = load("benchmark/tests/data/recorded_trace_mesh4.json")
+    assert sorted(events["devices"]) == [f"/device:TPU:{k}" for k in range(4)]
+    r = reduce_trace.reduce(events, chips=4)
+    assert r["chips"] == 4 and r["window_s"] == pytest.approx(0.9121)
+    # 20,559 + 20,791 + 20,581 + 20,571 ns of ops, over four chips
+    assert r["busy_s"] == pytest.approx(2.06255e-05)
+    assert list(r["modules"]) == ["relay_affine_step_window"]
+    mod = r["modules"]["relay_affine_step_window"]
+    assert mod["count"] == 12 and mod["seconds"] == pytest.approx(
+        0.000395675)
+    # the instant's fifteen P frames as 4 shards of 4 x 16 x 256, twice,
+    # and its IDR's one stream padded to 4 shards of 1 x 64 x 256
+    assert sorted((p["count"], kernels.megabatch_shape(p["shapes"]))
+                  for p in mod["programs"].values()) == [
+        (4, (1, 64, 256)), (8, (4, 16, 256))]
+    # two gaps a plane, taken per plane: the frame period, and the 3 ms
+    # between the instant's two passes
+    gaps = [g[1] for g in r["idle_gaps"]]
+    assert len(gaps) == 8 and gaps == sorted(gaps, reverse=True)
+    assert all(0.9076 < g < 0.9078 for g in gaps[:4])
+    assert all(0.0032 < g < 0.0033 for g in gaps[4:])
+    assert {g[0] for g in r["idle_gaps"]} == {
+        "pump.wake -> relay_affine_step_window"}
+    # no row of KERNELS has that name, so the stacked pass's roofline is
+    # silent here; under the kernel's name the same shapes read the work
+    # of two 16 x 16 x 256 passes and one 4 x 64 x 256 pass
+    from benchmark.readers import trace_op
+    args = {"module": "megabatch_window_step", "bound": "hbm_bytes_per_s"}
+    peaks = load("benchmark/peaks.json")["devices"]["TPU v5 lite"]
+    assert trace_op.read(args, {"trace": r, "peaks": peaks}) is None
+    renamed = dict(r, modules={"megabatch_window_step": mod})
+    need = (2 * kernels.megabatch_window_step_bytes(16, 16, 256)
+            + kernels.megabatch_window_step_bytes(4, 64, 256)) / 819e9
+    assert trace_op.read(args, {"trace": renamed, "peaks": peaks}) == \
+        pytest.approx(100 * need / mod["seconds"])
+    assert 0 < 100 * need / mod["seconds"] < 1
 
 
 def test_module_name_drops_prefix_and_fingerprint():
@@ -294,9 +386,8 @@ def test_debug_run_prints_the_contracts_last_line():
     assert last["correct"] is True and last["failed"] == 0, tail
     assert last["attempted"] > 0
     assert last["device"]["platform"] == "cpu"
-    assert set(last["metrics"]) == {
-        m["name"] for m in BENCH["end_to_end"]
-        if reports(m, "relay-16x256.paced")}
+    assert set(last["metrics"]) == file_checks.judged_on(
+        file_checks.BENCH, "relay-16x256.paced")
     for m in last["metrics"].values():
         assert m["value"] > 0 and UNIT.match(m["unit"])
     assert all(v["value"] <= v["limit"] for v in last["compared"].values())

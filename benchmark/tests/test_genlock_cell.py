@@ -17,15 +17,12 @@ import sys
 
 import pytest
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(os.path.dirname(HERE))
-sys.path.insert(0, ROOT)
+import file_checks
+from file_checks import GENLOCK as CELL, ROOT, load
 
-from benchmark import loadgen, readers  # noqa: E402
-from benchmark.run import EXIT_NO_DEVICE  # noqa: E402
+from benchmark import loadgen
+from benchmark.run import EXIT_NO_DEVICE
 
-CELL = "relay-16x256.genlock"
-PACED = "relay-16x256.paced"
 SEED, SECONDS, N_SRC, N_SUB = 3400000007, 4.0, 16, 16
 #: what the parent's ``push_frames`` planned for SEED at relay-16x256 x
 #: ``paced``, 4 s: (entries, sha256 of repr(plan), first three, last)
@@ -42,12 +39,6 @@ PARENT_BYTES = ("8b645180730fdeb39fbde177feb208132857948ad5cbd1a83322d0eb"
                 "4672948b", 2617)
 
 
-def load(path):
-    with open(os.path.join(ROOT, path)) as f:
-        return json.load(f)
-
-
-BENCH = load("BENCHMARK.json")
 CFG = load("benchmark/configs/relay-16x256.json")
 
 
@@ -160,71 +151,12 @@ def test_an_unknown_phase_is_refused_before_a_socket(monkeypatch, value):
 
 
 # ------------------------------------------------------------ the cell
-def test_the_cell_resolves_to_its_files():
-    cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
-    assert cell == {"name": CELL, "config": "relay-16x256",
-                    "traffic": "genlock", "chips": 1, "why": cell["why"]}
-    assert 0 < len(cell["why"]) <= 200
-    t, paced = load("benchmark/traffic/genlock.json"), traffic(None)
-    assert t["frame_phase"] == "locked" and t["name"] == "genlock"
-    # .paced's pace and drains; a warm-up long enough that the sources
-    # come on line one or two to an instant (the file's "what" says why)
-    assert t["fps_per_source"] == paced["fps_per_source"] == 1.1
-    assert t["bulk_drain_procs"] == paced["bulk_drain_procs"] == 4
-    assert t["warm_frames"] == 24 and "warm_frames 24" in t["what"]
-    assert set(t) == set(paced) | {"frame_phase"}
-    assert "stream.phases" in t["what"] and "GOP" in t["what"]
-    # the configuration is left as it is and says what the mix overrides
-    assert "frame_phase" not in json.dumps(CFG)
-    assert "own frame phase" in CFG["stream"]["phases"]
-
-
-def test_the_cell_is_judged_on_what_the_issue_names():
-    listed = [m["name"] for m in BENCH["end_to_end"]
-              if CELL in m.get("workloads", [CELL])]
-    assert listed == ["delay_p60_ms", "delay_p95_ms", "setup_s"]
-    bounds = {m["name"]: m["bound"] for m in BENCH["end_to_end"]}
-    assert bounds == {"delivered_per_s": 0.25, "delay_p60_ms": 0.15,
-                      "delay_p95_ms": 0.04, "setup_s": 0.25}
-
-
-@pytest.mark.parametrize(
-    "entry", [m for m in BENCH["per_layer"] if PACED in m["workloads"]],
-    ids=lambda m: m["name"])
-def test_the_cell_joins_every_list_paced_is_in(entry):
-    assert entry["workloads"][-1] == CELL
-    assert entry["workloads"].count(CELL) == 1
-    moved = next(m for m in BENCH["end_to_end"] if m["name"] == entry["moves"])
-    assert CELL in moved.get("workloads", [CELL])
-
-
-def test_paced_is_in_nineteen_lists_and_no_other_entry_took_the_cell():
-    names = [m["name"] for m in BENCH["per_layer"]]
-    with_paced = [m["name"] for m in BENCH["per_layer"]
-                  if PACED in m["workloads"]]
-    assert len(with_paced) == 19
-    with_cell = [m["name"] for m in BENCH["per_layer"]
-                 if CELL in m["workloads"]]
-    assert with_cell == with_paced + ["megabatch.fill_pct.genlock"]
-    assert names[-1] == "megabatch.fill_pct.genlock"
-
-
-def test_the_fill_entry_reads_the_walls_counters():
-    entry = BENCH["per_layer"][-1]
-    assert entry == {"name": "megabatch.fill_pct.genlock", "unit": "%",
-                     "better": "higher", "source": "program_counter",
-                     "layer": "Megabatch scheduler", "moves": "delay_p95_ms",
-                     "workloads": [CELL]}
-    spec = load("benchmark/layer_metrics/megabatch.fill_pct.genlock.json")
-    wall = load("benchmark/layer_metrics/megabatch.fill_pct.wall.json")
-    assert spec["name"] == entry["name"] and spec["what"] != wall["what"]
-    assert spec["reader"] == wall["reader"]     # no new reader code
-    ctx = {"m0": {}, "harness": {}, "trace": None, "peaks": None,
-           "m1": {'megabatch_cells_total{kind="real"}': 53248.0,
-                  'megabatch_cells_total{kind="staged"}': 262144.0}}
-    # 16 streams x 13 packets x 256 viewers of a 16 x 64 x 256 program
-    assert readers.read(spec, ctx) == pytest.approx(20.3125)
-    assert readers.read(spec, dict(ctx, m1={})) is None
+@pytest.mark.parametrize("check", file_checks.params("genlock"))
+def test_the_cell_in_the_benchmark(check):
+    """The cell and its mix; what it is judged on; the lists it joined,
+    after the cells accepted before it; its fill entry, found by name
+    (file_checks.py: ``check_genlock_*``)."""
+    check()
 
 
 # ------------------------------------------------------------ whole runs

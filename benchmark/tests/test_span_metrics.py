@@ -6,110 +6,22 @@ nothing on a program that has none of the families.
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q -p no:cacheprovider
 """
 
-import importlib
-import json
-import os
-import sys
-
 import pytest
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(os.path.dirname(HERE))
-sys.path.insert(0, ROOT)
+import file_checks
+from file_checks import load, silent_ctx as ctx
 
-from benchmark import readers  # noqa: E402
+from benchmark import readers
 
-#: PR 34 appended its cell to every list ``relay-16x256.paced`` is in
-GENLOCK = "relay-16x256.genlock"
-BELOW = ["relay-16x256.paced", "relay-1x64.live", GENLOCK]
-ABOVE = ["relay-16x256.saturated"]
-#: name -> (cells, moves, better, reader kind)
-NEW = {
-    "relay.due_to_wire_ms.below_knee":
-        (BELOW, "delay_p95_ms", "lower", "ratio_of_deltas"),
-    "relay.due_to_wire_p95_ms.below_knee":
-        (BELOW, "delay_p95_ms", "lower", "histogram_quantile"),
-    "pump.wake_ms.below_knee":
-        (BELOW, "delay_p95_ms", "lower", "ratio_of_deltas"),
-    "pump.wake_ms.above_knee":
-        (ABOVE, "delivered_per_s", "lower", "ratio_of_deltas"),
-    "pump.busy_pct.below_knee":
-        (BELOW, "delay_p95_ms", "lower", "ratio_of_deltas"),
-    "pump.busy_pct.above_knee":
-        (ABOVE, "delivered_per_s", "lower", "ratio_of_deltas"),
-    "engine.due_outputs_pct.below_knee":
-        (BELOW, "delay_p95_ms", "higher", "ratio_of_deltas"),
-    "egress.bracket_ms_per_step.above_knee":
-        (ABOVE, "delivered_per_s", "lower", "ratio_of_deltas"),
-    "pump.live_relay_ms_per_wake.below_knee":
-        (BELOW, "delay_p95_ms", "lower", "ratio_of_deltas"),
-    "pump.live_relay_ms_per_wake.above_knee":
-        (ABOVE, "delivered_per_s", "lower", "ratio_of_deltas"),
-    "pump.megabatch_ms_per_wake.below_knee":
-        ([BELOW[0], GENLOCK], "delay_p95_ms", "lower", "ratio_of_deltas"),
-    "pump.megabatch_ms_per_wake.above_knee":
-        (ABOVE, "delivered_per_s", "lower", "ratio_of_deltas"),
-    "egress.bracket_ms_per_wake.below_knee":
-        (BELOW, "delay_p95_ms", "lower", "ratio_of_deltas"),
-    "pump.timer_wakes_pct.below_knee":
-        (BELOW, "delay_p95_ms", "higher", "ratio_of_deltas"),
-}
-
-
-def load(path):
-    with open(os.path.join(ROOT, path)) as f:
-        return json.load(f)
-
-
-BENCH = load("BENCHMARK.json")
 WINDOW = load("benchmark/tests/data/metrics_window.json")
 
 
-def ctx(**window):
-    return {"m0": window.get("m0", {}), "m1": window.get("m1", {}),
-            "harness": {}, "trace": None, "peaks": None}
-
-
-@pytest.mark.parametrize("name", sorted(NEW))
-def test_new_metric_file_matches_its_entry(name):
-    cells, moves, better, kind = NEW[name]
-    entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == cells and entry["moves"] == moves
-    assert entry["better"] == better
-    spec = load(f"benchmark/layer_metrics/{name}.json")
-    assert spec["name"] == name and spec["reader"]["kind"] == kind
-    assert spec["what"]
-    assert callable(importlib.import_module(
-        f"benchmark.readers.{kind}").read)
-    # a program that has none of it (the parent): nothing, and no raise
-    assert readers.read(spec, ctx()) is None
-
-
-def test_new_entries_come_last_and_the_old_ones_stand():
-    """PR 25's fourteen follow the twelve before them, in order; what a
-    later PR appends follows these."""
-    names = [m["name"] for m in BENCH["per_layer"]]
-    start = names.index("relay.due_to_wire_ms.below_knee")
-    assert names[start:start + len(NEW)] == [
-        "relay.due_to_wire_ms.below_knee",
-        "relay.due_to_wire_p95_ms.below_knee",
-        "pump.wake_ms.below_knee", "pump.wake_ms.above_knee",
-        "pump.busy_pct.below_knee", "pump.busy_pct.above_knee",
-        "engine.due_outputs_pct.below_knee",
-        "egress.bracket_ms_per_step.above_knee",
-        "pump.live_relay_ms_per_wake.below_knee",
-        "pump.live_relay_ms_per_wake.above_knee",
-        "pump.megabatch_ms_per_wake.below_knee",
-        "pump.megabatch_ms_per_wake.above_knee",
-        "egress.bracket_ms_per_wake.below_knee",
-        "pump.timer_wakes_pct.below_knee"]
-    assert names[:start] == [
-        "loadgen.late_p99_ms", "rtsp.join_s", "pump.step_ms.below_knee",
-        "pump.step_ms.above_knee", "megabatch.streams_per_pass",
-        "egress.us_per_datagram", "egress.datagrams_per_syscall",
-        "compiles_in_window", "megabatch_window_step_roofline",
-        "device.idle_pct.below_knee", "device.idle_pct.above_knee",
-        "pdv_p95_ms"]
+@pytest.mark.parametrize("check", file_checks.params("spans"))
+def test_pr25s_entries_stand_as_accepted(check):
+    """Each of the fourteen matches its file and keeps its cells at the
+    head of its list; they follow the nine before them, in order
+    (file_checks.py: ``PR25``, ``OLD``)."""
+    check()
 
 
 def quantile(q, **window):
