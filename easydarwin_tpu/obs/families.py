@@ -245,6 +245,15 @@ MEGABATCH_CELLS = REGISTRY.counter(
     "real (new packets x subscribers, summed over a pass's streams) and "
     "staged (b_pad x p_pad x s_pad, what the program computes); real / "
     "staged = how much of a pass is not padding", labels=("kind",))
+MEGABATCH_PAIRS = REGISTRY.counter(
+    "megabatch_pairs_total",
+    "Owned (stream, engine) pairs of the scheduler's wakes: handed (the "
+    "owned roster the pump handed over, summed over wakes) and walked "
+    "(the pairs whose output plan the scheduler read: the ones the pump's "
+    "ready set named plus its own carry-over — a deferred wake, a failed "
+    "dispatch, a pair it held no record of; every pair for a caller with "
+    "no ready set); walked / handed = how much of the roster a wake's "
+    "scheduling cost follows", labels=("kind",))
 MEGABATCH_FALLBACK = REGISTRY.counter(
     "megabatch_fallback_total",
     "Per-stream device param queries taken while a stream was megabatch-"

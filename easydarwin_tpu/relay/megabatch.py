@@ -6,8 +6,15 @@ per pump wake (the PR 3 profiler put the per-pass floor at ~5 ms p50 by
 scheduler coalesces every eligible stream's device work into **one
 shape-bucketed stacked pass per wake**:
 
-* **collect** — each megabatch-owned stream contributes its ring window
-  tail (the packets not yet staged) and its fast-output rewrite state;
+* **collect** — a megabatch-owned stream contributes its ring window
+  tail (the packets not yet staged) and its fast-output rewrite state.
+  The scheduler reads the plan of the pairs the pump's ready set names
+  (``begin_wake`` / ``end_wake``'s ``ready``: new packets and a moved
+  params key both mark a stream, ``relay.pump.needs_step``) and of the
+  pairs it carries over itself — a wake deferred at ``MAX_INFLIGHT``, a
+  dispatch that failed, a pair it holds no record of — not of the
+  roster: what a wake costs follows what is ready, not what is owned.
+  ``ready=None`` is every pair (a caller with no ready set);
 * **bucket** — streams are grouped by padded (window, subscriber)
   shape.  The pads come from three ladders (``_stream_pad``,
   ``PACKET_PADS``, ``_sub_pad``), so the programs the handed pairs can
@@ -162,10 +169,30 @@ class _InFlight:
         self.rows_per = rows_per
 
 
+class _Pair:
+    """What the scheduler keeps of one owned pair from wake to wake."""
+
+    __slots__ = ("head", "rides", "epoch", "state")
+
+    def __init__(self):
+        #: ring id staged up to; None = nothing yet (the live window)
+        self.head: int | None = None
+        #: what the pair counts as in the closed set
+        #: (``MegabatchScheduler.rides``); None = its plan was not read
+        self.rides: int | None = None
+        #: the stream's plan epoch as ``_collect`` last left its plan
+        self.epoch = -1
+        #: (params_key, packed out_state row) — the packed state is a
+        #: pure function of the key, and the key comparison is paid
+        #: anyway; skips the O(S) python pack loop on unchanged membership
+        self.state: tuple | None = None
+
+
 class MegabatchScheduler:
     """One per server; the pump (``relay/pump.py: serve``) marks the
     engines it hands over ``megabatch_owned`` and calls ``begin_wake``
-    before the per-stream step loop and ``end_wake`` after it."""
+    before the per-stream step loop and ``end_wake`` after it, each with
+    the owned roster and the owned pairs of its ready set."""
 
     #: never stage more than this many packets per stream per pass (a
     #: burst beyond it restages from the newest tail, mirroring the
@@ -195,12 +222,23 @@ class MegabatchScheduler:
         #: staging buffers kept per hot shape: 2 per device (the double
         #: buffer), since every shard of a bucket draws from one pool
         self._pool_cap = 2 * max(1, len(self._mesh_devices))
-        self._tracked: dict[int, int] = {}     # id(stream) → staged head
-        #: id(stream) → (params_key, packed out_state row) — the packed
-        #: state is a pure function of the key, and the key comparison
-        #: is already paid every wake; skips the O(S) python pack loop
-        #: on unchanged membership
-        self._state_cache: dict[int, tuple] = {}
+        #: stream → its record, one for every pair of the owned roster
+        #: as ``_enrol`` last read it.  Keyed by the stream itself: while
+        #: a record lives its stream does, so a torn-down stream's
+        #: ``id()`` cannot pass to a new one with its record still here
+        self._tracked: dict = {}
+        #: pairs by ``_Pair.rides``: the count ``_build_ahead`` closes
+        #: the shape set over, kept across wakes (``riders``)
+        self._riders: dict[int, int] = {}
+        #: stream → engine of the pairs the next wake reads whether the
+        #: pump names them or not
+        self._carry: dict = {}
+        #: pairs of the roster the last ``begin_wake`` was handed; -1
+        #: while the scheduler is not engaged
+        self._roster_len = -1
+        #: the last wake's hand-over: pairs owned, pairs whose plan was
+        #: read (``megabatch_pairs_total``, ``pump.wake``'s arguments)
+        self.handed = self.walked = 0
         self._inflight: list[_InFlight] = []
         # double-buffered staging: a free pool per (b_pad, p_pad) shape;
         # a buffer leaves the pool at dispatch and returns at harvest,
@@ -222,48 +260,64 @@ class MegabatchScheduler:
         self.mismatches = 0
 
     # ------------------------------------------------------------- wake API
-    def begin_wake(self, pairs, now_ms: int) -> None:
+    @property
+    def engaged(self) -> bool:
+        """Whether the last wake was the scheduler's (``begin_wake``)
+        and not an ``idle_wake``."""
+        return self._roster_len >= 0
+
+    def begin_wake(self, pairs, now_ms: int, ready=None) -> None:
         """Harvest any finished stacked pass and prime params for
         streams whose membership changed — ONE stacked pass for every
         joined/rebased stream instead of one per-stream query each (the
-        mass-join case the per-stream path serves linearly)."""
+        mass-join case the per-stream path serves linearly).  ``pairs``
+        is the owned roster, ``ready`` the pairs of it the wake steps
+        (None: all of them)."""
         self.wakes += 1
         self._harvest()
-        self._prime_stale(pairs, now_ms)
+        self.handed = len(pairs)
+        obs.MEGABATCH_PAIRS.inc(len(pairs), kind="handed")
+        self._prime_stale(self._walk(pairs, ready), now_ms)
 
     def idle_wake(self) -> None:
         """Called by the pump on wakes where the megabatch is NOT
         engaged (eligible streams fell below ``megabatch_min_streams``):
         keeps harvesting whatever is still in flight so a mass teardown
         can't pin streams/buffers inside ``_InFlight`` records forever,
-        and drops the per-stream cursors once nothing is in flight (a
-        later re-engagement re-tracks from the live window)."""
+        and drops the per-stream records once nothing is in flight (a
+        later re-engagement reads every pair anew, from the live
+        window)."""
+        self._roster_len = -1
+        self.handed = self.walked = 0
         if self._inflight:
             self._harvest()
         if not self._inflight and self._tracked:
             self._tracked.clear()
-            self._state_cache.clear()
+            self._riders.clear()
+            self._carry.clear()
 
-    def end_wake(self, pairs, now_ms: int) -> None:
+    def end_wake(self, pairs, now_ms: int, ready=None) -> None:
         """Collect, bucket, stage and dispatch the next stacked pass."""
-        # prune dead streams BEFORE any early return: a torn-down
-        # stream's id() can be recycled by a new RelayStream, and a
-        # stale staged-head surviving a saturated wake would silently
-        # skip the new stream's first packets
-        live = {id(s) for s, _ in pairs}
-        for sid in [k for k in self._tracked if k not in live]:
-            del self._tracked[sid]
-            self._state_cache.pop(sid, None)
+        # (a stream that left has lost its record by here, BEFORE any
+        # early return: ``_walk``)
+        walk = self._walk(pairs, ready)
+        self.walked = len(walk)
+        obs.MEGABATCH_PAIRS.inc(len(walk), kind="walked")
         if len(self._inflight) >= self.MAX_INFLIGHT:
-            # saturated: this wake's dispatch is DEFERRED — every pair's
-            # fresh packets wait at least one more wake for device
-            # service.  The wake ledger counts the skip per stream (the
+            # saturated: this wake's dispatch is DEFERRED — the walked
+            # pairs' fresh packets wait at least one more wake for device
+            # service, and are walked then whether they are ready again
+            # or not.  The wake ledger counts the skip per stream (the
             # queue-delay decomposition's megabatch deferral signal).
             from ..obs.ledger import LEDGER
-            LEDGER.defer("megabatch", len(pairs))
+            LEDGER.defer("megabatch", len(walk))
+            self._carry.update(walk)
             return
         span = TRACER.open("megabatch.dispatch", "tpu")
-        work = self._collect(pairs, now_ms)
+        work = self._collect(walk, now_ms)
+        # until its bucket is dispatched a pair is carried: a dispatch
+        # that raises leaves the rest un-staged, for the next wake
+        self._carry = {item[0]: item[1] for item in work}
         if not work:
             TRACER.close(span, buckets=0, streams=0)
             return
@@ -284,49 +338,101 @@ class MegabatchScheduler:
                                              s_pad)
                 gather_ns += g
                 h2d_ns += h
+        self._carry.clear()
         total = TRACER.lap(span, buckets=len(buckets), streams=len(work))
         PROFILER.account_pass("megabatch", total,
                               {"stage_gather": gather_ns, "h2d": h2d_ns})
 
+    # --------------------------------------------------- which pairs are read
+    def _walk(self, pairs, ready) -> list:
+        """The pairs this wake reads the plan of.  With a ready set:
+        ``ready`` and the carry-over — and the records follow the roster
+        only when it changed, which shows as another length or a ready
+        pair without a record (a pair new to the roster is ready in its
+        first wake there: first rostered, or a route move).  Every pair
+        for a caller with none, and in the first wake after one that was
+        not the scheduler's: what was kept across it is stale."""
+        if ready is None:
+            self._enrol(pairs)
+            return pairs
+        tracked = self._tracked
+        if self._roster_len < 0:
+            self._enrol(pairs)
+            self._carry = dict(pairs)
+        elif len(pairs) != self._roster_len or any(
+                s not in tracked for s, _ in ready):
+            self._carry.update(self._enrol(pairs))
+        carry = self._carry
+        if not carry:
+            return ready
+        named = {s for s, _ in ready}
+        return ready + [(s, e) for s, e in carry.items() if s not in named]
+
+    def _enrol(self, pairs) -> dict:
+        """Hold the records to the owned roster: the record of a stream
+        that left goes (so does its count in the closed set and its
+        place in the carry-over) and every newcomer gets one; returns
+        the newcomers."""
+        tracked = self._tracked
+        new = {s: e for s, e in pairs if s not in tracked}
+        if len(tracked) + len(new) != len(pairs):
+            live = {s for s, _ in pairs}
+            for s in [k for k in tracked if k not in live]:
+                self._ride(tracked.pop(s), None)
+                self._carry.pop(s, None)
+        for s in new:
+            tracked[s] = _Pair()
+        self._roster_len = len(pairs)
+        return new
+
+    def behind(self, stream) -> bool:
+        """The guard's question (``relay.pump.Pump.audit``), of an owned
+        stream nothing has marked since the last wake: does the
+        scheduler's record lag the stream — none, packets not staged, a
+        plan epoch not read — with no carry-over to catch it up?  Then a
+        mark or a hand-over went missing: the stream's steps take the
+        per-stream query meanwhile (``megabatch_fallback_total``)."""
+        if self._roster_len < 0:
+            return False
+        rec = self._tracked.get(stream)
+        if rec is None:
+            return True
+        if stream in self._carry:
+            return False
+        ring = stream.rtp_ring
+        head = rec.head
+        if head is None:
+            head = max(ring.tail, ring.head - self.MAX_STAGE_ROWS)
+        return ring.head > head or stream.plan_epoch != rec.epoch
+
     # ------------------------------------------------------------- prime
-    def _prime_stale(self, pairs, now_ms: int) -> None:
+    def _prime_stale(self, walk, now_ms: int) -> None:
         """Synchronous stacked param pass for key-stale streams.
 
-        Reads each engine's output plan (``TpuFanoutEngine.plan``): its
-        deterministic bookmark/rebase latch runs first, over the
-        un-primed residue only (idempotent — the engine's step re-runs
-        it as a no-op with the same wake timestamp), so the key read
-        here is the key the engine will check moments later in the same
-        wake.  The affine
-        params depend only on that rewrite state, so the windows staged
-        here are all-zero padding: no packet bytes ride the prime.  The
-        same walk counts the streams riding each subscriber pad for
-        ``_build_ahead``: a stream with media rides its fast list's; a
-        thin stream still waiting for its first packet will ride the
-        floor.  A fatter one is not counted until its first packet — its
-        audience walks through every pad on the way up while players
-        join, and loading each would cost the join six programs a pad."""
+        Reads each walked engine's output plan (``TpuFanoutEngine.
+        plan``): its deterministic bookmark/rebase latch runs first,
+        over the un-primed residue only (idempotent — the engine's step
+        re-runs it as a no-op with the same wake timestamp), so the key
+        read here is the key the engine will check moments later in the
+        same wake.  The affine params depend only on that rewrite state,
+        so the windows staged here are all-zero padding: no packet bytes
+        ride the prime.  The same walk corrects what each pair counts as
+        in the closed set (``rides``) for ``_build_ahead``; a pair that
+        was not walked counts as it did."""
         stale = []
-        riders: dict[int, int] = {}
-        live = False                       # media flows on a handed pair
-        for stream, eng in pairs:
+        tracked = self._tracked
+        for stream, eng in walk:
             # the engine's own tables: the un-primed residue is latched,
             # nothing else is walked on an unchanged epoch
             p = eng.plan(stream, now_ms)
+            self._ride(tracked[stream], self.rides(p))
             fast, key = p.fast, p.key
-            if not fast:
-                if p.n_outputs <= SUB_FLOOR:
-                    riders[SUB_FLOOR] = riders.get(SUB_FLOOR, 0) + 1
-                continue
-            live = True
-            s_pad = _sub_pad(len(fast))
-            riders[s_pad] = riders.get(s_pad, 0) + 1
-            if key == eng._params_key or (
+            if not fast or key == eng._params_key or (
                     eng.megabatch_params is not None
                     and eng.megabatch_params[0] == key):
                 continue
             stale.append((eng, fast, key))
-        self._build_ahead(riders, live)
+        self._build_ahead(*self.riders())
         if not stale:
             return
         import jax
@@ -365,6 +471,46 @@ class MegabatchScheduler:
         TRACER.close(span)
 
     # ------------------------------------------------------- the closed set
+    @staticmethod
+    def rides(p) -> int:
+        """What a pair with output plan ``p`` counts as: the subscriber
+        pad of its fast list with media; 0 for a thin stream still
+        waiting for its first packet, which will ride the floor; -1, not
+        counted, for a fatter one until then — its audience walks through
+        every pad on the way up while players join, and loading each
+        would cost the join six programs a pad."""
+        if p.fast:
+            return _sub_pad(len(p.fast))
+        return 0 if p.n_outputs <= SUB_FLOOR else -1
+
+    def _ride(self, rec: _Pair, rides: int | None) -> None:
+        """``rec``'s pair counts as ``rides`` from here on (None: it
+        left)."""
+        if rec.rides == rides:
+            return
+        n = self._riders
+        if rec.rides is not None:
+            n[rec.rides] -= 1
+            if not n[rec.rides]:
+                del n[rec.rides]
+        if rides is not None:
+            n[rides] = n.get(rides, 0) + 1
+        rec.rides = rides
+
+    def riders(self) -> tuple[dict, bool]:
+        """({subscriber pad: the handed pairs that ride it}, whether
+        media flows on any) as kept across wakes — equal to a count over
+        every pair's plan."""
+        riders: dict[int, int] = {}
+        live = False
+        for rides, n in self._riders.items():
+            if rides < 0:
+                continue
+            live = live or rides > 0
+            pad = rides or SUB_FLOOR
+            riders[pad] = riders.get(pad, 0) + n
+        return riders, live
+
     @staticmethod
     def members(riders: dict) -> set:
         """Every (b_pad, p_pad, s_pad) a wake can dispatch for handed
@@ -405,19 +551,22 @@ class MegabatchScheduler:
             self._built.add((b_pad, p_pad, s_pad))
 
     # ------------------------------------------------------------- collect
-    def _collect(self, pairs, now_ms: int) -> list:
+    def _collect(self, walk, now_ms: int) -> list:
         work = []
-        for stream, eng in pairs:
+        tracked = self._tracked
+        for stream, eng in walk:
             ring = stream.rtp_ring
             p = eng.plan(stream, now_ms)
+            rec = tracked[stream]
+            rec.epoch = stream.plan_epoch
             fast, key = p.fast, p.key
             if not fast:
-                self._tracked[id(stream)] = ring.head
+                rec.head = ring.head
                 continue
-            base = self._tracked.get(id(stream))
+            base = rec.head
             floor = max(ring.tail, ring.head - self.MAX_STAGE_ROWS)
             if base is None or base > ring.head or base < floor:
-                base = floor               # new/recycled/fell-behind
+                base = rec.head = floor    # new/recycled/fell-behind
             n_new = ring.head - base
             need_params = (key != eng._params_key
                            and not (eng.megabatch_params is not None
@@ -490,11 +639,11 @@ class MegabatchScheduler:
         obs.MEGABATCH_CELLS.inc(staged, kind="staged")
 
     def _packed_state(self, stream, fast, key) -> np.ndarray:
-        cached = self._state_cache.get(id(stream))
-        if cached is not None and cached[0] == key:
-            return cached[1]
+        rec = self._tracked[stream]
+        if rec.state is not None and rec.state[0] == key:
+            return rec.state[1]
         packed = np.asarray(pack_output_state(fast))
-        self._state_cache[id(stream)] = (key, packed)
+        rec.state = (key, packed)
         return packed
 
     def _dispatch_bucket(self, entries, p_pad: int,
@@ -520,7 +669,10 @@ class MegabatchScheduler:
         for i, (stream, eng, fast, key, base, n_new) in enumerate(entries):
             staging.gather_window(stream.rtp_ring, base, n_new, win[i])
             state[i, :len(fast)] = self._packed_state(stream, fast, key)
-            self._tracked[id(stream)] = base + n_new
+            # (a stream's further rows may ride a pass dispatched after
+            # its last row's: the cursor only moves on)
+            rec = self._tracked[stream]
+            rec.head = max(rec.head, base + n_new)
             recs.append((stream, eng, key, len(fast), base, -1))
             real += n_new * len(fast)
         if b_pad > len(entries):
@@ -575,7 +727,8 @@ class MegabatchScheduler:
             staging.gather_window(stream.rtp_ring, base, n_new,
                                   shard_bufs[k][r])
             state[i, :len(fast)] = self._packed_state(stream, fast, key)
-            self._tracked[id(stream)] = base + n_new
+            rec = self._tracked[stream]
+            rec.head = max(rec.head, base + n_new)
             recs.append((stream, eng, key, len(fast), base, k))
             filled[k] = r + 1
         for k, buf in enumerate(shard_bufs):

@@ -7,16 +7,24 @@ server hands ``wake`` its ``(stream, engine)`` pairs.  Both run ``serve``
 over a roster of ``(session path, stream, engine | None, route)``, the
 engine ``None`` exactly when the route is ``SCALAR``.
 
-**Which streams a wake steps.**  The scheduler sees every owned pair of
-the roster every wake (its closed shape set counts them); ``_step`` is
-handed the live streams that have something to do — ``needs_step`` is
-the definition, for every route alike.  ``Pump`` does not evaluate it
-over the roster: it keeps a ready set, marked where the state
-``needs_step`` reads is written (``PlanCell.mark`` / ``touch`` from
-ingest and every plan move, the wheel's fired timers, ``_step``'s own
-carry-over), and audits the marks against the rule once a second
-(``Pump.audit``).  The VOD roster and a caller with no wheel step all
-they hand in.
+**Which streams a wake steps.**  ``_step`` is handed the live streams
+that have something to do — ``needs_step`` is the definition, for every
+route alike.  ``Pump`` does not evaluate it over the roster: it keeps a
+ready set, marked where the state ``needs_step`` reads is written
+(``PlanCell.mark`` / ``touch`` from ingest and every plan move, the
+wheel's fired timers, ``_step``'s own carry-over), and audits the marks
+against the rule once a second (``Pump.audit``).  The VOD roster and a
+caller with no wheel step all they hand in.
+
+**Which pairs the scheduler looks at.**  ``serve`` hands it the owned
+roster and, beside it, the owned pairs among the entries the wake steps
+(``ready``; None — every pair — where the caller keeps no ready set).
+The scheduler reads the plan of those and of what it carries over
+itself, keeps its count of the closed shape set across wakes, and holds
+its records to the roster when the roster changed
+(``MegabatchScheduler._walk``); ``Pump.audit`` holds it to that rule
+too.  ``megabatch_owned`` is written on the engines a wake steps, and on
+all of them when the engagement flips: a step is its only reader.
 """
 
 from __future__ import annotations
@@ -133,9 +141,9 @@ def serve(live, vod, sched, t: int, *, min_streams: int = 1, ladder=None,
           log=None, stepped=None) -> int:
     """One wake over a built roster: ``live`` entries inside the
     ``live_relay`` ledger unit — ``stepped`` of them where the caller
-    keeps a ready set, the scheduler still handed every owned pair —
-    and ``vod`` entries (they neither consult nor move the ladder)
-    inside ``vod_fill``.  ``OWNED`` falls to
+    keeps a ready set, the scheduler handed the owned pairs among them
+    beside every owned pair — and ``vod`` entries (they neither consult
+    nor move the ladder) inside ``vod_fill``.  ``OWNED`` falls to
     ``DEVICE`` for the whole wake without a ``sched``, under
     ``min_streams`` owned entries, or when the harvest raises: a
     scheduler failure degrades to per-stream stepping, never to a halted
@@ -145,22 +153,31 @@ def serve(live, vod, sched, t: int, *, min_streams: int = 1, ladder=None,
     owned = [(s, eng) for _p, s, eng, r in roster if r == OWNED]
     if sched is None or len(owned) < min_streams:
         owned = []
+    if stepped is None:             # no ready set: every entry, every pair
+        stepped, served, ready = live, roster, None
+    else:
+        served = stepped + vod if vod else stepped
+        ready = [(s, eng) for _p, s, eng, r in served if r == OWNED]
 
-    def mark(engaged: bool) -> None:
-        for _p, _s, eng, r in roster:
+    def mark(entries, engaged: bool) -> None:
+        for _p, _s, eng, r in entries:
             if eng is not None:
                 eng.megabatch_owned = engaged and r == OWNED
 
-    mark(bool(owned))               # before the harvest's prime pass
+    # before the harvest's prime pass.  An engine reads the flag inside
+    # its step only: the engines this wake steps, and every engine when
+    # the wake is the scheduler's and the last was not, or the reverse
+    flips = bool(owned) != (sched is not None and sched.engaged)
+    mark(roster if flips else served, bool(owned))
     if owned:
         _u = LEDGER.unit_start("megabatch", part="harvest")
         try:
-            sched.begin_wake(owned, t)
+            sched.begin_wake(owned, t, ready=ready)
         except Exception as e:
             if ladder is not None:
                 ladder.note_scheduler_error(
                     [s.session_path for s, _ in owned])
-            mark(False)
+            mark(roster, False)
             owned = []
             if log:
                 log.warning(f"megabatch harvest: {e!r}")
@@ -177,8 +194,6 @@ def serve(live, vod, sched, t: int, *, min_streams: int = 1, ladder=None,
         LEDGER.unit_end(_u)
     # the slowest stream's trace_id rides the unit's record (the
     # critical-path correlation a p99 sample decomposes by)
-    if stepped is None:
-        stepped = live
     _u = LEDGER.unit_start("live_relay")
     sent, worst = _step(stepped, t, ladder, log, "", LEDGER.enabled)
     LEDGER.unit_end(_u, items=max(len(stepped), 1), trace_id=worst)
@@ -189,7 +204,7 @@ def serve(live, vod, sched, t: int, *, min_streams: int = 1, ladder=None,
     if owned:
         _u = LEDGER.unit_start("megabatch", part="stage")
         try:
-            sched.end_wake(owned, t)
+            sched.end_wake(owned, t, ready=ready)
         except Exception as e:
             if ladder is not None:
                 ladder.note_scheduler_error(
@@ -340,16 +355,22 @@ class Pump:
         skipped for which ``needs_step`` is true at that wake's clock and
         which nothing has marked since is stepped next wake and counted
         in ``pump_ready_missed_total`` — a missed mark costs the second
-        between two audits, not a stream."""
+        between two audits, not a stream.  The scheduler reads the pairs
+        the ready set names, so it is held to the same marks: an owned
+        stream its records lag (``MegabatchScheduler.behind``) is
+        counted and stepped alike."""
         if self.wheel is None:
             return 0
-        ready, t = self.ready, self.t
+        ready, t, sched = self.ready, self.t, self.megabatch
         stepped = {id(e[1]) for e in self.stepped}
         missed = 0
         for _path, stream, _eng, route in self.live:
             c = stream._plan_cell
-            if (id(stream) not in stepped and c.key not in ready
-                    and needs_step(stream, t, route)):
+            if c.key in ready:
+                continue
+            if ((id(stream) not in stepped and needs_step(stream, t, route))
+                    or (route == OWNED and sched is not None
+                        and sched.behind(stream))):
                 ready.add(c.key)
                 missed += 1
         if missed:

@@ -1235,8 +1235,11 @@ class StreamingServer:
         span, t0, w2p_us = self._wake_open_rec
         self._wake_open_rec = None
         obs.LEDGER.end_wake()
+        mb = self.pump.megabatch
         end = TRACER.close(span, streams=self.pump.streams,
                            stepped=len(self.pump.stepped),
+                           handed=mb.handed if mb else 0,
+                           walked=mb.walked if mb else 0,
                            sent=self.pump.sent, wake_to_pass_us=w2p_us)
         TRACER.wake = None
         obs.PUMP_WAKE_SECONDS.observe((end - t0) / 1e9)

@@ -290,17 +290,22 @@ class _Engine:
 
 
 class _Sched:
-    def __init__(self):
-        self.begun, self.ended = [], []
+    engaged = False
 
-    def begin_wake(self, pairs, t):
+    def __init__(self):
+        self.begun, self.ended, self.ready = [], [], []
+
+    def begin_wake(self, pairs, t, ready=None):
+        self.engaged = True
         self.begun.append(len(pairs))
+        self.ready.append([s for s, _ in ready])
 
     def idle_wake(self):
-        pass
+        self.engaged = False
 
-    def end_wake(self, pairs, t):
+    def end_wake(self, pairs, t, ready=None):
         self.ended.append(len(pairs))
+        assert [s for s, _ in ready] == self.ready[-1]
 
 
 def test_three_of_256_pushed_three_steps_and_the_scheduler_sees_them_all():
@@ -330,6 +335,9 @@ def test_three_of_256_pushed_three_steps_and_the_scheduler_sees_them_all():
     p.wake(reg.sessions, [], T0 + 20)
     assert log == ["/live/c3", "/live/c77", "/live/c200"]
     assert p.megabatch.begun == [256, 256] == p.megabatch.ended
+    # ... and is named the three beside them (all 256 when first rostered)
+    assert [len(r) for r in p.megabatch.ready] == [256, 3]
+    assert p.megabatch.ready[1] == [streams[k] for k in (3, 77, 200)]
     assert all(r == OWNED for _p, _s, _e, r in p.live) and p.streams == 256
     assert [s for _p, s, _e, _r in p.stepped] == [streams[k]
                                                   for k in (3, 77, 200)]

@@ -58,17 +58,23 @@ class _Sched:
     def __init__(self, log, harvest_raises=False):
         self.log = log
         self.harvest_raises = harvest_raises
+        self.engaged = False
+        self.ready = []                 # what each begin_wake was named
 
-    def begin_wake(self, pairs, t):
+    def begin_wake(self, pairs, t, ready=None):
+        self.engaged = True
+        self.ready.append(None if ready is None else
+                          sorted(s.session_path for s, _ in ready))
         self.log.append(("begin", sorted(s.session_path for s, _ in pairs),
                          all(e.megabatch_owned for _, e in pairs)))
         if self.harvest_raises:
             raise RuntimeError("harvest fell over")
 
     def idle_wake(self):
+        self.engaged = False
         self.log.append(("idle",))
 
-    def end_wake(self, pairs, t):
+    def end_wake(self, pairs, t, ready=None):
         self.log.append(("end", sorted(s.session_path for s, _ in pairs)))
 
 

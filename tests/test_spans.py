@@ -236,6 +236,28 @@ def test_span_breakdown_reads_a_ring_dump(tmp_path):
     assert tool.per_wake([{"streams": 3}]) == {"streams": 3.0}  # a parent
 
 
+def test_span_breakdown_closes_with_the_pairs_handed_and_walked(
+        tmp_path, capsys):
+    """``pump.wake`` carries the scheduler's hand-over beside the ready
+    set's count, and the tool's closing line prints both; a program
+    whose wakes carry neither (a parent) prints the line it printed."""
+    tool = _load("tools/span_breakdown.py", "span_breakdown")
+    for carried, want in (
+            (dict(stepped=4, handed=256, walked=5),
+             "a wake: streams 256.0, stepped 4.00 (1.6 %), pairs handed "
+             "256.0, walked 5.00, sent 61.0"),
+            (dict(stepped=4),
+             "a wake: streams 256.0, stepped 4.00 (1.6 %), sent 61.0")):
+        tr = SpanTracer(capacity=16)
+        for wake in range(3):
+            tr.add("pump.wake", wake * 10_000_000, 4_000_000, cat="pump",
+                   streams=256, sent=61, **carried)
+        path = tmp_path / "ring.json"
+        path.write_text(__import__("json").dumps(tr.dump()))
+        assert tool.main(["span_breakdown.py", str(path)]) == 0
+        assert capsys.readouterr().out.splitlines()[-1] == want
+
+
 # ------------------------------------------------------ (b) due → wire
 class _Recorder:
     """Keep every array a histogram's ``observe_many`` is given, one

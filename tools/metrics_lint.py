@@ -845,6 +845,7 @@ PUMP_STATES = ("wake", "sleep")
 WAKE_CAUSES = ("ingest", "timer", "interval")
 STEP_RESULTS = ("idle", "worked")
 CELL_KINDS = ("real", "staged")
+PAIR_KINDS = ("handed", "walked")
 
 
 def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
@@ -866,7 +867,9 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
     31), counted where ``pump.sleep`` closes: ``pump_drain_rounds_total``
     / ``pump_drain_packets_total``; and the ready set's three (ISSUE 33):
     ``pump_roster_streams_total`` / ``pump_stepped_streams_total`` once a
-    wake, ``pump_ready_missed_total`` by the 1 Hz audit."""
+    wake, ``pump_ready_missed_total`` by the 1 Hz audit; and the
+    scheduler's hand-over (ISSUE 36): ``megabatch_pairs_total{kind}``,
+    handed in ``begin_wake`` and walked in ``end_wake``."""
     from easydarwin_tpu.obs.ledger import WORK_CLASSES
     from easydarwin_tpu.obs.metrics import TIME_BUCKETS
     from easydarwin_tpu.obs.trace import SPAN_PREFIXES, SPANS
@@ -908,6 +911,7 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
             "engine_plan_rebuilds_total": ((), ()),
             "engine_steps_total": (("result",), STEP_RESULTS),
             "megabatch_cells_total": (("kind",), CELL_KINDS),
+            "megabatch_pairs_total": (("kind",), PAIR_KINDS),
             "ingest_interleaved_packets_total": ((), ()),
             "ingest_interleaved_seconds_total": ((), ())}
     for fam_name, (labels, closed) in want.items():

@@ -10,8 +10,10 @@ run) through ``benchmark/reduce_trace.load_xplane``, or the span ring as
 ``obs.trace.SPANS``: count, seconds, ms per ``pump.wake`` and share of
 the time the pump loop spent awake or asleep, then what ``pump.wake``
 carries per wake: the streams it served and, where the program has a
-ready set, how many of them it stepped.  Spans nest, so a child's ms are
-inside its parent's.  Holds no chip: run it with ``JAX_PLATFORMS=cpu``."""
+ready set, how many of them it stepped and how many of the owned pairs it
+handed the megabatch scheduler had their plan read.  Spans nest, so a
+child's ms are inside its parent's.  Holds no chip: run it with
+``JAX_PLATFORMS=cpu``."""
 
 from __future__ import annotations
 
@@ -39,7 +41,8 @@ def host_rows(path: str) -> list:
 
 def wake_args(path: str) -> list[dict]:
     """The arguments of every ``pump.wake`` span (``streams``, ``sent``
-    and, where the wake has a ready set, ``stepped``)."""
+    and, where the wake has a ready set, ``stepped``, ``handed`` and
+    ``walked``)."""
     if path.endswith(".json"):
         with open(path) as f:
             return [e.get("args", {}) for e in json.load(f)["traceEvents"]
@@ -59,7 +62,7 @@ def per_wake(args: list[dict]) -> dict:
     """Mean of each numeric ``pump.wake`` argument over the wakes that
     carry it."""
     out = {}
-    for key in ("streams", "stepped", "sent"):
+    for key in ("streams", "stepped", "handed", "walked", "sent"):
         vals = [float(a[key]) for a in args if key in a]
         if vals:
             out[key] = sum(vals) / len(vals)
@@ -97,6 +100,9 @@ def main(argv) -> int:
             line += (f", stepped {mean['stepped']:.2f} "
                      f"({100 * mean['stepped'] / mean['streams']:.1f} %)"
                      if mean["streams"] else ", stepped 0")
+        if "handed" in mean:
+            line += (f", pairs handed {mean['handed']:.1f}, walked "
+                     f"{mean.get('walked', 0):.2f}")
         print(line + f", sent {mean.get('sent', 0):.1f}")
     return 0
 
