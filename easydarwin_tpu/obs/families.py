@@ -271,24 +271,35 @@ MEGABATCH_WIRE_MISMATCH = REGISTRY.counter(
 # bounds the cardinality (a full v5 pod slice is 256 chips; an id string
 # like "TPU_v5litepod_..." would shard the family per hostname).  On a
 # 1-device box (no mesh) these families stay at zero with no children.
+# Under a mesh they are a full account of its passes: a pass that is not
+# sharded (one row) runs whole on the mesh's first device and is counted
+# once, there.
 MEGABATCH_DEVICE_PASSES = REGISTRY.counter(
     "megabatch_device_passes_total",
-    "Stacked megabatch shard passes executed per mesh device (one per "
-    "device per dispatched bucket that carried at least one real stream "
-    "row for that shard)", labels=("device",))
+    "Stacked megabatch passes executed per mesh device: one per device "
+    "per sharded bucket that carried at least one real stream row for "
+    "that shard, and one on device 0 for a pass that rode it whole (one "
+    "row: nothing to shard)", labels=("device",))
 MEGABATCH_DEVICE_STREAMS = REGISTRY.counter(
     "megabatch_device_streams_total",
-    "Streams whose window rode each mesh device's shard of a stacked "
-    "megabatch pass (streams/passes per device = shard occupancy; a "
-    "skewed distribution means the stream->shard split is unbalanced)",
+    "Stream rows each mesh device computed: its shard's of a sharded "
+    "stacked pass, all of a pass that rode it whole (streams/passes per "
+    "device = shard occupancy; a skewed distribution means the "
+    "stream->shard split is unbalanced)",
     labels=("device",))
+MEGABATCH_SHARDED_STREAMS = REGISTRY.counter(
+    "megabatch_sharded_streams_total",
+    "Of megabatch_streams_total, the streams whose pass was sharded over "
+    "the serving mesh (two or more rows); 0 without a mesh, and where "
+    "one was configured and failed to build")
 MEGABATCH_DEVICE_PHASE_SECONDS = REGISTRY.histogram(
     "megabatch_device_phase_seconds",
-    "Per-mesh-device phase durations of the sharded megabatch path: h2d "
-    "= that shard's contiguous staging upload, device_step = the "
-    "harvest-side wait for that shard's result to become ready, d2h = "
-    "fetching that shard's packed params slice; device label is the "
-    "shard index within the serving mesh",
+    "Per-mesh-device phase durations of the sharded megabatch path, the "
+    "laps of its shard spans: h2d = that shard's contiguous staging "
+    "upload (megabatch.shard_h2d), device_step = the harvest-side wait "
+    "for that shard's result to become ready (megabatch.shard_wait), d2h "
+    "= fetching that shard's packed params slice (megabatch.shard_fetch); "
+    "device label is the shard index within the serving mesh",
     labels=("device", "phase"), buckets=TIME_BUCKETS)
 STAGE_GATHER_BYTES = REGISTRY.counter(
     "stage_gather_bytes_total",

@@ -59,6 +59,7 @@ SPANS = (
     "engine.egress", "engine.account", "engine.rtcp",
     "megabatch.harvest", "megabatch.fetch", "megabatch.prime",
     "megabatch.dispatch", "megabatch.gather", "megabatch.h2d",
+    "megabatch.shard_h2d", "megabatch.shard_wait", "megabatch.shard_fetch",
     "ingest.read",
     "native.egress", "native.stream_egress",
     "pipeline.step", "jax.build")

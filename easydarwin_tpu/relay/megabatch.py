@@ -57,12 +57,23 @@ server startup from ``megabatch_devices``), each bucket's leading
 stream axis is sharded over the mesh instead of landing on the default
 device:
 
-* staging is split into PER-DEVICE buffers (``ops.staging.
-  rows_per_shard`` rows each, same pow2 bucket-shape latching), so each
-  shard's H2D is one contiguous upload only that device reads;
+* staging is split into PER-DEVICE buffers (``_rows_per`` rows each:
+  the pass is as tall as its rung of the stream ladder, split over the
+  devices, and its entries are dealt round them), so each shard's H2D
+  is one contiguous upload only that device reads;
 * one ``models.relay_pipeline.sharded_megabatch_step`` dispatch per
   bucket — the pass is a pure vmap over streams, so the ``src``
-  sharding partitions it with zero collectives;
+  sharding partitions it with zero collectives.  It is the same kernel
+  under the same name: the program, and the profiler's module on each
+  device's plane, is ``megabatch_window_step``;
+* a pass of ONE row has nothing to shard: it rides the single-device
+  program on the mesh's first device (three shards of pure padding
+  would run in full beside it) and is harvested as a single-device
+  pass; the synchronous prime follows the same rule.  The
+  ``megabatch_device_*`` counters count such a pass once, on that
+  device, so they stay a full account of the mesh's work;
+  ``megabatch_sharded_streams_total`` counts the streams that did ride
+  a sharded pass;
 * harvest stays non-blocking under the same ``MAX_INFLIGHT`` double
   buffer and fetches each device's packed slice independently
   (``addressable_shards``), and the egress scatter is keyed by shard:
@@ -71,7 +82,15 @@ device:
   bug degrades that stream to per-stream stepping, never the wire;
 * uneven stream counts pad-mask the ``src`` axis exactly as the
   multichip dryrun does: tail rows are zero windows + zero state,
-  which stage nothing and install nothing.
+  which stage nothing and install nothing;
+* the closed set is loaded at join under a mesh too (``programs``):
+  each member mapped to the one program the mesh dispatches for it —
+  the single-device program for the first rung, the sharded
+  ``(rows_per × n_dev, p_pad, s_pad)`` for every other;
+* a shard's upload, wait and fetch are spans (``megabatch.shard_h2d``,
+  ``.shard_wait``, ``.shard_fetch``: ``device``, ``rows``) inside
+  ``megabatch.h2d`` / ``megabatch.fetch``, and
+  ``megabatch_device_phase_seconds`` is fed from their laps.
 
 With no mesh (1-device box, ``megabatch_devices=1``, mesh build
 failure) every dispatch takes the original single-device path and the
@@ -213,12 +232,19 @@ class MegabatchScheduler:
         self.mesh = None
         self._mesh_devices: list = []
         self._sharded_step = None
+        #: where a pass that is not sharded runs: the default device
+        #: (None) and no shard (-1) off a mesh, the mesh's first under one
+        self._home, self._home_shard = None, -1
         if mesh is not None and mesh.devices.size > 1:
             self.mesh = mesh
             # src-major flat order: shard k of the leading stream axis
             # lands on _mesh_devices[k]
             self._mesh_devices = list(mesh.devices.reshape(-1))
             self._sharded_step = sharded_megabatch_step(mesh)
+            self._home, self._home_shard = self._mesh_devices[0], 0
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            #: leading (stream) axis over the mesh's ``src``
+            self._src_sharding = NamedSharding(mesh, P("src", None, None))
         #: staging buffers kept per hot shape: 2 per device (the double
         #: buffer), since every shard of a bucket draws from one pool
         self._pool_cap = 2 * max(1, len(self._mesh_devices))
@@ -246,8 +272,9 @@ class MegabatchScheduler:
         # the host gathers the next wake into a fresh/recycled one
         # (steady state: two buffers per hot shape)
         self._free: dict[tuple, list[np.ndarray]] = {}
-        #: (b_pad, p_pad, s_pad) of every stacked-pass program this
-        #: process has traced and loaded
+        #: every stacked-pass program this process has traced and
+        #: loaded: (b_pad, p_pad, s_pad) of a single-device one, with
+        #: the mesh's device count as a fourth of a sharded one
         self._built: set[tuple] = set()
         # a bracket that held an XLA build (a bucket-growth retrace) is
         # never a phase sample: obs.profile.builds() tells
@@ -435,39 +462,47 @@ class MegabatchScheduler:
         self._build_ahead(*self.riders())
         if not stale:
             return
-        import jax
-
         span = TRACER.open("megabatch.prime", "tpu", streams=len(stale))
         buckets: dict[int, list] = {}
         for item in stale:
             buckets.setdefault(_sub_pad(len(item[1])), []).append(item)
         p_pad = PACKET_PADS[0]
+        n_dev = len(self._mesh_devices)
         for s_pad, items in sorted(buckets.items()):
-            b_pad = _stream_pad(len(items))
-            self._built.add((b_pad, p_pad, s_pad))
+            rows_per = self._rows_per(len(items))
+            b_pad = rows_per * n_dev or _stream_pad(len(items))
+            # item i's row of the pass, and the shard that holds it
+            rows = [(i % n_dev) * rows_per + i // n_dev if rows_per else i
+                    for i in range(len(items))]
+            self._built.add(self._program(b_pad, p_pad, s_pad, rows_per))
             # fresh zeros, never a recycled buffer: a stale le32 length
             # row would resurrect a previous wake's packets into the
             # keyframe scan
             win = np.zeros((b_pad, p_pad, staging.ROW_STRIDE), np.uint8)
             state = np.zeros((b_pad, s_pad, STATE_COLS), np.uint32)
-            for i, (_eng, fast, _key) in enumerate(items):
-                state[i, :len(fast)] = np.asarray(pack_output_state(fast))
+            for row, (_eng, fast, _key) in zip(rows, items):
+                state[row, :len(fast)] = np.asarray(pack_output_state(fast))
             built0 = builds()
             t_h = time.perf_counter_ns()
-            res = megabatch_window_step(jax.device_put(win), state)
+            res = self._step(win, state, rows_per)
             t_d = time.perf_counter_ns()
             packed = np.asarray(res)             # the blocking fetch
             t_f = time.perf_counter_ns()         # scatter is host work,
             segs = scatter_affine_segments(      # NOT d2h — unphased
-                packed, [len(f) for (_e, f, _k) in items])
+                packed[rows], [len(f) for (_e, f, _k) in items])
             if builds() == built0:
                 PROFILER.account_pass(
                     "megabatch", t_f - t_h,
                     {"device_step": t_d - t_h, "d2h": t_f - t_d})
-            for (eng, _fast, key), seg in zip(items, segs):
-                self._install_segment(eng, key, seg)
-            self._note_pass(len(items), win.nbytes + state.nbytes,
-                            0, b_pad * p_pad * s_pad)
+            for row, (eng, _fast, key), seg in zip(rows, items, segs):
+                self._install_segment(
+                    eng, key, seg,
+                    shard=row // rows_per if rows_per else self._home_shard)
+            self._note_pass(
+                len(items), win.nbytes + state.nbytes, 0,
+                b_pad * p_pad * s_pad,
+                by_shard=[len(range(k, len(items), n_dev))
+                          for k in range(n_dev)] if rows_per else None)
         TRACER.close(span)
 
     # ------------------------------------------------------- the closed set
@@ -526,29 +561,72 @@ class MegabatchScheduler:
                 b_pad <<= 2
         return out
 
+    def _rows_per(self, n_rows: int) -> int:
+        """Rows each shard holds of a pass of ``n_rows`` stream rows; 0
+        for a pass that is not sharded — no mesh, or one row, which has
+        nothing to shard (three shards of pure padding would run in
+        full beside it) and rides the single-device program on the
+        mesh's first device.  A sharded pass is as tall as its rung of
+        the stream ladder, split over the devices, so a member of the
+        closed set is ONE program under a mesh as off it; entry i rides
+        shard ``i % n_dev``, row ``i // n_dev`` of it — dealt round the
+        devices, so five rows over four fill all four."""
+        if self._sharded_step is None or n_rows < 2:
+            return 0
+        return staging.rows_per_shard(_stream_pad(n_rows),
+                                      len(self._mesh_devices))
+
+    def _program(self, b_pad: int, p_pad: int, s_pad: int,
+                 rows_per: int) -> tuple:
+        """A program as ``_built`` keys it."""
+        if rows_per:
+            return (b_pad, p_pad, s_pad, len(self._mesh_devices))
+        return (b_pad, p_pad, s_pad)
+
+    def _step(self, win, state, rows_per: int):
+        """One pass over host arrays, uploaded whole (the prime, a
+        load): sharded over the mesh, or on one device."""
+        import jax
+
+        if rows_per:
+            return self._sharded_step(
+                jax.device_put(win, self._src_sharding),
+                jax.device_put(state, self._src_sharding))
+        return megabatch_window_step(jax.device_put(win, self._home), state)
+
+    def programs(self, riders: dict) -> set:
+        """The programs this scheduler runs for ``members(riders)``, as
+        ``_built`` keys them: off a mesh a member is its program; under
+        one the first rung's is the single-device program a row alone
+        rides, and every other rung's the sharded program of that many
+        rows (of one row a device where the devices outnumber them)."""
+        n_dev = len(self._mesh_devices)
+        out = set()
+        for b_pad, p_pad, s_pad in self.members(riders):
+            rows_per = self._rows_per(b_pad)
+            out.add(self._program(rows_per * n_dev or b_pad, p_pad, s_pad,
+                                  rows_per))
+        return out
+
     def _build_ahead(self, riders: dict, live: bool) -> None:
-        """Trace and load the members the handed pairs can reach and
+        """Trace and load the programs the handed pairs can reach and
         this process has not built, when a pair joins past a rung or
         brings a new subscriber pad — not at the wake that first stacks
         that many streams.  Before any media (players join before their
         camera's first packet) nothing waits behind a build and every
-        missing member loads now; once media flows (``live``) one a
+        missing program loads now; once media flows (``live``) one a
         wake, so a relayed packet waits behind at most one.  A zero pass
-        per member, fetched; nothing is staged or installed.  The mesh
-        path keeps building at first use."""
-        if self._sharded_step is not None:
-            return
-        missing = sorted(self.members(riders) - self._built)
-        if not missing:
-            return
-        import jax
-
-        for b_pad, p_pad, s_pad in missing[:1] if live else missing:
-            np.asarray(megabatch_window_step(
-                jax.device_put(np.zeros(
-                    (b_pad, p_pad, staging.ROW_STRIDE), np.uint8)),
-                np.zeros((b_pad, s_pad, STATE_COLS), np.uint32)))
-            self._built.add((b_pad, p_pad, s_pad))
+        per program, fetched; nothing is staged or installed.  Under a
+        mesh the same rule loads what the mesh dispatches
+        (``programs``)."""
+        missing = sorted(self.programs(riders) - self._built)
+        for prog in missing[:1] if live else missing:
+            b_pad, p_pad, s_pad = prog[:3]
+            np.asarray(self._step(
+                np.zeros((b_pad, p_pad, staging.ROW_STRIDE), np.uint8),
+                np.zeros((b_pad, s_pad, STATE_COLS), np.uint32),
+                len(prog) > 3))
+            self._built.add(prog)
 
     # ------------------------------------------------------------- collect
     def _collect(self, walk, now_ms: int) -> list:
@@ -626,9 +704,12 @@ class MegabatchScheduler:
         return True
 
     def _note_pass(self, n_streams: int, h2d_bytes: int, real: int,
-                   staged: int) -> None:
+                   staged: int, by_shard=None) -> None:
         """One dispatched pass: ``real`` (new packet, subscriber) cells
-        of the ``staged`` = b_pad × p_pad × s_pad its program computes."""
+        of the ``staged`` = b_pad × p_pad × s_pad its program computes.
+        ``by_shard``: a sharded pass's rows on each device.  A mesh
+        scheduler's per-device counters take every pass — one that is
+        not sharded once, on the device that ran it whole."""
         self.passes += 1
         self.streams_coalesced += n_streams
         obs.MEGABATCH_PASSES.inc()
@@ -637,6 +718,17 @@ class MegabatchScheduler:
         if real:
             obs.MEGABATCH_CELLS.inc(real, kind="real")
         obs.MEGABATCH_CELLS.inc(staged, kind="staged")
+        if self._sharded_step is None:
+            return
+        if by_shard is None:
+            by_shard = (n_streams,)
+        else:
+            self.sharded_passes += 1
+            obs.MEGABATCH_SHARDED_STREAMS.inc(n_streams)
+        for k, n in enumerate(by_shard):
+            if n:                          # pad-only shards count nothing
+                obs.MEGABATCH_DEVICE_PASSES.inc(device=str(k))
+                obs.MEGABATCH_DEVICE_STREAMS.inc(n, device=str(k))
 
     def _packed_state(self, stream, fast, key) -> np.ndarray:
         rec = self._tracked[stream]
@@ -655,13 +747,13 @@ class MegabatchScheduler:
             # mutates cursors — the pump catches it, degrades the wake
             # to per-stream stepping and charges the ladder
             INJECTOR.device_dispatch("megabatch.dispatch")
-        if self._sharded_step is not None:
+        if self._rows_per(len(entries)):
             return self._dispatch_bucket_mesh(entries, p_pad, s_pad)
         b_pad = _stream_pad(len(entries))
         self._built.add((b_pad, p_pad, s_pad))
         bucket = f"{b_pad}x{p_pad}x{s_pad}"
         tok = TRACER.open("megabatch.gather", "tpu", streams=len(entries),
-                          bucket=bucket)
+                          bucket=bucket, sharded=0)
         win = self._buffer(b_pad, p_pad)
         state = np.zeros((b_pad, s_pad, STATE_COLS), np.uint32)
         recs = []
@@ -673,15 +765,16 @@ class MegabatchScheduler:
             # its last row's: the cursor only moves on)
             rec = self._tracked[stream]
             rec.head = max(rec.head, base + n_new)
-            recs.append((stream, eng, key, len(fast), base, -1))
+            recs.append((stream, eng, key, len(fast), base,
+                         self._home_shard))
             real += n_new * len(fast)
         if b_pad > len(entries):
             win[len(entries):] = 0         # bucket padding rows
         gather_ns = TRACER.lap(tok)
         built0 = builds()
         tok = TRACER.open("megabatch.h2d", "tpu", streams=len(entries),
-                          bucket=bucket)
-        dwin = jax.device_put(win)
+                          bucket=bucket, sharded=0)
+        dwin = jax.device_put(win, self._home)
         res = megabatch_window_step(dwin, state)
         res.copy_to_host_async()
         h2d_ns = TRACER.lap(tok)
@@ -699,8 +792,10 @@ class MegabatchScheduler:
                               s_pad: int) -> tuple[int, int]:
         """One bucket sharded over the serving mesh's ``src`` axis.
 
-        Stream i rides global row i; shard k owns the contiguous row
-        block [k·rows_per, (k+1)·rows_per), staged into its OWN host
+        Entries are dealt round the devices (``_rows_per``): entry i
+        rides row i // n_dev of shard i % n_dev, and shard k owns the
+        contiguous row block [k·rows_per, (k+1)·rows_per) of the global
+        pass, staged into its OWN host
         buffer so each device's upload is one contiguous H2D.  The
         global window is assembled from the per-device uploads without
         any host-side concatenation (``make_array_from_single_device_
@@ -708,14 +803,14 @@ class MegabatchScheduler:
         bucket pow2 padding AND the uneven-stream-count remainder — are
         zero windows + zero state, the dryrun's pad-mask rule."""
         import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
         n_dev = len(self._mesh_devices)
-        rows_per = staging.rows_per_shard(len(entries), n_dev)
+        rows_per = self._rows_per(len(entries))
         b_pad = rows_per * n_dev
+        self._built.add(self._program(b_pad, p_pad, s_pad, rows_per))
         bucket = f"{b_pad}x{p_pad}x{s_pad}"
         tok = TRACER.open("megabatch.gather", "tpu", streams=len(entries),
-                          bucket=bucket)
+                          bucket=bucket, sharded=1)
         shard_bufs = [self._buffer(rows_per, p_pad) for _ in range(n_dev)]
         state = np.zeros((b_pad, s_pad, STATE_COLS), np.uint32)
         recs = []
@@ -723,10 +818,11 @@ class MegabatchScheduler:
         filled = [0] * n_dev
         for i, (stream, eng, fast, key, base, n_new) in enumerate(entries):
             real += n_new * len(fast)
-            k, r = divmod(i, rows_per)
+            k, r = i % n_dev, i // n_dev
             staging.gather_window(stream.rtp_ring, base, n_new,
                                   shard_bufs[k][r])
-            state[i, :len(fast)] = self._packed_state(stream, fast, key)
+            state[k * rows_per + r, :len(fast)] = self._packed_state(
+                stream, fast, key)
             rec = self._tracked[stream]
             rec.head = max(rec.head, base + n_new)
             recs.append((stream, eng, key, len(fast), base, k))
@@ -737,15 +833,14 @@ class MegabatchScheduler:
         gather_ns = TRACER.lap(tok)
         built0 = builds()
         tok = TRACER.open("megabatch.h2d", "tpu", streams=len(entries),
-                          bucket=bucket)
-        win_s = NamedSharding(self.mesh, P("src", None, None))
+                          bucket=bucket, sharded=1)
+        win_s = self._src_sharding
         arrs = []
         for k, buf in enumerate(shard_bufs):
-            t_k = time.perf_counter_ns()
+            tok_k = TRACER.open("megabatch.shard_h2d", "tpu", device=k,
+                                rows=filled[k])
             arrs.append(jax.device_put(buf, self._mesh_devices[k]))
-            obs.MEGABATCH_DEVICE_PHASE_SECONDS.observe(
-                (time.perf_counter_ns() - t_k) / 1e9,
-                device=str(k), phase="h2d")
+            self._shard_lap(tok_k, "h2d")
         dwin = jax.make_array_from_single_device_arrays(
             (b_pad, p_pad, staging.ROW_STRIDE), win_s, arrs)
         dstate = jax.device_put(state, win_s)
@@ -757,15 +852,21 @@ class MegabatchScheduler:
         self._inflight.append(
             _InFlight(res, recs, shard_bufs, time.perf_counter_ns(),
                       rows_per=rows_per))
-        self.sharded_passes += 1
-        for k, n in enumerate(filled):
-            if n:                          # pad-only shards count nothing
-                obs.MEGABATCH_DEVICE_PASSES.inc(device=str(k))
-                obs.MEGABATCH_DEVICE_STREAMS.inc(n, device=str(k))
         self._note_pass(len({id(e[0]) for e in entries}),
                         sum(b.nbytes for b in shard_bufs) + state.nbytes,
-                        real, b_pad * p_pad * s_pad)
+                        real, b_pad * p_pad * s_pad, by_shard=filled)
         return gather_ns, h2d_ns
+
+    @staticmethod
+    def _shard_lap(tok, phase: str) -> int:
+        """Close one shard's span and give its lap to the per-device
+        phase histogram: one clock for both (none with the bracket
+        off)."""
+        ns = TRACER.lap(tok)
+        if tok is not None:
+            obs.MEGABATCH_DEVICE_PHASE_SECONDS.observe(
+                ns / 1e9, device=str(tok.args["device"]), phase=phase)
+        return ns
 
     def _consume_mesh(self, inf: _InFlight, ready: bool) -> tuple[int, int]:
         """Harvest one mesh pass per device: fetch each shard's packed
@@ -782,25 +883,23 @@ class MegabatchScheduler:
         shards = sorted(inf.result.addressable_shards,
                         key=lambda s: s.index[0].start or 0)
         for k, sh in enumerate(shards):
-            ents = inf.entries[k * inf.rows_per:(k + 1) * inf.rows_per]
+            ents = inf.entries[k::len(shards)]     # as they were dealt
             if not ents:
                 continue               # padding-only shard: nothing to fetch
             dat = sh.data
-            t_w = time.perf_counter_ns()
             # whole array ready ⇒ every shard is
-            if not (ready or dat.is_ready()):
+            shard_ready = ready or dat.is_ready()
+            if not shard_ready:
                 # the un-hidden remainder of THIS device's compute (a
                 # skewed shard shows up here, not smeared over the mesh)
+                tok = TRACER.open("megabatch.shard_wait", "tpu", device=k,
+                                  rows=len(ents))
                 jax.block_until_ready(dat)
-                obs.MEGABATCH_DEVICE_PHASE_SECONDS.observe(
-                    (time.perf_counter_ns() - t_w) / 1e9,
-                    device=str(k), phase="device_step")
-            t_f = time.perf_counter_ns()
+                fetch_ns += self._shard_lap(tok, "device_step")
+            tok = TRACER.open("megabatch.shard_fetch", "tpu", device=k,
+                              rows=len(ents), ready=int(shard_ready))
             packed = np.asarray(dat)
-            t_d = time.perf_counter_ns()
-            fetch_ns += t_d - t_w
-            obs.MEGABATCH_DEVICE_PHASE_SECONDS.observe(
-                (t_d - t_f) / 1e9, device=str(k), phase="d2h")
+            fetch_ns += self._shard_lap(tok, "d2h")
             obs.TPU_D2H_BYTES.inc(packed.nbytes)
             segs = scatter_affine_segments(
                 packed, [n for (_s, _e, _k, n, _b, _sh) in ents])
@@ -842,7 +941,8 @@ class MegabatchScheduler:
                              in inf.entries])
                 for (stream, eng, key, n_fast, base, _sh), seg in zip(
                         inf.entries, segs):
-                    if self._install_segment(eng, key, seg, base=base):
+                    if self._install_segment(eng, key, seg, base=base,
+                                             shard=_sh):
                         installed += 1
             # honest split (PR 3 attribution discipline): a READY result's
             # fetch is the d2h copy, same meaning as the engine's d2h; a

@@ -436,7 +436,7 @@ def _check_closed_set(w: _ServedWorld, built_after_join) -> None:
         (s for s, _ in w.owned()), key=id)
     if built_after_join is not None:
         assert sched._built == built_after_join
-        assert sched.members(sched.riders()[0]) <= sched._built
+        assert sched.programs(sched.riders()[0]) <= sched._built
 
 
 def _scheduled_run(sched, wire: _Wire, send_fd: int, seed: int):

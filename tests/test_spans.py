@@ -107,6 +107,9 @@ async def test_served_spans_land_on_the_profilers_host_plane(tmp_path):
     rx = _Rx()
     app, streams = _server(rx)
     TRACER.clear()
+    # (a test before this one, in this process, may have left a wake of
+    # its own open: ``_reflect_all`` without ``_wake_close``)
+    TRACER.wake = None
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
@@ -203,7 +206,7 @@ async def test_served_spans_land_on_the_profilers_host_plane(tmp_path):
         ["jit_megabatch_window_step(1)", sleep[1] - 2000, 1000, {}],
         ["jit_megabatch_window_step(1)", sleep[1] + sleep[2] + 1000, 1000,
          {}]]}
-    gaps = reduce_trace.reduce(events)["idle_gaps"]
+    gaps = reduce_trace.reduce(events, chips=1)["idle_gaps"]
     assert gaps[0][0] == "pump.sleep -> megabatch_window_step"
 
 

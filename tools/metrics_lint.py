@@ -171,12 +171,17 @@ def lint_megabatch_devices(registry) -> list[str]:
     (never a backend device-id string — "TPU_v5litepod_4x4_..." would
     shard the family per hostname and break every per-device ratio);
     and the per-device phase vocabulary stays inside the closed
-    ``MESH_PHASES`` subset of ``obs.profile.PHASES``."""
+    ``MESH_PHASES`` subset of ``obs.profile.PHASES``.  The passes and
+    streams families take every pass of a mesh scheduler — one that is
+    not sharded (one row) once, on the device that ran
+    it whole (ISSUE 37) — so the streams that did ride a sharded pass
+    have a counter of their own, ``megabatch_sharded_streams_total``."""
     errs: list[str] = []
     want_labels = {
         "megabatch_device_passes_total": ("device",),
         "megabatch_device_streams_total": ("device",),
         "megabatch_device_phase_seconds": ("device", "phase"),
+        "megabatch_sharded_streams_total": (),
     }
     fams = {}
     for fam_name, labels in want_labels.items():
