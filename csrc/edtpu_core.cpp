@@ -5,11 +5,15 @@
 #include <arpa/inet.h>
 #include <atomic>
 #include <cerrno>
+#include <condition_variable>
 #include <cstring>
-#include <map>
 #include <ctime>
+#include <deque>
+#include <map>
+#include <mutex>
 #include <netinet/in.h>
 #include <netinet/udp.h>
+#include <pthread.h>
 #include <sys/mman.h>
 #include <sys/resource.h>
 #include <sys/socket.h>
@@ -67,6 +71,11 @@ struct StatCells {
       stream_writev_calls{0}, stream_packets{0}, stream_bytes{0};
 };
 StatCells g_stat;
+
+// ed_fanout_send_multi calls in flight at this instant, over every thread,
+// and the most there ever were (ed_sender_stats: the pipeline's "never two
+// sends at once" is read off this, not assumed)
+std::atomic<int64_t> g_sends_in_flight{0}, g_sends_in_flight_max{0};
 
 inline void stat_add(std::atomic<int64_t> &c, int64_t v) {
   c.fetch_add(v, std::memory_order_relaxed);
@@ -546,6 +555,14 @@ int32_t ed_fanout_send_multi(int fd, const uint8_t *ring_data,
                              int32_t n_outs, const ed_sendop *ops,
                              int32_t n_ops, int32_t use_gso) {
   if (param_stride < n_outs) return -EINVAL;
+  struct InFlight {
+    InFlight() {
+      int64_t n = g_sends_in_flight.fetch_add(1, std::memory_order_relaxed);
+      if (n + 1 > g_sends_in_flight_max.load(std::memory_order_relaxed))
+        g_sends_in_flight_max.store(n + 1, std::memory_order_relaxed);
+    }
+    ~InFlight() { g_sends_in_flight.fetch_sub(1, std::memory_order_relaxed); }
+  } in_flight;
   int64_t total = 0;
   for (int32_t s = 0; s < n_src; ++s) {
     const uint32_t *sq = seq_off + static_cast<size_t>(s) * param_stride;
@@ -621,6 +638,142 @@ int32_t ed_scalar_baseline_send(int fd, const uint8_t *ring_data,
     }
   }
   return n_ops;
+}
+
+// ---------------------------------------------------------- send pipeline
+// One sender thread, jobs in submission order (ISSUE 38).  Heap-allocated
+// and never freed: a thread asleep on its condition variable at process
+// exit must not meet a static destructor.  A forked child starts from a
+// fresh one (the thread did not come along).
+}  // extern "C"
+
+namespace {
+struct Sender {
+  std::mutex mu;
+  std::condition_variable work, done;
+  std::deque<ed_send_job *> q;
+  pthread_t th{};
+  bool running = false, stop = false;
+  ed_send_job *cur = nullptr;  // the job being sent
+  int64_t starts = 0, jobs = 0;
+};
+Sender *g_sender = new Sender;
+
+void sender_run(ed_send_job *j) {
+  int64_t calls0 = g_stat.sendmmsg_calls.load(std::memory_order_relaxed) +
+                   g_stat.sendto_calls.load(std::memory_order_relaxed);
+  g_stop_errno = 0;
+  j->start_ns = mono_ns();
+  j->result = ed_fanout_send_multi(
+      j->fd, j->ring_data, j->ring_len, j->capacity, j->slot_size,
+      j->seq_off, j->ts_off, j->ssrc, j->n_src, j->param_stride, j->dest,
+      j->n_outs, j->ops, j->n_ops, j->use_gso);
+  j->done_ns = mono_ns();
+  j->err = g_stop_errno;
+  j->syscalls = g_stat.sendmmsg_calls.load(std::memory_order_relaxed) +
+                g_stat.sendto_calls.load(std::memory_order_relaxed) - calls0;
+}
+
+void *sender_main(void *arg) {
+  Sender &s = *static_cast<Sender *>(arg);
+  std::unique_lock<std::mutex> lk(s.mu);
+  for (;;) {
+    // asleep only on an empty queue: between two jobs of one wake the
+    // next is already waiting
+    s.work.wait(lk, [&] { return s.stop || !s.q.empty(); });
+    if (s.q.empty()) break;  // stop, and everything queued was sent
+    ed_send_job *j = s.q.front();
+    s.q.pop_front();
+    s.cur = j;
+    lk.unlock();
+    sender_run(j);
+    lk.lock();
+    s.cur = nullptr;
+    // last touch of *j: its owner may free it once this reads done
+    __atomic_store_n(&j->state, 2, __ATOMIC_RELEASE);
+    s.done.notify_all();
+  }
+  return nullptr;
+}
+
+void sender_after_fork_child() { g_sender = new Sender; }
+}  // namespace
+
+extern "C" {
+
+int32_t ed_send_job_size(void) {
+  return static_cast<int32_t>(sizeof(ed_send_job));
+}
+
+int32_t ed_sender_submit(ed_send_job *j) {
+  static const int atfork =
+      pthread_atfork(nullptr, nullptr, sender_after_fork_child);
+  (void)atfork;
+  Sender &s = *g_sender;
+  j->result = 0;
+  j->err = 0;
+  j->start_ns = j->done_ns = j->syscalls = 0;
+  j->submit_ns = mono_ns();
+  std::unique_lock<std::mutex> lk(s.mu);
+  // a stop in progress: its thread may already be past its last look at
+  // the queue, so wait the stop out and start another
+  s.done.wait(lk, [&] { return !s.stop; });
+  if (!s.running) {
+    int rc = pthread_create(&s.th, nullptr, sender_main, &s);
+    if (rc) return -rc;
+    s.running = true;
+    s.stop = false;
+    s.starts++;
+  }
+  __atomic_store_n(&j->state, 1, __ATOMIC_RELAXED);
+  s.q.push_back(j);
+  s.jobs++;
+  s.work.notify_one();
+  return 0;
+}
+
+int32_t ed_sender_wait(ed_send_job *j) {
+  int32_t st = __atomic_load_n(&j->state, __ATOMIC_ACQUIRE);
+  if (st == 2) return 0;
+  if (st != 1) return -EINVAL;
+  Sender &s = *g_sender;
+  std::unique_lock<std::mutex> lk(s.mu);
+  s.done.wait(lk, [&] {
+    return __atomic_load_n(&j->state, __ATOMIC_ACQUIRE) == 2;
+  });
+  return 0;
+}
+
+void ed_sender_drain(void) {
+  Sender &s = *g_sender;
+  std::unique_lock<std::mutex> lk(s.mu);
+  s.done.wait(lk, [&] { return s.q.empty() && s.cur == nullptr; });
+}
+
+void ed_sender_stop(void) {
+  Sender &s = *g_sender;
+  pthread_t th;
+  {
+    std::lock_guard<std::mutex> lk(s.mu);
+    if (!s.running || s.stop) return;
+    s.stop = true;
+    th = s.th;
+    s.work.notify_one();
+  }
+  pthread_join(th, nullptr);
+  std::lock_guard<std::mutex> lk(s.mu);
+  s.running = false;
+  s.stop = false;
+  s.done.notify_all();  // a submit that met the stop goes on
+}
+
+void ed_sender_stats(int64_t out[4]) {
+  Sender &s = *g_sender;
+  std::lock_guard<std::mutex> lk(s.mu);
+  out[0] = s.starts;
+  out[1] = s.jobs;
+  out[2] = s.running && !s.stop ? 1 : 0;
+  out[3] = g_sends_in_flight_max.load(std::memory_order_relaxed);
 }
 
 // ---------------------------------------------------------- stream egress

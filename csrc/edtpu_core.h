@@ -187,6 +187,51 @@ int32_t ed_fanout_send_multi(int fd, const uint8_t *ring_data,
                              int32_t n_outs, const ed_sendop *ops,
                              int32_t n_ops, int32_t use_gso);
 
+/* ------------------------------------------------------ send pipeline
+ * ONE native sender thread and a FIFO of jobs (ISSUE 38).  A job is one
+ * ed_fanout_send_multi call — same arguments, same rungs, same prefix
+ * contract — run by the sender while the submitting thread plans and
+ * settles other streams.  Never two sends at once: jobs run one at a
+ * time, in the order they were submitted.  The caller owns the job's
+ * memory and everything it points into until `state` reads done; the
+ * sender never touches a job after that.  The thread is started by the
+ * first submit and sleeps only on an empty queue. */
+typedef struct ed_send_job {
+  /* in: ed_fanout_send_multi's arguments */
+  const uint8_t *ring_data;
+  const int32_t *ring_len;
+  const uint32_t *seq_off;
+  const uint32_t *ts_off;
+  const uint32_t *ssrc;
+  const ed_dest *dest;
+  const ed_sendop *ops;
+  int32_t fd, capacity, slot_size, n_src, param_stride, n_outs, n_ops,
+      use_gso;
+  /* out: what the inline call returns, the JOB's errno (the thread-local
+   * ed_last_send_errno() of the submitting thread knows nothing of it),
+   * its stamps on CLOCK_MONOTONIC and the send syscalls it made */
+  int32_t result;
+  int32_t err;
+  int64_t submit_ns, start_ns, done_ns, syscalls;
+  int32_t state;            /* 0 new, 1 queued or being sent, 2 done */
+  int32_t _pad;
+} ed_send_job;
+
+int32_t ed_send_job_size(void);     /* sizeof: the bridge's ABI handshake */
+/* Queue `job` (returns at once; a stop in progress is waited out first):
+ * 0, or -errno if no thread could start. */
+int32_t ed_sender_submit(ed_send_job *job);
+/* Block until `job` is done: 0, or -EINVAL for a job never submitted. */
+int32_t ed_sender_wait(ed_send_job *job);
+/* Block until nothing is queued and nothing is being sent. */
+void ed_sender_drain(void);
+/* Finish what is queued, then end the thread (the next submit starts
+ * another). */
+void ed_sender_stop(void);
+/* out[0] threads started so far, [1] jobs submitted, [2] 1 while a
+ * thread runs, [3] the most sends ever in flight at one instant. */
+void ed_sender_stats(int64_t out[4]);
+
 /* Framed interleaved-RTSP egress onto ONE stream (TCP) socket
  * (ISSUE 14).  For each slot in `slots`: renders the 4-byte interleave
  * frame ($ | channel | be16 packet-length) plus the 12-byte rewritten

@@ -129,6 +129,62 @@ class Dest(ctypes.Structure):
                 ("_pad", ctypes.c_uint16)]
 
 
+class _SendJobC(ctypes.Structure):
+    """``struct ed_send_job`` (csrc/edtpu_core.h), field for field; the
+    loader checks its size against the library's."""
+    _fields_ = [("ring_data", ctypes.c_void_p), ("ring_len", ctypes.c_void_p),
+                ("seq_off", ctypes.c_void_p), ("ts_off", ctypes.c_void_p),
+                ("ssrc", ctypes.c_void_p), ("dest", ctypes.c_void_p),
+                ("ops", ctypes.c_void_p),
+                ("fd", ctypes.c_int32), ("capacity", ctypes.c_int32),
+                ("slot_size", ctypes.c_int32), ("n_src", ctypes.c_int32),
+                ("param_stride", ctypes.c_int32), ("n_outs", ctypes.c_int32),
+                ("n_ops", ctypes.c_int32), ("use_gso", ctypes.c_int32),
+                ("result", ctypes.c_int32), ("err", ctypes.c_int32),
+                ("submit_ns", ctypes.c_int64), ("start_ns", ctypes.c_int64),
+                ("done_ns", ctypes.c_int64), ("syscalls", ctypes.c_int64),
+                ("state", ctypes.c_int32), ("_pad", ctypes.c_int32)]
+
+
+class SendJob:
+    """One ``fanout_send_multi`` call handed to the native sender thread
+    (``submit=True``): the ticket ``wait`` takes.  Holds every array the
+    job points into until it is dropped — drop it only once ``done``.
+
+    ``result`` is what the inline call returns; ``err`` is the JOB's
+    errno (``last_send_errno()`` is the calling thread's and knows
+    nothing of a send another thread made); ``start_ns`` / ``done_ns``
+    are the send's own stamps on ``CLOCK_MONOTONIC`` — the clock
+    ``time.perf_counter_ns`` reads here."""
+
+    __slots__ = ("c", "n_ops", "use_gso", "_ref", "_keep", "_lib")
+
+    def __init__(self, lib, c: _SendJobC, keep: tuple):
+        self.c = c
+        self.n_ops = c.n_ops
+        self.use_gso = c.use_gso
+        self._ref = ctypes.byref(c)
+        self._keep = keep
+        self._lib = lib
+
+    @property
+    def done(self) -> bool:
+        return self.c.state == 2
+
+    def wait(self) -> "SendJob":
+        """Block (the GIL released) until the sender is through with it."""
+        if self._lib.ed_sender_wait(self._ref) < 0:
+            raise RuntimeError("wait on a send job that was never submitted")
+        return self
+
+    result = property(lambda self: self.c.result)
+    err = property(lambda self: self.c.err)
+    submit_ns = property(lambda self: self.c.submit_ns)
+    start_ns = property(lambda self: self.c.start_ns)
+    done_ns = property(lambda self: self.c.done_ns)
+    syscalls = property(lambda self: self.c.syscalls)
+
+
 def source_digest() -> str:
     """sha256 (first 16 hex) over every tracked build input, in the
     order ``csrc/Makefile`` hashes them."""
@@ -243,6 +299,25 @@ def _load():
             ctypes.c_int32, ctypes.c_int32]
         lib.ed_scalar_baseline_send.restype = ctypes.c_int32
         lib.ed_scalar_baseline_send.argtypes = lib.ed_fanout_send_udp.argtypes
+        # the send pipeline (ISSUE 38): one sender thread, jobs in order
+        lib.ed_send_job_size.restype = ctypes.c_int32
+        lib.ed_send_job_size.argtypes = []
+        if lib.ed_send_job_size() != ctypes.sizeof(_SendJobC):
+            _load_error = (f"ed_send_job ABI mismatch: library has "
+                           f"{lib.ed_send_job_size()} bytes, bridge "
+                           f"expects {ctypes.sizeof(_SendJobC)}")
+            return None
+        jobp = ctypes.POINTER(_SendJobC)
+        lib.ed_sender_submit.restype = ctypes.c_int32
+        lib.ed_sender_submit.argtypes = [jobp]
+        lib.ed_sender_wait.restype = ctypes.c_int32
+        lib.ed_sender_wait.argtypes = [jobp]
+        lib.ed_sender_drain.restype = None
+        lib.ed_sender_drain.argtypes = []
+        lib.ed_sender_stop.restype = None
+        lib.ed_sender_stop.argtypes = []
+        lib.ed_sender_stats.restype = None
+        lib.ed_sender_stats.argtypes = [i64p]
         lib.ed_last_send_errno.restype = ctypes.c_int32
         lib.ed_last_send_errno.argtypes = []
         lib.ed_udp_drain.restype = ctypes.c_int64
@@ -749,7 +824,8 @@ def fanout_send_multi(fd: int, ring_data: np.ndarray, ring_len: np.ndarray,
                       seq_off: np.ndarray, ts_off: np.ndarray,
                       ssrc: np.ndarray, dests, ops, n_ops: int,
                       *, use_gso: bool | int = True,
-                      trace_id: str | None = None) -> int:
+                      trace_id: str | None = None,
+                      submit: bool = False) -> "int | SendJob":
     """Multi-source egress: ``seq_off``/``ts_off``/``ssrc`` are
     [n_src, n_outs]; ONE C call sends every source's window (the hot loop
     makes one Python→C transition per pass instead of n_src).
@@ -757,7 +833,16 @@ def fanout_send_multi(fd: int, ring_data: np.ndarray, ring_len: np.ndarray,
     ``use_gso``: 0/False plain sendmmsg, 1/True UDP_SEGMENT, 2 the
     scalar sendto baseline (the forced ``egress_backend="scalar"``
     rung).  ``trace_id`` stamps the egress span for session correlation
-    (the engine passes the stream's session trace)."""
+    (the engine passes the stream's session trace).
+
+    ``submit``: hand the call to the native sender thread and return its
+    ``SendJob`` at once (the engine's way: the loop thread plans and
+    settles other streams while this one is sent; jobs are sent one at
+    a time, in submission order).  Every array passed must stay
+    unwritten until the job is done; the job keeps them alive.  (A flag
+    and not a function of its own: the benchmark's control,
+    ``benchmark/tests/broken_child.py``, alters the answers by wrapping
+    this one name, so the engine's sends have to enter through it.)"""
     lib = _load()
     assert lib is not None
     assert ring_data.dtype == np.uint8 and ring_data.flags.c_contiguous
@@ -768,6 +853,20 @@ def fanout_send_multi(fd: int, ring_data: np.ndarray, ring_len: np.ndarray,
     # the param row may be wider than the dest table (fewer real sockets
     # than logical subscribers); ops only reference outs < len(dests)
     assert seq.shape[1] >= len(dests)
+    if submit:
+        rlen = np.ascontiguousarray(ring_len, np.int32)
+        c = _SendJobC(
+            ring_data.ctypes.data, rlen.ctypes.data, seq.ctypes.data,
+            ts.ctypes.data, sc.ctypes.data, ctypes.addressof(dests),
+            ctypes.cast(ops, ctypes.c_void_p).value,
+            fd, ring_data.shape[0], ring_data.shape[1], seq.shape[0],
+            seq.shape[1], len(dests), n_ops, int(use_gso))
+        job = SendJob(lib, c, (ring_data, rlen, seq, ts, sc, dests, ops))
+        rc = lib.ed_sender_submit(job._ref)
+        if rc < 0:
+            raise OSError(-rc, "native sender thread: "
+                          + os.strerror(-rc))
+        return job
     # gso: 0 = plain sendmmsg, 1 = GSO, 2 = scalar sendto rung
     opened = _egress_open(lib, "native.egress", trace_id, ops=n_ops,
                           gso=int(use_gso))
@@ -852,6 +951,32 @@ def last_send_errno() -> int:
     lib = _load()
     assert lib is not None
     return lib.ed_last_send_errno()
+
+
+def sender_drain() -> None:
+    """Block until the native sender has nothing queued and nothing in
+    flight (a wake's barrier on its way out through an exception)."""
+    if _lib is not None:
+        _lib.ed_sender_drain()
+
+
+def sender_stop() -> None:
+    """Send what is queued, then end the sender thread (server stop);
+    a later ``submit`` starts another."""
+    if _lib is not None:
+        _lib.ed_sender_stop()
+
+
+def sender_stats() -> dict[str, int]:
+    """``starts`` (threads started so far), ``jobs`` (submitted),
+    ``running`` and ``max_in_flight`` — the most ``fanout_send_multi``
+    calls ever running at one instant, on any thread."""
+    lib = _load()
+    assert lib is not None
+    out = (ctypes.c_int64 * 4)()
+    lib.ed_sender_stats(out)
+    return dict(zip(("starts", "jobs", "running", "max_in_flight"),
+                    (int(v) for v in out)))
 
 
 def scalar_baseline_send(fd: int, ring_data: np.ndarray,

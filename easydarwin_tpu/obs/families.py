@@ -345,6 +345,23 @@ EGRESS_BUSY_SECONDS = REGISTRY.counter(
     "(clock_gettime deltas in ed_stats; the denominator for per-call "
     "egress cost and the native half of the egress_native phase)")
 
+#: the send pipeline (ISSUE 38; ``relay.pump._step``): a wake's UDP sends
+#: are jobs of the one native sender thread, begun in roster order and
+#: settled when their results are in.  Counted once a wake that had a job
+EGRESS_PIPELINE_SECONDS = REGISTRY.counter(
+    "egress_pipeline_seconds_total",
+    "Seconds of the native sender's jobs: send (each job's start -> done "
+    "on the sender thread) and hidden (per job, its send seconds less "
+    "what the loop thread spent blocked waiting for it, floored at 0: "
+    "the sending that went on while the loop thread planned and settled "
+    "other streams); hidden / send = how much of the wire time a wake "
+    "overlaps with its Python", labels=("part",))
+EGRESS_PIPELINE_JOBS = REGISTRY.counter(
+    "egress_pipeline_jobs_total",
+    "Send jobs handed to the native sender thread (one per stream step "
+    "with a due UDP cohort; a rung's fallback is a second job); jobs / "
+    "pump_wakes_total = how many sends a wake has to put back to back")
+
 # --------------------------------------------------------- egress backends
 # The boot-time probe ladder (ISSUE 8): io_uring → GSO/sendmmsg →
 # scalar.  ``egress_backend_info`` is an info-style gauge — exactly one

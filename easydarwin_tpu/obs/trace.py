@@ -56,7 +56,8 @@ SPANS = (
     "pump.cluster_tick",
     "engine.step", "engine.plan", "engine.prime", "engine.ring_sync",
     "engine.params",
-    "engine.egress", "engine.account", "engine.rtcp",
+    "engine.egress", "engine.account", "engine.rtcp", "engine.settle",
+    "egress.wait",
     "megabatch.harvest", "megabatch.fetch", "megabatch.prime",
     "megabatch.dispatch", "megabatch.gather", "megabatch.h2d",
     "megabatch.shard_h2d", "megabatch.shard_wait", "megabatch.shard_fetch",

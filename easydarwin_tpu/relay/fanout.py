@@ -169,6 +169,51 @@ def _column_runs(parts: list) -> list:
             for lo, hi in zip([0] + cuts, cuts + [len(cols)])]
 
 
+class _Scatter:
+    """One step's UDP send between its two halves (``_udp_plan`` →
+    ``_udp_settle``): the units and op list the plan half built, the
+    rung it chose and the job it submitted."""
+
+    __slots__ = ("due", "units", "total", "ops_np", "dests", "params",
+                 "job", "res", "backend", "used_gso", "uring_failed",
+                 "uring_err")
+
+
+class _Inline:
+    """The result of a send made on the loop thread (the io_uring rung),
+    in the form a finished ``native.SendJob`` has."""
+
+    __slots__ = ("result", "err", "start_ns", "done_ns")
+
+    def __init__(self, result, err, start_ns, done_ns):
+        self.result, self.err = result, err
+        self.start_ns, self.done_ns = start_ns, done_ns
+
+
+class _Pass:
+    """One step between ``begin`` and ``finish``."""
+
+    __slots__ = ("stream", "now_ms", "plan", "begin_ns", "udp", "tcp",
+                 "jobs", "send_ns", "wait_ns", "hidden_ns")
+
+    def __init__(self, stream, now_ms):
+        self.stream, self.now_ms = stream, now_ms
+        self.plan = None                # None: the step left by its first exit
+        self.begin_ns = 0               # what the begin half took
+        self.udp: _Scatter | None = None
+        self.tcp: tuple | None = None   # ``_tcp_scatter``'s arguments
+        #: the send jobs this step settled, their start → done seconds,
+        #: what the loop thread spent blocked on them, and per job the
+        #: send less that wait (floored at 0)
+        self.jobs = self.send_ns = self.wait_ns = self.hidden_ns = 0
+
+    @property
+    def done(self) -> bool:
+        """Nothing of this step is still with the sender: ``finish``
+        will not block."""
+        return self.udp is None or self.udp.job is None or self.udp.job.done
+
+
 class TpuFanoutEngine:
     """Batched fan-out for one stream, built and stepped once a wake by
     the pump (``relay/pump.py``).  The relay state stays in the stream
@@ -276,6 +321,9 @@ class TpuFanoutEngine:
         self._pass_walked = 0
         self._pass_due = 0
         self._profiled = False
+        #: the pass ``begin`` returned and ``finish`` has not taken yet:
+        #: the scratch above is that pass's (None between steps)
+        self.open_pass: _Pass | None = None
         #: ``trace_id`` of the stream being stepped, on every child span
         self._span_args: dict = {}
         # a bracket that held an XLA build (a cold pass's compile, or a
@@ -507,8 +555,13 @@ class TpuFanoutEngine:
         self._pass_phases[key] = self._pass_phases.get(key, 0) + dur_ns
 
     def _open(self, name: str, **args):
-        """Open one child span of ``engine.step`` (``obs.trace``)."""
+        """Open one span of a step's two halves (``obs.trace``)."""
         return TRACER.open(name, "tpu", **self._span_args, **args)
+
+    def _spans_of(self, stream: RelayStream) -> None:
+        """Every span opened from here on carries ``stream``'s trace."""
+        self._span_args = ({} if stream.trace_id is None
+                           else {"trace_id": stream.trace_id})
 
     def _close(self, span, phase: str | None = None, engine: str = "native",
                built0: float | None = None, **args) -> int:
@@ -523,24 +576,62 @@ class TpuFanoutEngine:
         return end
 
     def step(self, stream: RelayStream, now_ms: int) -> int:
-        self._span_args = ({} if stream.trace_id is None
-                           else {"trace_id": stream.trace_id})
+        """One pass over ``stream``, begun and finished in this call
+        (every caller with no pump)."""
+        return self.finish(self.begin(stream, now_ms))
+
+    def begin(self, stream: RelayStream, now_ms: int) -> _Pass:
+        """The first half of a step: the plan, the device params, the op
+        list, and the UDP send handed to the native sender.  Returns at
+        once; ``finish`` takes what it returns.  Between the two the
+        ring's slots, the param rows and the dest table the job points
+        into must not be written — the pump finishes every pass it began
+        before the wake ends."""
+        self._spans_of(stream)
         step_span = self._open("engine.step")
         t0 = t0_of(step_span)
+        ps = _Pass(stream, now_ms)
         ring = stream.rtp_ring
         if not stream.num_outputs or len(ring) == 0:
-            TRACER.close(step_span, outputs=stream.num_outputs, sent=0)
+            TRACER.close(step_span, outputs=stream.num_outputs)
             obs.ENGINE_STEPS.inc(result="idle")
-            return 0
-        profiled = self._profiled = PROFILER.enabled
+            return ps
+        self._profiled = PROFILER.enabled
         self._pass_phases = {}
         self._pass_wire_bytes = 0
         self._pass_walked = self._pass_due = 0
-        plan = self.plan(stream, now_ms)
-        fast, tcp, slow = plan.udp, plan.tcp, plan.slow
+        plan = ps.plan = self.plan(stream, now_ms)
+        if plan.udp or plan.tcp:
+            self._native_begin(ps)
+        ps.begin_ns = TRACER.close(step_span, outputs=plan.n_outputs,
+                                   due_outputs=self._pass_due) - t0
+        self.open_pass = ps
+        return ps
+
+    def finish(self, ps: _Pass) -> int:
+        """The second half: settle the UDP job (blocking until the sender
+        is through with it), then the stream's TCP and batch-header
+        outputs and its RTCP, inline and in that order."""
+        plan = ps.plan
+        if plan is None:
+            return 0
+        self.open_pass = None
+        stream, now_ms = ps.stream, ps.now_ms
+        self._spans_of(stream)
+        settle_span = self._open("engine.settle")
+        t0 = t0_of(settle_span)
+        profiled = self._profiled
         sent = 0
-        if fast or tcp:
-            sent += self._native_step(stream, plan, now_ms)
+        if ps.udp is not None:
+            try:
+                sent += self._udp_settle(ps)
+            except BaseException:
+                stream.touch_plan()     # due cohorts may be half settled
+                raise
+        if ps.tcp is not None:
+            sent += self._tcp_scatter(stream, plan.tcp, len(plan.udp),
+                                      *ps.tcp, now_ms)
+        slow = plan.slow
         if slow:
             sent += self._batch_header_step(stream, slow, now_ms)
         # RTCP relay + SR origination, identical to the scalar path
@@ -553,7 +644,8 @@ class TpuFanoutEngine:
             # splitting the bracket so a mixed pass neither hides the
             # batch path's share under "native" nor double-counts the
             # wall time in the session's phase_ns
-            engines = [e for e, ran in (("native", bool(fast) or bool(tcp)),
+            engines = [e for e, ran in (("native",
+                                         bool(plan.udp) or bool(plan.tcp)),
                                         ("batch", bool(slow))) if ran]
             share = dt // len(engines)
             for i, e in enumerate(engines):
@@ -566,8 +658,9 @@ class TpuFanoutEngine:
         stream.stats.packets_out += sent
         self.steps += 1
         self.packets_sent += sent
-        dur = TRACER.close(step_span, sent=sent, outputs=plan.n_outputs,
-                           due_outputs=self._pass_due) - t0
+        # the loop thread's time in both halves, its waits for the
+        # sender included
+        dur = ps.begin_ns + TRACER.close(settle_span, sent=sent) - t0
         obs.TPU_PASS_SECONDS.observe(dur / 1e9, stage="engine_step")
         obs.TPU_PASSES.inc()
         # idle: no cohort was past its hold and nothing else was sent
@@ -724,18 +817,20 @@ class TpuFanoutEngine:
                                      stage="device_params")
         return self._params
 
-    def _native_step(self, stream: RelayStream, plan: _Plan,
-                     now_ms: int) -> int:
-        """Send every eligible (packet, output) pair through the native
+    def _native_begin(self, ps: _Pass) -> None:
+        """Every eligible (packet, output) pair goes through the native
         senders — ONE sendmmsg/GSO scatter for the UDP set, one framed
         writev/io_uring batch per interleaved-TCP connection — all from
         ONE device param pass (the affine rewrite plus the interleave
-        channel column ride the same query).
+        channel column ride the same query).  This is the begin half:
+        the UDP scatter is planned and submitted (``ps.udp``), the TCP
+        scatter's arguments are left for ``finish`` (``ps.tcp``).
 
         Due selection is bucket-major: one ``searchsorted`` over the
         buckets' deadlines, then one comparison per cohort.  A stream
         with nothing due returns here, before the device params and
         before any output object is touched."""
+        stream, plan, now_ms = ps.stream, ps.plan, ps.now_ms
         ring = stream.rtp_ring
         tcp = plan.tcp
         # extracting the host window view is part of staging it: one
@@ -755,11 +850,11 @@ class TpuFanoutEngine:
                 start = o._bookmark
         if start >= head:                   # everyone has caught up
             TRACER.close(tok)
-            return 0
+            return
         ids, lengths, _flags = ring.window_meta(start, head - start)
         if len(ids) == 0:
             TRACER.close(tok)
-            return 0
+            return
         start = int(ids[0])                 # window_meta clamps to tail
         idx = (ids % ring.capacity).astype(np.int32)
         arrivals = ring.arrival[idx]        # nondecreasing (ingest clock)
@@ -778,7 +873,7 @@ class TpuFanoutEngine:
                             due.append((bi, mark, hi))
         if not due and not tcp:
             TRACER.close(tok)
-            return 0
+            return
         valid = lengths >= 12
         if not self.megabatch_owned:
             # scheduler-owned streams skip the per-wake device append:
@@ -795,21 +890,18 @@ class TpuFanoutEngine:
         self.h2d_window_equiv_bytes += live_window * (self.prefix_width + 8)
         seq_off, ts_off, ssrc, chan = self._device_params(
             plan.fast, plan.key, ring, now_ms)
-        sent = 0
         if due:
             try:
-                sent += self._udp_scatter(stream, plan, due, start, ids,
-                                          idx, valid, lengths, seq_off,
-                                          ts_off, ssrc)
+                ps.udp = self._udp_plan(stream, plan, due, start, ids, idx,
+                                        valid, lengths, seq_off, ts_off,
+                                        ssrc)
             except BaseException:
-                stream.touch_plan()     # due cohorts may be half settled
+                stream.touch_plan()     # a runt-only span was being filed
                 raise
         if tcp:
-            sent += self._tcp_scatter(stream, tcp, len(plan.udp), start,
-                                      ids, idx, arrivals, valid, lengths,
-                                      seq_off, ts_off, ssrc, chan, now_ms)
+            ps.tcp = (start, ids, idx, arrivals, valid, lengths, seq_off,
+                      ts_off, ssrc, chan)
         self.native_passes += 1
-        return sent
 
     def _clamp_cohorts(self, plan: _Plan, tail: int) -> int:
         """The ring evicted past a stalled cohort: it resumes at the
@@ -819,12 +911,54 @@ class TpuFanoutEngine:
                 _file(co, plan.udp, co.pop(mark), tail)
         return tail
 
-    def _udp_scatter(self, stream: RelayStream, plan: _Plan, due, start,
-                     ids, idx, valid, lengths, seq_off, ts_off,
-                     ssrc) -> int:
+    def _submit(self, ring, sc: _Scatter, ops, n_ops: int, use_gso,
+                trace_id):
+        """One UDP send job to the native sender thread."""
+        from .. import native
+        seq_off, ts_off, ssrc = sc.params
+        return native.fanout_send_multi(
+            self.egress_fd, ring.data, ring.length, seq_off, ts_off, ssrc,
+            sc.dests, ops, n_ops, use_gso=use_gso, trace_id=trace_id,
+            submit=True)
+
+    def _await(self, ps: _Pass, job):
+        """Block until the sender is through with ``job`` (``egress.wait``
+        is the loop thread blocked), file the send as ``native.egress``
+        from the job's own stamps, and account it to the pass: its send
+        seconds into the ``egress_native`` phase, and what of them the
+        loop thread did not spend waiting as hidden."""
+        waited = 0
+        if not job.done:
+            tok = self._open("egress.wait")
+            t0 = t0_of(tok)
+            job.wait()
+            waited = TRACER.close(tok) - t0
+        send_ns = job.done_ns - job.start_ns
+        if TRACER.enabled:
+            args = dict(self._span_args, ops=job.n_ops, gso=job.use_gso,
+                        sent=job.result, datagrams=max(job.result, 0),
+                        syscalls=job.syscalls,
+                        queued_us=(job.start_ns - job.submit_ns) // 1000)
+            if TRACER.wake is not None:
+                args["wake"] = TRACER.wake
+            TRACER.add("native.egress", job.start_ns, send_ns,
+                       cat="native", **args)
+        if self._profiled:
+            self._phase_add("egress_native", send_ns)
+        ps.jobs += 1
+        ps.send_ns += send_ns
+        ps.wait_ns += waited
+        ps.hidden_ns += max(send_ns - waited, 0)
+        return job
+
+    def _udp_plan(self, stream: RelayStream, plan: _Plan, due, start,
+                  ids, idx, valid, lengths, seq_off, ts_off,
+                  ssrc) -> _Scatter | None:
+        """The plan half of the UDP scatter: the due cohorts' units, the
+        op list, and the send — submitted to the native sender, not
+        waited for.  None where every due span was runts."""
         from .. import native
         ring = stream.rtp_ring
-        delay = stream.settings.bucket_delay_ms
         fast = plan.udp
         cohorts = plan.cohorts
         # egress_native starts HERE: everything from params-in-hand to
@@ -865,7 +999,7 @@ class TpuFanoutEngine:
                 _file(cohorts[bi], fast, cols, hi_abs)  # runt-only: skip
             TRACER.close(egress, outputs=len(fast), due_outputs=n_due,
                          sent=0)
-            return 0
+            return None
         # the SAME op list, row for row, as one span per output would
         # build: columns ascend (fast order is bucket-major) and a
         # cohort's columns each carry its slots
@@ -877,58 +1011,86 @@ class TpuFanoutEngine:
                 ops_np[pos:pos + n, 0] = np.tile(slots, len(cols))
                 ops_np[pos:pos + n, 1] = np.repeat(cols, len(pids))
                 pos += n
-        dests = self._dests_for(plan)
+        sc = _Scatter()
+        sc.due, sc.units, sc.total = due, units, total
+        sc.ops_np = ops_np
+        sc.dests = self._dests_for(plan)
+        sc.params = (seq_off, ts_off, ssrc)
+        sc.job = sc.res = None
+        sc.used_gso = sc.uring_failed = False
+        sc.uring_err = 0
         ops = native.ops_from_numpy(ops_np)
         trace_id = stream.trace_id
         backend = self.effective_backend()
-        used_backend = backend
-        used_gso = False
-        uring_failed = False
-        uring_err = 0
-        r = -1
         if backend == "io_uring":
             # one linked-SQE submission per chain instead of one
             # sendmmsg slot per run — EAGAIN/hard semantics identical,
-            # so the bookmark accounting below is backend-blind
+            # so the bookmark accounting is backend-blind.  The ring
+            # belongs to the loop thread: this rung sends here, inline
+            t_send = time.perf_counter_ns()
             r = self.uring.send_multi(
-                ring.data, ring.length, seq_off, ts_off, ssrc, dests,
+                ring.data, ring.length, seq_off, ts_off, ssrc, sc.dests,
                 ops, total, trace_id=trace_id)
             if r < 0:
                 # whole-batch ring failure with nothing sent: serve this
                 # pass from the GSO rung; strike io_uring only if a
                 # lower rung proves the destinations are fine
-                uring_failed = True
-                uring_err = native.last_send_errno() or -r
-                backend = used_backend = "gso"
+                sc.uring_failed = True
+                sc.uring_err = native.last_send_errno() or -r
+                backend = "gso"
+            else:
+                sc.res = _Inline(r, native.last_send_errno(), t_send,
+                                 time.perf_counter_ns())
         if backend == "scalar":
             # forced per-datagram sendto baseline (egress_backend=scalar)
-            r = native.fanout_send_multi(
-                self.egress_fd, ring.data, ring.length, seq_off, ts_off,
-                ssrc, dests, ops, total, use_gso=2, trace_id=trace_id)
+            sc.job = self._submit(ring, sc, ops, total, 2, trace_id)
         elif backend == "gso":
-            used_gso = not self._gso_disabled
-            r = -1
-            if used_gso:
-                r = native.fanout_send_multi(
-                    self.egress_fd, ring.data, ring.length, seq_off,
-                    ts_off, ssrc, dests, ops, total, use_gso=True,
-                    trace_id=trace_id)
-            if r < 0:                       # GSO off/unsupported/failed
+            sc.used_gso = not self._gso_disabled
+            sc.job = self._submit(ring, sc, ops, total, sc.used_gso,
+                                  trace_id)
+        sc.backend = backend
+        # the Python-side bracket of the send: the op-list build and the
+        # hand-over (an io_uring send too, made right here); the job's
+        # own send seconds join it at settle.  Filed under the BACKEND's
+        # phase so per-pass egress cost is comparable across rungs on
+        # one dashboard
+        self._close(egress, "egress_io_uring" if backend == "io_uring"
+                    else "egress_native", outputs=len(fast),
+                    due_outputs=n_due, ops=total)
+        return sc
+
+    def _udp_settle(self, ps: _Pass) -> int:
+        """The settle half: the job's result in hand, the rungs'
+        fallbacks (each a second job, submitted and awaited here), then
+        the bookmark and stat accounting."""
+        from .. import native
+        stream, plan, sc = ps.stream, ps.plan, ps.udp
+        ring = stream.rtp_ring
+        delay = stream.settings.bucket_delay_ms
+        fast = plan.udp
+        cohorts = plan.cohorts
+        due, units, total, ops_np = sc.due, sc.units, sc.total, sc.ops_np
+        trace_id = stream.trace_id
+        used_gso = sc.used_gso
+        res = sc.res if sc.job is None else self._await(ps, sc.job)
+        r = res.result
+        if sc.backend == "gso":
+            if used_gso and r < 0:          # GSO unsupported/failed
                 used_gso = False
-                r = native.fanout_send_multi(
-                    self.egress_fd, ring.data, ring.length, seq_off,
-                    ts_off, ssrc, dests, ops, total, use_gso=False,
-                    trace_id=trace_id)
+                res = self._await(ps, self._submit(
+                    ring, sc, native.ops_from_numpy(ops_np), total, False,
+                    trace_id))
+                r = res.result
                 if r >= 0 and not self._gso_disabled:
                     self._gso_strikes += 1  # GSO failed, plain path works
                     if self._gso_strikes >= 2:
                         self._gso_disabled = True
-            elif self._gso_strikes:
+            elif used_gso and self._gso_strikes:
                 self._gso_strikes = 0
-            if uring_failed and r >= 0:
+            if sc.uring_failed and r >= 0:
                 # io_uring failed outright but a lower rung delivered:
                 # a backend strike, not a destination failure
-                self._note_uring_failure(uring_err)
+                self._note_uring_failure(sc.uring_err)
         hard = False
         if r < 0:
             # hard error with nothing sent: fall through to accounting as
@@ -937,7 +1099,8 @@ class TpuFanoutEngine:
             hard = True
             r = 0
         elif r < total:
-            hard = native.last_send_errno() not in (
+            # the JOB's errno: the sender thread's, carried in its result
+            hard = res.err not in (
                 0, errno_mod.EAGAIN, errno_mod.EWOULDBLOCK)
             if hard and used_gso:
                 # A partial GSO pass stopped on a hard errno.  On a kernel
@@ -950,26 +1113,19 @@ class TpuFanoutEngine:
                 if self._gso_strikes >= 2:
                     self._gso_disabled = True
                 rem = ops_np[r:]            # row slice stays C-contiguous
-                r2 = native.fanout_send_multi(
-                    self.egress_fd, ring.data, ring.length, seq_off,
-                    ts_off, ssrc, dests, native.ops_from_numpy(rem),
-                    total - r, use_gso=False, trace_id=trace_id)
-                if r2 >= 0:
-                    r += r2
-                    hard = r < total and native.last_send_errno() not in (
+                res2 = self._await(ps, self._submit(
+                    ring, sc, native.ops_from_numpy(rem), total - r, False,
+                    trace_id))
+                if res2.result >= 0:
+                    res = res2
+                    r += res2.result
+                    hard = r < total and res2.err not in (
                         0, errno_mod.EAGAIN, errno_mod.EWOULDBLOCK)
-        # the packets are ON THE WIRE here: latency stamps below use this
-        # instant, not a fresh read after the accounting walk (which
-        # would bill our own bookkeeping to the network)
-        # every native send this pass (op-list build, backend try,
-        # lower-rung fallback, GSO remainder retry) — the Python-side
-        # bracket; csrc's ed_stats.send_ns carries the in-library
-        # half.  Filed under the BACKEND's phase so per-pass egress
-        # cost is comparable across rungs on one dashboard
-        wire_ns = self._close(
-            egress, "egress_io_uring" if used_backend == "io_uring"
-            else "egress_native", outputs=len(fast), due_outputs=n_due,
-            sent=int(r))
+        # the packets were ON THE WIRE at the job's done stamp: latency
+        # stamps below use that instant, not this thread's clock now
+        # (which would bill the other streams' plans and our own
+        # bookkeeping to the network)
+        wire_ns = res.done_ns
         # bookmark/stat accounting by cohort, exact under partial
         # (EAGAIN) sends: a partial send splits the cohort at its
         # boundary, and a straggler is a cohort of one

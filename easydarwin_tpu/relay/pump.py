@@ -29,11 +29,12 @@ all of them when the engagement flips: a step is its only reader.
 
 from __future__ import annotations
 
+import collections
 import itertools
 import time
 import weakref
 
-from .. import obs
+from .. import native, obs
 from .fanout import TpuFanoutEngine
 
 #: a stream's path through one wake: ``RelayStream.reflect``, its own
@@ -74,34 +75,57 @@ def needs_step(stream, t: int, route: int | None = None) -> bool:
 
 def _step(entries, t: int, ladder, log, label: str, timed: bool):
     """Step every entry once; returns (packets sent, the slowest
-    stream's trace id when ``timed``).  ``ladder`` hears how the DEVICE
-    path fared; an oracle-path failure (one broken output) is logged
-    only — it is not device health and must not move a rung.  Leaves in
-    each stream's cell what ``needs_step`` compares the next wake's
-    state with, and in its pump's ready set the mark of a stream that is
-    to be stepped again whatever happens."""
-    sent = 0
+    stream's trace id when ``timed``, and of the send jobs the native
+    sender was handed: their number, their send ns and how many of those
+    the loop thread did not spend waiting).
+
+    **Begin every entry in roster order, finish them in the same
+    order.**  An engine's ``begin`` plans the stream and submits its UDP
+    send to the one native sender thread; ``finish`` settles it from the
+    job's result and runs the rest of the stream's pass.  After each
+    begin the loop finishes whatever earlier entries' jobs are already
+    done, and goes on; at the end it waits for the rest.  So the sends
+    of one wake go out back to back while this thread plans and settles,
+    never two at once, each stream's own order of events as it was.
+    **The call returns with no job in flight**: the ring slots, param
+    rows and dest tables a job points into are written only between
+    wakes.
+
+    ``ladder`` hears how the DEVICE path fared; an oracle-path failure
+    (one broken output) is logged only — it is not device health and
+    must not move a rung.  Leaves in each stream's cell what
+    ``needs_step`` compares the next wake's state with, and in its
+    pump's ready set the mark of a stream that is to be stepped again
+    whatever happens."""
+    sent = jobs = send_ns = hidden_ns = 0
     worst_ns, worst_trace = -1, None
-    for path, stream, eng, route in entries:
+    #: begun, not finished: [entry, pass | None, stalls before, raised,
+    #: ns spent on it so far]
+    pending: collections.deque = collections.deque()
+
+    def finish(rec) -> None:
+        nonlocal sent, jobs, send_ns, hidden_ns, worst_ns, worst_trace
+        (path, stream, eng, route), ps, pre_stalls, raised, el = rec
         s0 = time.perf_counter_ns() if timed else 0
-        pre_stalls = stream.stats.stalls
-        raised = False
         # per-stream guard: one bad output (broken socket, buggy
         # transcoder tap) must never halt fan-out for the rest
-        try:
-            if eng is not None:
-                sent += eng.step(stream, t)
+        if eng is not None and not raised:
+            try:
+                if ps is not None:
+                    sent += eng.finish(ps)
                 if ladder is not None:
                     ladder.note_device_ok(path)
-            else:
-                sent += stream.reflect(t)
-        except Exception as e:
-            raised = True
-            if eng is not None and ladder is not None:
-                # bounded retry with backoff; a rung only past the budget
-                ladder.note_device_error(path)
-            if log:
-                log.warning(f"{label}reflect error on {path}: {e!r}")
+            except Exception as e:
+                raised = True
+                if ladder is not None:
+                    # bounded retry with backoff; a rung only past the budget
+                    ladder.note_device_error(path)
+                if log:
+                    log.warning(f"{label}reflect error on {path}: {e!r}")
+        if ps is not None:
+            jobs += ps.jobs
+            send_ns += ps.send_ns
+            hidden_ns += ps.hidden_ns
         try:
             for out in stream.tickable_outputs:
                 sent += out.tick(t)     # reliable-UDP retransmit sweep
@@ -131,14 +155,52 @@ def _step(entries, t: int, ladder, log, label: str, timed: bool):
             else:
                 c.ready.discard(c.key)
         if timed:
-            el = time.perf_counter_ns() - s0
+            el += time.perf_counter_ns() - s0
             if el > worst_ns:
                 worst_ns, worst_trace = el, stream.trace_id
-    return sent, worst_trace
+
+    try:
+        for entry in entries:
+            path, stream, eng, _route = entry
+            s0 = time.perf_counter_ns() if timed else 0
+            pre_stalls = stream.stats.stalls
+            ps, raised = None, False
+            try:
+                if eng is None:
+                    sent += stream.reflect(t)
+                else:
+                    if eng.open_pass is not None:
+                        # an engine shared by two entries holds one
+                        # pass's scratch: settle up to the first
+                        while pending:
+                            finish(pending.popleft())
+                    ps = eng.begin(stream, t)
+            except Exception as e:
+                raised = True
+                if eng is not None and ladder is not None:
+                    ladder.note_device_error(path)
+                if log:
+                    log.warning(f"{label}reflect error on {path}: {e!r}")
+            pending.append((entry, ps, pre_stalls, raised,
+                            time.perf_counter_ns() - s0 if timed else 0))
+            while pending and (pending[0][1] is None or pending[0][1].done):
+                finish(pending.popleft())
+        while pending:
+            finish(pending.popleft())   # blocks on the sender
+    finally:
+        # the barrier, whatever the way out: a BaseException may have
+        # left a popped entry's job with the sender (one uncontended
+        # mutex when there is none)
+        native.sender_drain()
+    if jobs:
+        obs.EGRESS_PIPELINE_JOBS.inc(jobs)
+        obs.EGRESS_PIPELINE_SECONDS.inc(send_ns / 1e9, part="send")
+        obs.EGRESS_PIPELINE_SECONDS.inc(hidden_ns / 1e9, part="hidden")
+    return sent, worst_trace, (jobs, send_ns, hidden_ns)
 
 
 def serve(live, vod, sched, t: int, *, min_streams: int = 1, ladder=None,
-          log=None, stepped=None) -> int:
+          log=None, stepped=None) -> tuple[int, tuple]:
     """One wake over a built roster: ``live`` entries inside the
     ``live_relay`` ledger unit — ``stepped`` of them where the caller
     keeps a ready set, the scheduler handed the owned pairs among them
@@ -147,7 +209,8 @@ def serve(live, vod, sched, t: int, *, min_streams: int = 1, ladder=None,
     ``DEVICE`` for the whole wake without a ``sched``, under
     ``min_streams`` owned entries, or when the harvest raises: a
     scheduler failure degrades to per-stream stepping, never to a halted
-    pump.  The one writer of ``TpuFanoutEngine.megabatch_owned``."""
+    pump.  The one writer of ``TpuFanoutEngine.megabatch_owned``.
+    Returns (packets sent, ``_step``'s tally of the send jobs)."""
     LEDGER = obs.LEDGER             # (tests put a private one there)
     roster = live + vod if vod else live
     owned = [(s, eng) for _p, s, eng, r in roster if r == OWNED]
@@ -195,11 +258,13 @@ def serve(live, vod, sched, t: int, *, min_streams: int = 1, ladder=None,
     # the slowest stream's trace_id rides the unit's record (the
     # critical-path correlation a p99 sample decomposes by)
     _u = LEDGER.unit_start("live_relay")
-    sent, worst = _step(stepped, t, ladder, log, "", LEDGER.enabled)
+    sent, worst, jobs = _step(stepped, t, ladder, log, "", LEDGER.enabled)
     LEDGER.unit_end(_u, items=max(len(stepped), 1), trace_id=worst)
     if vod:
         _u = LEDGER.unit_start("vod_fill")
-        sent += _step(vod, t, None, log, "vod ", False)[0]
+        v_sent, _worst, v_jobs = _step(vod, t, None, log, "vod ", False)
+        sent += v_sent
+        jobs = tuple(a + b for a, b in zip(jobs, v_jobs))
         LEDGER.unit_end(_u, items=len(vod))
     if owned:
         _u = LEDGER.unit_start("megabatch", part="stage")
@@ -212,7 +277,7 @@ def serve(live, vod, sched, t: int, *, min_streams: int = 1, ladder=None,
             if log:
                 log.warning(f"megabatch stage: {e!r}")
         LEDGER.unit_end(_u, items=len(owned))
-    return sent
+    return sent, jobs
 
 
 def wake(pairs, sched, t: int, *, min_streams: int = 1) -> int:
@@ -221,7 +286,7 @@ def wake(pairs, sched, t: int, *, min_streams: int = 1) -> int:
     takes the scalar loop."""
     return serve([(s.session_path, s, eng,
                    SCALAR if eng is None else OWNED) for s, eng in pairs],
-                 [], sched, t, min_streams=min_streams)
+                 [], sched, t, min_streams=min_streams)[0]
 
 
 class Pump:
@@ -252,11 +317,14 @@ class Pump:
         self._keys = itertools.count(1)
         #: the last wake: its clock, its live roster, the entries of it
         #: that were stepped (the deadlines pass re-arms those), how many
-        #: entries it served in all and the packets it sent
+        #: entries it served in all, the packets it sent and the send
+        #: jobs it handed the native sender: (their number, their send
+        #: ns, the ns of those the loop thread did not wait for)
         self.t = 0
         self.live: list = []
         self.stepped: list = []
         self.streams = self.sent = 0
+        self.jobs = (0, 0, 0)
 
     def engine_for(self, stream) -> TpuFanoutEngine:
         eng = self.engines.get(stream)
@@ -323,9 +391,9 @@ class Pump:
         self.streams = len(live) + len(vod)
         obs.PUMP_ROSTER_STREAMS.inc(len(live))
         obs.PUMP_STEPPED_STREAMS.inc(len(stepped))
-        self.sent = serve(live, vod, self.megabatch, t,
-                          min_streams=min_streams, ladder=self.ladder,
-                          log=self.error_log, stepped=stepped)
+        self.sent, self.jobs = serve(
+            live, vod, self.megabatch, t, min_streams=min_streams,
+            ladder=self.ladder, log=self.error_log, stepped=stepped)
         return self.sent
 
     def arm(self, sessions) -> None:

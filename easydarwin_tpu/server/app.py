@@ -556,6 +556,10 @@ class StreamingServer:
         self.transcodes.stop_all()
         await self.pulls.stop_all()
         await self.rtsp.stop()
+        # the native sender thread goes with the server (no wake is
+        # running: every job it was handed has been settled)
+        from .. import native
+        native.sender_stop()
         if self.uring_egress is not None:
             self.uring_egress.close()
             self.uring_egress = None
@@ -1236,11 +1240,15 @@ class StreamingServer:
         self._wake_open_rec = None
         obs.LEDGER.end_wake()
         mb = self.pump.megabatch
+        jobs, send_ns, hidden_ns = self.pump.jobs
         end = TRACER.close(span, streams=self.pump.streams,
                            stepped=len(self.pump.stepped),
                            handed=mb.handed if mb else 0,
                            walked=mb.walked if mb else 0,
-                           sent=self.pump.sent, wake_to_pass_us=w2p_us)
+                           sent=self.pump.sent, jobs=jobs,
+                           send_us=send_ns // 1000,
+                           hidden_us=hidden_ns // 1000,
+                           wake_to_pass_us=w2p_us)
         TRACER.wake = None
         obs.PUMP_WAKE_SECONDS.observe((end - t0) / 1e9)
         obs.PUMP_LOOP_SECONDS.inc((end - t0) / 1e9, state="wake")

@@ -57,23 +57,26 @@ def test_partial_gso_hard_error_retries_remainder_plain(monkeypatch):
     total = n * 2
 
     calls = []
-    errno_box = {"v": 0}
 
     def fake_send_multi(fd, data, length, seq_off, ts_off, ssrc, dests,
-                        ops, n_ops, *, use_gso=True, trace_id=None):
+                        ops, n_ops, *, use_gso=True, trace_id=None,
+                        submit=False):
+        """The engine's sends are jobs of the native sender: a finished
+        one, with the job's own errno in its result."""
+        assert submit
         calls.append((n_ops, use_gso))
-        if use_gso:
-            errno_box["v"] = 22            # EINVAL after a partial delivery
-            return 2
-        errno_box["v"] = 0
-        return n_ops                       # plain sendmmsg drains the rest
+        # GSO: EINVAL after a partial delivery; plain drains the rest
+        r, err = (2, 22) if use_gso else (n_ops, 0)
+        return types.SimpleNamespace(
+            done=True, result=r, err=err, n_ops=n_ops, use_gso=use_gso,
+            submit_ns=1, start_ns=2, done_ns=3, syscalls=1)
 
     fake = types.SimpleNamespace(
         available=lambda: True,
         make_dests=native.make_dests,
         ops_from_numpy=native.ops_from_numpy,
         fanout_send_multi=fake_send_multi,
-        last_send_errno=lambda: errno_box["v"])
+        last_send_errno=lambda: 0)          # the loop thread sent nothing
     monkeypatch.setattr(fanout_mod, "_native_mod", lambda: fake)
     # the engine resolves `native` lazily inside _native_step too
     import easydarwin_tpu

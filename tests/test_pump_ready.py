@@ -15,6 +15,7 @@ import types
 import pytest
 
 from easydarwin_tpu import native, obs
+from easydarwin_tpu.relay.fanout import _Pass
 from easydarwin_tpu.relay.output import CollectingOutput, WriteResult
 from easydarwin_tpu.relay.pump import OWNED, Pump, needs_step
 from easydarwin_tpu.protocol.rtcp import parse_compound
@@ -284,8 +285,13 @@ class _Engine:
         self.megabatch_owned = False
         self.log = log
 
-    def step(self, stream, t):
+    open_pass = None
+
+    def begin(self, stream, t):
         self.log.append(stream.session_path)
+        return _Pass(stream, t)         # nothing with the sender: done
+
+    def finish(self, ps):
         return 1
 
 

@@ -19,6 +19,7 @@ import weakref
 import pytest
 
 from easydarwin_tpu.relay import pump
+from easydarwin_tpu.relay.fanout import _Pass
 from easydarwin_tpu.relay.output import CollectingOutput
 from easydarwin_tpu.relay.pump import DEVICE, OWNED, SCALAR, Pump
 from easydarwin_tpu.relay.session import SessionRegistry
@@ -44,10 +45,15 @@ class _Engine:
         self.log = log
         self.fail = fail
 
-    def step(self, stream, t):
+    open_pass = None
+
+    def begin(self, stream, t):
         self.log.append(("step", stream.session_path, self.megabatch_owned))
         if self.fail:
             raise RuntimeError("device fell over")
+        return _Pass(stream, t)         # nothing with the sender: done
+
+    def finish(self, ps):
         return 1
 
 

@@ -138,19 +138,27 @@ async def test_served_spans_land_on_the_profilers_host_plane(tmp_path):
         host.setdefault(row[0], []).append(row)
     for name in ("pump.sleep", "pump.wake", "pump.live_relay",
                  "pump.megabatch", "engine.step", "engine.egress",
-                 "engine.account", "native.egress"):
+                 "engine.settle", "engine.account"):
         assert host.get(name), f"{name} is not on the host plane"
+    # the send is the native sender thread's: its span is filed from the
+    # job's own stamps at settle, so the ring has it and this plane has
+    # ``egress.wait`` where the loop thread stood still for it (a send
+    # already done when its settle came leaves none)
+    assert "native.egress" not in host
     # the program's own names and nothing else of its making
     ours = {n for n in host if n.startswith(
-        ("pump.", "engine.", "megabatch.", "native.", "ingest."))}
+        ("pump.", "engine.", "megabatch.", "native.", "egress.",
+         "ingest."))}
     assert ours <= set(SPANS)
     # each child inside its parent's interval, on the profiler's clock
     for child, parent in (("engine.egress", "engine.step"),
-                          ("native.egress", "engine.egress"),
+                          ("engine.account", "engine.settle"),
+                          ("egress.wait", "engine.settle"),
                           ("engine.step", "pump.live_relay"),
+                          ("engine.settle", "pump.live_relay"),
                           ("pump.live_relay", "pump.wake"),
                           ("pump.megabatch", "pump.wake")):
-        for row in host[child]:
+        for row in host.get(child, ()):
             assert any(_inside(row, p) for p in host[parent]), (child, row)
     # no wake overlaps a sleep: the loop is in one state at a time
     for w in host["pump.wake"]:
@@ -159,19 +167,26 @@ async def test_served_spans_land_on_the_profilers_host_plane(tmp_path):
 
     # tools/span_breakdown.py reads the same plane: a wake's decomposition
     tool = _load("tools/span_breakdown.py", "span_breakdown")
-    doc = tool.breakdown(events["host"])
-    assert doc["wakes"] == len(host["pump.wake"]) >= 6
     # and what each wake carries: the roster and how much of it the ready
-    # set had it step (two streams, pushed in six of the wakes)
+    # set had it step (two streams, pushed in six of the wakes), the send
+    # jobs and their seconds — of which the tool makes the plane's
+    # ``native.egress`` row
     carried = tool.wake_args(str(tmp_path))
+    doc = tool.breakdown(events["host"], carried)
+    assert doc["wakes"] == len(host["pump.wake"]) >= 6
     assert len(carried) == doc["wakes"]
     mean = tool.per_wake(carried)
     assert mean["streams"] == 2 and 0 < mean["stepped"] <= 2
     assert mean["sent"] * doc["wakes"] == pytest.approx(2 * 32 * 24)
+    assert mean["jobs"] * doc["wakes"] == 12
+    assert 0 <= mean["hidden_us"] <= mean["send_us"] > 0
+    assert doc["spans"]["native.egress"]["count"] == 12
     per_wake = {n: r["ms_per_wake"] for n, r in doc["spans"].items()}
     assert (per_wake["pump.wake"] >= per_wake["pump.live_relay"]
-            >= per_wake["engine.step"] >= per_wake["engine.egress"]
-            >= per_wake["native.egress"] > 0)
+            >= per_wake["engine.step"] >= per_wake["engine.egress"] > 0)
+    assert (per_wake["pump.live_relay"] >= per_wake["engine.settle"]
+            >= per_wake["engine.account"] > 0)
+    assert per_wake["native.egress"] > 0
     assert doc["spans"]["pump.wake"]["loop_pct"] \
         + doc["spans"]["pump.sleep"]["loop_pct"] == pytest.approx(100.0)
 
@@ -196,7 +211,12 @@ async def test_served_spans_land_on_the_profilers_host_plane(tmp_path):
     assert {a["trace_id"] for a in steps} == {"trace-0", "trace-1"}
     assert all(a["outputs"] == 32 and a["due_outputs"] <= 32
                for a in steps)
-    assert sum(a["sent"] for a in steps) == 2 * 32 * 24
+    # the two halves of a step, and between them the sender's own span
+    for name, key in (("engine.settle", "sent"),
+                      ("native.egress", "datagrams")):
+        rows = [args for n, *_x, args in TRACER.records() if n == name]
+        assert {a["trace_id"] for a in rows} == {"trace-0", "trace-1"}
+        assert sum(a[key] for a in rows) == 2 * 32 * 24, name
 
     # the benchmark's reduction names a device-idle gap by what the
     # host was doing in it: put two program executions of a chip either
@@ -411,7 +431,14 @@ def test_a_wake_allocates_no_traceme_when_nobody_listens(monkeypatch):
         monkeypatch.setattr(TRACER, "_session_live", lambda: True)
         _CountingAnnotation.made = 0
         spans = wake()
-        assert spans >= 12 and _CountingAnnotation.made == spans
+        # (native.egress is the sender thread's, filed after the fact
+        # like engine.plan and jax.build: the ring's alone)
+        post_hoc = [r[0] for r in TRACER.records()[-spans:]
+                    if r[0] in ("native.egress", "engine.plan",
+                                "jax.build")]
+        assert post_hoc.count("native.egress") == len(streams)
+        assert spans >= 12
+        assert _CountingAnnotation.made == spans - len(post_hoc)
         # at most 12 spans a stream-step plus 12 a wake
         assert spans <= 12 * len(streams) + 12
         # no session: the ring only, one flag test a span

@@ -851,6 +851,7 @@ WAKE_CAUSES = ("ingest", "timer", "interval")
 STEP_RESULTS = ("idle", "worked")
 CELL_KINDS = ("real", "staged")
 PAIR_KINDS = ("handed", "walked")
+PIPELINE_PARTS = ("send", "hidden")
 
 
 def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
@@ -874,7 +875,11 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
     ``pump_roster_streams_total`` / ``pump_stepped_streams_total`` once a
     wake, ``pump_ready_missed_total`` by the 1 Hz audit; and the
     scheduler's hand-over (ISSUE 36): ``megabatch_pairs_total{kind}``,
-    handed in ``begin_wake`` and walked in ``end_wake``."""
+    handed in ``begin_wake`` and walked in ``end_wake``; and the send
+    pipeline's two (ISSUE 38), counted once a wake that handed the
+    native sender a job, from the jobs' own stamps and the
+    ``egress.wait`` spans: ``egress_pipeline_seconds_total{part}``,
+    ``egress_pipeline_jobs_total``."""
     from easydarwin_tpu.obs.ledger import WORK_CLASSES
     from easydarwin_tpu.obs.metrics import TIME_BUCKETS
     from easydarwin_tpu.obs.trace import SPAN_PREFIXES, SPANS
@@ -917,6 +922,8 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
             "engine_steps_total": (("result",), STEP_RESULTS),
             "megabatch_cells_total": (("kind",), CELL_KINDS),
             "megabatch_pairs_total": (("kind",), PAIR_KINDS),
+            "egress_pipeline_seconds_total": (("part",), PIPELINE_PARTS),
+            "egress_pipeline_jobs_total": ((), ()),
             "ingest_interleaved_packets_total": ((), ()),
             "ingest_interleaved_seconds_total": ((), ())}
     for fam_name, (labels, closed) in want.items():

@@ -11,8 +11,12 @@ run) through ``benchmark/reduce_trace.load_xplane``, or the span ring as
 the time the pump loop spent awake or asleep, then what ``pump.wake``
 carries per wake: the streams it served and, where the program has a
 ready set, how many of them it stepped and how many of the owned pairs it
-handed the megabatch scheduler had their plan read.  Spans nest, so a
-child's ms are inside its parent's.  Holds no chip: run it with
+handed the megabatch scheduler had their plan read; then the send jobs it
+handed the native sender, their milliseconds of sending and the share of
+those the loop thread did not wait for.  Spans nest, so a child's ms are
+inside its parent's — but for ``native.egress``, which is the sender
+thread's time: ``egress.wait`` beside it is what of that the loop thread
+stood still for.  Holds no chip: run it with
 ``JAX_PLATFORMS=cpu``."""
 
 from __future__ import annotations
@@ -22,8 +26,8 @@ import os
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-OURS = ("pump.", "engine.", "megabatch.", "native.", "pipeline.",
-        "ingest.")
+OURS = ("pump.", "engine.", "megabatch.", "native.", "egress.",
+        "pipeline.", "ingest.")
 
 
 def host_rows(path: str) -> list:
@@ -62,20 +66,30 @@ def per_wake(args: list[dict]) -> dict:
     """Mean of each numeric ``pump.wake`` argument over the wakes that
     carry it."""
     out = {}
-    for key in ("streams", "stepped", "handed", "walked", "sent"):
+    for key in ("streams", "stepped", "handed", "walked", "sent", "jobs",
+                "send_us", "hidden_us"):
         vals = [float(a[key]) for a in args if key in a]
         if vals:
             out[key] = sum(vals) / len(vals)
     return out
 
 
-def breakdown(rows: list) -> dict:
+def breakdown(rows: list, wakes_args: list[dict] | None = None) -> dict:
+    """``native.egress`` is filed from the sender thread's own stamps,
+    after the fact, so only the ring has it; for a profiler trace the
+    row is made of what ``pump.wake`` carries (``jobs``, ``send_us``)
+    and stands beside ``egress.wait``, the loop thread blocked on it."""
     spans: dict[str, list] = {}
     for name, _start, dur in rows:
         if name.startswith(OURS):
             c = spans.setdefault(name, [0, 0.0])
             c[0] += 1
             c[1] += dur / 1e9
+    if "native.egress" not in spans and wakes_args:
+        jobs = sum(int(a.get("jobs", 0)) for a in wakes_args)
+        if jobs:
+            spans["native.egress"] = [jobs, sum(
+                float(a.get("send_us", 0)) for a in wakes_args) / 1e6]
     wakes = spans.get("pump.wake", [0, 0.0])[0]
     loop_s = sum(spans.get(n, [0, 0.0])[1]
                  for n in ("pump.wake", "pump.sleep"))
@@ -87,13 +101,14 @@ def breakdown(rows: list) -> dict:
 
 
 def main(argv) -> int:
-    doc = breakdown(host_rows(argv[1]))
+    carried = wake_args(argv[1])
+    doc = breakdown(host_rows(argv[1]), carried)
     print(f"{doc['wakes']} wakes, {doc['loop_s']:.3f} s of pump loop")
     for name, row in doc["spans"].items():
         print(f"{name:22s} {row['count']:7d} {row['seconds']:10.4f} s "
               f"{row['ms_per_wake'] or 0:10.3f} ms/wake "
               f"{row['loop_pct'] or 0:6.2f} %")
-    mean = per_wake(wake_args(argv[1]))
+    mean = per_wake(carried)
     if "streams" in mean:
         line = f"a wake: streams {mean['streams']:.1f}"
         if "stepped" in mean:
@@ -103,7 +118,14 @@ def main(argv) -> int:
         if "handed" in mean:
             line += (f", pairs handed {mean['handed']:.1f}, walked "
                      f"{mean.get('walked', 0):.2f}")
-        print(line + f", sent {mean.get('sent', 0):.1f}")
+        line += f", sent {mean.get('sent', 0):.1f}"
+        if "jobs" in mean:
+            send = mean.get("send_us", 0.0)
+            line += (f", send jobs {mean['jobs']:.2f} ({send / 1e3:.2f} ms "
+                     f"of sending, "
+                     f"{100 * mean.get('hidden_us', 0) / send if send else 0:.1f}"
+                     f" % of it hidden behind the loop thread)")
+        print(line)
     return 0
 
 
