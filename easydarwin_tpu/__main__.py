@@ -6,13 +6,19 @@ watchdog (see ``server.supervisor`` for the restart loop).
 
 from __future__ import annotations
 
-import argparse
-import asyncio
-import os
-import signal
-import sys
+import time
 
-from .server import ServerConfig, StreamingServer
+#: stands in for the process's start where the OS keeps no record of it
+_FIRST_LINE_NS = time.perf_counter_ns()
+
+import argparse                              # noqa: E402
+import asyncio                               # noqa: E402
+import os                                    # noqa: E402
+import signal                                # noqa: E402
+import sys                                   # noqa: E402
+
+from .obs.boot import BootPhases             # noqa: E402
+from .server import ServerConfig, StreamingServer  # noqa: E402
 
 
 #: exit code when tpu_fanout is on and the engine tier cannot run where
@@ -80,13 +86,17 @@ def config_from_args(args) -> ServerConfig:
     return cfg
 
 
-async def amain(cfg: ServerConfig, exit_after_boot: bool = False) -> int:
+async def amain(cfg: ServerConfig, exit_after_boot: bool = False,
+                boot: BootPhases | None = None) -> int:
     app = StreamingServer(cfg)
+    app.boot = boot
     await app.start()
     print(f"easydarwin-tpu listening: rtsp://{cfg.bind_ip}:{app.rtsp.port} "
           f"service http://{cfg.bind_ip}:{app.rest.port}/api/v1 "
           f"tpu_fanout={'on' if cfg.tpu_fanout else 'off'} "
           f"{app.engine_banner()}", flush=True)
+    if boot is not None:
+        boot.done()
     if exit_after_boot:
         await app.stop()
         return 0
@@ -115,12 +125,14 @@ def main(argv=None) -> int:
             a for a in (sys.argv[1:] if argv is None else argv)
             if a not in ("-w", "--watchdog")]
         return run_supervised(child)
+    # post hoc from the module's first line: nothing before this is lost
+    boot = BootPhases(_FIRST_LINE_NS)
     cfg = config_from_args(args)
     from . import device, native
     print(f"jax: JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '(unset)')} "
           f"compile_cache={device.enable_compile_cache()}", flush=True)
     try:
-        return asyncio.run(amain(cfg, args.exit_after_boot))
+        return asyncio.run(amain(cfg, args.exit_after_boot, boot))
     except (device.DeviceError, native.NativeCoreError) as e:
         # the engine tier cannot run where it was told to: stop, loudly
         print(f"easydarwin-tpu: boot refused: {e}", file=sys.stderr,

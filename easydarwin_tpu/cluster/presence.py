@@ -75,7 +75,6 @@ class LeaseManager:
         await self.redis.fset(self.key, self.token, self.payload(),
                               ttl=self.ttl_sec)
         self.acquired_at = time.monotonic()
-        obs.CLUSTER_LEASE_ACQUIRED.inc()
         self._events.emit("cluster.lease_acquire", node=self.node_id,
                           token=self.token)
         return self.token
@@ -100,7 +99,6 @@ class LeaseManager:
             return False
         await self.redis.fset(self.key, self.token, self.payload(),
                               ttl=self.ttl_sec)
-        obs.CLUSTER_LEASE_RENEWALS.inc()
         return True
 
     async def release(self) -> None:

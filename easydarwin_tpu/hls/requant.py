@@ -39,8 +39,7 @@ from ..codecs.h264_requant import (FusedRequantDispatch, RequantStats,
                                    device_batch_chroma, gather_slice,
                                    parse_slice_nal, recode_parsed)
 from ..obs import (REQUANT_AUS, REQUANT_REASSEMBLY_MISMATCH,
-                   REQUANT_RENDITIONS, REQUANT_SHED, REQUANT_SLICES,
-                   REQUANT_STAGE_SECONDS)
+                   REQUANT_RENDITIONS, REQUANT_SHED, REQUANT_STAGE_SECONDS)
 from ..relay.output import RelayOutput, WriteResult
 from ..vod.depacketize import AccessUnit
 from .segmenter import HlsOutput
@@ -617,7 +616,6 @@ class RequantLadder(RelayOutput):
             with job.lock:
                 job.outs[delta][pos] = out
                 job.stats[delta].append(d)
-        REQUANT_SLICES.inc(len(unit_deltas))
         self._complete_unit(loop, job)
 
     def _parse_unit(self, loop, job: _AuJob, pos: int) -> None:
@@ -674,7 +672,6 @@ class RequantLadder(RelayOutput):
                     job.outs[delta][pos] = job.au.nals[pos]
                     job.stats[delta].append(
                         d if delta == job.deltas[0] else _copy_delta(d))
-        REQUANT_SLICES.inc(len(failed) * len(job.deltas))
         if not order:
             if loop is not None:
                 loop.call_soon_threadsafe(self._emit, job)
@@ -717,7 +714,6 @@ class RequantLadder(RelayOutput):
         with job.lock:
             job.outs[delta][pos] = out
             job.stats[delta].append(d)
-        REQUANT_SLICES.inc()
         self._complete_unit(loop, job)
 
     # -- synchronous path --------------------------------------------------

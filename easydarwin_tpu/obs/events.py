@@ -176,6 +176,18 @@ SCHEMA: dict[str, tuple[str, ...]] = {
     # megabatch mesh build) — counted in device_errors_swallowed_total;
     # never silent, a chip smoke fails on any occurrence
     "device.error_swallowed": ("site", "error"),
+    # one per built executable (device.py, fed by jax.monitoring): which
+    # program, compiled or loaded from the persistent cache, the seconds
+    # of its three parts (also apart: trace_us, lower_us, backend_us)
+    # and the pump wake it fell into (None outside one).  Builds are
+    # rare by contract — a served window adds none — so an operator
+    # reads "which, when" here and /metrics carries no per-program label
+    "jax.build": ("program", "source", "seconds", "wake"),
+    # one per process, when it listens (obs/boot.py): the seconds of
+    # the five boot phases and their sum — a slow restart read from
+    # /api/v1/events says which phase was slow
+    "server.boot": ("interpreter", "imports", "native", "backend",
+                    "listen", "total"),
     # recording crash safety (vod/record.py): a leftover <file>.tmp
     # found at boot means a recorder died mid-write — the orphan is
     # reported, never silently deleted or served

@@ -147,6 +147,31 @@ PUMP_READY_MISSED = REGISTRY.counter(
     "write that bypassed the marks.  Each is stepped in the next wake; "
     "anything but 0 is a fault to find")
 
+# ------------------------------------------ server boot / RTSP front end
+#: set once, when the ``listening:`` line is printed (``obs.boot``): the
+#: five ``boot.*`` spans' seconds and their sum
+SERVER_BOOT_SECONDS = REGISTRY.gauge(
+    "server_boot_seconds",
+    "Seconds of this process's boot, by phase: interpreter (process "
+    "start, as the OS recorded it, to main's entry), imports (arguments, "
+    "configuration, import jax), native (the native core built or "
+    "loaded), backend (the JAX backend initialised: the TPU runtime), "
+    "listen (the rest, to the listening line), and total, their sum.  A "
+    "phase a boot does not go through reads 0", labels=("phase",))
+#: both counted where the ``rtsp.<method>`` span is filed, from its two
+#: clock reads
+RTSP_REQUEST_SECONDS = REGISTRY.counter(
+    "rtsp_request_seconds_total",
+    "Seconds between an RTSP handler's start and its end on the "
+    "event-loop thread, by method, whoever waited for the answer "
+    "(seconds / rtsp_requests_total = a request's handler time)",
+    labels=("method",))
+RTSP_REQUESTS = REGISTRY.counter(
+    "rtsp_requests_total",
+    "RTSP requests that reached their handler, by method (one refused "
+    "by a filter, by authorization or as unknown is not counted)",
+    labels=("method",))
+
 # -------------------------------------------------------------- SLO watchdog
 SLO_VIOLATIONS = REGISTRY.counter(
     "slo_violations_total",
@@ -213,8 +238,13 @@ JAX_EXECUTABLES_BUILT = REGISTRY.counter(
     "(compiled, or loaded from the persistent compilation cache)")
 JAX_EXECUTABLE_BUILD_SECONDS = REGISTRY.counter(
     "jax_executable_build_seconds_total",
-    "Wall seconds spent building XLA executables (backend compile, or "
-    "the persistent-cache load that replaced it)")
+    "Wall seconds a first call cost the thread that made it, by the part "
+    "JAX times it under: trace (Python to jaxpr), lower (jaxpr to MLIR), "
+    "backend (the XLA compile, or the persistent-cache load that "
+    "replaced it).  Each second once: a nested jit's trace is inside its "
+    "caller's and is not added again.  Counted when the executable is "
+    "built, so the sum over phases is the sum of the jax.build spans' "
+    "three parts", labels=("phase",))
 JAX_CACHE_HITS = REGISTRY.counter(
     "jax_persistent_cache_hits_total",
     "Executable builds served from the persistent compilation cache "
@@ -467,11 +497,6 @@ REQUANT_AUS = REGISTRY.counter(
     "requant_aus_total",
     "Access units admitted into the requant ladder pipeline (each fans "
     "out to every rendition of its source's q-rung ladder)")
-REQUANT_SLICES = REGISTRY.counter(
-    "requant_slices_total",
-    "Slice recode jobs completed by the ladder worker pool (one serial "
-    "CAVLC/CABAC state machine per slice per rendition, slices of one "
-    "AU fanned across workers)")
 REQUANT_RENDITIONS = REGISTRY.counter(
     "requant_renditions_total",
     "Rendition access units emitted by the ladder (renditions_total / "
@@ -788,14 +813,6 @@ REDIS_ERRORS = REGISTRY.counter(
     "Redis commands that failed (timeout, connection error, partition — "
     "real or injected); the caller degrades gracefully, a lapsed lease "
     "simply ages out and a peer takes over")
-CLUSTER_LEASE_ACQUIRED = REGISTRY.counter(
-    "cluster_lease_acquired_total",
-    "Server leases acquired in Redis (boot + every re-acquire after an "
-    "observed loss); each acquire mints a fresh monotonic fencing token")
-CLUSTER_LEASE_RENEWALS = REGISTRY.counter(
-    "cluster_lease_renewals_total",
-    "Successful lease heartbeat renewals (TTL re-asserted while the "
-    "stored fencing token still matches ours)")
 CLUSTER_LEASE_LOST = REGISTRY.counter(
     "cluster_lease_lost_total",
     "Heartbeats that found our lease gone or stolen (TTL expiry during "

@@ -22,7 +22,7 @@ with ``EDTPU_PROFILE=0`` ``open`` returns ``None`` after one attribute
 check.  The served path uses only open/close, with names from the closed
 ``SPANS`` vocabulary below (``tools/metrics_lint.py`` pins it); the one
 post-hoc form, ``add``, stays for call sites off the served path and
-reaches the ring only.
+reaches the ring only.  Both stamp the wake in progress.
 
 Correlation: callers thread a session's ``trace_id`` through span args
 (``TRACER.open(..., trace_id=sid)``); the per-session flight recorder
@@ -63,7 +63,10 @@ SPANS = (
     "megabatch.shard_h2d", "megabatch.shard_wait", "megabatch.shard_fetch",
     "ingest.read",
     "native.egress", "native.stream_egress",
-    "pipeline.step", "jax.build")
+    "pipeline.step", "jax.build",
+    # a process's life before it listens (``obs.boot``), in order
+    "boot.interpreter", "boot.imports", "boot.native", "boot.backend",
+    "boot.listen")
 #: span families whose last part is data: ``rtsp.<method>``
 SPAN_PREFIXES = ("rtsp.",)
 
@@ -173,9 +176,12 @@ class SpanTracer:
     def add(self, name: str, t0_ns: int, dur_ns: int | None = None,
             cat: str = "relay", **args) -> None:
         """Record a span post hoc (ring only): ``dur_ns`` as the caller
-        measured it, or [t0_ns, now] where it gives none."""
+        measured it, or [t0_ns, now] where it gives none.  Filed inside
+        a wake it carries the wake's number, as an opened span does."""
         if dur_ns is None:
             dur_ns = time.perf_counter_ns() - t0_ns
+        if self.wake is not None:
+            args["wake"] = self.wake
         self._record(name, cat, t0_ns, dur_ns, args)
 
     # -- read side ---------------------------------------------------

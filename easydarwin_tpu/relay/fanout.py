@@ -430,11 +430,8 @@ class TpuFanoutEngine:
         self._rebuild_walked += p.n_outputs
         obs.ENGINE_PLAN_REBUILDS.inc()
         if TRACER.enabled:              # ring only: rare, and post hoc
-            args = dict(self._span_args, outputs=p.n_outputs,
-                        fast=len(p.udp))
-            if TRACER.wake is not None:
-                args["wake"] = TRACER.wake
-            TRACER.add("engine.plan", t0, cat="tpu", **args)
+            TRACER.add("engine.plan", t0, cat="tpu", **self._span_args,
+                       outputs=p.n_outputs, fast=len(p.udp))
         return p
 
     def _build_plan(self, stream: RelayStream, now_ms: int,
@@ -935,14 +932,11 @@ class TpuFanoutEngine:
             waited = TRACER.close(tok) - t0
         send_ns = job.done_ns - job.start_ns
         if TRACER.enabled:
-            args = dict(self._span_args, ops=job.n_ops, gso=job.use_gso,
-                        sent=job.result, datagrams=max(job.result, 0),
-                        syscalls=job.syscalls,
-                        queued_us=(job.start_ns - job.submit_ns) // 1000)
-            if TRACER.wake is not None:
-                args["wake"] = TRACER.wake
             TRACER.add("native.egress", job.start_ns, send_ns,
-                       cat="native", **args)
+                       cat="native", **self._span_args, ops=job.n_ops,
+                       gso=job.use_gso, sent=job.result,
+                       datagrams=max(job.result, 0), syscalls=job.syscalls,
+                       queued_us=(job.start_ns - job.submit_ns) // 1000)
         if self._profiled:
             self._phase_add("egress_native", send_ns)
         ps.jobs += 1

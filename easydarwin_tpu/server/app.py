@@ -165,6 +165,9 @@ class StreamingServer:
         #: filled by start() when tpu_fanout is on, BEFORE any listener
         #: opens; None = the engine tier is off and JAX was not touched
         self.device_info: dict | None = None
+        #: the boot in progress where ``main`` started this server
+        #: (``obs.boot.BootPhases``); None for one started any other way
+        self.boot = None
         #: VOD segment cache + shared group pacer (ISSUE 10): hot file
         #: sessions become megabatch-eligible relay streams the pump
         #: steps alongside live; built in start() (engines need the
@@ -253,6 +256,9 @@ class StreamingServer:
             self.config.fec_config()    # raises at boot on a bad window/kind
         if self.config.tpu_fanout:
             self._resolve_engine_tier()
+            self._boot_phase("listen", devices=self.device_info["count"])
+        else:
+            self._boot_phase("listen")
         # chaos plan (resilience/inject.py): armed before anything serves
         # so the very first pass already runs under the fault schedule
         plan = self.config.fault_plan()
@@ -580,10 +586,19 @@ class StreamingServer:
         would serve from the scalar loop behind a ``tpu_fanout=on``
         banner."""
         from .. import device, native
+        self._boot_phase("native")
         native.require()
+        self._boot_phase(
+            "backend", built=int(native.build_info()["built_this_process"]))
         self.device_info = device.resolve(require_tpu=True)
         if self.error_log:
             self.error_log.info("engine tier: " + self.engine_banner())
+
+    def _boot_phase(self, phase: str, **ended) -> None:
+        """Where ``main`` started this server: the boot goes on to
+        ``phase`` (``obs.boot``)."""
+        if self.boot is not None:
+            self.boot.enter(phase, **ended)
 
     def engine_banner(self) -> str:
         """``platform=… device_kind=… devices=… native=…`` — the boot

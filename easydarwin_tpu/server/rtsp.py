@@ -21,7 +21,8 @@ import time
 from dataclasses import dataclass, field
 
 from ..obs import (EVENTS, FLIGHT, INGEST_INTERLEAVED_PACKETS,
-                   INGEST_INTERLEAVED_SECONDS, TRACER, t0_of)
+                   INGEST_INTERLEAVED_SECONDS, RTSP_REQUEST_SECONDS,
+                   RTSP_REQUESTS, TRACER, t0_of)
 from ..protocol import rtsp, sdp
 from ..relay.session import RelaySession, SessionRegistry, now_ms
 from .config import ServerConfig
@@ -328,8 +329,15 @@ class RtspConnection:
                         trace_id=self.trace_id, method=req.method,
                         status=e.status)
         finally:
-            TRACER.add(f"rtsp.{req.method.lower()}", t0, cat="rtsp",
+            # the span's two clock reads are also the method's seconds:
+            # what the event-loop thread spent in the handler, whoever
+            # waited for the answer
+            method = req.method.lower()
+            dur_ns = time.perf_counter_ns() - t0
+            TRACER.add(f"rtsp.{method}", t0, dur_ns, cat="rtsp",
                        trace_id=self.trace_id)
+            RTSP_REQUEST_SECONDS.inc(dur_ns / 1e9, method=method)
+            RTSP_REQUESTS.inc(method=method)
         if (not errored and req.method in self._EVENT_METHODS
                 and self._last_response is not None):
             EVENTS.emit(f"rtsp.{req.method.lower()}",

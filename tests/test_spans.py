@@ -364,6 +364,9 @@ def test_span_vocabulary_is_closed(tmp_path):
     assert len(set(SPANS)) == len(SPANS)
     for wc in obs.WORK_CLASSES:
         assert f"pump.{wc}" in SPANS
+    from easydarwin_tpu.obs.boot import PHASES
+    assert [s for s in SPANS if s.startswith("boot.")] == [
+        f"boot.{p}" for p in PHASES]
     # a stray name at a call site is caught, an rtsp.<method> is not
     (tmp_path / "x.py").write_text(
         'TRACER.open("pump.mystery", "pump")\n'
@@ -392,6 +395,17 @@ def test_span_families_are_in_the_lints_inventory():
     assert any("napping" in e for e in errs)
     assert any("relay_due_to_wire_seconds: bucket bounds" in e for e in errs)
     assert any("pump_wakes_total missing" in e for e in errs)
+    # ISSUE 39's: the boot gauge, the build seconds by part, the RTSP pair
+    for fam in ("server_boot_seconds", "jax_executable_build_seconds_total",
+                "rtsp_request_seconds_total", "rtsp_requests_total"):
+        assert any(f"{fam} missing" in e for e in errs), fam
+    reg.counter("jax_executable_build_seconds_total", "s",
+                labels=("phase",)).inc(1.0, phase="linking")
+    reg.gauge("server_boot_seconds", "s", labels=("phase",)).set(
+        1.0, phase="coffee")
+    errs = lint.lint_spans(reg)
+    assert any("linking" in e for e in errs)
+    assert any("coffee" in e for e in errs)
 
 
 class _CountingAnnotation:

@@ -339,8 +339,6 @@ def lint_cluster(registry, schema: dict) -> list[str]:
     errs: list[str] = []
     want_labels = {
         "redis_errors_total": (),
-        "cluster_lease_acquired_total": (),
-        "cluster_lease_renewals_total": (),
         "cluster_lease_lost_total": (),
         "cluster_lease_fence_rejected_total": (),
         "cluster_placement_moves_total": (),
@@ -436,7 +434,6 @@ def lint_requant(registry) -> list[str]:
     errs: list[str] = []
     want_labels = {
         "requant_aus_total": (),
-        "requant_slices_total": (),
         "requant_renditions_total": (),
         "requant_shed_total": (),
         "requant_reassembly_mismatch_total": (),
@@ -847,6 +844,7 @@ SPAN_SITE_RE = re.compile(
     r"""\b(?:TRACER\.(?:open|add)|_open|_egress_open|unit_start)"""
     r"""\(\s*(?:(?:self\._lib|lib)\s*,\s*)?(f?)['"]([^'"]+)['"]""")
 PUMP_STATES = ("wake", "sleep")
+BUILD_PARTS = ("trace", "lower", "backend")
 WAKE_CAUSES = ("ingest", "timer", "interval")
 STEP_RESULTS = ("idle", "worked")
 CELL_KINDS = ("real", "staged")
@@ -879,7 +877,16 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
     pipeline's two (ISSUE 38), counted once a wake that handed the
     native sender a job, from the jobs' own stamps and the
     ``egress.wait`` spans: ``egress_pipeline_seconds_total{part}``,
-    ``egress_pipeline_jobs_total``."""
+    ``egress_pipeline_jobs_total``; and what a process did before its
+    window (ISSUE 39): a ``boot.<phase>`` span per ``obs.boot.PHASES``
+    entry, set once into ``server_boot_seconds{phase}`` with their
+    ``total`` and emitted as ``server.boot``; the three counters a
+    ``jax.build`` span and event is counted by,
+    ``jax_executable_build_seconds_total{phase}`` by its three parts;
+    ``rtsp_request_seconds_total{method}`` / ``rtsp_requests_total
+    {method}`` where ``rtsp.<method>`` is filed."""
+    from easydarwin_tpu.obs.boot import PHASES as BOOT_PHASES
+    from easydarwin_tpu.obs.events import SCHEMA
     from easydarwin_tpu.obs.ledger import WORK_CLASSES
     from easydarwin_tpu.obs.metrics import TIME_BUCKETS
     from easydarwin_tpu.obs.trace import SPAN_PREFIXES, SPANS
@@ -893,6 +900,15 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
         if f"pump.{wc}" not in SPANS:
             errs.append(f"span pump.{wc} missing: every ledger work class "
                         "is a span")
+    for ph in BOOT_PHASES:
+        if f"boot.{ph}" not in SPANS:
+            errs.append(f"span boot.{ph} missing: every boot phase is a "
+                        "span")
+    if tuple(SCHEMA.get("server.boot", ())) != BOOT_PHASES + ("total",):
+        errs.append("event server.boot must carry the boot phases and "
+                    "their total")
+    if "jax.build" not in SCHEMA:
+        errs.append("event jax.build missing from SCHEMA")
     for py in sorted(root.rglob("*.py")) if root else ():
         text = py.read_text(encoding="utf-8", errors="replace")
         for m in SPAN_SITE_RE.finditer(text):
@@ -925,7 +941,13 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
             "egress_pipeline_seconds_total": (("part",), PIPELINE_PARTS),
             "egress_pipeline_jobs_total": ((), ()),
             "ingest_interleaved_packets_total": ((), ()),
-            "ingest_interleaved_seconds_total": ((), ())}
+            "ingest_interleaved_seconds_total": ((), ()),
+            "server_boot_seconds": (("phase",), BOOT_PHASES + ("total",)),
+            "jax_executables_built_total": ((), ()),
+            "jax_persistent_cache_hits_total": ((), ()),
+            "jax_executable_build_seconds_total": (("phase",), BUILD_PARTS),
+            "rtsp_request_seconds_total": (("method",), ()),
+            "rtsp_requests_total": (("method",), ())}
     for fam_name, (labels, closed) in want.items():
         try:
             fam = registry.get(fam_name)

@@ -16,7 +16,15 @@ handed the native sender, their milliseconds of sending and the share of
 those the loop thread did not wait for.  Spans nest, so a child's ms are
 inside its parent's — but for ``native.egress``, which is the sender
 thread's time: ``egress.wait`` beside it is what of that the loop thread
-stood still for.  Holds no chip: run it with
+stood still for.  Under the table, for a ring dump (the two are the
+ring's alone): the ``boot.*`` phases still in the ring, and every
+``jax.build`` with its program, whether it was compiled or loaded, its
+three parts, its wake and the chain of spans that enclose it in time on
+its thread (``pump.wake > pump.megabatch > megabatch.dispatch >
+megabatch.h2d``, ``rtsp.play``, ``boot.listen``): the cause of a build.
+A dump taken after boot (``command=trace``) has them until 16,384 later
+spans have overrun them; ``/api/v1/events`` keeps a ``jax.build`` and the
+``server.boot`` event longer.  Holds no chip: run it with
 ``JAX_PLATFORMS=cpu``."""
 
 from __future__ import annotations
@@ -41,6 +49,46 @@ def host_rows(path: str) -> list:
     if os.path.isdir(path):
         path = reduce_trace.newest_xplane(path) or path
     return reduce_trace.load_xplane(path)["host"]
+
+
+def ring_events(path: str) -> list[dict]:
+    """The events of a ring dump; none for a profiler trace, which has
+    neither ``boot.*`` nor ``jax.build``."""
+    if not path.endswith(".json"):
+        return []
+    with open(path) as f:
+        return json.load(f)["traceEvents"]
+
+
+def boot_phases(events: list[dict]) -> list[dict]:
+    """The ``boot.*`` spans in the order they ran: ``name``, ``seconds``
+    and their arguments."""
+    return [{"name": e["name"], "seconds": e["dur"] / 1e6,
+             "args": e.get("args", {})}
+            for e in sorted(events, key=lambda e: e["ts"])
+            if e["name"].startswith("boot.")]
+
+
+def builds(events: list[dict]) -> list[dict]:
+    """Every ``jax.build`` in the order they ended, each with ``chain``:
+    the names of the spans of its thread that hold its whole interval,
+    outermost first."""
+    by_tid: dict = {}
+    for e in events:
+        # native.egress is the sender thread's time, filed from the loop
+        # thread: it is beside what that thread does, not around it
+        if e["name"] not in ("jax.build", "native.egress"):
+            by_tid.setdefault(e["tid"], []).append(e)
+    out = []
+    for b in (e for e in events if e["name"] == "jax.build"):
+        t0, t1 = b["ts"], b["ts"] + b["dur"]
+        around = [e for e in by_tid.get(b["tid"], ())
+                  if e["ts"] <= t0 and t1 <= e["ts"] + e["dur"]]
+        around.sort(key=lambda e: (e["ts"], -e["dur"]))
+        out.append({"at_s": t1 / 1e6, "seconds": b["dur"] / 1e6,
+                    "chain": [e["name"] for e in around],
+                    **b.get("args", {})})
+    return sorted(out, key=lambda b: b["at_s"])
 
 
 def wake_args(path: str) -> list[dict]:
@@ -126,6 +174,30 @@ def main(argv) -> int:
                      f"{100 * mean.get('hidden_us', 0) / send if send else 0:.1f}"
                      f" % of it hidden behind the loop thread)")
         print(line)
+    events = ring_events(argv[1])
+    boot = boot_phases(events)
+    if boot:
+        print("boot: " + ", ".join(
+            f"{p['name'][5:]} {p['seconds']:.3f} s" + "".join(
+                f" ({k}={v})" for k, v in p["args"].items())
+            for p in boot)
+            + f"; in all {sum(p['seconds'] for p in boot):.3f} s")
+    built = builds(events)
+    if built:
+        part = {k: sum(b.get(f"{k}_us", 0) for b in built) / 1e6
+                for k in ("trace", "lower", "backend")}
+        print(f"{len(built)} builds, "
+              f"{sum(b.get('source') == 'cache' for b in built)} of them "
+              f"loaded from the cache: trace {part['trace']:.3f} s, lower "
+              f"{part['lower']:.3f} s, backend {part['backend']:.3f} s")
+        for b in built:
+            print(f"  {b['at_s']:10.3f} s {b.get('program', '?'):40s} "
+                  f"{b.get('source', '?'):7s} "
+                  f"trace {b.get('trace_us', 0) / 1e3:8.1f} ms lower "
+                  f"{b.get('lower_us', 0) / 1e3:8.1f} ms backend "
+                  f"{b.get('backend_us', 0) / 1e3:8.1f} ms "
+                  f"wake {b.get('wake', '-')} in "
+                  f"{' > '.join(b['chain']) or '(no span)'}")
     return 0
 
 
