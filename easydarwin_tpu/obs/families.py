@@ -127,8 +127,9 @@ PUMP_DRAIN_PACKETS = REGISTRY.counter(
     "loop's ready queue and waited for the one after.  What was pushed "
     "while the pump still waited on its event is not counted: a pump "
     "that is not at the head of the queue is woken behind the batch")
-#: the wake's ready set (ISSUE 33; ``relay.pump``): the first two counted
-#: once a wake in ``Pump.wake``, the third by the 1 Hz ``Pump.audit``
+#: the wake's ready set (ISSUE 33; ``relay.pump``) and its kept roster
+#: (ISSUE 40): the first three counted once a wake in ``Pump.wake``, the
+#: last two by the 1 Hz ``Pump.audit``
 PUMP_ROSTER_STREAMS = REGISTRY.counter(
     "pump_roster_streams_total",
     "Live streams on the wake's roster, summed over wakes (the VOD "
@@ -140,6 +141,20 @@ PUMP_STEPPED_STREAMS = REGISTRY.counter(
     "change, a wheel timer (bucket release, RTO, SR) or the step's own "
     "carry-over (stepped / pump_roster_streams_total: the share of the "
     "roster that had something to do)")
+PUMP_ROUTED_STREAMS = REGISTRY.counter(
+    "pump_routed_streams_total",
+    "Live roster entries the wake routed (relay.pump.Pump.route), summed "
+    "over wakes: every entry where the wake built the roster anew, else "
+    "those of the kept roster it may step (ready, on a path the ladder "
+    "moved, with a dropped engine).  Over pump_roster_streams_total: the "
+    "share of the roster a wake routes")
+PUMP_ROSTER_STALE = REGISTRY.counter(
+    "pump_roster_stale_total",
+    "Kept roster entries the 1 Hz audit found with another route or "
+    "engine than a walk from scratch gives, or streams of the sessions "
+    "map the roster did not hold: an invalidation that went missing.  "
+    "Each is routed again in the next wake; anything but 0 is a fault "
+    "to find")
 PUMP_READY_MISSED = REGISTRY.counter(
     "pump_ready_missed_total",
     "Streams the 1 Hz audit found in need of a step (relay.pump."

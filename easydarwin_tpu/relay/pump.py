@@ -1,6 +1,6 @@
 """The relay wake (ARCHITECTURE.md §2b): the only place that knows the
-order "harvest the scheduler's pass, route every stream, step each once,
-stage the next pass" and the rule that picks a stream's path.
+order "route, harvest the scheduler's pass, step each stream once, stage
+the next pass" and the rule that picks a stream's path.
 
 The server's ``_reflect_all`` calls ``Pump.wake``; a caller with no
 server hands ``wake`` its ``(stream, engine)`` pairs.  Both run ``serve``
@@ -15,6 +15,12 @@ ready set, marked where the state ``needs_step`` reads is written
 wheel's fired timers, ``_step``'s own carry-over), and audits the marks
 against the rule once a second (``Pump.audit``).  The VOD roster and a
 caller with no wheel step all they hand in.
+
+**Which streams a wake routes.**  ``Pump`` keeps the roster across
+wakes and routes again only the entries a wake may step, where nothing
+it was built from has moved: the registry's ``generation``, the route's
+configuration, the paths the ladder names (``Pump.wake``); ``audit``
+holds the kept roster to a walk of its own once a second.
 
 **Which pairs the scheduler looks at.**  ``serve`` hands it the owned
 roster and, beside it, the owned pairs among the entries the wake steps
@@ -200,7 +206,7 @@ def _step(entries, t: int, ladder, log, label: str, timed: bool):
 
 
 def serve(live, vod, sched, t: int, *, min_streams: int = 1, ladder=None,
-          log=None, stepped=None) -> tuple[int, tuple]:
+          log=None, stepped=None, owned=None) -> tuple[int, tuple]:
     """One wake over a built roster: ``live`` entries inside the
     ``live_relay`` ledger unit — ``stepped`` of them where the caller
     keeps a ready set, the scheduler handed the owned pairs among them
@@ -209,11 +215,14 @@ def serve(live, vod, sched, t: int, *, min_streams: int = 1, ladder=None,
     ``DEVICE`` for the whole wake without a ``sched``, under
     ``min_streams`` owned entries, or when the harvest raises: a
     scheduler failure degrades to per-stream stepping, never to a halted
-    pump.  The one writer of ``TpuFanoutEngine.megabatch_owned``.
-    Returns (packets sent, ``_step``'s tally of the send jobs)."""
+    pump.  ``owned`` is the roster's owned pairs where the caller keeps
+    them (``Pump``), in roster order.  The one writer of
+    ``TpuFanoutEngine.megabatch_owned``.  Returns (packets sent,
+    ``_step``'s tally of the send jobs)."""
     LEDGER = obs.LEDGER             # (tests put a private one there)
     roster = live + vod if vod else live
-    owned = [(s, eng) for _p, s, eng, r in roster if r == OWNED]
+    if owned is None:
+        owned = [(s, eng) for _p, s, eng, r in roster if r == OWNED]
     if sched is None or len(owned) < min_streams:
         owned = []
     if stepped is None:             # no ready set: every entry, every pair
@@ -294,7 +303,8 @@ class Pump:
     a torn-down stream's engine, HBM ring and strike counters go with it
     and a new stream never inherits them through a recycled ``id()`` —
     and the megabatch scheduler — and the ready set, with the wheel whose
-    timers feed it (``_pump_loop`` builds the wheel and sleeps by it)."""
+    timers feed it (``_pump_loop`` builds the wheel and sleeps by it) —
+    and the roster, kept across wakes and corrected where it changes."""
 
     def __init__(self, config=None, *, on_device=None,
                  new_engine=TpuFanoutEngine, ladder=None, error_log=None):
@@ -325,6 +335,18 @@ class Pump:
         self.stepped: list = []
         self.streams = self.sent = 0
         self.jobs = (0, 0, 0)
+        #: the roster kept across wakes: ``live`` and its owned pairs in
+        #: roster order, each entry's place by its cell's key and the
+        #: keys of each path (what a ladder move names); what it was
+        #: built from — the sessions map, its generation, the route's
+        #: configuration — and whether it may be kept at all; the keys
+        #: whose engine was dropped since the last wake
+        self.owned: list = []
+        self._pos: dict[int, int] = {}
+        self._by_path: dict[str, list[int]] = {}
+        self._sessions = self._gen = self._cfg = None
+        self._kept = False
+        self._dropped: set[int] = set()
 
     def engine_for(self, stream) -> TpuFanoutEngine:
         eng = self.engines.get(stream)
@@ -334,6 +356,9 @@ class Pump:
 
     def engine_drop(self, stream) -> None:
         self.engines.pop(stream, None)
+        c = stream._plan_cell
+        if c.ready is self.ready:
+            self._dropped.add(c.key)    # its roster entry holds the engine
 
     def route(self, stream, path, *, vod: bool = False) -> int:
         """Which path serves ``stream`` this wake, from what can be
@@ -351,50 +376,130 @@ class Pump:
             return SCALAR
         return OWNED if mode == 0 and cfg.megabatch_enabled else DEVICE
 
+    def _route_inputs(self) -> tuple:
+        """The configuration ``route`` reads, beside the stream and the
+        ladder: a REST edit of one of them re-routes the whole roster."""
+        cfg = self.config
+        return (cfg.tpu_fanout, cfg.tpu_min_outputs, cfg.megabatch_enabled)
+
     def wake(self, sessions, vod_pairs, t: int) -> int:
-        """Serve every stream of ``sessions`` (the registry's map, walked
-        once) and every ``(stream, engine | None)`` pair of the VOD
-        pacer.  The scheduler is built on the first wake that has
-        ``megabatch_min_streams`` owned entries."""
+        """Serve every stream of ``sessions`` (the registry's map) and
+        every ``(stream, engine | None)`` pair of the VOD pacer.  The
+        scheduler is built on the first wake that has
+        ``megabatch_min_streams`` owned entries.
+
+        **The roster is kept across wakes.**  Where nothing it was built
+        from has moved — the same map at the same ``generation`` (the
+        registry bumps it on every session it adds or removes), the same
+        route configuration, a ladder that names the paths it moved
+        (``take_moved``) — the wake routes again only the entries it may
+        step: the ready set's, the moved paths', those whose engine was
+        dropped (``_reroute``).  A join or leave that moves ``on_device``
+        moved the plan epoch, which marked the stream.  Otherwise, or with
+        no wheel, it walks the map and routes every stream (``_build``).
+        ``audit`` holds the kept roster to a walk of its own."""
         ready, wheel = self.ready, self.wheel
         if wheel is not None:
             # before the wake picks its streams, against its own clock
             ready.update(wheel.advance(t))
-        live, stepped, vod = [], [], []
-        n_owned = 0
+        ladder = self.ladder
+        if ladder is None:
+            moved = ()
+        else:
+            take = getattr(ladder, "take_moved", None)
+            moved = None if take is None else take()
+        gen, cfg = getattr(sessions, "generation", None), self._route_inputs()
+        if (self._kept and sessions is self._sessions and gen == self._gen
+                and cfg == self._cfg and moved is not None):
+            stepped, routed = self._reroute(ready, moved)
+        else:
+            stepped = self._build(sessions, ready, wheel, keep=(
+                wheel is not None and gen is not None and moved is not None))
+            routed = len(self.live)
+            self._sessions, self._gen, self._cfg = sessions, gen, cfg
+        self._dropped.clear()
+        # marks made from here on are the next wake's (and a torn-down
+        # stream's key goes with the rest)
+        ready.clear()
+        live, vod = self.live, []
+        for stream, eng in vod_pairs:
+            path = stream.session_path
+            r = SCALAR if eng is None else self.route(stream, path, vod=True)
+            vod.append((path, stream, eng if r else None, r))
+        owned = self.owned
+        if vod:
+            owned = owned + [(s, eng) for _p, s, eng, r in vod if r == OWNED]
+        min_streams = self.config.megabatch_min_streams
+        if self.megabatch is None and owned and len(owned) >= min_streams:
+            from .megabatch import MegabatchScheduler
+            self.megabatch = MegabatchScheduler(mesh=self.mesh)
+        self.t, self.stepped = t, stepped
+        self.streams = len(live) + len(vod)
+        obs.PUMP_ROSTER_STREAMS.inc(len(live))
+        obs.PUMP_ROUTED_STREAMS.inc(routed)
+        obs.PUMP_STEPPED_STREAMS.inc(len(stepped))
+        self.sent, self.jobs = serve(
+            live, vod, self.megabatch, t, min_streams=min_streams,
+            ladder=self.ladder, log=self.error_log, stepped=stepped,
+            owned=owned)
+        return self.sent
+
+    def _build(self, sessions, ready, wheel, *, keep: bool) -> list:
+        """Walk ``sessions`` once and route every stream: the roster anew,
+        its owned pairs and, to ``keep`` it, each entry's place and each
+        path's keys.  Returns the entries to step: all of them
+        with no wheel, else those marked ready (a stream first rostered
+        here is) or whose route is not the one they last took."""
+        live, stepped, pos, by_path = [], [], {}, {}
         for sess in sessions.values():
             path = sess.path
             for stream in sess.streams.values():
                 r = self.route(stream, path)
-                n_owned += r == OWNED
                 entry = (path, stream,
                          self.engine_for(stream) if r else None, r)
-                live.append(entry)
                 c = stream._plan_cell
                 if c.ready is not ready:        # first rostered here
                     c.install(ready, next(self._keys))
+                if keep:
+                    pos[c.key] = len(live)
+                    by_path.setdefault(path, []).append(c.key)
+                live.append(entry)
                 if wheel is None or c.key in ready or r != c.route:
                     stepped.append(entry)
-        # marks made from here on are the next wake's (and a torn-down
-        # stream's key goes with the rest)
-        ready.clear()
-        for stream, eng in vod_pairs:
-            path = stream.session_path
-            r = SCALAR if eng is None else self.route(stream, path, vod=True)
-            n_owned += r == OWNED
-            vod.append((path, stream, eng if r else None, r))
-        min_streams = self.config.megabatch_min_streams
-        if self.megabatch is None and n_owned and n_owned >= min_streams:
-            from .megabatch import MegabatchScheduler
-            self.megabatch = MegabatchScheduler(mesh=self.mesh)
-        self.t, self.live, self.stepped = t, live, stepped
-        self.streams = len(live) + len(vod)
-        obs.PUMP_ROSTER_STREAMS.inc(len(live))
-        obs.PUMP_STEPPED_STREAMS.inc(len(stepped))
-        self.sent, self.jobs = serve(
-            live, vod, self.megabatch, t, min_streams=min_streams,
-            ladder=self.ladder, log=self.error_log, stepped=stepped)
-        return self.sent
+        self.live, self._pos, self._by_path = live, pos, by_path
+        self.owned = [(s, eng) for _p, s, eng, r in live if r == OWNED]
+        self._kept = keep
+        return stepped
+
+    def _reroute(self, ready, moved) -> tuple[list, int]:
+        """The kept roster's wake: route again the entries of the ready
+        set, of the ``moved`` paths and of the dropped engines, in roster
+        order; replace an entry, and the owned pairs, where its route or
+        engine changed; step those marked ready or whose route is not the
+        one they last took — what ``_build`` would step, since every
+        entry it did not route is as the last wake left it.  Returns (the
+        entries to step, the routes made)."""
+        pos, live, keys = self._pos, self.live, ready
+        if moved or self._dropped:
+            keys = ready | self._dropped
+            for path in moved:
+                keys.update(self._by_path.get(path, ()))
+        stepped, owned_moved = [], False
+        at = sorted(pos[k] for k in keys if k in pos)
+        for i in at:
+            entry = live[i]
+            path, stream, eng, r0 = entry
+            r = self.route(stream, path)
+            e = self.engine_for(stream) if r else None
+            if r != r0 or e is not eng:
+                entry = live[i] = (path, stream, e, r)
+                owned_moved = owned_moved or OWNED in (r, r0)
+            c = stream._plan_cell
+            if c.key in ready or r != c.route:
+                stepped.append(entry)
+        if owned_moved:
+            self.owned = [(s, eng) for _p, s, eng, r in live if r == OWNED]
+        return stepped, len(at)
 
     def arm(self, sessions) -> None:
         """The deadlines pass: one wheel timer a stepped stream, at the
@@ -419,16 +524,19 @@ class Pump:
             c.timer, c.due = wheel.schedule(d, c.key), t + d
 
     def audit(self) -> int:
-        """Hold the marks against the rule: a stream the last wake
-        skipped for which ``needs_step`` is true at that wake's clock and
-        which nothing has marked since is stepped next wake and counted
-        in ``pump_ready_missed_total`` — a missed mark costs the second
-        between two audits, not a stream.  The scheduler reads the pairs
-        the ready set names, so it is held to the same marks: an owned
-        stream its records lag (``MegabatchScheduler.behind``) is
-        counted and stepped alike."""
+        """The guard, once a second: hold the kept roster against a walk
+        of its own (``_audit_roster``), then the marks against the rule —
+        a stream the last wake skipped for which ``needs_step`` is true
+        at that wake's clock and which nothing has marked since is
+        stepped next wake and counted in ``pump_ready_missed_total``: a
+        missed mark costs the second between two audits, not a stream.
+        The scheduler reads the pairs the ready set names, so it is held
+        to the same marks: an owned stream its records lag
+        (``MegabatchScheduler.behind``) is counted and stepped alike.
+        Returns the entries found stale and the streams found unmarked."""
         if self.wheel is None:
             return 0
+        stale = self._audit_roster()
         ready, t, sched = self.ready, self.t, self.megabatch
         stepped = {id(e[1]) for e in self.stepped}
         missed = 0
@@ -443,4 +551,42 @@ class Pump:
                 missed += 1
         if missed:
             obs.PUMP_READY_MISSED.inc(missed)
-        return missed
+        return stale + missed
+
+    def _audit_roster(self) -> int:
+        """Route every kept entry from scratch.  An entry whose route or
+        engine differs is marked ready — the next wake routes it again
+        and replaces it — and a map whose streams are not the roster's
+        has the next wake build it anew; both are counted in
+        ``pump_roster_stale_total``.  A missed invalidation costs a
+        second, never a stream, and shows.  What the next wake routes
+        anyway is left out: a roster it builds anew, and the entries
+        ready, with a dropped engine or on a path the ladder moved or
+        holds in a retry window since."""
+        sessions, live = self._sessions, self.live
+        if (not self._kept
+                or getattr(sessions, "generation", None) != self._gen
+                or self._route_inputs() != self._cfg):
+            return 0
+        walked = [st for sess in sessions.values()
+                  for st in sess.streams.values()]
+        stale = abs(len(walked) - len(live)) + sum(
+            st is not e[1] for st, e in zip(walked, live))
+        if stale:
+            self._kept = False
+        else:
+            ladder, ready, dropped = self.ladder, self.ready, self._dropped
+            unsettled = (ladder.moved | ladder.retrying
+                         if ladder is not None else ())
+            for path, stream, eng, r0 in live:
+                key = stream._plan_cell.key
+                if key in ready or key in dropped or path in unsettled:
+                    continue
+                r = self.route(stream, path)
+                if r != r0 or (self.engines.get(stream) if r else None) \
+                        is not eng:
+                    ready.add(key)
+                    stale += 1
+        if stale:
+            obs.PUMP_ROSTER_STALE.inc(stale)
+        return stale

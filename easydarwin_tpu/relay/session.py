@@ -147,12 +147,21 @@ class RelaySession:
         }
 
 
+class SessionMap(dict):
+    """The registry's path → session map, with the ``generation`` the
+    registry bumps on every session it adds or removes: the pump keeps
+    its roster while it has not moved (``relay.pump.Pump.wake``)."""
+
+    generation = 0
+
+
 class SessionRegistry:
-    """Path → RelaySession map (``sSessionMap`` / ``OSRefTable`` stand-in)."""
+    """Path → RelaySession map (``sSessionMap`` / ``OSRefTable`` stand-in).
+    ``find_or_create`` and ``remove`` are the map's only writers."""
 
     def __init__(self, settings: StreamSettings | None = None):
         self.settings = settings or StreamSettings()
-        self.sessions: dict[str, RelaySession] = {}
+        self.sessions: SessionMap[str, RelaySession] = SessionMap()
         self.sdp_cache = sdp_mod.SdpCache()
 
     def find(self, path: str) -> RelaySession | None:
@@ -164,6 +173,7 @@ class SessionRegistry:
         if sess is None:
             sess = RelaySession(key, sdp_mod.parse(sdp_text), self.settings)
             self.sessions[key] = sess
+            self._moved()
             self.sdp_cache.set(key, sdp_text)
             EVENTS.emit("session.create", stream=key,
                         trace_id=sess.trace_id, path=key,
@@ -175,8 +185,16 @@ class SessionRegistry:
         sess = self.sessions.pop(key, None)
         self.sdp_cache.pop(key)
         if sess is not None:
+            self._moved()
             EVENTS.emit("session.remove", stream=key,
                         trace_id=sess.trace_id, path=key)
+
+    def _moved(self) -> None:
+        """A session came or went: the next wake builds its roster anew
+        (a map put in place of the registry's has no generation, and is
+        walked every wake)."""
+        if isinstance(self.sessions, SessionMap):
+            self.sessions.generation += 1
 
     def paths(self) -> list[str]:
         return sorted(self.sessions)

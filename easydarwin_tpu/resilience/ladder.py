@@ -92,6 +92,13 @@ class DegradationLadder:
         self._retries = retries if retries is not None \
             else obs.RESILIENCE_RETRIES
         self._streams: dict[str, _Health] = {}
+        #: paths whose ``engine_mode`` may have changed since the pump
+        #: last took them (``take_moved``): a rung move, a retry backoff
+        #: set or cleared, a health record dropped
+        self.moved: set[str] = set()
+        #: paths inside a retry backoff window, where ``engine_mode``
+        #: reads the clock: taken every wake until it has passed
+        self.retrying: set[str] = set()
         self._slo_was_violating = False
         self.degrades = 0
         self.recovers = 0
@@ -118,6 +125,24 @@ class DegradationLadder:
             if (self._clock() if now is None else now) < h.backoff_until:
                 return LEVEL_CPU
         return h.level
+
+    def take_moved(self) -> set[str]:
+        """The paths whose ``engine_mode`` may differ from what the last
+        call's caller read (the pump's kept roster routes their streams
+        again): every move since, and every path inside a retry backoff
+        window, the first call after the window has passed included."""
+        moved = self.moved
+        if moved:
+            self.moved = set()
+        if not self.retrying:
+            return moved
+        moved = moved | self.retrying
+        now = self._clock()
+        for path in list(self.retrying):
+            h = self._streams.get(path)
+            if h is None or now >= h.backoff_until:
+                self.retrying.discard(path)
+        return moved
 
     def worst_level(self) -> int:
         return max((h.level for h in self._streams.values()), default=0)
@@ -154,6 +179,8 @@ class DegradationLadder:
                           * (2 ** (h.retries - 1)),
                           self.config.backoff_cap_ms) / 1000.0
             h.backoff_until = now + backoff
+            self.moved.add(path)
+            self.retrying.add(path)
             self._retries.inc()
         else:
             self._degrade(path, h, now, reason=reason)
@@ -172,6 +199,7 @@ class DegradationLadder:
         if now - h.last_error >= self.config.recover_sec:
             h.retries = 0
             h.backoff_until = 0.0
+            self.moved.add(path)
 
     def note_scheduler_error(self, paths, now: float | None = None) -> None:
         """A megabatch-scheduler failure (the pump already degraded the
@@ -196,6 +224,7 @@ class DegradationLadder:
         if stalls is not None:
             for path in [p for p in self._streams if p not in stalls]:
                 del self._streams[path]
+                self.moved.add(path)
                 self._gauge.remove(stream=path)
         # SLO burn rising edge: the worst-p99 session pays one rung
         if slo_status is not None:
@@ -245,6 +274,7 @@ class DegradationLadder:
         h.retries = 0
         h.backoff_until = 0.0
         h.last_change = now
+        self.moved.add(path)
         self.degrades += 1
         self._gauge.set(h.level, stream=path)
         self._transitions.inc(direction="down")
@@ -259,6 +289,7 @@ class DegradationLadder:
         # rung per tick, so a deep degradation recovers in seconds, not
         # rungs × recover_sec (the 30 s post-clearance budget)
         h.last_change = 0.0
+        self.moved.add(path)
         self.recovers += 1
         self._gauge.set(h.level, stream=path)
         self._transitions.inc(direction="up")

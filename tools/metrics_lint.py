@@ -871,7 +871,9 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
     31), counted where ``pump.sleep`` closes: ``pump_drain_rounds_total``
     / ``pump_drain_packets_total``; and the ready set's three (ISSUE 33):
     ``pump_roster_streams_total`` / ``pump_stepped_streams_total`` once a
-    wake, ``pump_ready_missed_total`` by the 1 Hz audit; and the
+    wake, ``pump_ready_missed_total`` by the 1 Hz audit; and the kept
+    roster's two (ISSUE 40): ``pump_routed_streams_total`` once a wake,
+    ``pump_roster_stale_total`` by the same audit; and the
     scheduler's hand-over (ISSUE 36): ``megabatch_pairs_total{kind}``,
     handed in ``begin_wake`` and walked in ``end_wake``; and the send
     pipeline's two (ISSUE 38), counted once a wake that handed the
@@ -931,6 +933,8 @@ def lint_spans(registry, root: pathlib.Path | None = None) -> list[str]:
             "pump_roster_streams_total": ((), ()),
             "pump_stepped_streams_total": ((), ()),
             "pump_ready_missed_total": ((), ()),
+            "pump_routed_streams_total": ((), ()),
+            "pump_roster_stale_total": ((), ()),
             "relay_due_to_wire_seconds": (("engine",), ()),
             "engine_outputs_walked_total": ((), ()),
             "engine_outputs_due_total": ((), ()),
