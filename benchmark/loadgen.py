@@ -21,6 +21,8 @@ import time
 
 import numpy as np
 
+from benchmark import lastmile
+
 SDP = ("v=0\r\no=- 1 1 IN IP4 127.0.0.1\r\ns=benchmark\r\nt=0 0\r\n"
        "m=video 0 RTP/AVP 96\r\na=rtpmap:96 H264/90000\r\n"
        "a=control:trackID=1\r\n")
@@ -69,13 +71,17 @@ class Source:
     ``shapes`` is the configuration's ``stream`` group."""
 
     def __init__(self, idx: int, seed: int, n_frames: int, fps: float,
-                 shapes: dict, start_frame: int = 0, first_gop_at: int = 0):
+                 shapes: dict, start_frame: int = 0, first_gop_at: int = 0,
+                 warm_idrs: dict[int, int] | None = None):
         """``start_frame``: the frame with which this source comes on
         line, an IDR (cameras do not all start in one frame period);
         earlier frames hold no packets.  ``first_gop_at``: the frame at
         which its GOP cycle starts after that opening IDR: cameras are
         not GOP-locked, so each source's IDRs fall at their own place
-        in the GOP period."""
+        in the GOP period.  ``warm_idrs``: frame -> the size of an IDR
+        that the warm-up carries there in place of a P frame (the mix's
+        ``warm_idr_bytes``)."""
+        warm_idrs = warm_idrs or {}
         rng = np.random.default_rng([seed, idx])
         self.idx = idx
         self.path = f"/live/cam{idx:02d}"
@@ -92,12 +98,13 @@ class Source:
             self.frame_start.append(len(self.packets))
             if f < start_frame:
                 continue
-            if f == start_frame or (f >= first_gop_at
-                          and (f - first_gop_at) % gop == 0):
+            if f in warm_idrs or f == start_frame or (
+                    f >= first_gop_at and (f - first_gop_at) % gop == 0):
                 nals = [bytes((0x67,)) + rng.bytes(23),
-                        bytes((0x68,)) + rng.bytes(7),
-                        bytes((0x65,)) + rng.bytes(
-                            int(rng.integers(idr_lo, idr_hi)))]
+                        bytes((0x68,)) + rng.bytes(7)]
+                size = (warm_idrs[f] if f in warm_idrs
+                        else int(rng.integers(idr_lo, idr_hi)))
+                nals.append(bytes((0x65,)) + rng.bytes(size))
             else:
                 nals = [bytes((0x41,)) + rng.bytes(
                     int(rng.integers(p_lo, p_hi)))]
@@ -185,15 +192,17 @@ class RtspConn:
             "Transport": "RTP/AVP/TCP;unicast;interleaved=0-1;mode=record"})
         await self.expect("RECORD", uri)
 
-    async def play_start(self, uri: str, rtp_port: int
+    async def play_start(self, uri: str, rtp_port: int,
+                         setup_extra: dict | None = None
                          ) -> tuple[int | None, int | None]:
         """DESCRIBE / SETUP / PLAY over UDP; returns what the server
         announced for this session: (ssrc of SETUP's Transport, first
-        sequence number of PLAY's RTP-Info)."""
+        sequence number of PLAY's RTP-Info).  ``setup_extra``: more
+        SETUP headers (a lossy last mile asks for parity and RTX)."""
         await self.expect("DESCRIBE", uri, {"Accept": "application/sdp"})
         hdrs = await self.expect("SETUP", f"{uri}/trackID=1", {
             "Transport": f"RTP/AVP;unicast;client_port={rtp_port}-"
-                         f"{rtp_port + 1}"})
+                         f"{rtp_port + 1}", **(setup_extra or {})})
         ssrc = None
         for part in hdrs.get("transport", "").split(";"):
             k, _, v = part.strip().partition("=")
@@ -251,6 +260,8 @@ class Flow:
         self.stamps: list[int] = []         # perf_counter_ns at recv
         self.ssrc: int | None = None        # from the SETUP answer
         self.first_seq: int | None = None   # from the PLAY RTP-Info
+        #: behind a lossy last mile, its ``lastmile.StampedReceiver``
+        self.lm: lastmile.StampedReceiver | None = None
 
 
 class StampReader(threading.Thread):
@@ -284,6 +295,44 @@ class StampReader(threading.Thread):
                     pass
 
 
+class LossyStampReader(StampReader):
+    """A ``StampReader`` whose flows sit behind a lossy last mile: each
+    datagram goes to its flow's ``lastmile.StampedReceiver``, which holds
+    (and stamps) media as it survives the chain or is repaired; the
+    server's sender reports name the address RRs and NACKs go to; every
+    ``SWEEP_NS`` the receivers' timers run."""
+
+    SWEEP_NS = 20_000_000
+
+    def __init__(self, flows: list[Flow], name: str):
+        super().__init__(flows, name)
+        self.flows = flows
+        self.rtcp_of = {f.rtcp.fileno(): f for f in flows}
+
+    def run(self) -> None:
+        now = time.perf_counter_ns
+        for f in self.flows:
+            f.lm.start(now())
+        next_sweep = 0
+        while not self.stop_flag:
+            for fd, _ in self.ep.poll(0.02):
+                flow, sock = self.by_fd[fd], self.socks[fd]
+                try:
+                    while True:
+                        if flow is None:
+                            _, addr = sock.recvfrom(4096)
+                            self.rtcp_of[fd].lm.server_rtcp = addr
+                        else:
+                            flow.lm.on_rtp(sock.recv(4096), now())
+                except OSError:         # drained (BlockingIOError) or gone
+                    pass
+            t = now()
+            if t >= next_sweep:
+                next_sweep = t + self.SWEEP_NS
+                for f in self.flows:
+                    f.lm.sweep(t)
+
+
 class BulkDrains:
     """Counts datagrams on the bulk (unstamped) flows' sockets in forked
     worker processes of their own, so that neither the pusher nor the
@@ -291,18 +340,33 @@ class BulkDrains:
     (loopback IP, port) slot: every slot has its own receive queue.
 
     Counters live in shared memory: ``counts()`` reads them at any time.
+
+    Behind a lossy last mile (``last_mile``) every bulk session has a
+    slot of its own (``n_bulk`` of them), since the server routes RTCP
+    by its source address: the workers count media, parity and RTX
+    apart, run one chain a slot over the media, and send the slot's RR
+    every ``rr_interval_s`` from its RTCP socket; ``counts()`` then
+    reads media only.
     """
 
-    def __init__(self, n_procs: int):
+    #: per process: rtp datagrams, rtp bytes, rtcp datagrams; behind a
+    #: lossy last mile also parity and RTX datagrams, media the chains
+    #: lost, RRs sent
+    LOSSY_COUNTS = ("parity", "rtx", "lost_by_chain", "rrs_sent")
+
+    def __init__(self, n_procs: int, last_mile=None, seed: int = 0,
+                 n_bulk: int = 0):
         self.n_procs = n_procs
         self.rtp: list[socket.socket] = []
         self.rtcp: list[socket.socket] = []
         self.ports: list[int] = []
-        for _ in range(N_PORT):
+        groups = N_PORT if last_mile is None else -(-n_bulk // N_IP)
+        for _ in range(groups):
             self.ports.append(self._open_port_group())
-        # shared: [stop, pad] then per process (rtp datagrams, rtp bytes,
-        # rtcp datagrams)
-        self._mm = mmap.mmap(-1, 8 * (2 + 3 * n_procs))
+        self.last_mile, self.seed = last_mile, seed
+        self.stride = 3 + (len(self.LOSSY_COUNTS) if last_mile else 0)
+        # shared: [stop, pad] then ``stride`` counters a process
+        self._mm = mmap.mmap(-1, 8 * (2 + self.stride * n_procs))
         self._shared = memoryview(self._mm).cast("q")
         self.pids: list[int] = []
 
@@ -329,7 +393,11 @@ class BulkDrains:
         raise LoadgenError("no UDP port free on all bulk loopback IPs")
 
     def slot(self, j: int) -> tuple[str, int]:
-        """Bulk destination ``j`` of a source: (ip, rtp port)."""
+        """Bulk destination ``j`` of a source: (ip, rtp port).  Behind a
+        lossy last mile ``j`` is the session's place among all sources'
+        bulk sessions, and its slot is ``self.rtp[j]``."""
+        if self.last_mile:
+            return f"127.0.0.{1 + j % N_IP}", self.ports[j // N_IP]
         return (f"127.0.0.{1 + (j // N_PORT) % N_IP}",
                 self.ports[j % N_PORT])
 
@@ -340,7 +408,7 @@ class BulkDrains:
             pid = os.fork()
             if pid == 0:
                 try:
-                    self._work(k)
+                    (self._work_lossy if self.last_mile else self._work)(k)
                 finally:
                     os._exit(0)
             self.pids.append(pid)
@@ -373,11 +441,86 @@ class BulkDrains:
                     pass
             shared[base], shared[base + 1], shared[base + 2] = tot
 
+    def _work_lossy(self, k: int) -> None:
+        """``_work`` behind the last mile: media, parity and RTX counted
+        apart, each slot's media through its chain, and its RR sent from
+        its RTCP socket to the address the server's SRs come from."""
+        signal.signal(signal.SIGINT, signal.SIG_IGN)
+        lm, shared = self.last_mile, self._shared
+        base = 2 + self.stride * k
+        ep = select.epoll()
+        mine: dict[int, tuple[socket.socket, int, bool]] = {}
+        #: slot -> its chain and reports, next RR, own SSRC, the SSRC of
+        #: the session it reports on
+        slots: dict[int, list] = {}
+        t0 = time.perf_counter_ns()
+        for slot in range(k, len(self.rtp), self.n_procs):
+            for s, is_rtp in ((self.rtp[slot], True),
+                              (self.rtcp[slot], False)):
+                mine[s.fileno()] = (s, slot, is_rtp)
+                ep.register(s.fileno(), select.EPOLLIN)
+            r = lastmile.rng(self.seed, "bulk-rtcp", slot)
+            slots[slot] = [lastmile.BulkSource(lm.chain(self.seed, "bulk",
+                                                        slot)),
+                           t0 + int(r.random() * lm.rr_interval_ns),
+                           r.randrange(1, 1 << 32), None]
+        server = None
+        buf = bytearray(4096)
+        tot = [0] * self.stride
+        ppid = os.getppid()
+        while not shared[0]:
+            events = ep.poll(0.05)
+            if not events and os.getppid() != ppid:
+                return                      # orphaned: the parent died
+            for fd, _ in events:
+                s, slot, is_rtp = mine[fd]
+                try:
+                    while True:
+                        if not is_rtp:
+                            _, server = s.recvfrom_into(buf)
+                            tot[2] += 1
+                            continue
+                        n = s.recv_into(buf)
+                        kind = lm.kind(buf) if n >= 12 else "media"
+                        if kind != "media":
+                            tot[3 if kind == "parity" else 4] += 1
+                            continue
+                        tot[0] += 1
+                        tot[1] += n
+                        if n >= 12:
+                            st = slots[slot]
+                            st[3] = int.from_bytes(buf[8:12], "big")
+                            tot[5] += not st[0].on_media(buf)
+                except OSError:
+                    pass
+            now = time.perf_counter_ns()
+            for slot, st in slots.items():
+                if now < st[1]:
+                    continue
+                st[1] = lm.next_report(st[1], now)
+                if server is None or st[0].stats.base is None:
+                    continue
+                try:
+                    self.rtcp[slot].sendto(lastmile.receiver_report(
+                        st[2], [st[0].stats.block(st[3])]), server)
+                    tot[6] += 1
+                except OSError:
+                    pass
+            for c, v in enumerate(tot):
+                shared[base + c] = v
+
     def counts(self) -> tuple[int, int, int]:
-        """(RTP datagrams, RTP bytes, RTCP datagrams) so far."""
-        s = self._shared
-        return tuple(sum(s[2 + 3 * k + c] for k in range(self.n_procs))
+        """(RTP datagrams, RTP bytes, RTCP datagrams) so far; behind a
+        lossy last mile the RTP ones count media only."""
+        s, w = self._shared, self.stride
+        return tuple(sum(s[2 + w * k + c] for k in range(self.n_procs))
                      for c in range(3))
+
+    def lossy_counts(self) -> dict[str, int]:
+        """``LOSSY_COUNTS`` over every process so far."""
+        s, w = self._shared, self.stride
+        return {name: sum(s[2 + w * k + 3 + c] for k in range(self.n_procs))
+                for c, name in enumerate(self.LOSSY_COUNTS)}
 
     def stop(self) -> None:
         self._shared[0] = 1
@@ -417,6 +560,14 @@ class Loadgen:
                                f"{sorted(FRAME_PHASES)}")
         #: how much of i/n of a period source i's frames trail source 0's
         self.phase_spread = FRAME_PHASES[phase]
+        try:
+            self.last_mile = lastmile.LastMile.of(cfg)
+        except (lastmile.LastMileError, KeyError, TypeError,
+                ValueError) as e:
+            raise LoadgenError(f"last_mile: {e}") from e
+        #: SETUP headers a lossy last mile's players add: they ask for
+        #: parity and RTX (the server grants them only when asked)
+        self.setup_extra = {"x-FEC": "parity"} if self.last_mile else None
         self.fps = float(traffic["fps_per_source"])
         self.warm_frames = int(traffic["warm_frames"])
         self.n_src, self.n_sub = n_sources, n_players
@@ -428,25 +579,66 @@ class Loadgen:
         gop = cfg["stream"]["gop_frames"]
         deal = np.random.default_rng([seed, 1 << 20]).permutation(n_sources)
         W = self.warm_frames
+        warm_idrs = self._warm_idrs(traffic, cfg["stream"], n_sources)
         self.sources = [
             Source(i, seed, W + n_win, self.fps, cfg["stream"],
                    start_frame=i * W // (2 * n_sources),
-                   first_gop_at=W + int(deal[i]) * gop // n_sources)
+                   first_gop_at=W + int(deal[i]) * gop // n_sources,
+                   warm_idrs=warm_idrs)
             for i in range(n_sources)]
-        self.bulk = BulkDrains(int(traffic.get("bulk_drain_procs", 4)))
+        #: bulk sessions a source
+        self.n_bulk = n_players - len(range(0, n_players, self.stamped_every))
+        self.bulk = BulkDrains(int(traffic.get("bulk_drain_procs", 4)),
+                               self.last_mile, seed, n_sources * self.n_bulk)
         self.flows: list[Flow] = []
         for src in self.sources:
             for j in range(n_players):
                 if j % self.stamped_every == 0:
                     self.flows.append(Flow(
                         src, f"127.0.0.{1 + (src.idx + j) % N_IP}"))
-        self.readers = [StampReader(self.flows[k::4], f"stamp-reader-{k}")
+        reader = StampReader
+        if self.last_mile:
+            reader = LossyStampReader
+            for n, f in enumerate(self.flows):
+                f.lm = lastmile.StampedReceiver(f, self.last_mile, seed, n)
+        self.readers = [reader(self.flows[k::4], f"stamp-reader-{k}")
                         for k in range(4)]
         self.players: list[RtspConn] = []
         self.n_bulk_joined = 0
         self.lateness: list[float] = []
         #: (source idx, frame) -> perf_counter_ns at which it was due
         self.due_ns: dict[tuple[int, int], int] = {}
+
+    #: frames from one of the warm-up's stated IDRs to the next
+    WARM_IDR_EVERY = 6
+
+    def _warm_idrs(self, traffic: dict, stream: dict, n_sources: int
+                   ) -> dict[int, int]:
+        """The mix's ``warm_idr_bytes``: the warm-up's last frames carry
+        IDRs of these sizes, in this order, one every ``WARM_IDR_EVERY``
+        frames, and ``warm_tail`` says how many frames that is.  The
+        harness pushes those frames only once the server has sent all it
+        owes for the earlier ones, so that each such IDR reaches a server
+        no longer behind its first builds, in a read of its own: the
+        packet counts the window's IDRs take on either side of a
+        device-ring pad (an IDR is 30-34 packets, astride 32) are then
+        each met before the window opens.  Each size lies in the
+        stream's ``idr_bytes``; every source comes on line before the
+        tail."""
+        sizes = [int(b) for b in traffic.get("warm_idr_bytes", ())]
+        lo, hi = stream["idr_bytes"]
+        W = self.warm_frames
+        self.warm_tail = self.WARM_IDR_EVERY * len(sizes)
+        last_start = (n_sources - 1) * W // (2 * n_sources)
+        if any(not lo <= b <= hi for b in sizes) or (
+                sizes and last_start >= W - self.warm_tail):
+            raise LoadgenError(
+                f"warm_idr_bytes {sizes}: each in idr_bytes [{lo}, {hi}], "
+                f"and their {self.warm_tail} frames after every source's "
+                f"opening IDR (frame {last_start} at the latest) in the "
+                f"{W} warm-up frames")
+        return {W - self.warm_tail + self.WARM_IDR_EVERY * j: b
+                for j, b in enumerate(sizes)}
 
     def start_receivers(self) -> None:
         self.bulk.start()               # forks: before any thread
@@ -475,7 +667,7 @@ class Loadgen:
         c = RtspConn()
         await c.connect("127.0.0.1", port, local_ip=ip)
         ssrc, seq = await c.play_start(
-            f"rtsp://127.0.0.1:{port}{src.path}", rtp_port)
+            f"rtsp://127.0.0.1:{port}{src.path}", rtp_port, self.setup_extra)
         self.players.append(c)
         if flow is not None:
             flow.ssrc, flow.first_seq = ssrc, seq
@@ -502,7 +694,9 @@ class Loadgen:
                         jobs.append(self._join(port, src, flow.ip,
                                                flow.port, flow))
                     else:
-                        ip, p = self.bulk.slot(bulk_j)
+                        ip, p = self.bulk.slot(
+                            src.idx * self.n_bulk + bulk_j
+                            if self.last_mile else bulk_j)
                         bulk_j += 1
                         jobs.append(self._join(port, src, ip, p, None))
                 await asyncio.gather(*jobs)
@@ -560,3 +754,30 @@ class Loadgen:
 
     def expected_deliveries(self) -> int:
         return sum(s.pushed for s in self.sources) * self.n_sub
+
+    # -- the lossy last mile -------------------------------------------------
+    def mark_pushed(self) -> None:
+        """Every stamped receiver learns how many packets it is owed so
+        far, once the server has sent them all: a loss at the tail of
+        what was pushed has no later packet to show it.  (Before that, a
+        packet not read yet may be one the server has not sent: asking
+        for it would make the server send it twice.)"""
+        for f in self.flows:
+            f.lm.end = f.src.pushed
+
+    def quiesce(self) -> None:
+        """No more NACKs: the drain is over, what is missing stays so."""
+        for f in self.flows:
+            f.lm.nacking = False
+
+    def lossy_counts(self) -> dict[str, int]:
+        """What the players read and repaired behind the last mile."""
+        got = self.bulk.lossy_counts()
+        lms = [f.lm for f in self.flows]
+        got["parity"] += sum(len(m.parity) for m in lms)
+        got["rtx"] += sum(len(m.rtx) for m in lms)
+        got["lost_by_chain"] += sum(m.lost_by_chain for m in lms)
+        got["rrs_sent"] += sum(m.rrs_sent for m in lms)
+        for name in ("recovered_fec", "recovered_rtx", "nacks_sent"):
+            got[name] = sum(getattr(m, name) for m in lms)
+        return got

@@ -37,7 +37,8 @@ def reference_flow(pushed: list[bytes], first_seq: int, ssrc: int,
 
 
 def judge_flow(got: list[bytes], pushed: list[bytes], first_seq: int | None,
-               ssrc: int | None) -> dict[str, int]:
+               ssrc: int | None, repaired: list[bytes] = ()
+               ) -> dict[str, int]:
     """Counts of what is wrong with one subscriber's received packets.
 
     ``missing``: pushed packets that never arrived.  ``out_of_order``:
@@ -45,23 +46,22 @@ def judge_flow(got: list[bytes], pushed: list[bytes], first_seq: int | None,
     arrival's.  ``altered``: arrivals that are no pushed packet's
     rewrite — payload, first two bytes, SSRC, sequence number or
     timestamp offset differ.  ``unannounced``: 1 if the session's
-    answers did not carry the SSRC or first sequence number."""
+    answers did not carry the SSRC or first sequence number.
+
+    ``repaired``: behind a lossy last mile, the packets the subscriber
+    restored from parity or RTX.  They count as held, and as altered
+    where they are no pushed packet's rewrite; they came when they were
+    restored, so they are no part of the order."""
     out = {"missing": 0, "out_of_order": 0, "altered": 0, "unannounced": 0}
     if first_seq is None or ssrc is None:
         out["unannounced"] = 1
         out["missing"] = len(pushed)
         return out
     got = [g for g in got if len(g) >= 12]
-    if not got:
+    if not got and not repaired:
         out["missing"] = len(pushed)
         return out
-    # the timestamp offset is constant per session; the first packet that
-    # is in its place fixes it
-    k0 = (int.from_bytes(got[0][2:4], "big") - first_seq) & 0xFFFF
-    ts_offset = 0
-    if k0 < len(pushed):
-        ts_offset = (int.from_bytes(got[0][4:8], "big")
-                     - int.from_bytes(pushed[k0][4:8], "big")) & 0xFFFFFFFF
+    ts_offset = offset_of((got or repaired)[0], pushed, first_seq)
     seen = bytearray(len(pushed))
     last = -1
     for g in got:
@@ -77,8 +77,26 @@ def judge_flow(got: list[bytes], pushed: list[bytes], first_seq: int | None,
         if k < last:
             out["out_of_order"] += 1
         last = max(last, k)
+    for g in repaired:
+        k = place_of(g, first_seq, len(pushed), last)
+        if k >= len(pushed) or seen[k] or g != expected_packet(
+                pushed[k], k, first_seq, ssrc, ts_offset):
+            out["altered"] += 1
+            continue
+        seen[k] = 1
     out["missing"] = len(pushed) - sum(seen)
     return out
+
+
+def offset_of(g: bytes, pushed: list[bytes], first_seq: int) -> int:
+    """The session's timestamp offset, as packet ``g`` shows it: the
+    offset is constant per session, and the first packet that is in its
+    place fixes it."""
+    k0 = (int.from_bytes(g[2:4], "big") - first_seq) & 0xFFFF
+    if k0 >= len(pushed):
+        return 0
+    return (int.from_bytes(g[4:8], "big")
+            - int.from_bytes(pushed[k0][4:8], "big")) & 0xFFFFFFFF
 
 
 def place_of(g: bytes, first_seq: int, n_pushed: int, last: int) -> int:

@@ -40,10 +40,15 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
-from benchmark import loadgen, readers, reference, stats  # noqa: E402
+from benchmark import (lastmile, loadgen, readers, reference,  # noqa: E402
+                       reference_fec, stats)
 
 EXIT_NO_DEVICE = 3
 DRAIN_TIMEOUT_S = 60.0          # how long a delivery may come late
+#: behind a lossy last mile, how long the server is given, once the
+#: players stop asking, to put out what they asked for before its counts
+#: are read
+SETTLE_S = 1.0
 TRACE_AFTER_S, TRACE_MAX_S = 2.0, 8.0
 
 
@@ -235,14 +240,19 @@ class Run:
             f"sessions joined in {self.harness['rtsp.join_s']:.2f} s "
             f"({len(lg.flows)} stamped)")
 
-        # 2. warm-up at the cell's own pace: one IDR and a few P frames
-        W = lg.warm_frames
-        warm_s = W / lg.fps
-        await lg.push_frames(0, lambda due: due < warm_s,
-                             time.perf_counter_ns())
-        keep = asyncio.ensure_future(lg.pusher_keepalive())
-        sent = await self.wait_owed(120.0)
-        keep.cancel()
+        # 2. warm-up at the cell's own pace: one IDR and a few P frames;
+        # a mix that states warm-up IDRs has them in a tail of its own,
+        # pushed once the server has sent all it owes for the frames
+        # before it (``Loadgen._warm_idrs``)
+        W, tail = lg.warm_frames, lg.warm_tail
+        for lo, n in ((0, W - tail), (W - tail, tail)):
+            if not n:
+                continue
+            await lg.push_frames(lo, lambda due, n=n: due < n / lg.fps,
+                                 time.perf_counter_ns())
+            keep = asyncio.ensure_future(lg.pusher_keepalive())
+            sent = await self.wait_owed(120.0)
+            keep.cancel()
         log(f"warm-up: {W} frames/source at {lg.fps:g} fps, server sent "
             f"{sent} of {lg.expected_deliveries()}")
         lg.lateness.clear()
@@ -258,6 +268,7 @@ class Run:
         k0 = loadgen.udp_kernel_counters()
         got0 = lg.received()
         t0_ns = time.perf_counter_ns()
+        self.window_wall = time.time()      # the server's events' clock
         self.harness["setup_s"] = (t0_ns - T_START_NS) / 1e9
         tracer = (asyncio.ensure_future(self.trace_bracket(S))
                   if self.trace_dir else None)
@@ -290,6 +301,9 @@ class Run:
         keep.cancel()
         if tracer is not None:
             await tracer
+        if lg.last_mile:
+            lg.quiesce()
+            await asyncio.sleep(SETTLE_S)
         m_end = await asyncio.to_thread(srv.metrics)
         k_end = loadgen.udp_kernel_counters()
         self.account(m_end, k0, k_end)
@@ -316,6 +330,10 @@ class Run:
         while time.monotonic() - t0 < timeout:
             m = await asyncio.to_thread(self.server.metrics)
             sent = int(readers.fam(m, "egress_packets_total") or 0)
+            if sent >= want and self.lg.last_mile:
+                # the server has put every owed packet on the wire: what
+                # a player has not read by now, at the tail, is lost
+                self.lg.mark_pushed()
             if sent >= want and self.lg.received() >= want:
                 break
             await asyncio.sleep(0.5)
@@ -358,6 +376,15 @@ class Run:
             f"{bulk_got + stamped_got} (bulk {bulk_got} of {bulk_owed}, "
             f"stamped {stamped_got}), kernel UDP receive-queue drops over "
             f"window and drain {kd or 'not given by this kernel'}")
+        if lg.last_mile:
+            # the counts above are media only; parity and RTX leave the
+            # server outside ``egress_packets_total`` and are held against
+            # its own counts of them (from boot: the server is fresh)
+            self.acct["last_mile"] = lm = lg.lossy_counts()
+            lm["server_parity"] = int(
+                readers.fam(m_end, "fec_parity_packets_total") or 0)
+            lm["server_rtx"] = int(readers.fam(m_end, "rtx_sent_total") or 0)
+            log("last mile: " + ", ".join(f"{k} {v}" for k, v in lm.items()))
 
     def watch(self, m0, m_end) -> None:
         """What the server did to itself over the run: printed, not
@@ -367,11 +394,20 @@ class Run:
                  "megabatch_wire_mismatch_total", "megabatch_fallback_total",
                  "jax_executables_built_total",
                  "jax_persistent_cache_hits_total",
-                 'relay_ingest_to_wire_seconds_count{engine="scalar"}')
+                 'relay_ingest_to_wire_seconds_count{engine="scalar"}',
+                 "fec_parity_packets_total", "rtx_sent_total",
+                 "rtx_giveup_total", "fec_parity_oracle_mismatch_total",
+                 "qos_thins_total", "qos_thickens_total")
         grew = {n: (readers.fam(m_end, n) or 0) - (readers.fam(m0, n) or 0)
                 for n in names}
         log("server over window and drain: " + ", ".join(
             f"{n} +{v:g}" for n, v in grew.items()))
+        ratios = sorted(v for k, v in m_end.items()
+                        if k.startswith("fec_overhead_ratio{"))
+        if ratios:
+            log(f"fec_overhead_ratio at the end over {len(ratios)} streams: "
+                f"min {ratios[0]:g} median {stats.percentile(ratios, 50):g} "
+                f"max {ratios[-1]:g}")
         try:
             since = 0
             while True:
@@ -379,7 +415,12 @@ class Run:
                     f"/api/v1/events?n=1024&since={since}"
                 ).decode().splitlines()]
                 for e in recs:
-                    if e["event"].split(".")[0] in ("ladder", "slo"):
+                    if e["event"].split(".")[0] in ("ladder", "slo", "fec",
+                                                    "rtx") or (
+                            e["event"] == "jax.build"
+                            and e["ts"] >= self.window_wall):
+                        # a build after the window opened: which program,
+                        # compiled or loaded, and in which wake
                         log(f"server event: {json.dumps(e)[:300]}")
                 if len(recs) < 1024:
                     break
@@ -393,11 +434,13 @@ class Run:
         lg, a = self.lg, self.acct
         tot = {"missing": 0, "out_of_order": 0, "altered": 0,
                "unannounced": 0}
+        wire = {"parity": 0, "rtx": 0}      # behind a lossy last mile
         ctl = self.args.control or ""
         for n, f in enumerate(lg.flows):
             pushed = f.src.packets[:f.src.pushed]
-            got = f.packets
-            if ctl.startswith("ref:") and n == self.args.seed % len(lg.flows):
+            got = f.packets if f.lm is None else f.lm.received
+            if ctl.startswith("ref:") and ctl[4:] in reference.GUARANTEES \
+                    and n == self.args.seed % len(lg.flows):
                 # the control: the reference in the program's place,
                 # one guarantee broken once
                 got = reference.break_guarantee(
@@ -405,9 +448,15 @@ class Run:
                                              f.ssrc or 0, 0),
                     ctl[4:], self.args.seed,
                     int.from_bytes(pushed[0][8:12], "big"))
+            repaired = () if f.lm is None else f.lm.repaired
             for k, v in reference.judge_flow(
-                    got, pushed, f.first_seq, f.ssrc).items():
+                    got, pushed, f.first_seq, f.ssrc, repaired).items():
                 tot[k] += v
+            if f.lm is not None and None not in (f.ssrc, f.first_seq) \
+                    and (got or repaired):
+                for kind, v in self.judge_repair(f, pushed, got or repaired,
+                                                 ctl).items():
+                    wire[kind] += v
         self.compare("stamped_missing", tot["missing"])
         self.compare("stamped_out_of_order", tot["out_of_order"])
         self.compare("stamped_altered", tot["altered"])
@@ -421,6 +470,14 @@ class Run:
         short = max(a["bulk_owed"] - a["bulk_received"], 0)
         self.compare("bulk_short", short)
         self.failed = tot["missing"] + tot["altered"] + short
+        if lg.last_mile:
+            lm = a["last_mile"]
+            self.compare("parity_altered", wire["parity"])
+            self.compare("rtx_altered", wire["rtx"])
+            self.compare("parity_unaccounted",
+                         abs(lm["parity"] - lm["server_parity"]))
+            self.compare("rtx_unaccounted", abs(lm["rtx"] - lm["server_rtx"]))
+            self.failed += wire["parity"] + wire["rtx"]
         ok = all(v <= lim for v, lim in self.compared.values())
         if short and a["server_sent"] >= a["owed"]:
             # the server put them on the wire and this process's own
@@ -431,6 +488,58 @@ class Run:
                 f"them: the generator's loss, not the program's wrong "
                 f"output")
         return ok
+
+    def judge_repair(self, f: loadgen.Flow, pushed: list[bytes],
+                     held: list[bytes], ctl: str) -> dict[str, int]:
+        """One stamped flow's parity and RTX datagrams held against
+        ``reference_fec``'s over the packets the flow is owed.  The
+        controls ``ref:parity`` and ``ref:rtx`` put the reference's own
+        datagrams in the place of the first flow, from the seed's, that
+        read any, one of them broken."""
+        lm = self.lg.last_mile
+        ts_offset = reference.offset_of(held[0], pushed, f.first_seq)
+
+        def expected_at(seq: int) -> bytes | None:
+            k = (seq - f.first_seq) & 0xFFFF
+            return (reference.expected_packet(pushed[k], k, f.first_seq,
+                                              f.ssrc, ts_offset)
+                    if k < len(pushed) else None)
+
+        parity, rtx = f.lm.parity, f.lm.rtx
+        flows = self.lg.flows
+        start = self.args.seed % len(flows)
+        for kind, got in (("parity", parity), ("rtx", rtx)):
+            if ctl != "ref:" + kind:
+                continue
+            first = next((g for g in flows[start:] + flows[:start]
+                          if getattr(g.lm, kind)), None)
+            if first is not f:
+                continue
+            if kind == "parity":
+                made = []
+                for g in got:
+                    p = reference_fec.parse_parity(g)
+                    if p is not None:
+                        made.append(reference_fec.parity_packet(
+                            [expected_at((p["snbase"] + d) & 0xFFFF)
+                             or b"" for d in p["deltas"]],
+                            p["deltas"], p["idx"], snbase=p["snbase"],
+                            pt=lm.fec_pt, seq=int.from_bytes(g[2:4], "big"),
+                            ts=int.from_bytes(g[4:8], "big"), ssrc=f.ssrc,
+                            kind=p["kind"]))
+                parity = reference_fec.break_one(made, self.args.seed)
+            else:
+                made = [reference_fec.rtx_packet(
+                    expected_at(int.from_bytes(g[12:14], "big")) or g,
+                    rtx_pt=lm.rtx_pt, rtx_seq=int.from_bytes(g[2:4], "big"))
+                    for g in got]
+                rtx = reference_fec.break_one(made, self.args.seed)
+            log(f"control {ctl}: the reference's {len(made)} {kind} "
+                f"datagrams in flow {flows.index(f)}'s place, one broken")
+        return {"parity": reference_fec.parity_altered(
+                    parity, expected_at, f.ssrc, lm.fec_pt),
+                "rtx": reference_fec.rtx_altered(rtx, expected_at,
+                                                 lastmile.MEDIA_PT)}
 
     def end_to_end(self) -> dict[str, float]:
         """Every stamped delivery of a frame due in the window: delay
@@ -589,7 +698,10 @@ def main(argv=None) -> int:
     ap.add_argument("--control",
                     help="the control: 'ref:<guarantee>' puts the reference "
                          "with that guarantee broken in one flow's place; "
-                         "'fault:<plan>' arms the program's own fault plan")
+                         "behind a lossy last mile 'ref:parity' / 'ref:rtx' "
+                         "put the reference's parity / RTX datagrams, one "
+                         "broken, in one flow's place; 'fault:<plan>' arms "
+                         "the program's own fault plan")
     ap.add_argument("--child-script",
                     default=os.path.join(HERE, "server_child.py"),
                     help="the server child (tests put a broken one here)")

@@ -161,8 +161,10 @@ OLD = ["loadgen.late_p99_ms", "rtsp.join_s", "megabatch.streams_per_pass",
        "megabatch_window_step_roofline", "device.idle_pct.below_knee",
        "device.idle_pct.above_knee", "pdv_p95_ms"]
 
-#: PR 34 appended its cell to every list ``relay-16x256.paced`` is in
-BELOW = [PACED, LIVE, GENLOCK]
+#: PR 34 appended its cell to every list ``relay-16x256.paced`` is in;
+#: PR 41 took ``relay-1x64.live`` out of the lists that move
+#: ``delay_p95_ms`` (``LIVE_TWINS``)
+BELOW = [PACED, GENLOCK]
 ABOVE = [SATURATED]
 #: PR 25's fourteen: name -> (cells, moves, better, reader kind), in the
 #: entries' order
@@ -385,9 +387,63 @@ def check_genlock_fill_entry(bench, files):
     assert readers.read(spec, silent_ctx()) is None
 
 
+# ------------------------------------------ PR 41: the live cell's tail
+#: the entries that moved ``delay_p95_ms`` in ``relay-1x64.live`` until
+#: PR 41 made the cell's 95th percentile a per-layer metric: each keeps
+#: its other cells, and the cell reads it as ``<name>.live``, which moves
+#: ``delay_p60_ms``.  ``delay_p95_ms.live`` leads the twins, in this order
+LIVE_TWINS = [
+    "loadgen.late_p99_ms", "compiles_in_window", "device.idle_pct.below_knee",
+    "pdv_p95_ms", "relay.due_to_wire_ms.below_knee",
+    "relay.due_to_wire_p95_ms.below_knee", "pump.wake_ms.below_knee",
+    "pump.busy_pct.below_knee", "engine.due_outputs_pct.below_knee",
+    "pump.live_relay_ms_per_wake.below_knee",
+    "egress.bracket_ms_per_wake.below_knee", "pump.timer_wakes_pct.below_knee",
+    "engine.plan_rebuilds_per_100_steps.below_knee",
+    "pump.stepped_streams_pct.below_knee", "egress.hidden_pct.below_knee",
+    "loads_in_window", "build_s_in_window"]
+
+
+def check_live_judged(bench, files=None):
+    """The cell is judged on ``delay_p60_ms`` and ``setup_s``; its 95th
+    percentile is read per layer, by the harness, as the end-to-end
+    metric has it."""
+    assert judged_on(bench, LIVE) == {"delay_p60_ms", "setup_s"}
+    assert entry(bench, "delay_p95_ms")["workloads"][:len(BELOW)] == BELOW
+    e = entry(bench, "delay_p95_ms.live")
+    assert e["moves"] == "delay_p60_ms" and e["workloads"][0] == LIVE
+    assert (e["unit"], e["better"], e["source"]) == ("ms", "lower",
+                                                     "host_clock")
+    spec = files.load(f"{METRIC_DIR}delay_p95_ms.live.json")
+    assert spec["reader"] == {"kind": "harness", "key": "delay_p95_ms"}
+
+
+def check_live_twin(bench, files, name):
+    """A twin reads what its namesake reads, in the live cell, and moves
+    ``delay_p60_ms``; the namesake no longer lists the cell."""
+    e, twin = entry(bench, name), entry(bench, f"{name}.live")
+    assert e["moves"] == "delay_p95_ms" and LIVE not in e["workloads"]
+    assert twin["moves"] == "delay_p60_ms" and twin["workloads"][0] == LIVE
+    assert twin["workloads"].count(LIVE) == 1
+    assert {k: twin[k] for k in ("unit", "better", "source", "layer")} == {
+        k: e[k] for k in ("unit", "better", "source", "layer")}
+    spec = files.load(f"{METRIC_DIR}{name}.live.json")
+    assert spec["reader"] == files.load(f"{METRIC_DIR}{name}.json")["reader"]
+
+
+def check_live_order(bench, files=None):
+    """``delay_p95_ms.live`` and the twins follow one another in this
+    order, after every entry accepted before them."""
+    names = [m["name"] for m in bench["per_layer"]]
+    start = names.index("delay_p95_ms.live")
+    want = ["delay_p95_ms.live"] + [f"{n}.live" for n in LIVE_TWINS]
+    assert names[start:start + len(want)] == want
+    assert names.index("pump.routed_streams_pct") < start
+
+
 # ------------------------------------------------------------ the cases
 #: part -> the test module that runs it on the committed file
-PARTS = ("files", "spans", "wall", "genlock", "accepted")
+PARTS = ("files", "spans", "wall", "genlock", "accepted", "live")
 
 
 def cases(part, bench=BENCH, files=COMMITTED):
@@ -416,6 +472,10 @@ def cases(part, bench=BENCH, files=COMMITTED):
                 case("fill_entry", check_genlock_fill_entry)]
     if part == "accepted":
         return [case("stand", check_accepted_stand)]
+    if part == "live":
+        return [case("judged", check_live_judged),
+                *(case(f"twin[{n}]", check_live_twin, n) for n in LIVE_TWINS),
+                case("order", check_live_order)]
     raise KeyError(part)
 
 
