@@ -676,8 +676,27 @@ RTX_SENT = REGISTRY.counter(
 RTX_GIVEUP = REGISTRY.counter(
     "rtx_giveup_total",
     "NACKed packets NOT replayed because the per-output RTX token "
-    "bucket was exhausted (a black-holed client cannot amplify); "
-    "give-ups charge the degradation ladder")
+    "bucket was exhausted (a black-holed client cannot amplify); its "
+    "output's quality controller hears them with the output's next "
+    "judged receiver report, its stream's ladder never does")
+RTX_NACKED_SEQS = REGISTRY.counter(
+    "rtx_nacked_seqs_total",
+    "Output seqs named by players' generic NACKs, by what the replay "
+    "(rtx.replay) did with each: sent, giveup (no token), withheld (a "
+    "hole the output's thinning made, or any seq while it is thinned), "
+    "evicted (gone from the ring) or repeat (replayed under 100 ms ago: "
+    "that replay serves it)", labels=("result",))
+FEC_PARITY_PASS_SECONDS = REGISTRY.histogram(
+    "fec_parity_pass_seconds",
+    "Seconds of one window's parity computation (fec.parity_pass), by "
+    "part: the device pass (upload, kernel, fetch) or the host GF "
+    "oracle it is checked against", labels=("part",))
+RTCP_REPORT_BLOCKS = REGISTRY.counter(
+    "rtcp_report_blocks_total",
+    "RTCP receiver report blocks from players that named an output "
+    "(rtcp.rr), by what the fold did: folded (too few packets covered "
+    "yet to judge) or judged (a loss fraction handed to the output's "
+    "controllers)", labels=("result",))
 
 # ------------------------------------------------------------------- QoS
 QOS_FRACTION_LOST = REGISTRY.gauge(

@@ -62,6 +62,9 @@ SPANS = (
     "megabatch.dispatch", "megabatch.gather", "megabatch.h2d",
     "megabatch.shard_h2d", "megabatch.shard_wait", "megabatch.shard_fetch",
     "ingest.read",
+    # the reliability tier: a window's parity, a NACK's replay, an RR
+    # compound (ring only)
+    "fec.parity_pass", "rtx.replay", "rtcp.rr",
     "native.egress", "native.stream_egress",
     "pipeline.step", "jax.build",
     # a process's life before it listens (``obs.boot``), in order

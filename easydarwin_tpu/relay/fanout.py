@@ -1536,6 +1536,9 @@ class TpuFanoutEngine:
                     pid += 1
                     continue
                 if not out.thinning.admit(int(ring.flags[slot])):
+                    if out.rewrite.base_src_seq >= 0:
+                        out.thinning.withhold(
+                            out.rewrite.map_seq(int(ring.seq[slot])))
                     pid += 1
                     continue
                 payload = ring.data[slot, 12:ring.length[slot]]

@@ -37,12 +37,14 @@ module makes loss a measured, recovered quantity:
   swapped to ``rtx_pt``, fresh RTX seq, the Original Sequence Number
   riding as the first two payload bytes.  A per-output token-bucket
   budget bounds replay so a black-holed client can't amplify;
-  give-ups count ``rtx_giveup_total`` and are charged to the PR 5
-  degradation ladder.
+  give-ups count ``rtx_giveup_total`` and are heard by the output's
+  own quality controller, never by its stream's degradation ladder.
+  A hole the output's thinning made is never replayed.
 
 * **Closed-loop control.**  :class:`FecRateController` drives the
   per-subscriber overhead ratio (0–30%, the ``OVERHEAD_LADDER``) from
-  the RTCP RR ``fraction_lost`` stream with the same hysteresis shape
+  the RTCP RR loss stream — judged once folded reports cover enough
+  packets (``quality.ReportFold``) — with the same hysteresis shape
   as ``quality.QualityController`` (one heavy report steps now,
   sustained moderate loss steps slowly, sustained clean decays) and
   the NACK-vs-FEC split from the 3GPP NADU buffer gauges: a receiver
@@ -58,6 +60,7 @@ recovery in /metrics.
 
 from __future__ import annotations
 
+import math
 import struct
 import time
 from dataclasses import dataclass
@@ -65,6 +68,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from .. import obs
+from .quality import LOSS_THIN_SLOW
 
 # ------------------------------------------------------------ GF(256) tables
 #: the RS-standard polynomial x^8+x^4+x^3+x^2+1 (0x11D), generator 2 —
@@ -279,6 +283,14 @@ class FecRateController:
     def overhead(self) -> float:
         return min(OVERHEAD_LADDER[self._idx], self.max_overhead)
 
+    @property
+    def at_ceiling(self) -> bool:
+        """No higher rung is within ``max_overhead``: loss can no longer
+        be answered with more parity."""
+        nxt = self._idx + 1
+        return (nxt >= len(OVERHEAD_LADDER)
+                or OVERHEAD_LADDER[nxt] > self.max_overhead)
+
     def parity_rows(self, window: int, *, kind: int = KIND_RS) -> int:
         r = int(np.ceil(self.overhead * window))
         if kind == KIND_XOR:
@@ -337,9 +349,61 @@ class FecRateController:
         self._idx = new
 
 
+def residual_loss(fraction_lost: float, window: int, rows: int) -> float:
+    """The share of a ``window``'s packets that ``rows`` parity rows
+    leave lost at loss ``fraction_lost``, losses taken as independent: a
+    window that loses more than ``rows`` packets keeps every one of them
+    (the rows repair any ``rows`` erasures, and no more)."""
+    p = min(max(float(fraction_lost), 0.0), 1.0)
+    return sum(x * math.comb(window, x) * p ** x * (1 - p) ** (window - x)
+               for x in range(rows + 1, window + 1)) / window
+
+
 #: parity rows per window ceiling (8 of 48 mask slots; overhead ladder
 #: tops out well below this for every supported window size)
 MAX_PARITY_ROWS = 8
+#: the byte pad of a window's rows: its longest packet, pow2 from this
+PARITY_MIN_BYTES = 256
+#: a NACK naming a seq replayed less than this ago is served by that
+#: replay.  A player repeats its NACK every interval of its own (200 ms
+#: is common) while the hole stays open; after the event loop stood
+#: still, those repeats are read back to back, and replaying each drains
+#: the output's token bucket into give-ups for a healthy player
+RTX_REPEAT_MS = 100
+
+#: the parity programs this process has traced and loaded, (K, B, R)
+_BUILT: set[tuple[int, int, int]] = set()
+
+
+def parity_programs(cfg: "FecConfig", slot_bytes: int) -> set:
+    """Every ``fec_parity_window_step`` shape a stream's windows can
+    take: rows ``[K, B]`` × coefficients ``[R, K]`` with K the window,
+    B each byte pad up to the ring slot's (``_window_parity``'s rule),
+    R each row pad up to the most rows the rate controller asks for."""
+    from ..ops.staging import pow2
+    k = cfg.window
+    rows = min(int(np.ceil(cfg.max_overhead * k)), MAX_PARITY_ROWS)
+    if cfg.kind_code == KIND_XOR:
+        rows = min(rows, 1)
+    b_pads, b = [], PARITY_MIN_BYTES
+    while True:
+        b_pads.append(b)
+        if b >= slot_bytes:
+            break
+        b <<= 1
+    return {(k, b, pow2(r, 1)) for b in b_pads for r in range(1, rows + 1)}
+
+
+def load_parity_programs(programs) -> None:
+    """Trace and load each program not built yet, by a zero pass:
+    nothing is emitted and nothing is checked."""
+    from ..models.relay_pipeline import fec_parity_window_step
+    for k, b, r in sorted(programs):
+        if (k, b, r) in _BUILT:
+            continue
+        np.asarray(fec_parity_window_step(np.zeros((k, b), np.uint8),
+                                          np.zeros((r, k), np.uint8)))
+        _BUILT.add((k, b, r))
 
 
 @dataclass(frozen=True)
@@ -396,6 +460,47 @@ class FecOutputState:
         self._tokens = float(cfg.rtx_burst)
         self._last_refill_ms: int | None = None
         self._giveup_reported = False
+        #: seqs given up since the output's last judged report
+        self.giveups_unjudged = 0
+        #: output seq -> when it was last replayed (``RTX_REPEAT_MS``)
+        self._replayed: dict[int, int] = {}
+
+    def unrepaired(self, fraction_lost: float) -> float:
+        """What this output's quality controller hears of a judged loss
+        fraction, once its rate controller has heard it.  While that
+        controller can still climb, nothing: the tier's answer to loss
+        is its next rung, and a viewer's rare long burst (20 packets,
+        1.5 s of a 13 packets/s stream, read as 30 % of a 64-packet
+        judgment) moves the rung, not the frame rate.
+        At its ceiling, the share its parity leaves unrepaired
+        (:func:`residual_loss`): from ≈ 27 % raw loss at 5 rows of 16
+        that is ``LOSS_THIN_SLOW``, and at 40 % ``LOSS_THIN_NOW``.  No
+        less than ``LOSS_THIN_SLOW`` where the RTX budget gave up since
+        the last judgment: the tier failed in that interval.  One
+        interval of give-ups (a burst of NACKs after a stall) thins
+        nothing, and three in a row thin, as three lossy reports do."""
+        c, k = self.controller, self.cfg.window
+        lost = 0.0
+        if c.at_ceiling:
+            lost = residual_loss(fraction_lost, k, c.parity_rows(
+                k, kind=self.cfg.kind_code))
+        if self.giveups_unjudged:
+            self.giveups_unjudged = 0
+            lost = max(lost, LOSS_THIN_SLOW)
+        return lost
+
+    def repeats(self, seq: int, now_ms: int) -> bool:
+        """True when ``seq`` was replayed less than ``RTX_REPEAT_MS``
+        ago: that replay serves this NACK too."""
+        t = self._replayed.get(seq)
+        return t is not None and now_ms - t < RTX_REPEAT_MS
+
+    def note_replay(self, seq: int, now_ms: int) -> None:
+        replayed = self._replayed
+        replayed[seq] = now_ms
+        if len(replayed) > 64:
+            self._replayed = {s: t for s, t in replayed.items()
+                              if now_ms - t < RTX_REPEAT_MS}
 
     def refill(self, now_ms: int) -> None:
         if self._last_refill_ms is None:
@@ -447,6 +552,9 @@ class StreamFec:
         self.windows_emitted = 0
         self.windows_skipped = 0
         self.device_passes = 0
+        #: parity programs still to load ahead of the windows that need
+        #: them (``parity_programs``), one a pass once media flows
+        self._to_load: list = []
 
     # -- registration -------------------------------------------------
     def add_output(self, out) -> None:
@@ -461,11 +569,22 @@ class StreamFec:
             # corrupting it (config validation can't know per-SDP PTs)
             out.fec = None
             return
+        ring = self.stream.rtp_ring
+        if not self._states and self.cfg.use_device:
+            # the parity shapes are a closed set, loaded with the
+            # stream's first FEC output and not by the window that first
+            # meets one (the megabatch scheduler's discipline: before
+            # media all of them, with media flowing one a pass)
+            self._to_load = sorted(
+                parity_programs(self.cfg, ring.data.shape[1]) - _BUILT)
+            if ring.head == 0:
+                load_parity_programs(self._to_load)
+                self._to_load = []
         if f.next_window is None:
             # first FULL window after this subscriber joined — parity
             # must only describe packets the output actually sent
             k = self.cfg.window
-            f.next_window = (self.stream.rtp_ring.head + k - 1) // k
+            f.next_window = (ring.head + k - 1) // k
         self._states.append((out, f))
 
     def remove_output(self, out) -> None:
@@ -483,13 +602,26 @@ class StreamFec:
         a handful of windows per pass instead of stalling the pump."""
         if not self._states:
             return 0
+        if self._to_load:
+            load_parity_programs(self._to_load[:1])
+            del self._to_load[:1]
         ring = self.stream.rtp_ring
         k = self.cfg.window
         sent = 0
         max_ratio = 0.0
+        low = None                      # the lowest cursor, for the prune
         for out, f in self._states:
+            if not f.controller._idx:
+                # rung 0 sends no parity: the cursor is parked, and
+                # restarts at the live edge when the rung rises
+                f.next_window = None
+                continue
             max_ratio = max(max_ratio, f.controller.overhead)
-            if out.bookmark is None or f.next_window is None:
+            if f.next_window is None:
+                f.next_window = (ring.head + k - 1) // k
+            if low is None or f.next_window < low:
+                low = f.next_window
+            if out.bookmark is None:
                 continue
             if not out.thinning.passthrough():
                 # a thinned output deliberately dropped frames: parity
@@ -507,9 +639,7 @@ class StreamFec:
                     sent += self._emit_window(out, f, w, now_ms)
                 f.next_window = w + 1
         for w in [w for w in self._cache
-                  if w < min((f.next_window or 0)
-                             for _o, f in self._states)
-                  - self.CACHE_WINDOWS]:
+                  if low is not None and w < low - self.CACHE_WINDOWS]:
             self._cache.pop(w, None)
             self._cached_rows.pop(w, None)
         if self.stream.session_path is not None:
@@ -568,7 +698,7 @@ class StreamFec:
         # fixed-slot rows, byte axis pow2-padded so jit specializations
         # latch per shape family (the ONE rounding rule, ops.staging)
         from ..ops.staging import pow2
-        b_pad = pow2(max_len, 256)
+        b_pad = pow2(max_len, PARITY_MIN_BYTES)
         rows = np.zeros((k, b_pad), np.uint8)
         width = min(b_pad, ring.data.shape[1])
         rows[:len(slots), :width] = ring.data[slots, :width]
@@ -579,14 +709,25 @@ class StreamFec:
         r_pad = pow2(n_parity, 1)
         coeff = np.zeros((r_pad, k), np.uint8)
         coeff[:, :len(deltas)] = coeff_rows(deltas, r_pad)
-        host = gf_matmul(coeff, rows)
-        parity = host
-        if self.cfg.use_device and not self.host_fallback:
-            t0 = time.perf_counter_ns()
+        device = self.cfg.use_device and not self.host_fallback
+        span = obs.TRACER.open(
+            "fec.parity_pass", "fec", rows=len(slots), b_pad=b_pad,
+            r_pad=r_pad, source="device" if device else "host",
+            trace_id=self.stream.trace_id)
+        t0 = t1 = obs.t0_of(span)
+        if device:
             from ..models.relay_pipeline import fec_parity_window_step
             dev = np.asarray(fec_parity_window_step(rows, coeff))
-            obs.TPU_PASS_SECONDS.observe(
-                (time.perf_counter_ns() - t0) / 1e9, stage="fec_parity")
+            t1 = time.perf_counter_ns()
+            obs.FEC_PARITY_PASS_SECONDS.observe((t1 - t0) / 1e9,
+                                                part="device")
+        # the host oracle, computed for every window: the device's rows
+        # reach the wire only once they equal it
+        host = gf_matmul(coeff, rows)
+        obs.FEC_PARITY_PASS_SECONDS.observe(
+            (obs.TRACER.close(span) - t1) / 1e9, part="oracle")
+        parity = host
+        if device:
             obs.TPU_H2D_BYTES.inc(rows.nbytes + coeff.nbytes)
             obs.TPU_D2H_BYTES.inc(dev.nbytes)
             self.device_passes += 1
@@ -672,38 +813,53 @@ class StreamFec:
         return sent
 
     # -- NACK / RTX -------------------------------------------------------
-    def replay_nacked(self, out, seqs, now_ms: int,
-                      on_giveup=None) -> int:
+    def replay_nacked(self, out, seqs, now_ms: int) -> int:
         """Resolve NACKed OUTPUT seqs back to live ring bookmarks
         through the inverse affine rewrite and replay them as RTX
-        packets — the ring IS the retransmission buffer.  The
+        packets — the ring IS the retransmission buffer; returns the
+        replays sent.  A hole the output's thinning made is never owed,
+        and a seq replayed less than ``RTX_REPEAT_MS`` ago is served by
+        that replay: neither takes a token or is a give-up.  The
         per-output token bucket bounds replay; exhausted budget counts
-        ``rtx_giveup_total`` once per seq and charges the caller's
-        ladder hook."""
+        ``rtx_giveup_total`` once per seq and is heard by the output's
+        own quality controller with its next judged report
+        (``FecOutputState.unrepaired``) — a viewer's link is not its
+        stream's device health."""
         f = getattr(out, "fec", None)
         if f is None:
             return 0
-        if not out.thinning.passthrough():
+        seqs = list(seqs)
+        span = obs.TRACER.open("rtx.replay", "fec", seqs=len(seqs),
+                               trace_id=self.stream.trace_id)
+        ring = self.stream.rtp_ring
+        rw = out.rewrite
+        sent = giveups = withheld = evicted = repeated = 0
+        thinning = out.thinning
+        if not thinning.passthrough():
             # a thinned output's seq gaps are DELIBERATE frame drops
             # (map_seq is pure affine, so thinned frames leave output-
             # seq holes a conformant receiver will NACK): replaying
-            # them would defeat thinning, drain the token bucket and
-            # charge the ladder for a healthy client — the same guard
-            # the parity cursor applies in tick()
-            return 0
-        ring = self.stream.rtp_ring
-        rw = out.rewrite
-        if rw.base_src_seq < 0:
-            return 0
-        sent = 0
+            # them would defeat thinning and drain the token bucket —
+            # the same guard the parity cursor applies in tick()
+            withheld, seqs = len(seqs), ()
+        elif rw.base_src_seq < 0:
+            evicted, seqs = len(seqs), ()
         from .output import WriteResult
         for s_out in seqs:
+            if thinning.withheld(s_out):
+                withheld += 1              # a hole thinning made
+                continue
             src_seq = (int(s_out) - rw.out_seq_start
                        + rw.base_src_seq) & 0xFFFF
             pid = _find_ring_id(ring, src_seq)
             if pid is None:
-                continue                   # evicted / never ingested
+                evicted += 1               # evicted / never ingested
+                continue
+            if f.repeats(s_out, now_ms):
+                repeated += 1              # a replay just sent serves it
+                continue
             if not f.take_rtx_token(now_ms):
+                giveups += 1
                 f.rtx_giveups += 1
                 obs.RTX_GIVEUP.inc()
                 if not f._giveup_reported:
@@ -713,8 +869,6 @@ class StreamFec:
                         stream=self.stream.session_path,
                         trace_id=self.stream.trace_id,
                         giveups=f.rtx_giveups)
-                if on_giveup is not None:
-                    on_giveup(self.stream.session_path)
                 continue
             slot = ring.slot(pid)
             wire = bytearray(ring.data[slot, :ring.length[slot]].tobytes())
@@ -730,12 +884,20 @@ class StreamFec:
             if out.send_bytes(pkt, is_rtcp=False) is WriteResult.OK:
                 f.rtx_seq = (f.rtx_seq + 1) & 0xFFFF
                 f.rtx_sent += 1
+                f.note_replay(s_out, now_ms)
                 sent += 1
                 obs.RTX_SENT.inc()
         if sent:
             # credit the repairs to this subscriber's audience row
             # (one call per NACK batch — cold control path)
             obs.AUDIENCE.note_credit(out, rtx=sent)
+        f.giveups_unjudged += giveups
+        for result, n in (("sent", sent), ("giveup", giveups),
+                          ("withheld", withheld), ("evicted", evicted),
+                          ("repeat", repeated)):
+            obs.RTX_NACKED_SEQS.inc(n, result=result)
+        obs.TRACER.close(span, sent=sent, giveups=giveups,
+                         withheld=withheld, repeats=repeated)
         return sent
 
 

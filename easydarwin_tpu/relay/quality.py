@@ -19,14 +19,67 @@ boundaries and keyframes (the ingest classifier), so thinning here drops
 
 Decisions live per output (one slow client must not thin the others —
 exactly why the reference keeps quality on the RTPStream).
+
+A loss fraction is judged over enough packets: an RR's own 8-bit
+``fraction_lost`` covers whatever the receiver saw since its previous
+report — a handful of packets in a viewer's first report at a low frame
+rate, where one burst reads 40 %.  :class:`ReportFold` adds successive
+report blocks of an output by RFC 3550 A.3 arithmetic (Δ extended
+highest seq expected, Δ cumulative lost) and hands the controllers a
+fraction only once it covers :data:`MIN_JUDGED_PACKETS`.
 """
 
 from __future__ import annotations
 
+from collections import deque
 from dataclasses import dataclass, field
 
 from .. import obs
 from .ring import PacketFlags
+
+#: the fewest expected packets a judged loss fraction covers.  One lost
+#: burst of three in a viewer's first report of five packets reads 60 %
+#: and thinned at once; over 64 a fraction moves in steps of 1.6 %, so
+#: LOSS_THIN_NOW (30 %) needs 20 losses.  64 is about one 5 s report
+#: (RFC 3550's minimum interval) of a 1.1 frames/s stream of ≈ 13
+#: packets a frame; a faster stream is judged on every report
+MIN_JUDGED_PACKETS = 64
+#: a Δ extended highest seq above this is no interval's growth (a
+#: reordered report, a receiver that restarted): the fold rebases
+MAX_REPORT_SPAN = 0x8000
+
+
+@dataclass
+class ReportFold:
+    """One output's receiver reports, folded until they cover enough
+    packets (RFC 3550 A.3): the expected count is the growth of the
+    extended highest sequence number received (32-bit, wrap included),
+    the lost count the growth of the cumulative number lost (signed: a
+    duplicate-heavy receiver's goes down)."""
+
+    _last: tuple[int, int] | None = None    # (highest_seq, cumulative)
+    _expected: int = 0
+    _lost: int = 0
+
+    def add(self, highest_seq: int, cumulative_lost: int,
+            base_seq: int) -> float | None:
+        """Fold one report block; the loss fraction (0..1) once the fold
+        covers :data:`MIN_JUDGED_PACKETS`, else None.  ``base_seq`` is
+        the first sequence number the output sent (the PLAY answer's
+        RTP-Info ``seq``), from which a first block counts."""
+        last = self._last or (base_seq - 1, 0)
+        self._last = (highest_seq, cumulative_lost)
+        grew = (highest_seq - last[0]) & 0xFFFFFFFF
+        if grew == 0 or grew > MAX_REPORT_SPAN:
+            return None
+        self._expected += grew
+        self._lost += cumulative_lost - last[1]
+        if self._expected < MIN_JUDGED_PACKETS:
+            return None
+        frac = min(max(self._lost / self._expected, 0.0), 1.0)
+        self._expected = self._lost = 0
+        return frac
+
 
 MAX_LEVEL = 3
 
@@ -58,6 +111,10 @@ class QualityController:
     #: decides whether the engine may batch it (``passthrough``), so it
     #: moves the owner's stream's plan epoch (``relay.fanout``)
     owner: object = field(default=None, repr=False, compare=False)
+    #: this output's receiver reports, folded until they cover enough
+    #: packets to be judged
+    fold: ReportFold = field(default_factory=ReportFold, repr=False,
+                             compare=False)
 
     def __setattr__(self, name, value):
         object.__setattr__(self, name, value)
@@ -120,10 +177,14 @@ class QualityController:
 
     def _bump(self, d: int) -> None:
         new = max(0, min(MAX_LEVEL, self.level + d))
+        if new == self.level:
+            # a write of ``level`` moves the stream's plan epoch: a clean
+            # streak at level 0 must not rebuild the plan
+            return
         if new > self.level:
             self.thins += 1
             obs.QOS_THINS.inc()
-        elif new < self.level:
+        else:
             self.thickens += 1
             obs.QOS_THICKENS.inc()
         self.level = new
@@ -151,14 +212,42 @@ def drop_qos(path: str, track_id) -> None:
     obs.QOS_JITTER.remove(**labels)
 
 
+#: runs of withheld output seqs a filter remembers: a run is one dropped
+#: frame, so 256 hold a minute of level-1 thinning at 2 frames/s, far
+#: past any NACK of them
+WITHHELD_RUNS = 256
+
+
 @dataclass
 class ThinningFilter:
-    """Per-output frame-granular packet filter driven by a quality level."""
+    """Per-output frame-granular packet filter driven by a quality level.
+
+    The packets it drops leave holes in the output's sequence space (the
+    rewrite is affine), which a conformant receiver NACKs: the caller
+    notes each one's output seq (``withhold``) so that an RTX replay can
+    tell a deliberate hole from a loss (``withheld``)."""
 
     controller: QualityController = field(default_factory=QualityController)
     _frame_index: int = 0
     _dropping_frame: bool = False
     dropped: int = 0
+    #: [first output seq, count] runs of withheld packets, newest last
+    _runs: deque | None = field(default=None, repr=False)
+
+    def withhold(self, out_seq: int) -> None:
+        """Note that the packet of output seq ``out_seq`` was dropped."""
+        runs = self._runs
+        if runs is None:
+            runs = self._runs = deque(maxlen=WITHHELD_RUNS)
+        if runs and (runs[-1][0] + runs[-1][1]) & 0xFFFF == out_seq:
+            runs[-1][1] += 1
+        else:
+            runs.append([out_seq & 0xFFFF, 1])
+
+    def withheld(self, out_seq: int) -> bool:
+        """True when ``out_seq`` is a hole this filter made."""
+        return self._runs is not None and any(
+            (out_seq - first) & 0xFFFF < n for first, n in self._runs)
 
     def passthrough(self) -> bool:
         """True while the filter cannot drop anything (level 0, not mid

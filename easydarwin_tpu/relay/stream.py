@@ -354,6 +354,9 @@ class RelayStream:
                         pid += 1
                         continue
                     if not out.thinning.admit(ring.get_flags(pid)):
+                        if out.rewrite.base_src_seq >= 0:
+                            out.thinning.withhold(out.rewrite.map_seq(
+                                int(ring.seq[ring.slot(pid)])))
                         pid += 1            # thinned: frame dropped for this
                         continue            # output only (quality level)
                     res = out.write_rtp(data)

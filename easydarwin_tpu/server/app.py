@@ -122,12 +122,6 @@ class StreamingServer:
         if self.config.resilience_enabled:
             from ..resilience import DegradationLadder
             self.ladder = DegradationLadder(self.config.ladder_config())
-            # RTX budget exhaustion (relay/fec.py) is charged to the
-            # ladder: a black-holed client's NACK storm sheds load
-            # through the same machinery as any other overload
-            self.rtsp.on_rtx_giveup = (
-                lambda path: self.ladder.note_device_error(
-                    path, reason="rtx_giveup"))
         #: session checkpoint/hot-restore (resilience/checkpoint.py) —
         #: built in start() once log_folder is final
         self.checkpoint = None

@@ -12,9 +12,13 @@ sendmmsg/UDP-GSO scatter (``native.fanout_send_multi``), so the TPU
 engine's affine rewrite params drive the wire directly — no per-packet
 Python, no per-subscriber payload copies.
 
-RTCP still rides asyncio (low rate): one shared socket receives player
-receiver reports and demuxes them to the owning connection by source
-address, exactly the UDPDemuxer role.
+RTCP rides the event loop: one shared socket receives player receiver
+reports and NACKs and demuxes them to the owning connection by source
+address, exactly the UDPDemuxer role.  Each time the socket is readable
+the loop reads what it holds, not one datagram (asyncio's datagram
+transport reads one a turn): 4,096 viewers send ≈ 820 reports a second,
+and a turn a datagram falls behind a busy pump until the socket's queue
+overflows and a NACK waits seconds for its retransmission.
 """
 
 from __future__ import annotations
@@ -23,6 +27,12 @@ import asyncio
 import socket
 
 from ..relay.output import RelayOutput, WriteResult
+
+#: datagrams one readable turn of the RTCP socket reads at most, so that
+#: a flood cannot hold the loop
+RTCP_READS_PER_TURN = 256
+#: the RTCP socket's receive buffer, asked of the kernel (which may cap it)
+RTCP_RCVBUF_BYTES = 1 << 22
 
 
 class NativeUdpOutput(RelayOutput):
@@ -49,17 +59,6 @@ class NativeUdpOutput(RelayOutput):
             return self.egress.send_rtcp(data, self.rtcp_addr)
         return self.egress.send_rtp(data, self.rtp_addr)
 
-class _RtcpProtocol(asyncio.DatagramProtocol):
-    def __init__(self, egress: "SharedUdpEgress"):
-        self.egress = egress
-        self.transport = None
-
-    def connection_made(self, transport):
-        self.transport = transport
-
-    def datagram_received(self, data, addr):
-        self.egress._on_rtcp(data, addr)
-
 
 class SharedUdpEgress:
     """The server's shared (RTP, RTCP) UDP pair.
@@ -68,14 +67,16 @@ class SharedUdpEgress:
       it with sendmmsg/GSO; the scalar path with ``sendto`` (WouldBlock
       surfaces as a bookmark replay, same contract as the reference's
       ``RTPStream::Write``).
-    * RTCP: an asyncio endpoint; inbound receiver reports demux by source
-      address to the registered connection (UDPDemuxer equivalent).
+    * RTCP: a non-blocking socket the event loop reads in batches;
+      inbound receiver reports demux by source address to the registered
+      connection (UDPDemuxer equivalent).
     """
 
     def __init__(self, bind_ip: str = "0.0.0.0"):
         self.bind_ip = bind_ip
         self.rtp_sock: socket.socket | None = None
-        self.rtcp_transport = None
+        self.rtcp_sock: socket.socket | None = None
+        self._loop: asyncio.AbstractEventLoop | None = None
         self.rtp_port = 0
         self.rtcp_port = 0
         #: (ip, port) → (conn, handler) exact-address demux
@@ -97,18 +98,23 @@ class SharedUdpEgress:
         self.rtp_sock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 1 << 22)
         self.rtp_sock.bind((self.bind_ip, 0))
         self.rtp_port = self.rtp_sock.getsockname()[1]
-        loop = asyncio.get_running_loop()
-        self.rtcp_transport, _ = await loop.create_datagram_endpoint(
-            lambda: _RtcpProtocol(self), local_addr=(self.bind_ip, 0))
-        self.rtcp_port = self.rtcp_transport.get_extra_info("sockname")[1]
+        self.rtcp_sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+        self.rtcp_sock.setblocking(False)
+        self.rtcp_sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF,
+                                  RTCP_RCVBUF_BYTES)
+        self.rtcp_sock.bind((self.bind_ip, 0))
+        self.rtcp_port = self.rtcp_sock.getsockname()[1]
+        self._loop = asyncio.get_running_loop()
+        self._loop.add_reader(self.rtcp_sock.fileno(), self._read_rtcp)
 
     def close(self) -> None:
         if self.rtp_sock is not None:
             self.rtp_sock.close()
             self.rtp_sock = None
-        if self.rtcp_transport is not None:
-            self.rtcp_transport.close()
-            self.rtcp_transport = None
+        if self.rtcp_sock is not None:
+            self._loop.remove_reader(self.rtcp_sock.fileno())
+            self.rtcp_sock.close()
+            self.rtcp_sock = None
         self._demux.clear()
         self._by_ip.clear()
 
@@ -131,6 +137,17 @@ class SharedUdpEgress:
             conns.remove(conn)
             if not conns:
                 del self._by_ip[out.rtcp_addr[0]]
+
+    def _read_rtcp(self) -> None:
+        """What the RTCP socket holds, up to ``RTCP_READS_PER_TURN``."""
+        for _ in range(RTCP_READS_PER_TURN):
+            try:
+                data, addr = self.rtcp_sock.recvfrom(65536)
+            except (BlockingIOError, InterruptedError):
+                return
+            except OSError:
+                continue        # a pending ICMP error: the next datagram
+            self._on_rtcp(data, addr)
 
     def _on_rtcp(self, data: bytes, addr) -> None:
         self.rtcp_in += 1
@@ -188,9 +205,14 @@ class SharedUdpEgress:
         return WriteResult.OK
 
     def send_rtcp(self, data: bytes, addr) -> WriteResult:
-        if self.rtcp_transport is None or self.rtcp_transport.is_closing():
+        if self.rtcp_sock is None:
             return WriteResult.ERROR
-        self.rtcp_transport.sendto(data, addr)
+        try:
+            self.rtcp_sock.sendto(data, addr)
+        except BlockingIOError:
+            return WriteResult.WOULD_BLOCK
+        except OSError:
+            return WriteResult.ERROR
         return WriteResult.OK
 
     def fileno(self) -> int:

@@ -21,8 +21,8 @@ import time
 from dataclasses import dataclass, field
 
 from ..obs import (EVENTS, FLIGHT, INGEST_INTERLEAVED_PACKETS,
-                   INGEST_INTERLEAVED_SECONDS, RTSP_REQUEST_SECONDS,
-                   RTSP_REQUESTS, TRACER, t0_of)
+                   INGEST_INTERLEAVED_SECONDS, RTCP_REPORT_BLOCKS,
+                   RTSP_REQUEST_SECONDS, RTSP_REQUESTS, TRACER, t0_of)
 from ..protocol import rtsp, sdp
 from ..relay.session import RelaySession, SessionRegistry, now_ms
 from .config import ServerConfig
@@ -1339,23 +1339,37 @@ class RtspServer:
         from ..resilience.inject import INJECTOR
         for p in pkts:
             if isinstance(p, rtcp_mod.ReceiverReport):
+                t0 = time.perf_counter_ns()
+                judged_n = 0
                 for rb in p.reports:
                     out = outputs.get(rb.ssrc)
                     if out is not None:
                         proven = True
                         frac = rb.fraction_lost / 256.0
-                        if INJECTOR.active:
+                        spoof = (INJECTOR.rr_loss_spoof()
+                                 if INJECTOR.active else None)
+                        if spoof is not None:
                             # chaos site (ISSUE 11): drive the loss-fed
                             # controllers without a lossy wire
-                            spoof = INJECTOR.rr_loss_spoof()
-                            if spoof is not None:
-                                frac = spoof
-                        out.on_receiver_report(frac)
-                        fec = getattr(out, "fec", None)
-                        if fec is not None:
-                            # closed-loop FEC overhead rides the SAME
-                            # RR stream the thinning controller reads
-                            fec.controller.on_receiver_report(frac)
+                            judged = spoof
+                        else:
+                            # judged once the folded reports cover
+                            # enough packets (relay/quality.py)
+                            judged = out.thinning.controller.fold.add(
+                                rb.highest_seq, rb.cumulative_lost,
+                                out.rewrite.out_seq_start)
+                        RTCP_REPORT_BLOCKS.inc(
+                            result="folded" if judged is None else "judged")
+                        if judged is not None:
+                            judged_n += 1
+                            fec = getattr(out, "fec", None)
+                            if fec is not None:
+                                # closed-loop FEC overhead rides the SAME
+                                # RR stream; thinning hears only what the
+                                # tier leaves unrepaired
+                                fec.controller.on_receiver_report(judged)
+                                judged = fec.unrepaired(judged)
+                            out.on_receiver_report(judged)
                         # fold loss/jitter into the scrapeable per-stream
                         # QoS gauges (obs registry)
                         from ..relay import quality as quality_mod
@@ -1365,6 +1379,9 @@ class RtspServer:
                             rate = conn.relay.streams[tid].info.clock_rate
                         quality_mod.record_rr_qos(
                             conn.path, tid, frac, rb.jitter, rate)
+                # ring only, as the RTSP handlers' spans: 800 a second
+                TRACER.add("rtcp.rr", t0, cat="rtcp",
+                           blocks=len(p.reports), judged=judged_n)
             elif isinstance(p, rtcp_mod.Nadu):
                 # 3GPP NADU buffer state → per-output rate adaptation;
                 # each block names the media sender SSRC it reports on
@@ -1432,14 +1449,8 @@ class RtspServer:
         stream = conn.relay.streams.get(tid) if tid is not None else None
         if stream is None or stream.fec is None:
             return False
-        stream.fec.replay_nacked(out, nack.lost_seqs(), now_ms(),
-                                 on_giveup=self.on_rtx_giveup)
+        stream.fec.replay_nacked(out, nack.lost_seqs(), now_ms())
         return True
-
-    #: set by the app: a path whose RTX budget was exhausted is charged
-    #: to the PR 5 degradation ladder (a black-holed client must shed
-    #: load, never amplify)
-    on_rtx_giveup = None
 
     def wake_pump(self) -> None:
         if self._on_pump_wake is not None:

@@ -296,10 +296,8 @@ def test_nack_replay_byte_exact_and_budget():
     push_media(st, 16)
     media, _, _ = split_wire(out.rtp_packets, cfg)
     out.rtp_packets.clear()
-    give_paths = []
     base = obs.RTX_SENT.value()
-    n = st.fec.replay_nacked(out, [103, 110], 50_000,
-                             on_giveup=give_paths.append)
+    n = st.fec.replay_nacked(out, [103, 110], 50_000)
     assert n == 2 and obs.RTX_SENT.value() == base + 2
     _, _, rtxs = split_wire(out.rtp_packets, cfg)
     rx = FecReceiver(media_pt=96, fec_pt=cfg.payload_type,
@@ -311,14 +309,14 @@ def test_nack_replay_byte_exact_and_budget():
                     if struct.unpack_from("!H", m, 2)[0] == seq)
         assert rx.have(seq) == orig
     # bucket exhaustion: drain the remaining tokens at a FROZEN clock,
-    # then every further NACK is a counted give-up charged to the hook
+    # then every further NACKed seq is a counted give-up, which the
+    # output's own quality controller hears with its next judged report
     st.fec.replay_nacked(out, [100, 101], 50_000)
     gu = obs.RTX_GIVEUP.value()
-    st.fec.replay_nacked(out, [104, 105], 50_000,
-                         on_giveup=give_paths.append)
+    st.fec.replay_nacked(out, [104, 105], 50_000)
     assert out.fec.rtx_giveups == 2
     assert obs.RTX_GIVEUP.value() == gu + 2
-    assert len(give_paths) == 2
+    assert out.fec.giveups_unjudged == 2
     # evicted/never-ingested seqs are silently skipped, never replayed
     assert st.fec.replay_nacked(out, [9999], 60_000) == 0
 
@@ -669,12 +667,14 @@ async def test_server_e2e_nack_rtx_and_loss_driven_parity():
                     sorted(dropped_wire)).to_bytes(),
                     ("127.0.0.1", egress.rtcp_port))
             if i % 25 == 10:
-                # RRs reporting ~8% loss ramp the FEC ladder while
-                # staying BELOW the 10% thinning threshold — above it
-                # the tier yields to thinning by design (seq gaps
-                # become deliberate frame drops, not losses)
+                # RRs reporting 8% loss (2 of every 25 packets, by
+                # RFC 3550's cumulative count and extended highest
+                # seq) ramp the FEC ladder; the server judges them
+                # once folded over 64 packets, and thinning hears
+                # none of it — parity covers 8%
                 rr = rtcp.ReceiverReport(0x77, [rtcp.ReportBlock(
-                    out.rewrite.ssrc, 20, 10, i & 0xFFFF, 0, 0, 0)]
+                    out.rewrite.ssrc, 20, 2 * (i // 25 + 1),
+                    out.rewrite.out_seq_start + i, 0, 0, 0)]
                 ).to_bytes()
                 rtcp_s.sendto(rr, ("127.0.0.1", egress.rtcp_port))
             if got_parity and got_rtx:
@@ -689,6 +689,7 @@ async def test_server_e2e_nack_rtx_and_loss_driven_parity():
             assert cand and cand[0] == orig, seq   # byte-exact replay
         assert got_parity, "loss-reporting RRs never produced parity"
         assert out.fec.controller.overhead > 0
+        assert out.thinning.controller.level == 0
         rtp_s.close()
         rtcp_s.close()
         await player.close()

@@ -157,11 +157,14 @@ async def test_rtcp_feedback_demuxes_on_shared_pair():
             (rtp.getsockname()[1], rtcp.getsockname()[1])])
         out = next(cn for cn in app.rtsp.connections
                    if cn.player_tracks).player_tracks[1].output
-        # RR with 78% loss toward the output's SSRC, from the registered port
+        # RR with 78% loss toward the output's SSRC, from the registered
+        # port: 78 of the 100 packets its extended highest seq covers
+        # (enough to be judged, relay/quality.py MIN_JUDGED_PACKETS)
         rr = (struct.pack("!BBHI", 0x81, 201, 7, 1)
               + struct.pack("!I", out.rewrite.ssrc)
-              + bytes([200]) + b"\x00\x00\x00"          # fl, cum_lost
-              + struct.pack("!IIII", 0, 0, 0, 0))       # ehsn/jit/lsr/dlsr
+              + bytes([200]) + (78).to_bytes(3, "big")  # fl, cum_lost
+              + struct.pack("!IIII", out.rewrite.out_seq_start + 99,
+                            0, 0, 0))                   # ehsn/jit/lsr/dlsr
         egress = app.rtsp.shared_egress
         assert egress is not None and egress.active
         rtcp.sendto(rr, ("127.0.0.1", egress.rtcp_port))

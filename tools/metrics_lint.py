@@ -515,6 +515,16 @@ def lint_vod(registry) -> list[str]:
 
 #: closed parity-kind vocabulary of ``fec_parity_packets_total``
 FEC_KINDS = ("xor", "rs")
+#: the reliability tier's instruments and their closed label sets: what
+#: became of a NACKed seq (``rtx.replay``), a parity pass's two parts
+#: (``fec.parity_pass``), what the fold did with a report block
+#: (``rtcp.rr``)
+TIER_FAMILIES = {"rtx_nacked_seqs_total":
+                 ("result", ("sent", "giveup", "withheld", "evicted",
+                             "repeat")),
+                 "fec_parity_pass_seconds": ("part", ("device", "oracle")),
+                 "rtcp_report_blocks_total": ("result", ("folded", "judged"))}
+TIER_SPANS = ("fec.parity_pass", "rtx.replay", "rtcp.rr")
 
 
 def lint_fec(registry, schema: dict) -> list[str]:
@@ -552,6 +562,26 @@ def lint_fec(registry, schema: dict) -> list[str]:
                 errs.append(f"fec_parity_packets_total: observed kind "
                             f"{kind!r} outside the closed set "
                             f"{FEC_KINDS}")
+    for fam_name, (label, closed) in TIER_FAMILIES.items():
+        try:
+            fam = registry.get(fam_name)
+        except KeyError:
+            errs.append(f"fec family {fam_name} missing from the "
+                        "registry")
+            continue
+        if tuple(fam.label_names) != (label,):
+            errs.append(f"{fam_name}: labels must be ({label!r},), got "
+                        f"{tuple(fam.label_names)}")
+            continue
+        seen = getattr(fam, "_values", None) or getattr(fam, "_states", {})
+        for (v,) in list(seen):
+            if v not in closed:
+                errs.append(f"{fam_name}: observed {label} {v!r} outside "
+                            f"the closed set {closed}")
+    from easydarwin_tpu.obs.trace import SPANS
+    for name in TIER_SPANS:
+        if name not in SPANS:
+            errs.append(f"span {name} missing from obs.trace.SPANS")
     for name in ("fec.host_fallback", "rtx.giveup"):
         if name not in schema:
             errs.append(f"event {name} missing from SCHEMA")
