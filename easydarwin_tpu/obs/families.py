@@ -668,6 +668,21 @@ FEC_OVERHEAD_RATIO = REGISTRY.gauge(
     "per stream — the worst subscriber's rung, driven by RTCP RR "
     "fraction_lost with NADU buffer distress shifting recovery toward "
     "RTX instead", labels=("path", "track"))
+#: the tier's own reasons for a step (``relay.pump._step``'s carry,
+#: ``FecOutputState``'s rung mark), each exposed from the start: a
+#: window in which the tier asked for none reads 0
+FEC_TIER_MARKS = REGISTRY.counter(
+    "fec_tier_marks_total",
+    "Streams the reliability tier asked the pump to step in the next "
+    "wake, by reason: budget (an output's window backlog past the "
+    "tick's 8 a pass), load (parity programs still to load, one a "
+    "pass), cursor (a window cursor to park or place) — carried from "
+    "the stream's step — and rung (an RTCP report moved an output's FEC "
+    "controller onto or off rung 0).  Over pump_stepped_streams_total: "
+    "the share of the steps the tier asked for itself",
+    labels=("reason",))
+for _reason in ("budget", "load", "cursor", "rung"):
+    FEC_TIER_MARKS.inc(0, reason=_reason)
 RTX_SENT = REGISTRY.counter(
     "rtx_sent_total",
     "NACKed packets replayed from live ring bookmarks through the "

@@ -502,6 +502,30 @@ class FecOutputState:
             self._replayed = {s: t for s, t in replayed.items()
                               if now_ms - t < RTX_REPEAT_MS}
 
+    def on_receiver_report(self, out, fraction_lost: float) -> float:
+        """The rate controller hears a judged report of ``out``'s
+        (``FecRateController.on_receiver_report``); returns the overhead."""
+        parked = not self.controller._idx
+        ratio = self.controller.on_receiver_report(fraction_lost)
+        self._mark_if_moved(out, parked)
+        return ratio
+
+    def on_nadu(self, out, playout_delay_ms: int,
+                free_buffer_64b: int) -> float:
+        parked = not self.controller._idx
+        ratio = self.controller.on_nadu(playout_delay_ms, free_buffer_64b)
+        self._mark_if_moved(out, parked)
+        return ratio
+
+    def _mark_if_moved(self, out, parked: bool) -> None:
+        """A report that moved the controller onto or off rung 0 leaves
+        a cursor for the tick to park or place (``StreamFec.owes``): the
+        stream is marked for the next wake, as ingest marks it."""
+        cell = out._plan_cell
+        if cell is not None and parked != (not self.controller._idx):
+            cell.mark()
+            obs.FEC_TIER_MARKS.inc(reason="rung")
+
     def refill(self, now_ms: int) -> None:
         if self._last_refill_ms is None:
             self._last_refill_ms = now_ms
@@ -555,6 +579,10 @@ class StreamFec:
         #: parity programs still to load ahead of the windows that need
         #: them (``parity_programs``), one a pass once media flows
         self._to_load: list = []
+        #: what the last ``tick`` left for the next pass to do, which no
+        #: ingest, plan move or wheel timer will bring a step for: the
+        #: reason ``owes`` gives, or None (``relay.pump._step``'s carry)
+        self.owes_pass: str | None = None
 
     # -- registration -------------------------------------------------
     def add_output(self, out) -> None:
@@ -599,12 +627,17 @@ class StreamFec:
         """Advance every subscriber's window cursor past fully-sent
         windows, emitting parity for each; returns parity packets sent.
         Bounded per call: a subscriber that fell behind emits at most
-        a handful of windows per pass instead of stalling the pump."""
+        a handful of windows per pass instead of stalling the pump.
+        Leaves ``owes_pass``: programs still to load, or a backlog past
+        the budget.  (It parks and places every cursor: none is owed.)"""
+        self.owes_pass = None
         if not self._states:
             return 0
         if self._to_load:
             load_parity_programs(self._to_load[:1])
             del self._to_load[:1]
+            if self._to_load:
+                self.owes_pass = "load"
         ring = self.stream.rtp_ring
         k = self.cfg.window
         sent = 0
@@ -638,6 +671,9 @@ class StreamFec:
                 if w * k >= ring.tail:
                     sent += self._emit_window(out, f, w, now_ms)
                 f.next_window = w + 1
+            else:                       # the budget ran out: a backlog?
+                if self.owes_pass is None and self._window_owed(out, f):
+                    self.owes_pass = "budget"
         for w in [w for w in self._cache
                   if low is not None and w < low - self.CACHE_WINDOWS]:
             self._cache.pop(w, None)
@@ -652,6 +688,37 @@ class StreamFec:
                 round(max_ratio, 4), path=self.stream.session_path,
                 track=str(self.stream.info.track_id))
         return sent
+
+    def _window_owed(self, out, f: FecOutputState) -> bool:
+        """The output's next window is complete and sent: the tick
+        emits it at once, within its budget."""
+        end = (f.next_window + 1) * self.cfg.window
+        return end <= self.stream.rtp_ring.head and out.bookmark >= end
+
+    def owes(self) -> str | None:
+        """``owes_pass`` from the state as it stands, for a reader that
+        was not in the step (``relay.pump.needs_step``): ``load`` —
+        programs still to load; ``cursor`` — a rung-0 output's cursor
+        not parked or a higher rung's not placed (a report moved the
+        rung); ``budget`` — a window the tick would emit now.  Only
+        ingest (``end ≤ head``) and a step (the bookmark) make a window
+        owed, and a step's tick emits all it may: what is left past its
+        budget is the backlog.  None: a tick now would do nothing but
+        set the overhead gauge."""
+        if not self._states:
+            return None
+        if self._to_load:
+            return "load"
+        for out, f in self._states:
+            if not f.controller._idx:
+                if f.next_window is not None:
+                    return "cursor"
+            elif f.next_window is None:
+                return "cursor"
+            elif (out.bookmark is not None and out.thinning.passthrough()
+                  and self._window_owed(out, f)):
+                return "budget"
+        return None
 
     # -- window parity --------------------------------------------------
     def _window_rows(self, w: int):

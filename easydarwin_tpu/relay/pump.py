@@ -66,8 +66,12 @@ def needs_step(stream, t: int, route: int | None = None) -> bool:
        reliable-UDP RTO, the next SR (``next_deadline_ms``);
     4. its last step stalled, raised or left an output un-latched: it
        is retried every wake;
-    5. it carries a per-pass hook no deadline stands for (``fec.tick``
-       rides ``relay_rtcp``)."""
+    5. its reliability tier owes a pass (``StreamFec.owes``): parity
+       programs still to load, a window backlog past the tick's budget,
+       a cursor a receiver report left to park or place (the report
+       marked the stream).  An FEC output alone is no reason: the
+       tick's windows come from ingest and from the step that sends
+       them."""
     c = stream._plan_cell
     ring = stream.rtp_ring
     if (ring.head != c.rtp_head or stream.rtcp_ring.head != c.rtcp_head
@@ -76,7 +80,8 @@ def needs_step(stream, t: int, route: int | None = None) -> bool:
         return True
     if not len(ring) or not stream.num_outputs:
         return False
-    return c.retry or c.due <= t or stream.fec is not None
+    return (c.retry or c.due <= t
+            or (stream.fec is not None and stream.fec.owes() is not None))
 
 
 def _step(entries, t: int, ladder, log, label: str, timed: bool):
@@ -156,8 +161,12 @@ def _step(entries, t: int, ladder, log, label: str, timed: bool):
         c.retry = live and (stalled or raised
                             or stream._next_sr_due_ms <= t)
         if c.ready is not None:
-            if c.retry or (live and stream.fec is not None):
+            owed = (live and not c.retry and stream.fec is not None
+                    and stream.fec.owes_pass)
+            if c.retry or owed:
                 c.ready.add(c.key)
+                if owed:
+                    obs.FEC_TIER_MARKS.inc(reason=owed)
             else:
                 c.ready.discard(c.key)
         if timed:

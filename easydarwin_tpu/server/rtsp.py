@@ -1367,7 +1367,7 @@ class RtspServer:
                                 # closed-loop FEC overhead rides the SAME
                                 # RR stream; thinning hears only what the
                                 # tier leaves unrepaired
-                                fec.controller.on_receiver_report(judged)
+                                fec.on_receiver_report(out, judged)
                                 judged = fec.unrepaired(judged)
                             out.on_receiver_report(judged)
                         # fold loss/jitter into the scrapeable per-stream
@@ -1395,8 +1395,8 @@ class RtspServer:
                         if fec is not None:
                             # buffer distress shifts the NACK-vs-FEC
                             # split toward RTX (parity is bitrate)
-                            fec.controller.on_nadu(blk.playout_delay_ms,
-                                                   blk.free_buffer_64b)
+                            fec.on_nadu(out, blk.playout_delay_ms,
+                                        blk.free_buffer_64b)
             elif isinstance(p, rtcp_mod.GenericNack):
                 # RFC 4585 generic NACK → ring-bookmark RTX replay
                 # (relay/fec.py): the ring IS the retransmission buffer

@@ -451,3 +451,151 @@ def test_a_rung_0_cursor_is_parked_and_restarts_at_the_live_edge():
             if (p[1] & 0x7F) == out.fec.cfg.payload_type]
     assert pars and min(p["snbase"] for p in pars) >= 120
     assert out.fec.next_window * 8 >= 32
+
+
+# ------------------------------------------- what the tier owes the pump
+def pump_step(st, t):
+    """One step of ``st`` by the pump's own loop (``relay.pump._step``)
+    on a ready set of its own: True where the step carried the stream
+    into the next wake."""
+    from easydarwin_tpu.relay import pump
+    c = st._plan_cell
+    if c.ready is None:
+        c.install(set(), 1)
+    c.ready.clear()
+    pump._step([(st.session_path, st, None, pump.SCALAR)], t, None, None,
+               "", False)
+    return c.key in c.ready
+
+
+def parity_of(out):
+    return [p for p in out.rtp_packets
+            if (p[1] & 0x7F) == out.fec.cfg.payload_type]
+
+
+def test_fec_outputs_with_nothing_due_are_no_reason_for_a_step():
+    """Rule 5 asks what the tier owes, not whether it has an output: a
+    step that sent every complete window leaves nothing for the next."""
+    from easydarwin_tpu.relay.pump import needs_step
+    st = make_stream()
+    outs = [fec_output(30 + i, window=4, use_device=False) for i in range(2)]
+    for o in outs:
+        o.fec.controller._idx = 2
+        st.add_output(o)
+    for i in range(8):
+        st.push_rtp(vid_pkt(100 + i, nal_type=5 if i == 0 else 1), 1000)
+    assert pump_step(st, 1001) is False
+    # two windows, one row each (10 % of 4)
+    assert all(len(parity_of(o)) == 2 for o in outs)
+    assert st.fec.owes_pass is None and st.fec.owes() is None
+    assert not needs_step(st, 1002)
+    assert pump_step(st, 1002) is False
+    assert all(len(parity_of(o)) == 2 for o in outs)
+
+
+def test_a_backlog_past_the_budget_is_carried_until_it_drains():
+    """Twenty windows' worth sent at once: the tick emits eight a pass,
+    the step carries the stream twice, and the third pass drains it."""
+    st = make_stream()
+    out = fec_output(32, window=2, use_device=False)
+    out.fec.controller._idx = 1
+    st.add_output(out)
+    for i in range(40):
+        st.push_rtp(vid_pkt(100 + i, nal_type=5 if i == 0 else 1), 1000)
+    budget = obs.FEC_TIER_MARKS.value(reason="budget")
+    carried = [pump_step(st, 1001 + n) for n in range(4)]
+    assert carried == [True, True, False, False]
+    assert len(parity_of(out)) == 20 and out.fec.next_window == 20
+    assert obs.FEC_TIER_MARKS.value(reason="budget") - budget == 2
+    assert st.fec.owes() is None
+
+
+def test_programs_to_load_are_carried_one_a_pass(monkeypatch):
+    loaded = []
+    monkeypatch.setattr(fec_mod, "load_parity_programs",
+                        lambda progs: loaded.extend(progs))
+    st = make_stream()
+    push_frames(st, 100, 4)
+    out = fec_output(33, window=11)                 # shapes no test built
+    st.add_output(out)
+    pending = list(st.fec._to_load)
+    assert len(pending) > 2 and st.fec.owes() == "load"
+    load = obs.FEC_TIER_MARKS.value(reason="load")
+    carried = [pump_step(st, 5000 + n) for n in range(len(pending) + 1)]
+    assert carried == [True] * (len(pending) - 1) + [False, False]
+    assert loaded == pending and st.fec.owes() is None
+    assert obs.FEC_TIER_MARKS.value(reason="load") - load == len(pending) - 1
+
+
+def test_a_report_that_moves_a_rung_off_0_marks_the_stream():
+    """An RR that lifts an output's FEC rung off 0 marks its stream: the
+    next wake's tick places the cursor at the ring head as it stands
+    then, as a tick in every wake would; a clean streak that brings the
+    rung back to 0 marks it again, and the cursor is parked."""
+    from easydarwin_tpu.relay.fec import NUM_CLEAN_TO_STEP
+    from easydarwin_tpu.relay.pump import needs_step
+    st = make_stream()
+    out = fec_output(34, window=8, use_device=False)
+    st.add_output(out)
+    for i in range(20):
+        st.push_rtp(vid_pkt(100 + i, nal_type=5 if i == 0 else 1), 1000)
+    assert pump_step(st, 1001) is False
+    assert out.fec.next_window is None              # rung 0: parked
+    c, conn = st._plan_cell, player(out)
+    rung = obs.FEC_TIER_MARKS.value(reason="rung")
+    rtcp_in(conn, rr(out, 99 + 70, 20))             # 29 % lost: up at once
+    assert out.fec.controller._idx == 1 and c.key in c.ready
+    assert obs.FEC_TIER_MARKS.value(reason="rung") == rung + 1
+    assert st.fec.owes() == "cursor" and needs_step(st, 1002)
+    head = st.rtp_ring.head
+    assert pump_step(st, 1002) is False
+    assert out.fec.next_window == (head + 7) // 8
+    hi = 99 + 70
+    for _ in range(NUM_CLEAN_TO_STEP):
+        hi += 70
+        c.ready.clear()
+        rtcp_in(conn, rr(out, hi, 20))              # nothing more lost
+    assert out.fec.controller._idx == 0 and c.key in c.ready
+    assert obs.FEC_TIER_MARKS.value(reason="rung") == rung + 2
+    assert st.fec.owes() == "cursor"
+    pump_step(st, 1003)
+    assert out.fec.next_window is None and st.fec.owes() is None
+
+
+def test_a_dropped_overhead_gauge_is_set_again_at_the_next_step():
+    """A departing viewer's connection drops its path's gauge child; the
+    removal marks the stream, and its step sets the gauge again for the
+    subscriber that stays."""
+    st = make_stream()
+    stay, leave = (fec_output(35 + i, window=4, use_device=False)
+                   for i in range(2))
+    stay.fec.controller._idx = 2
+    for o in (stay, leave):
+        st.add_output(o)
+    push_frames(st, 100, 4)
+    key = f"{st.session_path},{st.info.track_id}"
+    pump_step(st, 2000)
+    assert obs.FEC_OVERHEAD_RATIO.as_value()[key] == 0.10
+    st.remove_output(leave)
+    fec_mod.drop_overhead_gauge(st.session_path, st.info.track_id)
+    assert key not in obs.FEC_OVERHEAD_RATIO.as_value()
+    assert st._plan_cell.key in st._plan_cell.ready
+    pump_step(st, 2001)
+    assert obs.FEC_OVERHEAD_RATIO.as_value()[key] == 0.10
+
+
+def test_the_tier_marks_family_passes_the_lint():
+    from easydarwin_tpu.obs import events as ev
+    from easydarwin_tpu.obs.metrics import Registry
+    from tools.metrics_lint import lint, lint_fec
+    assert not [e for e in lint(obs.REGISTRY)
+                if e.startswith("fec_tier_marks_total")]
+    assert lint_fec(obs.REGISTRY, ev.SCHEMA) == []
+    fam = obs.REGISTRY.get("fec_tier_marks_total")
+    assert fam.kind == "counter" and fam.label_names == ("reason",)
+    # every reason exposed from the start: a window without one reads 0
+    assert set(fam.as_value()) == {"budget", "load", "cursor", "rung"}
+    r = Registry()
+    r.counter("fec_tier_marks_total", "x", labels=("reason",)).inc(
+        reason="idle")
+    assert any("'idle'" in e for e in lint_fec(r, ev.SCHEMA))

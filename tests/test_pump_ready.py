@@ -16,6 +16,8 @@ import pytest
 
 from easydarwin_tpu import native, obs
 from easydarwin_tpu.relay.fanout import _Pass
+from easydarwin_tpu.relay.fec import NUM_CLEAN_TO_STEP, FecConfig, \
+    FecOutputState
 from easydarwin_tpu.relay.output import CollectingOutput, WriteResult
 from easydarwin_tpu.relay.pump import OWNED, Pump, needs_step
 from easydarwin_tpu.protocol.rtcp import parse_compound
@@ -113,11 +115,15 @@ class _World:
             return reflect(t)
         return counted
 
-    def join(self, k: int, *, bucket=None, reliable=False) -> _Stamped:
+    def join(self, k: int, *, bucket=None, reliable=False,
+             fec=False) -> _Stamped:
         out = _Stamped(self.clock, ssrc=len(self.outputs) + 1)
         held = out
         if reliable:
             held = ReliableUdpOutput(out, clock=lambda: self.clock.t)
+        elif fec:
+            # window 2: the tick's budget of 8 windows is 16 packets
+            out.fec = FecOutputState(FecConfig(window=2, use_device=False))
         self.streams[k].add_output(held, bucket=bucket)
         self.outputs.append((k, held, out))
         return out
@@ -151,16 +157,27 @@ class _World:
         self.clock.t += interval if nd < 0 else min(interval, max(nd, 1))
 
 
-def _apply(w: _World, op: str, k: int, arg: int) -> None:
+def _apply(w: _World, op: str, k: int, arg: int, fec: bool = False) -> None:
     st = w.streams[k]
     mine = [(held, out) for kk, held, out in w.outputs
             if kk == k and held._plan_cell is not None]
     if op == "push":
         w.push(k, 1 + arg % 4)
+    elif op == "burst":                 # past the tick's budget of 8
+        w.push(k, 17 + arg % 24)
     elif op == "rtcp":
         st.push_rtcp(b"\x80\xc9\x00\x01" + bytes(4), w.clock.t)
     elif op == "join":
-        w.join(k, bucket=arg % 4, reliable=arg % 5 == 0)
+        w.join(k, bucket=arg % 4, reliable=arg % 5 == 0,
+               fec=fec and arg % 3 != 0)
+    elif op == "rr" and mine:
+        # a judged receiver report, as the server hands it on: a heavy
+        # one moves the FEC rung up at once, a clean streak one down
+        held = mine[arg % len(mine)][0]
+        f = getattr(held, "fec", None)
+        if f is not None:
+            for _ in range(1 if arg % 2 else NUM_CLEAN_TO_STEP):
+                f.on_receiver_report(held, 0.5 if arg % 2 else 0.0)
     elif op == "leave" and mine:
         st.remove_output(mine[arg % len(mine)][0])
     elif op == "bookmark" and mine:
@@ -183,6 +200,34 @@ OPS = ["push"] * 6 + ["rtcp", "join", "leave", "bookmark", "stall", "ack"]
 GAPS = [0, 1, 3, 20, 20, 41, 90, 700, 1_300, SR_INTERVAL_MS + 1]
 
 
+def _twin_worlds(seed: int, *, fec: bool) -> tuple:
+    """The two worlds through one seeded sequence of ops; returns them.
+    ``fec``: two of three joined outputs carry an ``FecOutputState``,
+    receiver reports move their rungs and bursts of ingest leave a
+    backlog of windows past the tick's budget."""
+    worlds = [_World(5, ready_set=True), _World(5, ready_set=False)]
+    for w in worlds:
+        for k in range(5):
+            for b in range(1 + k % 3):
+                w.join(k, bucket=b, fec=fec and (k + b) % 3 != 0)
+    ops = OPS + ["rr"] * 3 + ["burst"] if fec else OPS
+    rng = random.Random(seed)
+    for _ in range(400):
+        op, k, arg = rng.choice(ops), rng.randrange(5), rng.randrange(60)
+        gap = rng.choice(GAPS)
+        for w in worlds:
+            _apply(w, op, k, arg, fec)
+            w.clock.t += gap
+            w.wake()
+    a, b = worlds
+    assert len(a.outputs) == len(b.outputs) > 10
+    for (_k, _h, oa), (_k2, _h2, ob) in zip(a.outputs, b.outputs):
+        assert oa.log == ob.log
+    assert sum(len(o.log) for _k, _h, o in a.outputs) > 1_000
+    assert a.steps < 0.8 * b.steps              # and it skipped some
+    return a, b
+
+
 @pytest.mark.parametrize("seed", [1, 2, 3, 4, 5, 3300000001])
 def test_no_stream_that_needs_a_step_is_skipped_and_nothing_arrives_later(
         seed):
@@ -192,26 +237,29 @@ def test_no_stream_that_needs_a_step_is_skipped_and_nothing_arrives_later(
     audit finds nothing, and every output of the ready-set world was
     sent the same bytes at the same clock as its twin's."""
     missed0 = obs.PUMP_READY_MISSED.value()
-    worlds = [_World(5, ready_set=True), _World(5, ready_set=False)]
-    for w in worlds:
-        for k in range(5):
-            for b in range(1 + k % 3):
-                w.join(k, bucket=b)
-    rng = random.Random(seed)
-    for _ in range(400):
-        op, k, arg = rng.choice(OPS), rng.randrange(5), rng.randrange(60)
-        gap = rng.choice(GAPS)
-        for w in worlds:
-            _apply(w, op, k, arg)
-            w.clock.t += gap
-            w.wake()
-    a, b = worlds
-    assert len(a.outputs) == len(b.outputs) > 10
-    for (_k, _h, oa), (_k2, _h2, ob) in zip(a.outputs, b.outputs):
-        assert oa.log == ob.log
-    assert sum(len(o.log) for _k, _h, o in a.outputs) > 1_000
-    assert a.steps < 0.8 * b.steps              # and it skipped some
+    _twin_worlds(seed, fec=False)
     assert obs.PUMP_READY_MISSED.value() == missed0
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4, 5, 3300000001])
+def test_with_fec_outputs_nothing_arrives_later_and_no_parity_either(seed):
+    """The same with FEC outputs among the joined and receiver reports
+    that move their rungs onto and off 0: the ready-set world sends the
+    same media and the same parity datagrams at the same clocks, steps
+    under 0.8 times as often (an FEC output alone is no reason for a
+    step, ``needs_step`` rule 5) and the audit finds nothing."""
+    missed0 = obs.PUMP_READY_MISSED.value()
+    rung0 = obs.FEC_TIER_MARKS.value(reason="rung")
+    budget0 = obs.FEC_TIER_MARKS.value(reason="budget")
+    a, _b = _twin_worlds(seed, fec=True)
+    assert obs.PUMP_READY_MISSED.value() == missed0
+    parity = [x for _k, _h, o in a.outputs for _t, x in o.log
+              if x != "rtcp" and x[1] & 0x7F == 127]
+    assert len(parity) > 50
+    # the reports moved rungs onto and off 0 in both worlds, and the
+    # ready-set world carried backlogs
+    assert obs.FEC_TIER_MARKS.value(reason="rung") - rung0 >= 4
+    assert obs.FEC_TIER_MARKS.value(reason="budget") > budget0
 
 
 def test_a_silent_sources_latched_output_gets_its_sr_from_the_wheel():

@@ -523,7 +523,9 @@ TIER_FAMILIES = {"rtx_nacked_seqs_total":
                  ("result", ("sent", "giveup", "withheld", "evicted",
                              "repeat")),
                  "fec_parity_pass_seconds": ("part", ("device", "oracle")),
-                 "rtcp_report_blocks_total": ("result", ("folded", "judged"))}
+                 "rtcp_report_blocks_total": ("result", ("folded", "judged")),
+                 "fec_tier_marks_total": ("reason", ("budget", "load",
+                                                     "cursor", "rung"))}
 TIER_SPANS = ("fec.parity_pass", "rtx.replay", "rtcp.rr")
 
 
