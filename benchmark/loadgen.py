@@ -1,5 +1,6 @@
-"""The load generator: seeded H.264 sources, RTSP push and PLAY sessions,
-stamped and bulk UDP receivers.  Copied out of ``chip_smoke.py`` (PR 21)
+"""The load generator: seeded H.264 or RTP/JPEG sources, RTSP push and
+PLAY sessions, players who join while the stream runs, stamped and bulk
+UDP receivers.  Copied out of ``chip_smoke.py`` (PR 21)
 and cut loose from the program: it imports nothing of ``easydarwin_tpu``
 — its own FU-A packetizer, its own RTSP client, its own receivers — so
 that what is measured is the server child and nothing else.
@@ -10,22 +11,35 @@ the same due times and the same sockets layout.
 
 from __future__ import annotations
 
+import array
 import asyncio
+import bisect
+import ctypes
+import functools
 import mmap
 import os
 import select
 import signal
 import socket
+import struct
 import threading
 import time
 
 import numpy as np
 
-from benchmark import lastmile
+from benchmark import lastmile, reference
 
 SDP = ("v=0\r\no=- 1 1 IN IP4 127.0.0.1\r\ns=benchmark\r\nt=0 0\r\n"
        "m=video 0 RTP/AVP 96\r\na=rtpmap:96 H264/90000\r\n"
        "a=control:trackID=1\r\n")
+#: an RTP/JPEG source's ANNOUNCE: the static payload type 26 (RFC 3551)
+SDP_JPEG = ("v=0\r\no=- 1 1 IN IP4 127.0.0.1\r\ns=benchmark\r\nt=0 0\r\n"
+            "m=video 0 RTP/AVP 26\r\na=rtpmap:26 JPEG/90000\r\n"
+            "a=control:trackID=1\r\n")
+JPEG_PT = 26
+#: RFC 2435 main header fields of every frame: type 1 (4:2:0, no restart
+#: markers) and a Q under 128, so no quantization tables ride in band
+JPEG_TYPE, JPEG_Q = 1, 75
 RCVBUF = 1 << 24
 N_IP, N_PORT = 64, 4        # bulk destinations: 64 loopback IPs x 4 ports
 #: a traffic file's ``frame_phase``: "spread" (and no key) puts source i's
@@ -66,9 +80,33 @@ def packetize_h264(nal: bytes, seq: int, ts: int, ssrc: int, mtu: int,
     return out
 
 
+def is_mjpeg(shapes: dict) -> bool:
+    """Whether a configuration's ``stream`` group names RTP/JPEG."""
+    return "JPEG" in str(shapes.get("codec", "")).upper()
+
+
+def packetize_jpeg(scan: bytes, seq: int, ts: int, ssrc: int, mtu: int,
+                   width: int, height: int) -> list[bytes]:
+    """One JPEG frame's scan as RTP/JPEG (RFC 2435): each packet opens
+    with the 8-byte main header (type-specific 0, the fragment's offset
+    into the scan, type, Q, width / 8, height / 8); the marker is on the
+    frame's last packet."""
+    out, off = [], 0
+    while off < len(scan):
+        chunk = scan[off:off + mtu - 8]
+        last = off + len(chunk) >= len(scan)
+        out.append(rtp_header(seq, ts, ssrc, last, JPEG_PT)
+                   + bytes((0,)) + off.to_bytes(3, "big")
+                   + bytes((JPEG_TYPE, JPEG_Q, width // 8, height // 8))
+                   + chunk)
+        seq, off = (seq + 1) & 0xFFFF, off + len(chunk)
+    return out
+
+
 class Source:
     """One pusher's seeded stream: every packet it will ever push.
-    ``shapes`` is the configuration's ``stream`` group."""
+    ``shapes`` is the configuration's ``stream`` group: H.264 in FU-A,
+    or RTP/JPEG where its ``codec`` names JPEG."""
 
     def __init__(self, idx: int, seed: int, n_frames: int, fps: float,
                  shapes: dict, start_frame: int = 0, first_gop_at: int = 0,
@@ -88,10 +126,19 @@ class Source:
         self.packets: list[bytes] = []
         #: packet index of each frame's first packet (+ end sentinel)
         self.frame_start: list[int] = []
+        #: the frames that a decoder can start from: those that open with
+        #: a keyframe run (SPS, PPS, IDR), or every JPEG frame
+        self.key_frames: list[int] = []
         seq = int(rng.integers(0, 1 << 16))
         ts = int(rng.integers(0, 1 << 32))
         ssrc = int(rng.integers(1, 1 << 32))
-        mtu, gop = shapes["rtp_payload_bytes"], shapes["gop_frames"]
+        self.mjpeg = is_mjpeg(shapes)
+        self.sdp = SDP_JPEG if self.mjpeg else SDP
+        mtu = shapes["rtp_payload_bytes"]
+        if self.mjpeg:
+            self._jpeg(rng, n_frames, fps, shapes, start_frame, seq, ts, ssrc)
+            return
+        gop = shapes["gop_frames"]
         idr_lo, idr_hi = shapes["idr_bytes"]
         p_lo, p_hi = shapes["p_frame_bytes"]
         for f in range(n_frames):
@@ -105,6 +152,7 @@ class Source:
                 size = (warm_idrs[f] if f in warm_idrs
                         else int(rng.integers(idr_lo, idr_hi)))
                 nals.append(bytes((0x65,)) + rng.bytes(size))
+                self.key_frames.append(f)
             else:
                 nals = [bytes((0x41,)) + rng.bytes(
                     int(rng.integers(p_lo, p_hi)))]
@@ -114,9 +162,66 @@ class Source:
                 self.packets += out
                 seq = (seq + len(out)) & 0xFFFF
             ts = (ts + round(90_000 / fps)) & 0xFFFFFFFF     # 90 kHz
+        self._close()
+
+    def _jpeg(self, rng, n_frames: int, fps: float, shapes: dict,
+              start_frame: int, seq: int, ts: int, ssrc: int) -> None:
+        """Every frame a JPEG scan of a size drawn from the stream's
+        ``frame_bytes``, and every frame a keyframe."""
+        lo, hi = shapes["frame_bytes"]
+        for f in range(n_frames):
+            self.frame_start.append(len(self.packets))
+            if f < start_frame:
+                continue
+            out = packetize_jpeg(rng.bytes(int(rng.integers(lo, hi))), seq,
+                                 ts, ssrc, shapes["rtp_payload_bytes"],
+                                 shapes["width"], shapes["height"])
+            self.packets += out
+            self.key_frames.append(f)
+            seq = (seq + len(out)) & 0xFFFF
+            ts = (ts + round(90_000 / fps)) & 0xFFFFFFFF     # 90 kHz
+        self._close()
+
+    def _close(self) -> None:
         self.frame_start.append(len(self.packets))
+        #: packet index of each keyframe's head, in order
+        self.key_heads = [self.frame_start[f] for f in self.key_frames]
         self.pushed = 0                 # packets written so far
+        #: the first packet of each frame that left late or was not
+        #: handed whole to the kernel (``Loadgen.push_frames``)
+        self.unsure: set[int] = set()
         self.conn: RtspConn | None = None
+        self._payloads: dict[bytes, int] | None = None
+
+    def newest_key_head(self) -> int | None:
+        """The head of the newest keyframe run pushed so far that the
+        server surely holds: one that left within a frame period of its
+        due instant and was handed whole to the kernel.  A frame pushed
+        late, in the burst with which the generator catches up after a
+        stall of its own, or kept in the transport's buffer, may still
+        be on its way when a PLAY written after it is answered."""
+        k = bisect.bisect_right(self.key_heads, self.pushed - 1)
+        return next((h for h in reversed(self.key_heads[:k])
+                     if h not in self.unsure), None)
+
+    def start_of(self, got: list[bytes], first_seq: int | None
+                 ) -> int | None:
+        """The pushed packet a session's first sequence number stands
+        for, as the first datagram of ``got`` shows it; None where there
+        is none, or it carries none of this source's packets."""
+        g = next((g for g in got if len(g) >= 12), None)
+        k = None if g is None else self.place_of_payload(g)
+        if k is None or first_seq is None:
+            return None
+        return reference.start_of(int.from_bytes(g[2:4], "big"), k, first_seq)
+
+    def place_of_payload(self, packet: bytes) -> int | None:
+        """Which of this source's packets ``packet`` carries, by its
+        bytes from 12 on (a relay leaves them as pushed); None where it
+        carries none of them."""
+        if self._payloads is None:
+            self._payloads = {p[12:]: k for k, p in enumerate(self.packets)}
+        return self._payloads.get(packet[12:])
 
     def frame_bytes(self, f: int) -> tuple[bytes, int]:
         lo, hi = self.frame_start[f], self.frame_start[f + 1]
@@ -185,20 +290,23 @@ class RtspConn:
             raise LoadgenError(f"{method} {uri}: status {status}")
         return hdrs
 
-    async def push_start(self, uri: str) -> None:
+    async def push_start(self, uri: str, sdp: str = SDP) -> None:
         await self.expect("ANNOUNCE", uri,
-                          {"Content-Type": "application/sdp"}, SDP.encode())
+                          {"Content-Type": "application/sdp"}, sdp.encode())
         await self.expect("SETUP", f"{uri}/trackID=1", {
             "Transport": "RTP/AVP/TCP;unicast;interleaved=0-1;mode=record"})
         await self.expect("RECORD", uri)
 
     async def play_start(self, uri: str, rtp_port: int,
-                         setup_extra: dict | None = None
+                         setup_extra: dict | None = None,
+                         before_play=None
                          ) -> tuple[int | None, int | None]:
         """DESCRIBE / SETUP / PLAY over UDP; returns what the server
         announced for this session: (ssrc of SETUP's Transport, first
         sequence number of PLAY's RTP-Info).  ``setup_extra``: more
-        SETUP headers (a lossy last mile asks for parity and RTX)."""
+        SETUP headers (a lossy last mile asks for parity and RTX).
+        ``before_play()``: called just before the PLAY is written, with
+        nothing awaited in between."""
         await self.expect("DESCRIBE", uri, {"Accept": "application/sdp"})
         hdrs = await self.expect("SETUP", f"{uri}/trackID=1", {
             "Transport": f"RTP/AVP;unicast;client_port={rtp_port}-"
@@ -208,6 +316,8 @@ class RtspConn:
             k, _, v = part.strip().partition("=")
             if k.lower() == "ssrc":
                 ssrc = int(v, 16)
+        if before_play is not None:
+            before_play()
         hdrs = await self.expect("PLAY", uri)
         info, seq = hdrs.get("rtp-info", ""), None
         if "seq=" in info:
@@ -250,7 +360,14 @@ def udp_pair(ip: str) -> tuple[socket.socket, socket.socket]:
 
 class Flow:
     """One stamped player: its own socket pair, every datagram kept with
-    the clock reading at ``recv``."""
+    the clock reading at ``recv``.
+
+    A player of an H.264 source joined before media is owed every packet
+    pushed.  Where the relay's fast start decides where a player begins —
+    a joiner's (``Joiner``), or any player's of an RTP/JPEG source, whose
+    every frame is a keyframe, so that the relay starts it at the newest
+    frame when it first steps it — the player is owed every packet from
+    the keyframe run its first datagram heads (``fast_start``)."""
 
     def __init__(self, src: Source, ip: str):
         self.src, self.ip = src, ip
@@ -262,11 +379,241 @@ class Flow:
         self.first_seq: int | None = None   # from the PLAY RTP-Info
         #: behind a lossy last mile, its ``lastmile.StampedReceiver``
         self.lm: lastmile.StampedReceiver | None = None
+        self.fast_start = src.mjpeg
+        #: the head of the newest keyframe run pushed before its PLAY
+        #: (before media: the first packet)
+        self.key_head: int | None = 0
+        self._start: int | None = None
+        #: while a reader in another process holds its datagrams: the
+        #: place among its source's packets and the sequence number of
+        #: the first (``StampReader.first``)
+        self.peek = None
+
+    def start(self) -> int | None:
+        """The pushed packet the session's first sequence number stands
+        for: 0, or under a fast start as the first datagram read and its
+        distance from the PLAY answer's RTP-Info ``seq`` show it; None
+        while nothing is read, or where the first datagram is no pushed
+        packet's."""
+        if not self.fast_start:
+            return 0
+        if self._start is None and self.packets:
+            self._start = self.src.start_of(self.packets, self.first_seq)
+        elif self._start is None and self.peek is not None:
+            first = self.peek()
+            if first and None not in (first[0], self.first_seq):
+                self._start = reference.start_of(first[1], first[0],
+                                                 self.first_seq)
+        return self._start
+
+    def owed(self) -> int:
+        """Packets owed so far: every one pushed from its start on (from
+        the newest keyframe at its PLAY while nothing is read yet)."""
+        start = self.start()
+        if start is None:
+            start = self.key_head or 0
+        return max(self.src.pushed - max(start, 0), 0)
 
 
-class StampReader(threading.Thread):
-    """Drains a fixed set of stamped flows, stamping each datagram as it
-    is read.  Blocking epoll: it wakes when a datagram lands."""
+class Joiner(Flow):
+    """A stamped player that joins once the window is open: what it was
+    owed is fixed by the instant its PLAY was written."""
+
+    def __init__(self, src: Source, ip: str):
+        super().__init__(src, ip)
+        self.fast_start = True
+        self.key_head = None
+        self.play_ns: int | None = None     # perf_counter_ns, PLAY written
+        self.answer_ns: int | None = None   # ... and its answer read
+
+    def before_play(self) -> None:
+        self.key_head = self.src.newest_key_head()
+        self.play_ns = time.perf_counter_ns()
+
+    def owed(self) -> int:
+        return 0 if self.answer_ns is None else super().owed()
+
+
+class _Iovec(ctypes.Structure):
+    _fields_ = [("base", ctypes.c_void_p), ("len", ctypes.c_size_t)]
+
+
+class _Msghdr(ctypes.Structure):
+    _fields_ = [("name", ctypes.c_void_p), ("namelen", ctypes.c_uint32),
+                ("iov", ctypes.POINTER(_Iovec)), ("iovlen", ctypes.c_size_t),
+                ("control", ctypes.c_void_p), ("controllen", ctypes.c_size_t),
+                ("flags", ctypes.c_int)]
+
+
+class _Mmsghdr(ctypes.Structure):
+    _fields_ = [("hdr", _Msghdr), ("len", ctypes.c_uint)]
+
+
+_recvmmsg = ctypes.CDLL(None, use_errno=True).recvmmsg
+_recvmmsg.argtypes = [ctypes.c_int, ctypes.POINTER(_Mmsghdr), ctypes.c_uint,
+                      ctypes.c_int, ctypes.c_void_p]
+_recvmmsg.restype = ctypes.c_int
+
+
+class BatchRecv:
+    """``recvmmsg(2)`` into buffers of its own: up to ``n`` queued
+    datagrams of a socket in one call.  A ``recv`` a datagram costs a
+    system call each, which on the chip's sandboxed host is most of what
+    reading costs."""
+
+    SLOT = 4096
+    MSG_DONTWAIT = 0x40
+
+    def __init__(self, n: int = 64):
+        self.n = n
+        self.buf = ctypes.create_string_buffer(n * self.SLOT)
+        base = ctypes.addressof(self.buf)
+        self.iov = (_Iovec * n)(*(_Iovec(base + i * self.SLOT, self.SLOT)
+                                  for i in range(n)))
+        self.msgs = (_Mmsghdr * n)()
+        for i in range(n):
+            self.msgs[i].hdr.iov = ctypes.pointer(self.iov[i])
+            self.msgs[i].hdr.iovlen = 1
+        self.base = base
+
+    def recv(self, fd: int) -> list[bytes]:
+        """What is queued on ``fd``, up to ``n`` datagrams; none where
+        nothing is (or the socket is gone)."""
+        got = _recvmmsg(fd, self.msgs, self.n, self.MSG_DONTWAIT, None)
+        return [ctypes.string_at(self.base + i * self.SLOT, self.msgs[i].len)
+                for i in range(max(got, 0))]
+
+
+class StampReader:
+    """Drains the stamped flows in forked worker processes of their own,
+    stamping each datagram as it is read: the datagrams one ``BatchRecv``
+    call reads share the stamp of the instant it returned.  Eighty
+    stamped players at 30 frames a second are more than threads that
+    share this process's interpreter lock with the pushers can read on
+    time.
+
+    While the workers run, shared memory holds each flow's count and the
+    place of its first datagram among its source's packets (what a fast
+    start is judged by, ``Flow.start``); ``stop()`` brings every flow's
+    datagrams and stamps home, through a pipe from each worker."""
+
+    #: per flow: datagrams read, the place of the first among its source's
+    #: packets (UNREAD before one is read, -1 where it is none of them),
+    #: its sequence number
+    UNREAD = -2
+
+    def __init__(self, flows: list[Flow], n_procs: int):
+        self.flows, self.n_procs = flows, n_procs
+        self._mm = mmap.mmap(-1, 8 * (1 + 3 * len(flows)))
+        self._shared = memoryview(self._mm).cast("q")
+        for i in range(len(flows)):
+            self._shared[2 + 3 * i] = self.UNREAD
+        self.pids: list[int] = []
+        self.pipes: list[int] = []
+
+    def start(self) -> None:
+        """Fork the workers.  Call before any thread or event loop
+        exists in this process."""
+        for i, f in enumerate(self.flows):
+            f.peek = functools.partial(self.first, i)
+        for k in range(min(self.n_procs, len(self.flows))):
+            r, w = os.pipe()
+            pid = os.fork()
+            if pid == 0:
+                try:
+                    os.close(r)
+                    self._work(k, w)
+                finally:
+                    os._exit(0)
+            os.close(w)
+            self.pids.append(pid)
+            self.pipes.append(r)
+
+    def _work(self, k: int, w: int) -> None:
+        signal.signal(signal.SIGINT, signal.SIG_IGN)
+        shared, ep = self._shared, select.epoll()
+        mine: dict[int, int | None] = {}
+        for i in range(k, len(self.flows), self.n_procs):
+            f = self.flows[i]
+            mine[f.rtp.fileno()], mine[f.rtcp.fileno()] = i, None
+            ep.register(f.rtp.fileno(), select.EPOLLIN)
+            ep.register(f.rtcp.fileno(), select.EPOLLIN)
+        stamps = {i: array.array("q") for i in mine.values() if i is not None}
+        packets: dict[int, list[bytes]] = {i: [] for i in stamps}
+        rx, now, ppid = BatchRecv(), time.perf_counter_ns, os.getppid()
+        while not shared[0]:
+            events = ep.poll(0.05)
+            if not events and os.getppid() != ppid:
+                return                      # orphaned: the parent died
+            for fd, _ in events:
+                i = mine[fd]
+                got = rx.recv(fd)
+                while got:
+                    if i is not None:
+                        if shared[2 + 3 * i] == self.UNREAD:
+                            self._note_first(i, got)
+                        stamps[i].extend([now()] * len(got))
+                        packets[i] += got
+                        shared[1 + 3 * i] += len(got)
+                    got = rx.recv(fd) if len(got) == rx.n else []
+        with os.fdopen(w, "wb") as out:
+            for i in sorted(stamps):
+                lens = array.array("I", map(len, packets[i]))
+                blob = b"".join(packets[i])
+                out.write(struct.pack("<qqq", i, len(lens), len(blob)))
+                out.write(stamps[i].tobytes() + lens.tobytes())
+                out.write(blob)
+
+    def _note_first(self, i: int, got: list[bytes]) -> None:
+        g = next((g for g in got if len(g) >= 12), None)
+        if g is not None:
+            k = self.flows[i].src.place_of_payload(g)
+            self._shared[3 + 3 * i] = int.from_bytes(g[2:4], "big")
+            self._shared[2 + 3 * i] = -1 if k is None else k
+
+    def read(self) -> int:
+        """Datagrams read so far, over every stamped flow."""
+        return sum(self._shared[1 + 3 * i] for i in range(len(self.flows)))
+
+    def first(self, i: int) -> tuple[int | None, int] | None:
+        """Flow ``i``'s first datagram so far: (its place among its
+        source's packets, None where it is none of them; its sequence
+        number), or None before one is read."""
+        k = self._shared[2 + 3 * i]
+        if k == self.UNREAD:
+            return None
+        return (None if k < 0 else k), self._shared[3 + 3 * i]
+
+    def stop(self) -> None:
+        """Stop the workers and bring every flow's datagrams and stamps
+        home."""
+        self._shared[0] = 1
+        for pid, r in zip(self.pids, self.pipes):
+            with os.fdopen(r, "rb") as inp:
+                while head := inp.read(24):
+                    i, n, size = struct.unpack("<qqq", head)
+                    stamps, lens = array.array("q"), array.array("I")
+                    stamps.frombytes(inp.read(8 * n))
+                    lens.frombytes(inp.read(4 * n))
+                    blob, f, at = inp.read(size), self.flows[i], 0
+                    f.stamps = stamps.tolist()
+                    f.packets = []
+                    for n_bytes in lens:
+                        f.packets.append(blob[at:at + n_bytes])
+                        at += n_bytes
+            os.waitpid(pid, 0)
+        self.pids, self.pipes = [], []
+
+
+class LossyStampReader(threading.Thread):
+    """Drains a fixed set of stamped flows that sit behind a lossy last
+    mile, on a thread: each datagram goes to its flow's
+    ``lastmile.StampedReceiver``, which holds (and stamps) media as it
+    survives the chain or is repaired; the server's sender reports name
+    the address RRs and NACKs go to; every ``SWEEP_NS`` the receivers'
+    timers run.  Blocking epoll: it wakes when a datagram lands."""
+
+    SWEEP_NS = 20_000_000
 
     def __init__(self, flows: list[Flow], name: str):
         super().__init__(daemon=True, name=name)
@@ -279,35 +626,11 @@ class StampReader(threading.Thread):
             self.ep.register(f.rtcp.fileno(), select.EPOLLIN)
         self.socks = {s.fileno(): s for f in flows for s in (f.rtp, f.rtcp)}
         self.stop_flag = False
-
-    def run(self) -> None:
-        now = time.perf_counter_ns
-        while not self.stop_flag:
-            for fd, _ in self.ep.poll(0.05):
-                flow, sock = self.by_fd[fd], self.socks[fd]
-                try:
-                    while True:
-                        data = sock.recv(4096)
-                        if flow is not None:
-                            flow.stamps.append(now())
-                            flow.packets.append(data)
-                except OSError:         # drained (BlockingIOError) or gone
-                    pass
-
-
-class LossyStampReader(StampReader):
-    """A ``StampReader`` whose flows sit behind a lossy last mile: each
-    datagram goes to its flow's ``lastmile.StampedReceiver``, which holds
-    (and stamps) media as it survives the chain or is repaired; the
-    server's sender reports name the address RRs and NACKs go to; every
-    ``SWEEP_NS`` the receivers' timers run."""
-
-    SWEEP_NS = 20_000_000
-
-    def __init__(self, flows: list[Flow], name: str):
-        super().__init__(flows, name)
         self.flows = flows
         self.rtcp_of = {f.rtcp.fileno(): f for f in flows}
+
+    def stop(self) -> None:
+        self.stop_flag = True
 
     def run(self) -> None:
         now = time.perf_counter_ns
@@ -548,6 +871,70 @@ def udp_kernel_counters() -> dict[str, int]:
         return {}
 
 
+# ------------------------------------------------------------------ joins
+#: a joining burst's phase in its source's GOP: burst i (in time order)
+#: lands at the fractional part of (i + 1/2) times this, of a GOP past a
+#: keyframe, so that the phases are spread and owe nothing to the sizes
+PHASE_STEP = (5 ** 0.5 - 1) / 2
+
+
+def burst_sizes(joins: dict) -> list[int]:
+    """The bursts' sizes, in the order they join: ``burst_size``'s range
+    from its low end up, again and again, until ``count`` players are
+    placed, the last burst cut to fit.  The same for every seed."""
+    lo, hi = (int(x) for x in joins["burst_size"])
+    sizes, left = [], int(joins["count"])
+    while left > 0:
+        for n in range(lo, hi + 1):
+            if left > 0:
+                sizes.append(min(n, left))
+                left -= sizes[-1]
+    return sizes
+
+
+def join_schedule(joins: dict, seed: int, gop_s: float, gop_at_s: float
+                  ) -> list[tuple[float, int]]:
+    """A mix's ``joins`` as bursts: ``(seconds into the window, players
+    that join then)``, in time order.  Every seed gets the same bursts
+    (``burst_sizes``) at the same phases of the source's GOP
+    (``PHASE_STEP``; ``gop_s`` its length, ``gop_at_s`` where one starts,
+    both in seconds of the window), each in a GOP of its own: the seed
+    draws which GOPs, uniformly among those that lie inside
+    ``within_s``.  What a joiner waits for is then the same for every
+    seed — how old the newest keyframe is at its PLAY, and which output
+    bucket it lands in — and the seed moves it in time."""
+    a, b = (float(x) for x in joins["within_s"])
+    sizes = burst_sizes(joins)
+    phase = [((i + 0.5) * PHASE_STEP) % 1.0 * gop_s
+             for i in range(len(sizes))]
+    first = int(np.ceil((a - gop_at_s) / gop_s))
+    gops = list(range(first, int(np.floor((b - gop_at_s) / gop_s))))
+    if len(gops) < len(sizes):
+        raise LoadgenError(f"joins {joins!r}: {len(sizes)} bursts need as "
+                           f"many GOPs of {gop_s:g} s inside within_s")
+    rng = np.random.default_rng([seed, 2 << 20])
+    drawn = sorted(int(g) for g in rng.choice(gops, len(sizes),
+                                              replace=False))
+    return [(gop_at_s + g * gop_s + p, n)
+            for g, p, n in zip(drawn, phase, sizes)]
+
+
+def check_joins(joins: dict, n_sources: int, seconds: float) -> None:
+    """A ``joins`` group the generator can play, or ``LoadgenError``."""
+    try:
+        src, count = int(joins["source"]), int(joins["count"])
+        lo, hi = (int(x) for x in joins["burst_size"])
+        a, b = (float(x) for x in joins["within_s"])
+    except (KeyError, TypeError, ValueError) as e:
+        raise LoadgenError(f"joins {joins!r}: {e!r}") from e
+    if not (0 <= src < n_sources and count >= 1 and 1 <= lo <= hi
+            and 0 < a < b <= seconds):
+        raise LoadgenError(
+            f"joins {joins!r}: a source of the {n_sources}, a count of 1 or "
+            f"more, burst sizes 1 <= lo <= hi, and instants inside the "
+            f"{seconds:g} s window")
+
+
 # ----------------------------------------------------------------- driver
 class Loadgen:
     """Sources, sessions and receivers of one run."""
@@ -565,6 +952,12 @@ class Loadgen:
         except (lastmile.LastMileError, KeyError, TypeError,
                 ValueError) as e:
             raise LoadgenError(f"last_mile: {e}") from e
+        self.joins = traffic.get("joins")
+        if self.joins is not None:
+            check_joins(self.joins, n_sources, seconds)
+            if self.last_mile:
+                raise LoadgenError("joins behind a lossy last mile: the "
+                                   "joiners would need chains and reports")
         #: SETUP headers a lossy last mile's players add: they ask for
         #: parity and RTX (the server grants them only when asked)
         self.setup_extra = {"x-FEC": "parity"} if self.last_mile else None
@@ -576,7 +969,7 @@ class Loadgen:
         n_win = int(np.ceil(seconds * self.fps)) + 1
         # GOP phases: the same evenly spread set for every seed, dealt to
         # the sources in the seed's order
-        gop = cfg["stream"]["gop_frames"]
+        gop = cfg["stream"].get("gop_frames", 1)     # RTP/JPEG: none
         deal = np.random.default_rng([seed, 1 << 20]).permutation(n_sources)
         W = self.warm_frames
         warm_idrs = self._warm_idrs(traffic, cfg["stream"], n_sources)
@@ -588,6 +981,12 @@ class Loadgen:
             for i in range(n_sources)]
         #: bulk sessions a source
         self.n_bulk = n_players - len(range(0, n_players, self.stamped_every))
+        if self.sources[0].mjpeg and (self.n_bulk or self.last_mile):
+            # where the relay starts a player is read off its stamps
+            raise LoadgenError("an RTP/JPEG source's players are all "
+                               "stamped (stamped_every 1), with no last "
+                               "mile: the relay starts each at the newest "
+                               "frame when it first steps it")
         self.bulk = BulkDrains(int(traffic.get("bulk_drain_procs", 4)),
                                self.last_mile, seed, n_sources * self.n_bulk)
         self.flows: list[Flow] = []
@@ -596,13 +995,27 @@ class Loadgen:
                 if j % self.stamped_every == 0:
                     self.flows.append(Flow(
                         src, f"127.0.0.{1 + (src.idx + j) % N_IP}"))
-        reader = StampReader
+        #: the players who join inside the window, all stamped, and when
+        self.joiners: list[Joiner] = []
+        self.join_plan: list[tuple[float, int]] = []
+        if self.joins is not None:
+            src = self.sources[int(self.joins["source"])]
+            gop = 1 if src.mjpeg else gop
+            # the window opens at frame W; the source's GOP cycle starts
+            # at its ``first_gop_at``
+            at = int(deal[src.idx]) * gop // n_sources % gop
+            self.join_plan = join_schedule(self.joins, seed, gop / self.fps,
+                                           at / self.fps)
+            self.joiners = [Joiner(src, f"127.0.0.{1 + j % N_IP}")
+                            for j in range(int(self.joins["count"]))]
         if self.last_mile:
-            reader = LossyStampReader
             for n, f in enumerate(self.flows):
                 f.lm = lastmile.StampedReceiver(f, self.last_mile, seed, n)
-        self.readers = [reader(self.flows[k::4], f"stamp-reader-{k}")
-                        for k in range(4)]
+            self.readers = [LossyStampReader(self.flows[k::4],
+                                             f"stamp-reader-{k}")
+                            for k in range(4)]
+        else:
+            self.readers = [StampReader(self.flows + self.joiners, 4)]
         self.players: list[RtspConn] = []
         self.n_bulk_joined = 0
         self.lateness: list[float] = []
@@ -626,7 +1039,7 @@ class Loadgen:
         stream's ``idr_bytes``; every source comes on line before the
         tail."""
         sizes = [int(b) for b in traffic.get("warm_idr_bytes", ())]
-        lo, hi = stream["idr_bytes"]
+        lo, hi = stream["idr_bytes"] if sizes else (0, 0)
         W = self.warm_frames
         self.warm_tail = self.WARM_IDR_EVERY * len(sizes)
         last_start = (n_sources - 1) * W // (2 * n_sources)
@@ -647,19 +1060,25 @@ class Loadgen:
 
     def stop_receivers(self) -> None:
         for r in self.readers:
-            r.stop_flag = True
+            r.stop()
         self.bulk.stop()
+
+    def stamped_read(self) -> int:
+        """Datagrams read from the stamped players' sockets so far."""
+        if self.last_mile:
+            return sum(len(f.stamps) for f in self.flows)
+        return self.readers[0].read()
 
     def received(self) -> int:
         """RTP datagrams read from any player socket so far."""
-        return self.bulk.counts()[0] + sum(len(f.stamps) for f in self.flows)
+        return self.bulk.counts()[0] + self.stamped_read()
 
     # -- sessions ----------------------------------------------------------
     async def start_pushers(self, port: int) -> None:
         for s in self.sources:
             c = RtspConn()
             await c.connect("127.0.0.1", port)
-            await c.push_start(f"rtsp://127.0.0.1:{port}{s.path}")
+            await c.push_start(f"rtsp://127.0.0.1:{port}{s.path}", s.sdp)
             s.conn = c
 
     async def _join(self, port: int, src: Source, ip: str, rtp_port: int,
@@ -667,12 +1086,31 @@ class Loadgen:
         c = RtspConn()
         await c.connect("127.0.0.1", port, local_ip=ip)
         ssrc, seq = await c.play_start(
-            f"rtsp://127.0.0.1:{port}{src.path}", rtp_port, self.setup_extra)
+            f"rtsp://127.0.0.1:{port}{src.path}", rtp_port, self.setup_extra,
+            flow.before_play if isinstance(flow, Joiner) else None)
         self.players.append(c)
         if flow is not None:
             flow.ssrc, flow.first_seq = ssrc, seq
+            if isinstance(flow, Joiner):
+                flow.answer_ns = time.perf_counter_ns()
         else:
             self.n_bulk_joined += 1
+
+    async def run_joins(self, port: int, t0_ns: int) -> None:
+        """The joiners, burst by burst at ``join_plan``'s instants after
+        ``t0_ns``; a burst's players connect and send their DESCRIBEs
+        back to back, and a burst does not wait for the one before it.
+        Returns once every joiner's PLAY is answered."""
+        todo, bursts = iter(self.joiners), []
+        for at, n in self.join_plan:
+            delay = (t0_ns + int(at * 1e9) - time.perf_counter_ns()) / 1e9
+            if delay > 0:
+                await asyncio.sleep(delay)
+            src = self.joiners[0].src
+            bursts.append(asyncio.ensure_future(asyncio.gather(*(
+                self._join(port, src, j.ip, j.port, j)
+                for j in (next(todo) for _ in range(n))))))
+        await asyncio.gather(*bursts)
 
     async def join_players(self, port: int) -> None:
         """Every source's players join in waves of ``join_wave``, a
@@ -734,12 +1172,15 @@ class Loadgen:
             delay = (due_ns - time.perf_counter_ns()) / 1e9
             if delay > 0:
                 await asyncio.sleep(delay)
-            self.lateness.append(
-                max(time.perf_counter_ns() - due_ns, 0) / 1e9)
+            late = max(time.perf_counter_ns() - due_ns, 0) / 1e9
+            self.lateness.append(late)
             src = self.sources[i]
             self.due_ns[(i, f)] = due_ns
             data, k = src.frame_bytes(f)
             src.conn.writer.write(data)
+            if late > 1 / self.fps or \
+                    src.conn.writer.transport.get_write_buffer_size():
+                src.unsure.add(src.frame_start[f])
             src.pushed += k
             await src.conn.writer.drain()
 
@@ -753,7 +1194,13 @@ class Loadgen:
                                    for s in self.sources))
 
     def expected_deliveries(self) -> int:
-        return sum(s.pushed for s in self.sources) * self.n_sub
+        """Pushed x joined before media, less what a fast start leaves
+        out (``Flow.owed``), and what each joiner is owed from its
+        start."""
+        return (sum(s.pushed for s in self.sources) * self.n_sub
+                - sum(f.src.pushed - f.owed() for f in self.flows
+                      if f.fast_start)
+                + sum(j.owed() for j in self.joiners))
 
     # -- the lossy last mile -------------------------------------------------
     def mark_pushed(self) -> None:

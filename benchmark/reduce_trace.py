@@ -127,12 +127,10 @@ def note_shapes(modules: dict, mods: list, ops: list) -> None:
         prog["shapes"] = shapes
 
 
-def reduce(events: dict, chips: int = 1) -> dict:
+def reduce(events: dict, chips: int) -> dict:
     """``chips`` is what the cell asks for.  A chip that ran nothing in
     the traced window has no XLA line and so no plane in ``events``: it
-    is idle, not absent, and ``busy_s`` is the mean over it too.  (Every
-    caller here names it; the default serves ``tests/test_spans.py``'s
-    one-chip call, which a benchmark PR may not edit.)"""
+    is idle, not absent, and ``busy_s`` is the mean over it too."""
     window_ns = events.get("window_ns") or 0
     devs = events.get("devices", {})
     n_chips = max(len(devs), chips)

@@ -11,13 +11,23 @@ announced, at a constant offset from the pusher's timestamps.
 holds what a subscriber's socket received against it, packet by packet,
 and returns counts; every count's limit is 0 (the comparison is exact).
 
+A subscriber who joins while the stream runs is owed the same from the
+head of the newest keyframe run pushed before its PLAY was written, or
+of a later one (the relay's fast start, within its over-buffer window):
+``judge_joiner``.
+
 ``break_guarantee`` is the control: the reference's own output with one
-stated guarantee broken once.  The comparison must fail it.
+stated guarantee broken once; ``join_control`` the reference in a
+joiner's place with its start, or one packet, wrong.  The comparison
+must fail both.
 """
 
 from __future__ import annotations
 
 GUARANTEES = ("every_packet", "in_order", "bit_equal", "header_rewritten")
+#: the controls of a joiner: a start inside a GOP, a start at the run
+#: before the newest, one packet dropped after a right start
+JOIN_CONTROLS = ("join_mid_gop", "join_stale", "join_every_packet")
 
 
 def expected_packet(pushed: bytes, k: int, first_seq: int, ssrc: int,
@@ -37,9 +47,11 @@ def reference_flow(pushed: list[bytes], first_seq: int, ssrc: int,
 
 
 def judge_flow(got: list[bytes], pushed: list[bytes], first_seq: int | None,
-               ssrc: int | None, repaired: list[bytes] = ()
+               ssrc: int | None, repaired: list[bytes] = (), start: int = 0
                ) -> dict[str, int]:
     """Counts of what is wrong with one subscriber's received packets.
+    It is owed ``pushed[start:]``: the first sequence number stands for
+    ``pushed[start]`` (0 for a subscriber joined before media).
 
     ``missing``: pushed packets that never arrived.  ``out_of_order``:
     arrivals whose place in the pushed order is before an earlier
@@ -52,6 +64,7 @@ def judge_flow(got: list[bytes], pushed: list[bytes], first_seq: int | None,
     restored from parity or RTX.  They count as held, and as altered
     where they are no pushed packet's rewrite; they came when they were
     restored, so they are no part of the order."""
+    pushed = pushed[start:]
     out = {"missing": 0, "out_of_order": 0, "altered": 0, "unannounced": 0}
     if first_seq is None or ssrc is None:
         out["unannounced"] = 1
@@ -86,6 +99,61 @@ def judge_flow(got: list[bytes], pushed: list[bytes], first_seq: int | None,
         seen[k] = 1
     out["missing"] = len(pushed) - sum(seen)
     return out
+
+
+def judge_joiner(got: list[bytes], pushed: list[bytes], key_heads: list[int],
+                 key_head: int, first_seq: int | None, ssrc: int | None,
+                 start: int | None) -> dict[str, int]:
+    """``judge_flow`` for a subscriber that joined while the stream ran.
+
+    ``key_heads``: the pushed packets that head a keyframe run (an SPS,
+    PPS and IDR in a row; every frame's first packet in RTP/JPEG);
+    ``key_head``: the newest of them pushed before its PLAY was written
+    (of those the server surely held then: pushed on time, not in a
+    stalled generator's catch-up burst);
+    ``start``: the pushed packet its first sequence number stands for,
+    as its first datagram shows (None where it read nothing, or nothing
+    pushed).  ``mid_gop``: 1 if that start heads no keyframe run;
+    ``stale``: 1 if it is older than ``key_head``.  Judged from the
+    start on; where there is none, from ``key_head``."""
+    out = {"mid_gop": 0, "stale": 0}
+    if start is not None:
+        out["mid_gop"] = int(start not in set(key_heads))
+        out["stale"] = int(start < key_head)
+    out.update(judge_flow(got, pushed, first_seq, ssrc,
+                          start=key_head if start is None else max(start, 0)))
+    return out
+
+
+def start_of(seq: int, k: int, first_seq: int) -> int:
+    """The pushed packet a session's first sequence number stands for,
+    from one datagram with sequence number ``seq`` that carries pushed
+    packet ``k``."""
+    return k - ((seq - first_seq) & 0xFFFF)
+
+
+def join_control(pushed: list[bytes], key_heads: list[int], key_head: int,
+                 control: str, first_seq: int, ssrc: int, at: int
+                 ) -> list[bytes]:
+    """A joiner's control: the reference's own packets for a subscriber
+    joined at ``key_head``, with the start moved inside the GOP (to a
+    packet after the head that heads no run) or to the run before it, or
+    with one packet after a right start dropped.  ``judge_joiner`` must
+    count exactly one fault."""
+    later = [k for k in key_heads if k > key_head]
+    if control == "join_mid_gop":
+        end = later[0] if later else len(pushed)
+        start = key_head + 1 + at % max(end - key_head - 1, 1)
+    elif control == "join_stale":
+        start = max(k for k in key_heads if k < key_head)
+    elif control == "join_every_packet":
+        start = key_head
+    else:
+        raise ValueError(f"unknown control {control!r}")
+    flow = reference_flow(pushed[start:], first_seq, ssrc, 0)
+    if control == "join_every_packet":
+        del flow[1 + at % max(len(flow) - 1, 1)]
+    return flow
 
 
 def offset_of(g: bytes, pushed: list[bytes], first_seq: int) -> int:

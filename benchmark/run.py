@@ -272,10 +272,18 @@ class Run:
         self.harness["setup_s"] = (t0_ns - T_START_NS) / 1e9
         tracer = (asyncio.ensure_future(self.trace_bracket(S))
                   if self.trace_dir else None)
+        # a mix's joiners play while the stream runs, on this loop
+        joins = (asyncio.ensure_future(lg.run_joins(srv.rtsp_port, t0_ns))
+                 if lg.joiners else None)
         await lg.push_frames(W, lambda due: due < S, t0_ns)
         await asyncio.sleep(max(
             (t0_ns + int(S * 1e9) - time.perf_counter_ns()) / 1e9, 0))
         t1_ns = time.perf_counter_ns()
+        if joins is not None:
+            await joins
+            log(f"{len(lg.joiners)} players joined in {len(lg.join_plan)} "
+                f"bursts inside the window: "
+                + ", ".join(f"{n} at {at:.2f} s" for at, n in lg.join_plan))
         lag.cancel()
         bulk1 = lg.bulk.counts()[0]
         m1 = await asyncio.to_thread(srv.metrics)
@@ -323,11 +331,12 @@ class Run:
 
     async def wait_owed(self, timeout: float) -> int:
         """Until the server's sent count and the harness's received
-        count both reach pushed x joined (or ``timeout`` passes)."""
-        want = self.lg.expected_deliveries()
+        count both reach pushed x joined (or ``timeout`` passes).  What a
+        joiner is owed is known once its first datagram is read."""
         t0 = time.monotonic()
         sent = -1
         while time.monotonic() - t0 < timeout:
+            want = self.lg.expected_deliveries()
             m = await asyncio.to_thread(self.server.metrics)
             sent = int(readers.fam(m, "egress_packets_total") or 0)
             if sent >= want and self.lg.last_mile:
@@ -363,7 +372,7 @@ class Run:
         bulk_got = lg.bulk.counts()[0]
         bulk_owed = sum(s.pushed for s in lg.sources) * (
             self.n_sub - len(lg.flows) // self.n_src)
-        stamped_got = sum(len(f.stamps) for f in lg.flows)
+        stamped_got = lg.stamped_read()
         kd = {k: k_end.get(k, 0) - k0.get(k, 0)
               for k in ("RcvbufErrors", "InErrors")} if k_end else {}
         self.acct = {"owed": owed, "server_sent": sent,
@@ -435,6 +444,8 @@ class Run:
         tot = {"missing": 0, "out_of_order": 0, "altered": 0,
                "unannounced": 0}
         wire = {"parity": 0, "rtx": 0}      # behind a lossy last mile
+        #: of the players whose start a fast start decides
+        fast = {"mid_gop": 0, "stale": 0}
         ctl = self.args.control or ""
         for n, f in enumerate(lg.flows):
             pushed = f.src.packets[:f.src.pushed]
@@ -449,18 +460,28 @@ class Run:
                     ctl[4:], self.args.seed,
                     int.from_bytes(pushed[0][8:12], "big"))
             repaired = () if f.lm is None else f.lm.repaired
-            for k, v in reference.judge_flow(
-                    got, pushed, f.first_seq, f.ssrc, repaired).items():
+            if f.fast_start:
+                counts = reference.judge_joiner(
+                    got, pushed, f.src.key_heads, f.key_head, f.first_seq,
+                    f.ssrc, f.src.start_of(got, f.first_seq))
+                for k in fast:
+                    fast[k] += counts.pop(k)
+            else:
+                counts = reference.judge_flow(got, pushed, f.first_seq,
+                                              f.ssrc, repaired)
+            for k, v in counts.items():
                 tot[k] += v
             if f.lm is not None and None not in (f.ssrc, f.first_seq) \
                     and (got or repaired):
                 for kind, v in self.judge_repair(f, pushed, got or repaired,
                                                  ctl).items():
                     wire[kind] += v
+        join = self.judge_joiners(ctl) if lg.joiners else None
         self.compare("stamped_missing", tot["missing"])
         self.compare("stamped_out_of_order", tot["out_of_order"])
         self.compare("stamped_altered", tot["altered"])
-        self.compare("sessions_unannounced", tot["unannounced"])
+        self.compare("sessions_unannounced", tot["unannounced"]
+                     + (join["unannounced"] if join else 0))
         self.compare("server_sent_short", max(a["owed"] - a["server_sent"], 0))
         self.compare("server_sent_excess",
                      max(a["server_sent"] - a["owed"], 0))
@@ -470,6 +491,13 @@ class Run:
         short = max(a["bulk_owed"] - a["bulk_received"], 0)
         self.compare("bulk_short", short)
         self.failed = tot["missing"] + tot["altered"] + short
+        if join is not None or any(f.fast_start for f in lg.flows):
+            for k in fast:
+                self.compare(f"joined_{k}", fast[k] + (join[k] if join else 0))
+        if join is not None:
+            for k in ("missing", "out_of_order", "altered"):
+                self.compare(f"joiner_{k}", join[k])
+            self.failed += join["missing"] + join["altered"]
         if lg.last_mile:
             lm = a["last_mile"]
             self.compare("parity_altered", wire["parity"])
@@ -488,6 +516,35 @@ class Run:
                 f"them: the generator's loss, not the program's wrong "
                 f"output")
         return ok
+
+    def judge_joiners(self, ctl: str) -> dict[str, int]:
+        """Every joiner against the reference's fast-start rule
+        (``reference.judge_joiner``), summed.  The controls
+        ``ref:join_*`` put the reference in one joiner's place, from the
+        seed's on, that has a keyframe run before its newest."""
+        lg = self.lg
+        tot = dict.fromkeys(("mid_gop", "stale", "missing", "out_of_order",
+                             "altered", "unannounced"), 0)
+        ctl = ctl[4:] if ctl.startswith("ref:") else ""
+        cut = self.args.seed % len(lg.joiners)
+        oldest = lg.joiners[0].src.key_heads[0]
+        at = next((j for j in lg.joiners[cut:] + lg.joiners[:cut]
+                   if (j.key_head or 0) > oldest), None)
+        for j in lg.joiners:
+            pushed = j.src.packets[:j.src.pushed]
+            got, start = j.packets, j.start()
+            if ctl in reference.JOIN_CONTROLS and j is at:
+                got = reference.join_control(
+                    pushed, j.src.key_heads, j.key_head, ctl,
+                    j.first_seq or 0, j.ssrc or 0, self.args.seed)
+                start = j.src.start_of(got, j.first_seq or 0)
+                log(f"control ref:{ctl}: the reference in joiner "
+                    f"{lg.joiners.index(j)}'s place")
+            for k, v in reference.judge_joiner(
+                    got, pushed, j.src.key_heads, j.key_head or 0,
+                    j.first_seq, j.ssrc, start).items():
+                tot[k] += v
+        return tot
 
     def judge_repair(self, f: loadgen.Flow, pushed: list[bytes],
                      held: list[bytes], ctl: str) -> dict[str, int]:
@@ -551,19 +608,26 @@ class Run:
             if f.first_seq is None:
                 continue
             fs, last, arrived, due = f.src.frame_start, -1, [], []
+            start = f.start() or 0
             for g, at in zip(f.packets, f.stamps):
                 if t0_ns <= at < t1_ns:
                     in_window += 1
                 if len(g) < 12:
                     continue
-                k = reference.place_of(g, f.first_seq, f.src.pushed, last)
+                k = reference.place_of(g, f.first_seq, f.src.pushed - start,
+                                       last)
                 last = max(last, k)
-                frame = bisect.bisect_right(fs, k) - 1
+                frame = bisect.bisect_right(fs, start + k) - 1
                 if frame >= lg.warm_frames and (f.src.idx, frame) in lg.due_ns:
                     arrived.append(at)
                     due.append(lg.due_ns[(f.src.idx, frame)])
             d = stats.flow_delays_ms(arrived, due)
             by_flow.append(d)
+        # the players who joined inside the window are timed by their
+        # join (``join_metrics``); the delays are the audience's that was
+        # there before media
+        in_window += sum(t0_ns <= at < t1_ns for j in lg.joiners
+                         for at in j.stamps)
         got0, bulk1 = self.got_window
         stamped0 = sum(1 for f in lg.flows for at in f.stamps if at < t0_ns)
         delivered = bulk1 + stamped0 + in_window - got0
@@ -572,7 +636,68 @@ class Run:
         if any(by_flow):
             out.update(stats.delay_metrics(by_flow))
             self.harness["stamped_deliveries"] = sum(map(len, by_flow))
+        if lg.joiners:
+            out.update(self.join_metrics())
         return out
+
+    def join_metrics(self) -> dict[str, float]:
+        """Each joiner's wait for its first picture: from the PLAY being
+        written to the arrival of the last packet (the marker) of the
+        first keyframe whose every packet it read — when a decoder can
+        show a picture — split at the PLAY answer; 90th percentiles over
+        the joiners.  Also the share of joiners whose first datagram was
+        the newest keyframe run at their PLAY."""
+        lg, t0_ns = self.lg, self.window[0]
+        first, answer, burst, newest = [], [], [], 0
+        for n, j in enumerate(lg.joiners):
+            start = j.start()
+            if start is None or j.play_ns is None:
+                continue
+            newest += start == j.key_head
+            shown = self.first_picture(j, start)
+            log(f"joiner {n}: PLAY at {(j.play_ns - t0_ns) / 1e9:.3f} s, "
+                f"answered +{(j.answer_ns - j.play_ns) / 1e6:.3f} ms, start "
+                f"{start} (newest keyframe run {j.key_head}), first datagram "
+                f"+{(j.stamps[0] - j.play_ns) / 1e6:.3f} ms, first picture "
+                + ("none" if shown is None
+                   else f"+{(shown - j.play_ns) / 1e6:.3f} ms"))
+            if shown is not None:
+                first.append((shown - j.play_ns) / 1e6)
+                answer.append((j.answer_ns - j.play_ns) / 1e6)
+                burst.append((shown - j.answer_ns) / 1e6)
+        if not first:
+            return {}
+        self.harness["join.play_answer_p90_ms"] = stats.percentile(answer, 90)
+        self.harness["join.burst_p90_ms"] = stats.percentile(burst, 90)
+        self.harness["join.newest_keyframe_pct"] = (
+            100.0 * newest / len(lg.joiners))
+        log(f"joins: {len(first)} of {len(lg.joiners)} joiners showed a "
+            f"picture; PLAY -> first picture median "
+            f"{stats.percentile(first, 50):.3f} ms p90 "
+            f"{stats.percentile(first, 90):.3f} ms max {max(first):.3f} ms; "
+            f"PLAY answered p90 "
+            f"{self.harness['join.play_answer_p90_ms']:.3f} ms; answer -> "
+            f"picture p90 {self.harness['join.burst_p90_ms']:.3f} ms; "
+            f"{newest} started at the newest keyframe run at their PLAY")
+        return {"join_first_idr_p90_ms": stats.percentile(first, 90)}
+
+    @staticmethod
+    def first_picture(j: loadgen.Joiner, start: int) -> int | None:
+        """When joiner ``j`` read the marker of the first keyframe it
+        read whole (perf_counter_ns), or None."""
+        at_place, last = {}, -1
+        for g, at in zip(j.packets, j.stamps):
+            if len(g) >= 12:
+                k = reference.place_of(g, j.first_seq, j.src.pushed - start,
+                                       last)
+                last = max(last, k)
+                at_place.setdefault(start + k, at)
+        fs = j.src.frame_start
+        for f in j.src.key_frames:
+            if start <= fs[f] and fs[f + 1] <= j.src.pushed and all(
+                    k in at_place for k in range(fs[f], fs[f + 1])):
+                return at_place[fs[f + 1] - 1]
+        return None
 
     def reduce_trace(self) -> dict | None:
         if not self.trace_dir:

@@ -441,9 +441,282 @@ def check_live_order(bench, files=None):
     assert names.index("pump.routed_streams_pct") < start
 
 
+# ------------------------------------- PR 42: the lossy deployment
+#: moved here from ``test_lossy_deployment.py``'s own tests (PR 45)
+LOSSY, LOSSY_CONFIG = "relay-16x256-lossy.paced", "relay-16x256-lossy"
+LAST_MILE = {"model": "gilbert", "mean_loss": 0.02, "mean_burst_packets": 3,
+             "rr_interval_s": 5, "nack": "stamped", "nack_after_ms": 200}
+#: the cell's own entries, in order: name -> (unit, better, layer, reader)
+LOSSY_OWN = {
+    "pump.fec_parity_ms_per_wake.lossy": ("ms", "lower", "Pump",
+                                          "ratio_of_deltas"),
+    "fec.parity_pass_ms.lossy": ("ms", "lower", "Reliability tier",
+                                 "ratio_of_deltas"),
+    "fec.parity_per_media_pct.lossy": ("%", "lower", "Reliability tier",
+                                       "ratio_of_deltas"),
+    "rtx.withheld_pct.lossy": ("%", "lower", "Reliability tier",
+                               "ratio_of_deltas"),
+    "qos.thins.lossy": ("thins", "lower", "Reliability tier",
+                        "counter_delta"),
+    "rtx.giveups.lossy": ("seqs", "lower", "Reliability tier",
+                          "counter_delta"),
+    "fec_parity_window_step_roofline.lossy": ("%", "higher", "Kernel",
+                                              "trace_op"),
+}
+#: the accepted entries that move ``delay_p95_ms`` in ``relay-16x256.paced``:
+#: the cell reads each as ``<name>.lossy``, after ``delay_p95_ms.lossy``
+LOSSY_TWINS = [
+    "loadgen.late_p99_ms", "megabatch.streams_per_pass", "compiles_in_window",
+    "megabatch_window_step_roofline", "device.idle_pct.below_knee",
+    "pdv_p95_ms", "relay.due_to_wire_ms.below_knee",
+    "relay.due_to_wire_p95_ms.below_knee", "pump.wake_ms.below_knee",
+    "pump.busy_pct.below_knee", "engine.due_outputs_pct.below_knee",
+    "pump.live_relay_ms_per_wake.below_knee",
+    "pump.megabatch_ms_per_wake.below_knee",
+    "egress.bracket_ms_per_wake.below_knee",
+    "pump.timer_wakes_pct.below_knee",
+    "engine.plan_rebuilds_per_100_steps.below_knee",
+    "pump.stepped_streams_pct.below_knee",
+    "megabatch.walked_pairs_pct.below_knee", "egress.hidden_pct.below_knee",
+    "loads_in_window", "build_s_in_window"]
+#: a window of the cell by hand: 1,000 wakes, 400 parity passes, 57,000
+#: media datagrams and 1,140 parity, 300 NACKed seqs of which 30 withheld
+LOSSY_M0 = {'pump_service_seconds_sum{work_class="fec_parity"}': 1.0,
+            "pump_wake_seconds_count": 500.0,
+            'fec_parity_pass_seconds_sum{part="device"}': 0.1,
+            'fec_parity_pass_seconds_count{part="device"}': 100.0,
+            "fec_parity_packets_total": 60.0, "egress_packets_total": 3000.0,
+            "qos_thins_total": 0.0, "rtx_giveup_total": 0.0}
+LOSSY_M1 = {'pump_service_seconds_sum{work_class="fec_parity"}': 3.5,
+            "pump_wake_seconds_count": 1500.0,
+            'fec_parity_pass_seconds_sum{part="device"}': 0.42,
+            'fec_parity_pass_seconds_count{part="device"}': 500.0,
+            "fec_parity_packets_total": 1200.0,
+            "egress_packets_total": 60000.0,
+            'rtx_nacked_seqs_total{result="sent"}': 270.0,
+            'rtx_nacked_seqs_total{result="giveup"}': 0.0,
+            'rtx_nacked_seqs_total{result="withheld"}': 30.0,
+            'rtx_nacked_seqs_total{result="evicted"}': 0.0,
+            "qos_thins_total": 0.0, "rtx_giveup_total": 0.0}
+LOSSY_READS = {"pump.fec_parity_ms_per_wake.lossy": 2.5,
+               "fec.parity_pass_ms.lossy": 0.8,
+               "fec.parity_per_media_pct.lossy": 2.0,
+               "rtx.withheld_pct.lossy": 10.0,
+               "qos.thins.lossy": 0.0, "rtx.giveups.lossy": 0.0}
+
+
+def check_lossy_configuration(bench, files):
+    """``relay-16x256`` behind a last mile, and nothing else served
+    differs."""
+    conf = next(c for c in bench["configs"] if c["name"] == LOSSY_CONFIG)
+    base = next(c for c in bench["configs"] if c["name"] == "relay-16x256")
+    assert conf["reduced"] == base["reduced"] == ["fps_per_source"]
+    assert len(conf["source"]) <= 200 and "\t" not in conf["source"]
+    for cites in ("configs[3]", "RFC 5109", "RFC 4588", "RFC 4585",
+                  "RFC 3550", "G.1050"):
+        assert cites in conf["source"], cites
+    cfg, ref = files.load(conf["file"]), files.load(base["file"])
+    assert cfg["source"] == conf["source"] and cfg["name"] == LOSSY_CONFIG
+    assert cfg["last_mile"] == LAST_MILE
+    differs = {k for k in set(cfg) | set(ref) if cfg.get(k) != ref.get(k)}
+    assert differs == {"name", "what", "source", "last_mile", "assumed"}
+    # the server is relay-16x256's: its FEC keys are stated, not changed
+    assert cfg["server"] == ref["server"]
+    assert not any(k.startswith(("fec_", "rtx_")) for k in cfg["server"])
+    assert {k: cfg["assumed"][k] for k in ref["assumed"]} == ref["assumed"]
+    assert {"mean_loss", "mean_burst_packets", "rr_interval_s",
+            "nack_after_ms", "fec_for_every_player",
+            "nack_from_stamped_players", "server_fec"} == \
+        set(cfg["assumed"]) - set(ref["assumed"])
+    # no width is cut: the shapes are relay-16x256's
+    for key in ("sources", "players", "stream", "guarantees",
+                "reduced", "reduced_why"):
+        assert cfg[key] == ref[key], key
+
+
+def check_lossy_mix(bench, files):
+    """``paced`` with a longer warm-up."""
+    t, paced = (files.load(f"{TRAFFIC_DIR}{n}.json")
+                for n in ("paced-lossy", "paced"))
+    assert t["name"] == "paced-lossy" and t["warm_frames"] == 24
+    assert set(t) == set(paced)
+    skip = ("name", "what", "warm_frames")
+    assert {k: t[k] for k in t if k not in skip} == {
+        k: paced[k] for k in paced if k not in skip}
+
+
+def check_lossy_tail(bench, files):
+    """Judged on ``delay_p60_ms`` and ``setup_s``: its 95th percentile
+    and the entries that move it in ``.paced`` are read as twins, each
+    over its namesake's reader, moving ``delay_p60_ms``; the namesakes do
+    not list the cell."""
+    assert judged_on(bench, LOSSY) == {"delay_p60_ms", "setup_s"}
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("delay_p95_ms.lossy")
+    assert names[at:at + 1 + len(LOSSY_TWINS)] == ["delay_p95_ms.lossy"] + [
+        f"{n}.lossy" for n in LOSSY_TWINS]
+    assert names.index(list(LOSSY_OWN)[-1]) < at
+    e = entry(bench, "delay_p95_ms.lossy")
+    assert (e["moves"], e["workloads"][0], e["unit"], e["source"]) == (
+        "delay_p60_ms", LOSSY, "ms", "host_clock")
+    assert files.load(f"{METRIC_DIR}delay_p95_ms.lossy.json")["reader"] == {
+        "kind": "harness", "key": "delay_p95_ms"}
+    for name in LOSSY_TWINS:
+        base, twin = entry(bench, name), entry(bench, f"{name}.lossy")
+        assert base["moves"] == "delay_p95_ms" and PACED in base["workloads"]
+        assert LOSSY not in base["workloads"]
+        assert twin["moves"] == "delay_p60_ms"
+        assert twin["workloads"][0] == LOSSY
+        assert twin["workloads"].count(LOSSY) == 1
+        assert {k: twin[k] for k in ("unit", "better", "source", "layer")} \
+            == {k: base[k] for k in ("unit", "better", "source", "layer")}
+        assert files.load(f"{METRIC_DIR}{name}.lossy.json")["reader"] == \
+            files.load(f"{METRIC_DIR}{name}.json")["reader"]
+
+
+def check_lossy_cell(bench, files=None):
+    """One chip, after the cells accepted before it."""
+    cells = bench["workloads"]
+    names = [w["name"] for w in cells]
+    cell = cells[names.index(LOSSY)]
+    assert cell == {"name": LOSSY, "config": LOSSY_CONFIG,
+                    "traffic": "paced-lossy", "chips": 1, "why": cell["why"]}
+    assert 0 < len(cell["why"]) <= 200
+    assert set(CELLS) <= set(names[:names.index(LOSSY)])
+    assert names.index("relay-16x256.genlock-mesh4") < names.index(LOSSY)
+
+
+def check_lossy_lists(bench, files=None):
+    """Every accepted list ``relay-16x256.paced`` is in and whose
+    end-to-end metric the cell reports has the cell, after the cells
+    accepted before it and in no list twice; it is in no list ``.paced``
+    is not in."""
+    judged = judged_on(bench, LOSSY)
+    names = [m["name"] for m in bench["per_layer"]]
+    landed = bench["end_to_end"] + bench["per_layer"][:names.index(
+        next(iter(LOSSY_OWN)))]
+    for m in landed:
+        wl = m.get("workloads")
+        if wl is None:
+            continue
+        want = PACED in wl and (m.get("moves") or m["name"]) in judged
+        assert wl.count(LOSSY) == int(want), m["name"]
+        if want:
+            before = wl[:wl.index(LOSSY)]
+            assert LOSSY not in before and PACED in before, m["name"]
+
+
+def check_lossy_own_entry(bench, files, name):
+    """One of the cell's own entries, in its place, reading what a
+    hand-made window holds; silent on a program without the families."""
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(next(iter(LOSSY_OWN)))
+    assert names[at:at + len(LOSSY_OWN)] == list(LOSSY_OWN)
+    assert set(ACCEPTED) <= set(names[:at])
+    unit, better, layer, kind = LOSSY_OWN[name]
+    e = entry(bench, name)
+    assert dict(e, workloads=e["workloads"][:1]) == {
+        "name": name, "unit": unit, "better": better,
+        "source": e["source"], "layer": layer, "moves": "delay_p60_ms",
+        "workloads": [LOSSY]}
+    spec = files.load(f"{METRIC_DIR}{name}.json")
+    assert spec["name"] == name and spec["what"]
+    assert spec["reader"]["kind"] == kind
+    assert readers.read(spec, silent_ctx()) is None
+    if name in LOSSY_READS:
+        assert readers.read(spec, silent_ctx(
+            m0=LOSSY_M0, m1=LOSSY_M1)) == pytest.approx(LOSSY_READS[name])
+
+
+# --------------------------------------------- PR 45: late joiners
+JOINS, JOIN_METRIC = "relay-1x64.joins", "join_first_idr_p90_ms"
+#: the join metric's bound (PR 45: from two sets of six on the chip)
+JOIN_BOUND = 0.05
+#: the mix's ``joins`` group (``benchmark/loadgen.py``)
+JOINS_KEY = {"source": 0, "count": 64, "burst_size": [1, 8],
+             "within_s": [0.5, 24]}
+#: the cell's own entries, in order, each the harness's own number:
+#: name -> (unit, better, layer)
+JOINS_OWN = {
+    "join.play_answer_p90_ms": ("ms", "lower", "RTSP front end"),
+    "join.burst_p90_ms": ("ms", "lower", "Per-stream engine"),
+    "join.newest_keyframe_pct": ("%", "higher", "Per-stream engine"),
+}
+
+
+def check_joins_cell(bench, files):
+    """``relay-1x64`` as it is, under ``live`` with a ``joins`` group, on
+    one chip, after the cells accepted before it."""
+    names = [w["name"] for w in bench["workloads"]]
+    cell = bench["workloads"][names.index(JOINS)]
+    assert cell == {"name": JOINS, "config": "relay-1x64",
+                    "traffic": "live-joins", "chips": 1, "why": cell["why"]}
+    assert 0 < len(cell["why"]) <= 200
+    assert names.index(LOSSY) < names.index(JOINS)
+    t = files.load(f"{TRAFFIC_DIR}live-joins.json")
+    live = files.load(f"{TRAFFIC_DIR}live.json")
+    assert t["name"] == "live-joins" and t["joins"] == JOINS_KEY
+    assert set(t) == set(live) | {"joins"} and "joins" not in live
+    skip = ("name", "what", "joins")
+    assert {k: t[k] for k in t if k not in skip} == {
+        k: live[k] for k in live if k not in skip}
+
+
+def check_joins_judged(bench, files=None):
+    """Judged on the delays of the audience there before media, the
+    joiners' wait for their first picture, and set-up."""
+    assert judged_on(bench, JOINS) == {"delay_p60_ms", JOIN_METRIC,
+                                       "setup_s"}
+    e = entry(bench, JOIN_METRIC)
+    assert (e["unit"], e["better"], e["source"], e["bound"]) == (
+        "ms", "lower", "host_clock", JOIN_BOUND)
+    assert e["workloads"][0] == JOINS and e["workloads"].count(JOINS) == 1
+    names = [m["name"] for m in bench["end_to_end"]]
+    assert names.index(JOIN_METRIC) >= len(BOUNDS)
+
+
+def check_joins_lists(bench, files=None):
+    """The cell is in every list ``relay-1x64.live`` is in, after the
+    cells that were there before it, once; and in no other list but its
+    own metric's and its own entries'."""
+    cells = [w["name"] for w in bench["workloads"]]
+    before = cells[:cells.index(JOINS)]
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        wl = m.get("workloads")
+        if wl is None:
+            continue
+        if LIVE in wl:
+            at = wl.index(JOINS)
+            assert wl.count(JOINS) == 1 and LIVE in wl[:at], m["name"]
+            assert not set(before) & set(wl[at:]), m["name"]
+        elif m["name"] not in (JOIN_METRIC, *JOINS_OWN):
+            assert JOINS not in wl, m["name"]
+
+
+def check_joins_own_entry(bench, files, name):
+    """One of the cell's own entries, in its place, over the accepted
+    ``harness`` reader; it moves the join's own metric."""
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(next(iter(JOINS_OWN)))
+    assert names[at:at + len(JOINS_OWN)] == list(JOINS_OWN)
+    assert names.index(list(LOSSY_OWN)[-1]) < at
+    unit, better, layer = JOINS_OWN[name]
+    e = entry(bench, name)
+    assert dict(e, workloads=e["workloads"][:1]) == {
+        "name": name, "unit": unit, "better": better, "source": "host_clock",
+        "layer": layer, "moves": JOIN_METRIC, "workloads": [JOINS]}
+    spec = files.load(f"{METRIC_DIR}{name}.json")
+    assert spec["name"] == name and spec["what"]
+    assert spec["reader"] == {"kind": "harness", "key": name}
+    assert readers.read(spec, silent_ctx()) is None
+    assert readers.read(spec, dict(silent_ctx(),
+                                   harness={name: 12.5})) == 12.5
+
+
 # ------------------------------------------------------------ the cases
 #: part -> the test module that runs it on the committed file
-PARTS = ("files", "spans", "wall", "genlock", "accepted", "live")
+PARTS = ("files", "spans", "wall", "genlock", "accepted", "live", "lossy",
+         "joins")
 
 
 def cases(part, bench=BENCH, files=COMMITTED):
@@ -476,6 +749,19 @@ def cases(part, bench=BENCH, files=COMMITTED):
         return [case("judged", check_live_judged),
                 *(case(f"twin[{n}]", check_live_twin, n) for n in LIVE_TWINS),
                 case("order", check_live_order)]
+    if part == "lossy":
+        return [case("configuration", check_lossy_configuration),
+                case("mix", check_lossy_mix), case("tail", check_lossy_tail),
+                case("cell", check_lossy_cell),
+                case("lists", check_lossy_lists),
+                *(case(f"own[{n}]", check_lossy_own_entry, n)
+                  for n in LOSSY_OWN)]
+    if part == "joins":
+        return [case("cell", check_joins_cell),
+                case("judged", check_joins_judged),
+                case("lists", check_joins_lists),
+                *(case(f"own[{n}]", check_joins_own_entry, n)
+                  for n in JOINS_OWN)]
     raise KeyError(part)
 
 

@@ -12,7 +12,7 @@ import pytest
 
 import file_checks
 from file_checks import BENCH, METRIC_DIR, load
-from test_lossy_deployment import CELL, OWN, TWINS, entries
+from file_checks import LOSSY as CELL, LOSSY_OWN as OWN, LOSSY_TWINS as TWINS
 
 from benchmark import readers
 
@@ -26,7 +26,7 @@ def test_the_tiers_own_marks_follow_the_accepted_entries():
     assert set(file_checks.ACCEPTED) | set(OWN) <= before
     assert {"delay_p95_ms.lossy"} | {f"{n}.lossy" for n in TWINS} <= before
     assert names.count(MARKED) == 1
-    assert entries()[MARKED] == {
+    assert file_checks.entry(BENCH, MARKED) == {
         "name": MARKED, "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "Pump",
         "moves": "delay_p60_ms", "workloads": [CELL]}

@@ -353,15 +353,16 @@ def test_a_configuration_without_last_mile_takes_todays_path(monkeypatch,
     src = types.SimpleNamespace(packets=pushed, pushed=12, idx=0)
     flows = [types.SimpleNamespace(src=src, packets=wire[:10] + wire[11:],
                                    stamps=[], first_seq=FIRST, ssrc=SSRC,
-                                   lm=None)]
+                                   lm=None, fast_start=False)]
 
     class Bulk:
         def counts(self):
             return (12 * 16 * 2 - 5, 0, 0)      # two sources, five short
 
     r.lg = types.SimpleNamespace(
-        flows=flows * 2, sources=[src, src], last_mile=None, bulk=Bulk(),
-        expected_deliveries=lambda: 12 * 17 * 2)
+        flows=flows * 2, joiners=[], sources=[src, src], last_mile=None,
+        bulk=Bulk(), expected_deliveries=lambda: 12 * 17 * 2,
+        stamped_read=lambda: 0)
     r.account({"egress_packets_total": 12 * 17 * 2 + 3,
                "egress_send_errors_total": 0}, {}, {})
     assert r.acct["bulk_owed"] == 12 * 2 * (17 - 1)

@@ -107,10 +107,11 @@ def test_what_was_accepted_leads_every_list(check):
 
 def test_no_test_module_judges_the_committed_file_itself():
     """An assertion on the loaded benchmark in a test's body judges the
-    committed file alone and is never run on the copy: the five accepted
+    committed file alone and is never run on the copy: the accepted
     modules hand it to ``file_checks`` and subscript it nowhere."""
     for module in ("test_benchmark", "test_genlock_cell", "test_span_metrics",
-                   "test_new_cell_is_data", "test_wall_cell"):
+                   "test_new_cell_is_data", "test_wall_cell",
+                   "test_lossy_deployment", "test_joins_cell"):
         with open(os.path.join(HERE, module + ".py")) as f:
             src = f.read()
         for subscript in ("[", ".get("):
@@ -146,6 +147,8 @@ FILE_TESTS = [
     "test_span_metrics.py::test_pr25s_entries_stand_as_accepted",
     "test_wall_cell.py::test_the_cell_and_its_entries_stand_as_accepted",
     "test_genlock_cell.py::test_the_cell_in_the_benchmark",
+    "test_lossy_deployment.py::test_the_lossy_cell_stands_as_accepted",
+    "test_joins_cell.py::test_the_joins_cell_stands_as_accepted",
     "test_new_cell_is_data.py"]
 
 
